@@ -18,7 +18,16 @@ each timed window is ONE dispatch of a lax.scan over ``--steps``
 iterations whose carry perturbs the weight by a data-dependent ~0 so no
 iteration hoists; median of ``--reps`` windows, spread reported.
 
+Before any timing, every row first COMPILES each Pallas kernel the routed
+op uses at that shape (forward, the VJP's dgrad/wgrad, the BN-stats
+epilogue, the fused wgrad+dsum) and compares it with XLA's conv and its
+``jax.grad`` within bf16 tolerance.  A kernel the compiler rejects is
+recorded with the compiler's message, its timing is skipped, and the
+script exits non-zero.  ``--steps 0`` stops after that check.
+
 Run:    python benchmark/conv_kernel.py               (TPU, bf16)
+        python benchmark/conv_kernel.py --steps 0     (TPU: compile and
+                                                       compare only)
         python benchmark/conv_kernel.py --interpret   (CPU correctness
                                                        pass, tiny shapes)
 Writes: benchmark/conv_kernel_results.json
@@ -144,6 +153,78 @@ def make_step(impl, pas, stride, interpret):
     return f
 
 
+BF16_TOL = 2e-2      # max |pallas - xla| over max |xla|
+
+
+def check_row(x, w, g, stride, interpret):
+    """Compile each Pallas kernel at this shape and compare with XLA.
+    Returns {kernel: {"ok", "rel_err"} | {"ok": False, "error"}} — an
+    exception here is the compiler's verdict on the kernel, so its message
+    is the recorded outcome (and fails the script), not a swallowed one."""
+    from paddle_tpu.ops.pallas_conv import (conv2d_1x1,
+                                            conv2d_1x1_grad_fused,
+                                            conv2d_1x1_with_bn_stats)
+    s2 = (stride, stride)
+
+    def rel(got, want):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        assert got.shape == want.shape, (got.shape, want.shape)
+        return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+    def loss(conv):
+        return lambda x_, w_: jnp.sum(
+            (conv(x_, w_) * g).astype(jnp.float32))
+
+    ref_out = _xla_conv(x, w, stride)
+    ref_dx, ref_dw = jax.grad(
+        loss(lambda a, b: _xla_conv(a, b, stride)), argnums=(0, 1))(x, w)
+    ref32 = ref_out.astype(jnp.float32)
+    g32 = g.astype(jnp.float32)
+
+    def k_fwd():
+        return {"out": rel(jax.jit(lambda a, b: conv2d_1x1(
+            a, b, s2, interpret=interpret))(x, w), ref_out)}
+
+    def k_vjp():
+        dx, dw = jax.jit(jax.grad(loss(lambda a, b: conv2d_1x1(
+            a, b, s2, interpret=interpret)), argnums=(0, 1)))(x, w)
+        return {"dx": rel(dx, ref_dx), "dw": rel(dw, ref_dw)}
+
+    def k_bn_stats():
+        out, cs, csq = jax.jit(lambda a, b: conv2d_1x1_with_bn_stats(
+            a, b, s2, interpret=interpret))(x, w)
+        return {"out": rel(out, ref_out),
+                "csum": rel(cs, jnp.sum(ref32, axis=(0, 2, 3))),
+                "csumsq": rel(csq, jnp.sum(ref32 * ref32, axis=(0, 2, 3)))}
+
+    def k_grad_fused():
+        dx, dw, dsum = jax.jit(lambda a, b, c: conv2d_1x1_grad_fused(
+            a, b, c, s2, interpret=interpret))(x, w, g)
+        return {"dx": rel(dx, ref_dx), "dw": rel(dw, ref_dw),
+                "dsum": rel(dsum, jnp.sum(g32, axis=(0, 2, 3)))}
+
+    out = {}
+    for name, fn in (("fwd", k_fwd), ("vjp_dgrad_wgrad", k_vjp),
+                     ("fwd_bn_stats", k_bn_stats),
+                     ("grad_fused_dsum", k_grad_fused)):
+        try:
+            errs = fn()
+        except Exception as e:            # recorded verbatim, fails the run
+            out[name] = {"ok": False,
+                         "error": f"{type(e).__name__}: {e}"[:1500]}
+            continue
+        out[name] = {"ok": max(errs.values()) < BF16_TOL,
+                     "rel_err": {k: round(v, 5) for k, v in errs.items()}}
+    return out
+
+
+# which compiled-and-compared kernels each timed pass depends on
+_PASS_NEEDS = {"fwd": ("fwd",), "dgrad": ("vjp_dgrad_wgrad",),
+               "wgrad": ("vjp_dgrad_wgrad",),
+               "wgrad_fused": ("grad_fused_dsum",)}
+
+
 def run_row(name, N, C, H, W, M, stride, steps, reps, dtype, interpret):
     OH, OW = (H - 1) // stride + 1, (W - 1) // stride + 1
     rng = np.random.RandomState(0)
@@ -153,8 +234,13 @@ def run_row(name, N, C, H, W, M, stride, steps, reps, dtype, interpret):
     P = N * OH * OW
     flops = 2.0 * P * C * M                       # per pass per step
     row = {"shape": name, "P": P, "C": C, "M": M, "stride": stride,
-           "steps": steps, "passes": {}}
+           "steps": steps, "check": check_row(x, w, g, stride, interpret),
+           "passes": {}}
+    print(json.dumps({"shape": name, "check": row["check"]}), flush=True)
     for pas in ("fwd", "dgrad", "wgrad", "wgrad_fused"):
+        if steps == 0 or not all(row["check"][k]["ok"]
+                                 for k in _PASS_NEEDS[pas]):
+            continue
         times = {}
         for impl in ("xla", "pallas"):
             step = make_step(impl, pas, stride, interpret)
@@ -203,8 +289,10 @@ def main():
     steps = 2 if args.interpret else args.steps
     reps = 1 if args.interpret else args.reps
     dtype = jnp.dtype(args.dtype)
-    results = {"device": str(jax.devices()[0]), "dtype": str(dtype),
-               "steps": steps, "rows": []}
+    dev = jax.devices()[0]
+    results = {"device": str(dev), "platform": dev.platform,
+               "device_kind": dev.device_kind, "jax": jax.__version__,
+               "dtype": str(dtype), "steps": steps, "rows": []}
     for spec in shapes:
         results["rows"].append(
             run_row(*spec, steps=steps, reps=reps, dtype=dtype,
@@ -213,6 +301,10 @@ def main():
         with open(OUT, "w") as f:
             json.dump(results, f, indent=1)
         print(f"wrote {OUT}")
+    bad = [(r["shape"], k) for r in results["rows"]
+           for k, v in r["check"].items() if not v["ok"]]
+    if bad:
+        sys.exit(f"conv_kernel: compile/compare failed for {bad}")
 
 
 if __name__ == "__main__":

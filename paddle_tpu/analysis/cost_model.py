@@ -21,11 +21,15 @@ benchmark/roofline_rnn.py, promoted here to a per-op pass:
   a ``backward`` pseudo-op every forward intermediate is pinned live until
   the backward — XLA holds activations for the VJP).
 
-The absolute numbers use nominal TPU constants (PEAK_FLOPS / HBM_GBPS /
-ICI_GBPS below) and a caller-supplied batch assumption for symbolic ``-1``
-dims; they are *ranking* quantities — two plans compared under the same
-constants — not predictions of wall-clock.  Symbolic dims that are not the
-batch dim also resolve to the batch assumption (documented caveat).
+The hardware constants come from ONE table keyed by ``device_kind``
+(:data:`DEVICE_PEAKS`, published peaks with their source): on a TPU the
+local device's row is used and an unknown kind is an error, never a
+default.  On a host with no TPU (the CPU test mesh) the :data:`NOMINAL`
+constants stand in — there the numbers are *ranking* quantities only, two
+plans compared under the same constants, and every row derived from them
+says ``"peaks": "nominal"``.  Symbolic ``-1`` dims resolve to a
+caller-supplied batch assumption (also the non-batch ones: documented
+caveat).
 """
 from __future__ import annotations
 
@@ -37,11 +41,50 @@ import numpy as np
 from .diagnostics import ValidationReport
 from .shard_prop import PropagationResult, spec_extent
 
-# nominal single-chip constants (TPU v4-class, bf16): only plan *ranking*
-# depends on them, so order-of-magnitude fidelity is enough
-PEAK_FLOPS = 275e12
-HBM_GBPS = 1.2e12
-ICI_GBPS = 4.5e10
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Per-chip hardware peaks (bf16 FLOP/s, HBM and ICI bytes/s)."""
+    name: str
+    flops: float
+    hbm_bytes_s: float
+    ici_bytes_s: float
+    source: str
+
+    def seconds(self, flops: float, hbm_bytes: float,
+                ici_bytes: float = 0.0) -> float:
+        return (flops / self.flops + hbm_bytes / self.hbm_bytes_s
+                + ici_bytes / self.ici_bytes_s)
+
+
+#: published peaks by ``jax.devices()[0].device_kind``
+DEVICE_PEAKS = {
+    "TPU v5 lite": Peaks(
+        "TPU v5 lite", flops=197e12, hbm_bytes_s=819e9, ici_bytes_s=200e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect"),
+}
+
+#: stand-in where no TPU is present: plan RANKING only, never a rate
+NOMINAL = Peaks("nominal", flops=275e12, hbm_bytes_s=1.2e12,
+                ici_bytes_s=4.5e10,
+                source="order-of-magnitude constants for ranking plans on "
+                       "a host with no TPU")
+
+
+def local_peaks() -> Peaks:
+    """The local TPU's row of :data:`DEVICE_PEAKS` (unknown kind: error);
+    :data:`NOMINAL` when the default backend is not a TPU."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return NOMINAL
+    try:
+        return DEVICE_PEAKS[dev.device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no published peaks for device_kind {dev.device_kind!r}: add "
+            f"a row with its source to analysis.cost_model.DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)})") from None
 
 
 def _numel(shape, assume: int) -> int:
@@ -80,16 +123,17 @@ class CostReport:
     peak_hbm_bytes_per_device: float = 0.0
     op_costs: List[OpCost] = dataclasses.field(default_factory=list)
     # per-op-CLASS calibrated proxy (measured/predicted ratios from the
-    # opprof profiler applied per op type); None = nominal constants only
+    # opprof profiler applied per op type); None = table constants only
     calibrated_step_time_s: Optional[float] = None
+    peaks: Peaks = NOMINAL
 
     @property
     def step_time_proxy_s(self) -> float:
         if self.calibrated_step_time_s is not None:
             return self.calibrated_step_time_s
-        return (self.flops_per_device / PEAK_FLOPS
-                + self.hbm_bytes_per_device / HBM_GBPS
-                + (self.collective_bytes + self.reshard_bytes) / ICI_GBPS)
+        return self.peaks.seconds(
+            self.flops_per_device, self.hbm_bytes_per_device,
+            self.collective_bytes + self.reshard_bytes)
 
     def to_dict(self) -> dict:
         return {
@@ -101,6 +145,7 @@ class CostReport:
             "reshard_bytes": self.reshard_bytes,
             "peak_hbm_bytes_per_device": self.peak_hbm_bytes_per_device,
             "step_time_proxy_s": self.step_time_proxy_s,
+            "peaks": self.peaks.name,
             "calibrated": self.calibrated_step_time_s is not None,
             "top_ops": [
                 {"op": t, "block": b, "index": i,
@@ -251,7 +296,7 @@ def estimate_cost(program, mesh_axes: Dict[str, int],
     dp_active = any(
         any(batch_axis in (e or ()) for e in sp)
         for sp in specs.values())
-    report = CostReport(mesh_axes=mesh_axes)
+    report = CostReport(mesh_axes=mesh_axes, peaks=local_peaks())
     fwd_flops = 0.0
     fwd_flops_per_dev = 0.0
     for op_idx, op in enumerate(gb.ops):
@@ -323,9 +368,9 @@ def estimate_cost(program, mesh_axes: Dict[str, int],
         t = 0.0
         for c in report.op_costs:
             ratio = float(op_class_ratios.get(c.loc[2], 1.0))
-            t += ratio * (c.flops / PEAK_FLOPS + c.bytes / HBM_GBPS) \
-                + c.collective_bytes / ICI_GBPS
-        t += report.reshard_bytes / ICI_GBPS
+            t += ratio * report.peaks.seconds(c.flops, c.bytes) \
+                + c.collective_bytes / report.peaks.ici_bytes_s
+        t += report.reshard_bytes / report.peaks.ici_bytes_s
         report.calibrated_step_time_s = t
 
     report.peak_hbm_bytes_per_device = _peak_hbm(

@@ -1,119 +1,50 @@
-"""JAX cross-version compatibility shims.
+"""The JAX surface this framework leans on, in one module.
 
-The public JAX surface this framework leans on moved between the 0.4.x
-line and newer releases:
-
-* ``jax.shard_map`` (new, with ``axis_names=``/``check_vma=`` partial-manual
-  kwargs) vs ``jax.experimental.shard_map.shard_map`` (old, with
-  ``auto=``/``check_rep=`` spelled from the opposite direction);
-* ``jax.lax.axis_size`` (new) vs the ``lax.psum(1, axis)`` constant-folding
-  idiom (old);
-* ``jax.lax.pvary`` (new varying-manual-axes type system) with no old
-  counterpart — on old JAX replication is inferred, so it is the identity;
-* ``jax.sharding.AxisType`` + ``get_abstract_mesh`` (new) vs the axis-env
-  trace state (old) for detecting a surrounding shard_map manual region.
-
-Everything that needs one of these APIs imports it from here, so exactly
-one module knows which JAX it is running on.  Resolution happens at call
-time (not import time): the shims stay importable even if a future JAX
-moves the surface again, failing only at the call site with a clear error.
+Written against the installed jax (0.9.0): ``jax.shard_map`` with
+``axis_names=``/``check_vma=`` partial-manual kwargs, the abstract mesh's
+per-axis ``AxisType`` for detecting a surrounding shard_map manual region,
+and the ``Compiled.cost_analysis()`` / ``memory_analysis()`` facts.  What a
+jax release no longer offers is repaired at the call site, not shimmed
+here.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-from jax import lax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "axis_size", "pvary", "manual_axes",
+__all__ = ["shard_map", "manual_axes",
            "executable_cost_analysis", "executable_memory_analysis"]
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` surface on every supported JAX.
-
-    ``axis_names``: the mesh axes the body is manual over (new-API
-    spelling); every other mesh axis stays auto/GSPMD-managed.  On old JAX
-    this maps to ``auto = mesh.axis_names - axis_names``.  ``check_vma``
-    maps to old ``check_rep`` (same role: verify replication/varying
-    claims; both sides accept False to opt out).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as esm  # old JAX
-    # No ``auto=``: old partial-auto lowers lax.axis_index to a PartitionId
-    # instruction the SPMD partitioner rejects ("meaning is ambiguous").
-    # Going full-manual instead is always numerically correct — axes the
-    # body never names are simply replicated through it (in_specs leaving
-    # them unmentioned), at the cost of redundant compute over those axes
-    # on multi-device meshes.  Only the old-JAX fallback pays this.
+    """``jax.shard_map`` with the mesh positional.  ``axis_names``: the
+    mesh axes the body is manual over (every other mesh axis stays
+    auto/GSPMD-managed); ``None`` for either keyword keeps jax's default."""
     kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = frozenset(axis_names)
     if check_vma is not None:
-        kw["check_rep"] = bool(check_vma)
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
-def axis_size(axis_name) -> int:
-    """Size of a bound mesh axis inside shard_map/pmap.
-
-    Old JAX: ``lax.psum`` of a non-tracer constant folds to the axis size
-    without emitting a collective — the pre-``lax.axis_size`` idiom.
-    Raises ``NameError`` for an unbound axis name on both paths.
-    """
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def pvary(x, axis_names):
-    """Mark ``x`` device-varying over ``axis_names`` (new shard_map type
-    system).  Old JAX infers replication and has no varying-manual-axes
-    types, so there the identity is exactly right — autodiff inside a
-    shard_map body never inserts the psum-of-replicated-cotangents the
-    new system needs ``pvary`` to elide."""
-    fn = getattr(lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axis_names)
-    return x
-
-
-def manual_axes() -> Optional[frozenset]:
+def manual_axes() -> frozenset:
     """Mesh axes currently bound manual (i.e. we are tracing inside a
-    shard_map body): frozenset of names, empty when outside.  Returns
-    ``None`` when no known JAX API can answer — callers should treat that
-    as "unknown" and degrade loudly, not assume "outside"."""
-    try:  # new JAX: abstract mesh carries per-axis Manual/Auto types
-        from jax.sharding import AxisType
-        am = jax.sharding.get_abstract_mesh()
-        return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
-                         if t == AxisType.Manual)
-    except (ImportError, AttributeError):
-        pass
-    try:  # old JAX: shard_map binds its axes in the trace-state axis env
-        from jax._src import core as _core
-        env = _core.get_axis_env()
-        return frozenset(env.axis_sizes)
-    except (ImportError, AttributeError):
-        pass
-    return None
+    shard_map body): frozenset of names, empty when outside."""
+    am = jax.sharding.get_abstract_mesh()
+    return frozenset(n for n, t in zip(am.axis_names, am.axis_types)
+                     if t == AxisType.Manual)
 
 
 def executable_cost_analysis(compiled) -> Optional[dict]:
     """XLA cost analysis of a compiled executable, normalized to one flat
     ``{"flops": ..., "bytes_accessed": ..., ...}`` dict.
 
-    The surface drifted across jax releases: ``Compiled.cost_analysis()``
-    returns a list with one dict per partition on the 0.4.x line and a
-    bare dict on newer jax; some backends (and serialized-executable
-    reloads) raise or return nothing.  ``None`` means "unavailable" —
-    callers fall back to the static cost model, never crash.
+    Some backends raise or return nothing; ``None`` means "unavailable"
+    — callers fall back to the static cost model, never crash.
     """
     fn = getattr(compiled, "cost_analysis", None)
     if fn is None:
@@ -122,8 +53,6 @@ def executable_cost_analysis(compiled) -> Optional[dict]:
         ca = fn()
     except Exception:   # backend without the analysis API
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict) or not ca:
         return None
     out = {}
@@ -136,9 +65,8 @@ def executable_cost_analysis(compiled) -> Optional[dict]:
 
 
 def executable_memory_analysis(compiled) -> Optional[dict]:
-    """``Compiled.memory_analysis()`` normalized to plain ints (the
-    return type is an opaque ``CompiledMemoryStats`` on this jax line, a
-    dict-like on others).  ``None`` when unavailable."""
+    """``Compiled.memory_analysis()`` (an opaque ``CompiledMemoryStats``)
+    normalized to plain ints.  ``None`` when unavailable."""
     fn = getattr(compiled, "memory_analysis", None)
     if fn is None:
         return None
@@ -152,7 +80,7 @@ def executable_memory_analysis(compiled) -> Optional[dict]:
     for k in ("argument_size_in_bytes", "output_size_in_bytes",
               "temp_size_in_bytes", "generated_code_size_in_bytes",
               "alias_size_in_bytes"):
-        v = getattr(ma, k, None) if not isinstance(ma, dict) else ma.get(k)
+        v = getattr(ma, k, None)
         if isinstance(v, (int, float)):
             out[k] = int(v)
     return out or None

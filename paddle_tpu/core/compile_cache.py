@@ -1,5 +1,5 @@
-"""Compilation-cache subsystem: fingerprints, LRU entry cache, persistent
-on-disk executables, and compile-time telemetry.
+"""Compilation-cache subsystem: fingerprints, LRU entry cache, the one
+persistent-cache directory, and compile-time telemetry.
 
 paddle_tpu's one-big-jit design (core/executor.py) pays trace->lower->compile
 for every (program, feed-signature) variant.  This module makes that cost
@@ -11,24 +11,23 @@ for every (program, feed-signature) variant.  This module makes that cost
    (names/shapes/dtypes), fetch names, state keys, executor configuration
    (amp, compute_dtype, compiler_options, conv1x1_pallas, check_nan_inf),
    mesh + sharding specs (ShardedExecutor), x64 mode, and the jax +
-   paddle_tpu versions.  Unlike the previous ``id(program)``/``version``
-   keys, fingerprints survive process restarts and deduplicate
-   content-identical programs (``prune().clone(for_test=True)`` slices built
-   per evaluation call now hit the same entry).
+   paddle_tpu versions.  Content-identical programs
+   (``prune().clone(for_test=True)`` slices built per evaluation call)
+   share one in-process :class:`ExecCache` entry.
 
-2. **Persistent cache** — when the ``cache_dir`` flag (env
-   ``PADDLE_TPU_CACHE_DIR``) is set, every compiled step executable is
-   serialized (``jax.experimental.serialize_executable``) to
-   ``<dir>/ptxc-<fingerprint>.pkl`` together with its StableHLO text and
-   compile-phase timings; a later process with the same fingerprint loads
-   the executable directly, skipping trace, lower AND compile.  JAX's own
-   persistent compilation cache (``jax_compilation_cache_dir``) is wired to
-   the same directory as a second layer that still helps when executable
-   deserialization is unavailable (it caches the XLA compile step keyed by
-   HLO).
+2. **Persistent cache** — JAX's own persistent compilation cache, and
+   nothing beside it.  :func:`cache_dir` resolves the ONE directory:
+   ``JAX_COMPILATION_CACHE_DIR`` when the caller set it (JAX read it at
+   import; this module never re-points it), else the fixed
+   ``<checkout>/.jax_cache``.  A fresh process re-traces and re-lowers,
+   and the XLA compile is a disk read; hits are counted from JAX's
+   ``/jax/compilation_cache/cache_hits`` monitoring event
+   (``jax_cache_hits`` in :class:`CompileStats`).  JAX's default size/time
+   thresholds apply, so sub-second compiles never land on disk.
 
 3. **Telemetry** — per-fingerprint trace/lower/compile wall times, cache
-   hit/miss/eviction counters and a retrace detector
+   hit/miss/eviction counters, trace-time kernel-routing counters
+   (``route/<op>:<path>``) and a retrace detector
    (:func:`retrace_guard` / :meth:`CompileStats.assert_no_retrace`), all
    surfaced through ``paddle_tpu.profiler.compile_stats()``.
 
@@ -44,23 +43,17 @@ import hashlib
 import json
 import logging
 import os
-import pickle
-import tempfile
 import threading
 import time
 import weakref
 from typing import Dict, List, Optional
 
 import jax
+from jax import monitoring as _jax_monitoring
 
 logger = logging.getLogger("paddle_tpu")
 
-DISK_FORMAT = 1                  # bump to invalidate every on-disk entry
-_DISK_PREFIX = "ptxc-"
-
 _env_key = None
-_jax_cc_dir_wired: Optional[str] = None
-_serialize_warned = False
 
 
 def framework_version() -> str:
@@ -120,8 +113,14 @@ class CompileStats:
 
     Counters:
       hits/misses/evictions       — in-process entry cache (ExecCache)
-      disk_hits/disk_misses       — persistent executable cache lookups
-      disk_stores                 — executables serialized to disk
+      jax_cache_hits              — XLA compiles served from JAX's
+                                    persistent compilation cache
+      lazy_jit_fallbacks          — mesh-step calls re-routed through a
+                                    lazily-specialized jit (CachedStep)
+      route/<op>:<path>           — trace-time kernel routing: which
+                                    implementation an op with several
+                                    lowered (e.g. flash_attention:pallas
+                                    vs :reference), one bump per trace
       traces                      — jit traces of step functions (a trace
                                     runs the Python interpreter over the
                                     whole Program; the retrace detector
@@ -190,10 +189,8 @@ class CompileStats:
             return dict(self.counters)
 
     def total_compile_seconds(self) -> float:
-        """Wall time spent in trace/lower/compile phases only — a warm
-        start's deserialize_s is deliberately excluded (it is disk-load
-        time, not compilation; bench.py reports this as the cold-start
-        cost the persistent cache removes)."""
+        """Wall time spent in trace/lower/compile phases (with a warm
+        persistent cache, compile_s is the disk read)."""
         with self._lock:
             return sum(e["times"].get(k, 0.0)
                        for e in self.entries.values()
@@ -346,95 +343,42 @@ class ExecCache:
 
 
 # ---------------------------------------------------------------------------
-# Persistent on-disk layer
+# The one persistent-cache directory (JAX's own compilation cache)
 # ---------------------------------------------------------------------------
+#: fixed in-checkout default (git-ignored); the path is part of what makes a
+#: cache reusable across processes, so never a temp name, a pid or a time
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_hit_listener_on = False
+
+
+def _on_jax_event(event: str, **_):
+    if event == _CACHE_HIT_EVENT:
+        _stats.bump("jax_cache_hits")
+
+
 def cache_dir() -> str:
-    """Active persistent-cache directory ('' = disabled).  Reads the
-    ``cache_dir`` flag, which the env var PADDLE_TPU_CACHE_DIR seeds."""
-    from .. import flags
-    try:
-        return str(flags.get_flag("cache_dir") or "")
-    except KeyError:
-        return ""
+    """THE persistent-cache directory, resolved in one place.
 
-
-def wire_jax_compilation_cache(path: str):
-    """Point JAX's persistent compilation cache at ``path`` (idempotent).
-    This caches the XLA compile step keyed by lowered HLO — the fallback
-    layer when whole-executable serialization is unavailable for a
-    backend."""
-    global _jax_cc_dir_wired
-    if not path or _jax_cc_dir_wired == path:
-        return
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass
-        _jax_cc_dir_wired = path
-    except Exception as e:          # very old jax: no persistent cache
-        logger.warning("persistent compilation cache unavailable (%s: %s)",
-                       type(e).__name__, e)
-        _jax_cc_dir_wired = path    # don't retry every entry
-
-
-def _disk_path(dirpath: str, fp: str) -> str:
-    return os.path.join(dirpath, f"{_DISK_PREFIX}{fp}.pkl")
-
-
-def disk_load(fp: str) -> Optional[dict]:
-    """Load a persisted entry payload for ``fp``, or None.  Any failure
-    (missing, corrupt, foreign format/version) is a miss — the fingerprint
-    already folds in jax/paddle_tpu versions and backend topology, so a
-    stale file can only be hit by a hash collision or a truncated write."""
-    d = cache_dir()
-    if not d:
-        return None
-    try:
-        with open(_disk_path(d, fp), "rb") as f:
-            payload = pickle.load(f)
-        if payload.get("format") != DISK_FORMAT or \
-                payload.get("fingerprint") != fp:
-            _stats.bump("disk_misses")
-            return None
-        _stats.bump("disk_hits")
-        return payload
-    except FileNotFoundError:
-        _stats.bump("disk_misses")
-        return None
-    except Exception as e:
-        logger.warning("compile cache: unreadable entry for %s… (%s: %s)",
-                       fp[:12], type(e).__name__, e)
-        _stats.bump("disk_misses")
-        return None
-
-
-def disk_store(fp: str, payload: dict):
-    """Atomically persist an entry payload (tmp file + rename, so a
-    concurrent reader never sees a truncated pickle)."""
-    d = cache_dir()
-    if not d:
-        return
-    try:
-        os.makedirs(d, exist_ok=True)
-        payload = dict(payload, format=DISK_FORMAT, fingerprint=fp)
-        fd, tmp = tempfile.mkstemp(dir=d, prefix=_DISK_PREFIX, suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                pickle.dump(payload, f, protocol=pickle.HIGHEST_PROTOCOL)
-            os.replace(tmp, _disk_path(d, fp))
-        except BaseException:
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            raise
-        _stats.bump("disk_stores")
-    except Exception as e:
-        logger.warning("compile cache: could not persist %s… (%s: %s)",
-                       fp[:12], type(e).__name__, e)
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX read it at import — that
+    directory is used as-is and this function makes NO
+    ``jax_compilation_cache_dir`` update.  Unset: JAX's cache is pointed
+    (once) at the fixed :data:`REPO_CACHE_DIR`.  The autotuner's winner
+    store lives under ``<dir>/tuning``.  Also hooks JAX's cache-hit
+    monitoring event into :class:`CompileStats` (``jax_cache_hits``)."""
+    global _hit_listener_on
+    if not _hit_listener_on:
+        _hit_listener_on = True
+        _jax_monitoring.register_event_listener(_on_jax_event)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.config.jax_compilation_cache_dir != REPO_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR
 
 
 # ---------------------------------------------------------------------------
@@ -450,17 +394,19 @@ class CachedStep:
 
     * per-phase wall-time telemetry (CompileStats),
     * ``compiler_options`` support (plain jit has no per-call hook),
-    * executable serialization to the persistent cache, and symmetric
-      deserialization that skips all three phases on a warm start,
     * an AOT ``prepare()`` entry point taking abstract avals
       (``jax.ShapeDtypeStruct``) for ``Executor.compile`` /
       ``Trainer.train(warmup=...)``.
 
-    If the compiled executable rejects a call's arguments (argument-check
-    errors happen before donation), the call retries once through an
-    equivalent lazily-compiled ``jax.jit`` — e.g. inputs committed to a
-    non-default device, which jit re-specializes on but an AOT executable
-    cannot.
+    A single-device step whose executable rejects a call's arguments
+    raises: what ran is always the variant the telemetry recorded.  Mesh
+    steps (``in_shardings`` given — ShardedExecutor) keep ONE named
+    retry: out_shardings are unpinned there, so GSPMD may hand a state var
+    back under another sharding than the executable took it in (a
+    replicated bias returned tp-sharded), and the next call's argument
+    check rejects it before donation.  Those calls go through the
+    equivalent lazily-specialized ``jax.jit``, counted as
+    ``lazy_jit_fallbacks`` in :class:`CompileStats` and logged once.
     """
 
     def __init__(self, fn, fingerprint: Optional[str],
@@ -481,6 +427,7 @@ class CachedStep:
         self._opts = dict(compiler_options or {})
         self._label = label
         self._compiled = None
+        self._mesh_step = in_shardings is not None
         self._fallback_recorded = False
         self._times: Dict[str, float] = {}
 
@@ -497,63 +444,42 @@ class CachedStep:
         """Ensure the executable exists; args may be abstract
         (ShapeDtypeStruct) or concrete — only shapes/dtypes are read."""
         if self._compiled is None:
-            self._compiled = self._load_or_compile(feeds, state, step)
+            self._compiled = self._compile(feeds, state, step)
         return self
 
-    def stablehlo(self) -> Optional[str]:
-        """StableHLO text of the lowered step, read back from the
-        persistent entry on demand (never pinned in memory — resnet-scale
-        module text runs to MBs per cache entry)."""
-        payload = disk_load(self._fp) if self._fp else None
-        return payload.get("stablehlo") if payload else None
-
     def __call__(self, feeds, state, step):
-        if self._compiled is None:
-            self._compiled = self._load_or_compile(feeds, state, step)
+        self.prepare(feeds, state, step)
         try:
             return self._compiled(feeds, state, step)
-        except (ValueError, TypeError):
-            # argument-check rejection (pre-donation): inputs jit would
-            # re-specialize on (foreign device commitment / layout).  Route
-            # THIS call through the equivalent lazy jit, keeping the AOT
-            # executable for calls that do match.  Guard: if any state
-            # buffer was already donated, execution STARTED — the error is
-            # a real execution failure and a re-run on deleted buffers
-            # would mask it (same hazard _AutoLayoutStep documents).
-            if any(v.is_deleted() for v in state.values()
-                   if hasattr(v, "is_deleted")):
+        except ValueError:
+            # the argument check runs before donation; a deleted state
+            # buffer means execution STARTED and the error is real
+            if not self._mesh_step or any(
+                    v.is_deleted() for v in state.values()
+                    if hasattr(v, "is_deleted")):
                 raise
-            # The jit trace is an honest retrace of this fingerprint —
-            # record it (once; jit caches its specializations) so
-            # retrace_guard and the telemetry don't under-report.
+            _stats.bump("lazy_jit_fallbacks")
             if not self._fallback_recorded:
                 self._fallback_recorded = True
                 logger.warning(
-                    "compile cache: AOT executable rejected call args for "
-                    "%s…; falling back to lazy jit for mismatching calls",
+                    "compile cache: mesh step %s… got arguments under "
+                    "other shardings than it was compiled for; falling "
+                    "back to lazy jit for mismatching calls",
                     (self._fp or "?")[:12])
+                # the jit trace is an honest retrace of this fingerprint
                 _stats.record_trace(self._fp)
             return self._jit(feeds, state, step)
 
     # -- internals -------------------------------------------------------
-    def _load_or_compile(self, feeds, state, step):
-        d = cache_dir()
-        if d:
-            wire_jax_compilation_cache(d)
-            loaded = self._try_deserialize()
-            if loaded is not None:
-                return loaded
+    def _compile(self, feeds, state, step):
+        cache_dir()                  # JAX's persistent cache, placed once
         t0 = time.perf_counter()
-        try:
-            traced = self._jit.trace(feeds, state, step)
-            t1 = time.perf_counter()
-            lowered = traced.lower()
-        except AttributeError:       # older jax: no jit.trace — fuse phases
-            t1 = t0
-            lowered = self._jit.lower(feeds, state, step)
+        traced = self._jit.trace(feeds, state, step)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
         t2 = time.perf_counter()
-        # the trace happened inside trace()/lower(): record it now (the
-        # retrace detector fires here if this fingerprint already traced)
+        # the trace happened inside trace(): record it now (the retrace
+        # detector fires here if this fingerprint already traced)
         _stats.record_trace(self._fp)
         compiled = lowered.compile(
             compiler_options=self._opts if self._opts else None)
@@ -563,56 +489,7 @@ class CachedStep:
         if self._fp:
             _stats.record_times(self._fp, source="compile",
                                 label=self._label, **self._times)
-        if d:
-            self._serialize(lowered, compiled)
         return compiled
-
-    def _try_deserialize(self):
-        payload = disk_load(self._fp) if self._fp else None
-        if payload is None or "executable" not in payload:
-            return None
-        try:
-            from jax.experimental.serialize_executable import \
-                deserialize_and_load
-            t0 = time.perf_counter()
-            compiled = deserialize_and_load(
-                payload["executable"], payload["in_tree"],
-                payload["out_tree"])
-            dt = time.perf_counter() - t0
-            self._times = {"deserialize_s": dt}
-            _stats.record_times(self._fp, source="disk", label=self._label,
-                                deserialize_s=dt)
-            return compiled
-        except Exception as e:
-            logger.warning(
-                "compile cache: executable deserialization failed for %s… "
-                "(%s: %s); recompiling (jax's HLO-keyed persistent cache "
-                "still shortcuts the XLA compile)",
-                self._fp[:12], type(e).__name__, e)
-            return None
-
-    def _serialize(self, lowered, compiled):
-        global _serialize_warned
-        try:
-            from jax.experimental.serialize_executable import serialize
-            payload_bytes, in_tree, out_tree = serialize(compiled)
-            hlo = None
-            try:
-                hlo = lowered.as_text()
-            except Exception:
-                pass
-            disk_store(self._fp, {
-                "executable": payload_bytes, "in_tree": in_tree,
-                "out_tree": out_tree, "stablehlo": hlo,
-                "times": dict(self._times), "label": self._label,
-            })
-        except Exception as e:
-            if not _serialize_warned:
-                _serialize_warned = True
-                logger.warning(
-                    "compile cache: executable serialization unavailable "
-                    "(%s: %s); warm starts will rely on jax's HLO-keyed "
-                    "persistent cache only", type(e).__name__, e)
 
 
 class CompiledProgram:
@@ -643,9 +520,6 @@ class CompiledProgram:
     @property
     def compile_times(self) -> Dict[str, float]:
         return self._step.times
-
-    def stablehlo(self) -> Optional[str]:
-        return self._step.stablehlo()
 
     def run(self, feed=None, scope=None, return_numpy=True):
         if self.num_steps is not None:

@@ -74,13 +74,6 @@ define_flag("seq_bucket_multiple", 8,
 define_flag("init_model_path", "", "checkpoint dir to resume from "
             "(Flags.cpp:81)")
 define_flag("save_dir", "", "parameter save root (v1 --save_dir)")
-define_flag("cache_dir", "",
-            "persistent compilation-cache directory (PADDLE_TPU_CACHE_DIR); "
-            "empty = off.  Wires JAX's persistent compilation cache and "
-            "additionally stores serialized step executables + StableHLO "
-            "keyed by program fingerprint, so a fresh process with the same "
-            "program/config skips trace, lower AND compile "
-            "(core/compile_cache.py; see README 'Compilation cache')")
 define_flag("validate", False,
             "run the static program verifier (paddle_tpu.analysis) before "
             "every new step variant is traced — and before its compile-"

@@ -9,8 +9,8 @@ Combines three information sources into one step-time (or request-time)
   staging time with real timestamps, so overlap with device compute is
   computed, not guessed);
 * **compiled-executable facts** — ``cost_analysis()`` /
-  ``memory_analysis()`` where this jax exposes them (guarded through
-  :mod:`paddle_tpu.compat`: the surface moved across 0.4.x releases);
+  ``memory_analysis()`` where the backend exposes them (normalized in
+  :mod:`paddle_tpu.compat`);
 * the **PR 7 static cost model** (``analysis.cost_model``) as the
   fallback — and as the *prediction* side of the calibration table:
   every doctored run with a program at hand records
@@ -164,8 +164,10 @@ _HINTS = {
                 "also absorbs device compute finishing under the "
                 "materialization; trim fetch_list, fetch less often, or "
                 "pass return_numpy=False and materialize lazily",
-    "compile_ms": "compile {pct}%: set PADDLE_TPU_CACHE_DIR for warm "
-                  "starts, or AOT-compile with Executor.compile() / "
+    "compile_ms": "compile {pct}%: keep the persistent compile cache "
+                  "(JAX_COMPILATION_CACHE_DIR, default <checkout>/"
+                  ".jax_cache) across runs for warm starts, or "
+                  "AOT-compile with Executor.compile() / "
                   "Trainer.train(warmup=True)",
     "compute_ms": "compute-bound {pct}%: the chip is the bottleneck — "
                   "tune device knobs (`python -m paddle_tpu tune "
@@ -448,6 +450,9 @@ def calibration_row(program, measured_step_ms: float,
         "measured_ms": round(float(measured_step_ms), 6),
         "ratio": round(float(measured_step_ms) / predicted_ms, 4)
         if predicted_ms > 0 else None,
+        # which row of cost_model.DEVICE_PEAKS priced the prediction
+        # ("nominal": no TPU here — a ranking constant, not a rate)
+        "peaks": report.peaks.name,
         "model": "static" if facts is None else "static+cost_analysis",
     }
     if facts:

@@ -434,8 +434,7 @@ def profile_program(program, *, executor=None, feed=None, state=None,
 # Modeled join
 # ---------------------------------------------------------------------------
 def _join_modeled(program, rows, mesh_axes, assume_batch):
-    from ..analysis.cost_model import (HBM_GBPS, ICI_GBPS, PEAK_FLOPS,
-                                       estimate_cost)
+    from ..analysis.cost_model import estimate_cost
     try:
         cost = estimate_cost(program, mesh_axes or {},
                              assume_batch=assume_batch)
@@ -450,10 +449,10 @@ def _join_modeled(program, rows, mesh_axes, assume_batch):
         c = by_idx.get(row["index"])
         if c is None:
             continue
-        compute_s = c.flops / PEAK_FLOPS
-        hbm_s = c.bytes / HBM_GBPS
-        pred_ms = (compute_s + hbm_s
-                   + c.collective_bytes / ICI_GBPS) * 1e3
+        compute_s = c.flops / cost.peaks.flops
+        hbm_s = c.bytes / cost.peaks.hbm_bytes_s
+        pred_ms = cost.peaks.seconds(c.flops, c.bytes,
+                                     c.collective_bytes) * 1e3
         row["modeled"] = {
             "flops": c.flops, "hbm_bytes": c.bytes,
             "predicted_ms": round(pred_ms, 9),

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core import compile_cache
 from ..core.registry import register_op
 
 
@@ -71,12 +72,16 @@ def _conv2d(ctx, ins, attrs):
         # single-device only: GSPMD treats pallas_call as opaque, so under
         # a >1-device mesh the routing would silently replicate the conv
         single = ctx.mesh is None or getattr(ctx.mesh, "size", 1) == 1
-        if (single and pallas_conv._HAVE_PALLAS
-                and (interpret or jax.default_backend() == "tpu")
+        if (single and (interpret or jax.default_backend() == "tpu")
                 and pallas_conv.conv1x1_eligible(
                     x.shape, w.shape, strides, pads, dil, groups)):
+            compile_cache.stats().bump(
+                "route/conv2d_1x1:" + ("interpret" if interpret
+                                       else "pallas"))
             return {"Output": pallas_conv.conv2d_1x1(
                 x, w, strides, interpret=interpret)}
+        # asked for, not taken (ineligible shape, mesh, or backend)
+        compile_cache.stats().bump("route/conv2d_1x1:xla")
     if (strides == (2, 2) and dil == (1, 1) and groups == 1
             and x.shape[1] <= 4 and x.ndim == 4
             and (x.shape[2] + 2 * pads[0]) % 2 == 0

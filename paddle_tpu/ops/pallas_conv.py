@@ -47,17 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-    # renamed TPUCompilerParams -> CompilerParams across jax versions
-    # (this container's jax 0.4.37 has only the old name); resolve at
-    # import so the drift fails loudly here, not at first on-TPU trace
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["pallas_matmul", "conv2d_1x1", "conv2d_1x1_with_bn_stats",
            "conv2d_1x1_grad_fused", "conv1x1_eligible"]
@@ -118,8 +109,8 @@ def _mm_kernel(a_ref, b_ref, *refs, nk, ta, tb, out_stats, a_colsum):
         out = acc_ref[...]
         o_ref[...] = out.astype(o_ref.dtype)
         if out_stats:
-            sum_ref[...] = jnp.sum(out, axis=0, keepdims=True)
-            sq_ref[...] = jnp.sum(out * out, axis=0, keepdims=True)
+            sum_ref[0] = jnp.sum(out, axis=0, keepdims=True)
+            sq_ref[0] = jnp.sum(out * out, axis=0, keepdims=True)
         if a_colsum:
             @pl.when(j == 0)
             def _cs_write():
@@ -158,9 +149,13 @@ def _mm(a, b, ta, tb, block_m, block_n, block_k, interpret,
     out_specs = [pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))]
     if out_stats:
         # per-M-block partials of the per-column output sums; the caller
-        # finishes the tiny [nm, N] reduction (BN statistics)
-        out_shape += [jax.ShapeDtypeStruct((nm, N), jnp.float32)] * 2
-        out_specs += [pl.BlockSpec((1, bn), lambda i, j, k: (i, j))] * 2
+        # finishes the tiny [nm, 1, N] reduction (BN statistics).  The
+        # unit middle dim keeps the block's last two dims (1, bn) legal
+        # for Mosaic: a (1, bn) block over an (nm, N) array is rejected
+        # (second-to-last must be a multiple of 8 or the whole dim).
+        out_shape += [jax.ShapeDtypeStruct((nm, 1, N), jnp.float32)] * 2
+        out_specs += [pl.BlockSpec((1, 1, bn),
+                                   lambda i, j, k: (i, 0, j))] * 2
     if a_colsum:
         assert ta, "a_colsum epilogue is the wgrad (gout^T) path"
         out_shape.append(jax.ShapeDtypeStruct((1, M), jnp.float32))
@@ -177,7 +172,7 @@ def _mm(a, b, ta, tb, block_m, block_n, block_k, interpret,
         # would copy its uninitialized VMEM output block over the result.
         # Keep j sequential whenever the epilogue is on.
         nsem = "arbitrary" if a_colsum else "parallel"
-        kwargs["compiler_params"] = _CompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", nsem, "arbitrary"))
     res = pl.pallas_call(
         functools.partial(_mm_kernel, nk=nk, ta=ta, tb=tb,
@@ -298,7 +293,7 @@ def conv2d_1x1_with_bn_stats(x, w, strides=(1, 1), block_m=512,
     om, psum, psq = _mm(xm, wm, False, True, block_m, block_n, block_k,
                         interpret, out_stats=True)
     return (_from_pixel_major(om, dims, M),
-            jnp.sum(psum, axis=0), jnp.sum(psq, axis=0))
+            jnp.sum(psum, axis=(0, 1)), jnp.sum(psq, axis=(0, 1)))
 
 
 def conv2d_1x1_grad_fused(x, w, gout, strides=(1, 1), block_m=512,

@@ -23,17 +23,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-    # renamed TPUCompilerParams -> CompilerParams across jax versions;
-    # interpret-mode tests never touch it, so resolve at import to fail
-    # loudly here rather than at first on-TPU trace
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..compat import manual_axes
+from ..core import compile_cache
 
 NEG_INF = -1e30
 
@@ -42,9 +36,7 @@ def _sds(x, shape, dtype):
     """ShapeDtypeStruct inheriting ``x``'s varying-manual-axes type, so the
     kernels compose with the new shard_map's vma checker (ring attention
     calls them per device hop)."""
-    aval = jax.typeof(x) if hasattr(jax, "typeof") else \
-        jax.core.get_aval(x)
-    vma = getattr(aval, "vma", None)
+    vma = getattr(jax.typeof(x), "vma", None)
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -114,7 +106,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     grid = (BH, Tq // block_q, nk)
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_flash_kernel, block_k=block_k, num_k_blocks=nk,
@@ -261,7 +253,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         delta = delta - g_lse.astype(jnp.float32).reshape(delta.shape)
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     dq = pl.pallas_call(
@@ -396,6 +388,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     use_pallas=None auto-selects the Pallas kernel on TPU only; every other
     backend gets the exact jnp reference.  interpret=True (explicit, as the
     CPU tests do) runs the kernel through the Pallas interpreter instead.
+    Which of the three ran is counted at trace time as
+    ``route/flash_attention:{pallas,interpret,reference}`` in
+    ``profiler.compile_stats()``.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -415,20 +410,22 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
             f"flash_attention: q feature dim {q3.shape[-1]} != k feature "
             f"dim {k3.shape[-1]}")
     if use_pallas is None:
-        use_pallas = _HAVE_PALLAS and jax.default_backend() == "tpu"
+        use_pallas = jax.default_backend() == "tpu"
     interpret = bool(interpret)
     Tq, Tk = q3.shape[1], k3.shape[1]
-    if use_pallas or interpret:
-        bq = min(block_q, Tq)
-        bk = min(block_k, Tk)
-        if Tq % bq or Tk % bk or (causal and Tq != Tk):
-            # ragged tail (kernel needs block-divisible lengths) or causal
-            # cross-attention (kernel's diagonal offset assumes Tq==Tk):
-            # run the exact jnp reference
-            out = _reference_attention(q3, k3, v3, causal, sm_scale)
-        else:
-            out = _flash(q3, k3, v3, causal, sm_scale, bq, bk, interpret)
+    bq = min(block_q, Tq)
+    bk = min(block_k, Tk)
+    # ragged tail (kernel needs block-divisible lengths) or causal
+    # cross-attention (kernel's diagonal offset assumes Tq==Tk) run the
+    # exact jnp reference, as does every non-TPU backend
+    if (use_pallas or interpret) and not (
+            Tq % bq or Tk % bk or (causal and Tq != Tk)):
+        compile_cache.stats().bump(
+            "route/flash_attention:" + ("interpret" if interpret
+                                        else "pallas"))
+        out = _flash(q3, k3, v3, causal, sm_scale, bq, bk, interpret)
     else:
+        compile_cache.stats().bump("route/flash_attention:reference")
         out = _reference_attention(q3, k3, v3, causal, sm_scale)
     if squeeze_heads:
         out = jnp.moveaxis(
@@ -485,38 +482,6 @@ register_tunable(
                   "gather is pure overhead and the slabs stay")
 
 
-_mesh_detect_warned = False
-
-
-def _in_manual_mesh_context() -> bool:
-    """True when tracing inside a shard_map manual region (e.g. a
-    pipeline stage body): entering another shard_map with a concrete mesh
-    there is an error, so the sp routing must fall back to the
-    device-global kernel.
-
-    Detection is version-shimmed in :mod:`paddle_tpu.compat`
-    (AxisType/get_abstract_mesh on new JAX, the trace-state axis env on
-    old).  Only the nothing-worked case degrades, and loudly, once: a
-    silent blanket except here would disable the nested-shard_map guard
-    without anyone noticing until a cryptic trace error deep in sp
-    routing."""
-    global _mesh_detect_warned
-    from ..compat import manual_axes
-    axes = manual_axes()
-    if axes is not None:
-        return bool(axes)
-    if not _mesh_detect_warned:
-        _mesh_detect_warned = True
-        import warnings
-        warnings.warn(
-            "paddle_tpu: manual-mesh detection failed on this JAX "
-            "(compat.manual_axes knows no working API) — JAX API "
-            "drift?  The nested-shard_map guard is disabled; "
-            "flash_attention inside pipeline stage bodies may "
-            "mis-route to ring attention.", RuntimeWarning, stacklevel=2)
-    return False
-
-
 @register_op("flash_attention")
 def _flash_attention_op(ctx, ins, attrs):
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
@@ -529,7 +494,9 @@ def _flash_attention_op(ctx, ins, attrs):
     # fall back to the GSPMD whole-array kernel.
     sp = ctx.mesh_axis_size("sp")
     if (sp > 1 and attrs.get("sequence_parallel", True)
-            and not _in_manual_mesh_context()
+            # inside a shard_map manual region (a pipeline stage body)
+            # entering another shard_map with a concrete mesh is an error
+            and not manual_axes()
             and q.ndim in (3, 4) and q.shape[1] == k.shape[1]
             and q.shape[1] % sp == 0):
         from ..parallel.ring_attention import ring_attention_sharded

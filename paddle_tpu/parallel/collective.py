@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 
 
 def _in_spmd(axis_name) -> bool:
@@ -71,7 +70,7 @@ def broadcast(x, axis_name="dp", src=0):
         idx = lax.axis_index(axis_name)
     except NameError:
         return x
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return lax.ppermute(x, axis_name, [(src, i) for i in range(n)])
 
 

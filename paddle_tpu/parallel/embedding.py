@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 
 
 def sharded_lookup(local_table, ids, axis_name="tp"):
@@ -36,7 +35,7 @@ def sharded_lookup(local_table, ids, axis_name="tp"):
     ``offset + r``).  ids: int [...] global row ids (replicated).
     Returns [..., D] replicated — one psum over the axis.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     vshard = local_table.shape[0]
     offset = idx * vshard
@@ -54,7 +53,7 @@ def sharded_lookup_grad_rows(ids, grad_out, vocab_size, axis_name="tp"):
     Utility for hand-rolled shard_map training loops; under jit+GSPMD this
     is derived automatically from sharded_lookup's vjp.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     vshard = vocab_size // n
     offset = idx * vshard

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 
 __all__ = ["make_local_sgd_step"]
 
@@ -53,7 +52,7 @@ def make_local_sgd_step(loss_fn, mesh, sync_every: int, learning_rate: float,
         # shard_map autodiff would otherwise psum cotangents of replicated
         # values on every step — the exact collective local SGD elides)
         params = jax.tree.map(
-            lambda p: compat.pvary(p, (axis_name,)), params)
+            lambda p: lax.pcast(p, (axis_name,), to="varying"), params)
         xs = x.reshape((K, x.shape[0] // K) + x.shape[1:])
         ys = y.reshape((K, y.shape[0] // K) + y.shape[1:])
 

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 
 __all__ = ["moe_dispatch", "moe_ffn", "load_balancing_loss"]
 
@@ -29,7 +28,7 @@ def _axis_size(axis_name):
     if axis_name is None:
         return 1
     try:
-        return compat.axis_size(axis_name)
+        return lax.axis_size(axis_name)
     except NameError:
         return 1
 

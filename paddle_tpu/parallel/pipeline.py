@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compat
 
 __all__ = ["pipeline_forward", "pipeline_spmd_fn", "stack_stage_params",
            "place_stage_params", "make_pipeline_train_step",
@@ -65,7 +64,7 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
     it.  x_microbatches: [M, ...] stacked microbatches (stage 0 injects
     them).  Returns [M, ...] last-stage outputs, replicated over the axis.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     my = jax.tree.map(lambda x: x[0], stage_params)
     M = x_microbatches.shape[0]
@@ -94,8 +93,8 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
     buf0 = jnp.zeros(out_aval.shape, out_aval.dtype)
     outs0 = jnp.zeros((M,) + buf0.shape, buf0.dtype)
     # carries become device-varying (ppermute / axis_index); mark the inits
-    buf0 = compat.pvary(buf0, (axis_name,))
-    outs0 = compat.pvary(outs0, (axis_name,))
+    buf0 = lax.pcast(buf0, (axis_name,), to="varying")
+    outs0 = lax.pcast(outs0, (axis_name,), to="varying")
     (_, outs), _ = lax.scan(tick, (buf0, outs0), jnp.arange(ticks))
     # only the last stage holds real results; psum broadcasts them so the
     # output is replicated over pp (callers can use out_specs=P())

@@ -20,6 +20,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .. import compat
+from ..core import compile_cache
 
 
 def _block_attn(q, k, v, bias=None):
@@ -53,16 +54,18 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     divisible = (T_loc % min(block_q, T_loc) == 0
                  and T_loc % min(block_k, T_loc) == 0)
     if use_flash is None:
-        import jax as _jax
-        from ..ops.pallas_kernels import _HAVE_PALLAS
-        use_flash = (_HAVE_PALLAS and _jax.default_backend() == "tpu"
-                     and divisible)
+        use_flash = jax.default_backend() == "tpu"
     # non-divisible local blocks always fall back to the exact jnp path —
     # same policy as the device-global wrapper, so forcing the kernel via
-    # use_flash/interpret degrades instead of raising mid-training
+    # use_flash/interpret degrades instead of raising mid-training; the
+    # choice is counted at trace time (route/ring_attention:*)
     if (use_flash or interpret) and divisible:
+        compile_cache.stats().bump(
+            "route/ring_attention:" + ("interpret" if interpret
+                                       else "pallas"))
         return _ring_attention_flash(q, k, v, axis_name, causal, scale,
                                      block_q, block_k, interpret)
+    compile_cache.stats().bump("route/ring_attention:reference")
     return _ring_attention_jnp(q, k, v, axis_name, causal, scale)
 
 
@@ -70,7 +73,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale, block_q,
                           block_k, interpret):
     from ..ops.pallas_kernels import flash_attention_with_lse
 
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     scale = scale if scale is not None else D ** -0.5
@@ -121,7 +124,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale, block_q,
 
 
 def _ring_attention_jnp(q, k, v, axis_name, causal, scale):
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
@@ -170,10 +173,17 @@ def ring_attention_sharded(q, k, v, mesh, causal=False, axis_name: str = "sp",
                            scale=None, block_q: int = 1024,
                            block_k: int = 1024, use_flash=None,
                            interpret: bool = False):
-    """Global-array entry point: partial-manual shard_map over ONLY the sp
-    axis (dp/tp stay GSPMD-managed, mirroring pipeline_program.py), with
+    """Global-array entry point: a shard_map over the sp axis with
     :func:`ring_attention` inside.  q,k,v: global [B, T, H, D]; returns the
     same global shape, time axis sharded on ``axis_name``.
+
+    When sp is the only mesh axis wider than 1 the region is manual over
+    EVERY axis: Mosaic refuses a Pallas kernel inside a partially-manual
+    region ("Mosaic kernels cannot be automatically partitioned"), and
+    width-1 axes shard nothing, so this is the same computation.  With
+    another axis in play (dp/tp stay GSPMD-managed, mirroring
+    pipeline_program.py) the region is manual over sp only and the hops
+    run the jnp blockwise path unless the caller forces a kernel.
 
     This is what the ``flash_attention`` op lowering calls when the mesh has
     sp>1 — the first-class framework path to sequence parallelism: a
@@ -182,13 +192,18 @@ def ring_attention_sharded(q, k, v, mesh, causal=False, axis_name: str = "sp",
     ring without touching shard_map themselves.
     """
     spec = P(None, axis_name)
+    sp_only = all(mesh.shape[a] == 1 for a in mesh.axis_names
+                  if a != axis_name)
+    if not sp_only and use_flash is None:
+        use_flash = False
     body = functools.partial(ring_attention, axis_name=axis_name,
                              causal=causal, scale=scale, block_q=block_q,
                              block_k=block_k, use_flash=use_flash,
                              interpret=interpret)
     return compat.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        axis_names=frozenset({axis_name}), check_vma=False)(q, k, v)
+        axis_names=mesh.axis_names if sp_only else {axis_name},
+        check_vma=False)(q, k, v)
 
 
 def sequence_parallel_attention(q, k, v, axis_name="sp", causal=False):

@@ -109,10 +109,11 @@ class SGD:
         starts: one batch is peeked from ``reader`` (for its shapes only)
         and the step variant(s) this loop will dispatch are compiled ahead
         of time (``Executor.compile``), so the first real batch executes a
-        ready executable.  With a persistent cache directory set
-        (``PADDLE_TPU_CACHE_DIR``), warmup in a deploy step also persists
-        the executables for later processes.  Bucketed readers whose later
-        batches change shape still compile those variants on first use.
+        ready executable.  The XLA compiles land in JAX's persistent
+        compilation cache (``core.compile_cache.cache_dir()``), so a
+        deploy-step warmup also serves later processes.  Bucketed readers
+        whose later batches change shape still compile those variants on
+        first use.
 
         ``validate=True`` runs the static program verifier
         (``paddle_tpu.analysis``) over the startup and training programs

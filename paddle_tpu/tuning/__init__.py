@@ -18,7 +18,7 @@ sweep into infrastructure):
   band; per-trial fault containment (a raising or overrunning config is
   a recorded ``failed``/``timeout`` trial, never a crashed search).
 * :mod:`.store` — winners persisted as JSON under
-  ``<PADDLE_TPU_CACHE_DIR>/tuning/`` keyed by the PR 3 content-
+  ``<compile_cache.cache_dir()>/tuning/`` keyed by the PR 3 content-
   fingerprint scheme extended with the tunable's schema digest and the
   device topology; ``tuned(name, default)`` replays them at trace time
   with zero search cost — and returns the default untouched when no

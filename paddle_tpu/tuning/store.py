@@ -1,6 +1,6 @@
 """Persistence + replay for autotuner winners.
 
-A winner is one JSON file under ``<PADDLE_TPU_CACHE_DIR>/tuning/`` named
+A winner is one JSON file under ``<compile_cache.cache_dir()>/tuning/`` named
 ``ptat-<fingerprint>.json`` — the PR 3 compile-cache discipline applied
 to configs instead of executables:
 
@@ -54,10 +54,10 @@ _memo: Dict[tuple, Optional[dict]] = {}
 
 
 def store_dir(base: Optional[str] = None) -> str:
-    """Active tuning-record directory ('' = persistence off).  ``base``
-    overrides the ``cache_dir`` flag (CLI --out, tests)."""
+    """Tuning-record directory: ``tuning/`` under the one resolved
+    compile-cache directory, or under ``base`` (CLI --cache-dir, tests)."""
     d = base if base is not None else compile_cache.cache_dir()
-    return os.path.join(d, "tuning") if d else ""
+    return os.path.join(d, "tuning")
 
 
 def topology_key():
@@ -80,27 +80,22 @@ def record_fingerprint(name: str, context: str = "") -> str:
 
 def record_path(name: str, context: str = "",
                 base: Optional[str] = None) -> str:
-    d = store_dir(base)
-    if not d:
-        return ""
-    return os.path.join(d, f"{_PREFIX}{record_fingerprint(name, context)}"
-                           f".json")
+    return os.path.join(store_dir(base),
+                        f"{_PREFIX}{record_fingerprint(name, context)}.json")
 
 
 def save_record(name: str, config: Dict[str, object], *,
                 context: str = "", base: Optional[str] = None,
                 **extra) -> str:
-    """Persist a winner config atomically; returns the path ('' when
-    persistence is off).  ``extra`` (score/speedup/windows/algo/...) is
-    stored verbatim for auditability — replay reads only ``config``."""
+    """Persist a winner config atomically; returns the path.  ``extra``
+    (score/speedup/windows/algo/...) is stored verbatim for auditability
+    — replay reads only ``config``."""
     entry = get_tunable(name)
     problems = _tn.validate_config(entry, config)
     if problems:
         raise ValueError(f"save_record({name!r}): config does not match "
                          f"the declared space: {problems}")
     d = store_dir(base)
-    if not d:
-        return ""
     fp = record_fingerprint(name, context)
     payload = {
         "format": TUNING_FORMAT, "fingerprint": fp, "tunable": name,
@@ -142,8 +137,6 @@ def load_record(name: str, context: str = "",
     declared space no longer admits (schema drift).  Misses other than
     plain not-found log a warning naming the file."""
     path = record_path(name, context, base)
-    if not path:
-        return None
     try:
         with open(path) as f:
             payload = json.load(f)
@@ -186,7 +179,7 @@ def tuned(name: str, default: Dict[str, object], *, context: str = "",
     ``autotune`` flag), so the off path never imports this package.
     """
     # base is part of the memo key (tests probe several stores in one
-    # process); a changed cache_dir flag needs clear_memo(), documented
+    # process)
     key = (name, str(context), base)
     with _lock:
         hit = key in _memo
@@ -218,7 +211,7 @@ def clear_memo():
 def list_records(base: Optional[str] = None):
     """(path, payload) for every readable record in the store."""
     d = store_dir(base)
-    if not d or not os.path.isdir(d):
+    if not os.path.isdir(d):
         return []
     out = []
     for fn in sorted(os.listdir(d)):

@@ -1,7 +1,7 @@
 """Compile-cache subsystem (core/compile_cache.py): fingerprint-keyed
-executor caching, retrace detection, LRU/weakref eviction, the persistent
-on-disk executable cache, AOT ``Executor.compile`` and
-``Trainer.train(warmup=...)``.
+executor caching, retrace detection, LRU/weakref eviction, the one
+persistent-cache directory (JAX's own compilation cache), AOT
+``Executor.compile`` and ``Trainer.train(warmup=...)``.
 
 The retrace contract under test: ONE jit trace per (program content, feed
 signature, executor config) — repeated ``run``/``run_steps``/
@@ -10,6 +10,10 @@ fingerprint ingredient changing (program mutation, feed dtype, mesh, amp,
 compiler options) must cost exactly one new trace.
 """
 import gc
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -24,19 +28,10 @@ from paddle_tpu.core.program import Program, program_guard
 
 @pytest.fixture(autouse=True)
 def _fresh_stats():
-    """Per-test telemetry isolation + persistent-cache knob restore."""
+    """Per-test telemetry isolation."""
     compile_cache.stats().reset()
     yield
-    pt.flags.set_flag("cache_dir", "")
     compile_cache.stats().reset()
-
-
-@pytest.fixture
-def cache_dir(tmp_path):
-    """Point the persistent layer at a tmp dir for one test."""
-    d = tmp_path / "ptcache"
-    pt.flags.set_flag("cache_dir", str(d))
-    return d
 
 
 def _build_net(rng, seed=0):
@@ -376,66 +371,96 @@ def test_sharded_compile_aot(rng):
 
 
 # ---------------------------------------------------------------------------
-# persistent on-disk layer
+# the one persistent-cache directory
 # ---------------------------------------------------------------------------
-def test_persistent_cache_roundtrip(rng, cache_dir):
-    """A second Executor (fresh in-process cache, same persistent dir)
-    loads the serialized executable instead of tracing, and its fetches
-    are bit-identical."""
-    loss, feed = _build_net(rng)
-    exe1 = pt.Executor()
-    exe1.run(pt.default_startup_program(), feed={}, fetch_list=[])
-    (v1,) = exe1.run(feed=feed, fetch_list=[loss])
-    snap = compile_cache.stats().snapshot()
-    assert snap["disk_stores"] >= 2            # startup + step executables
-    assert any(p.name.startswith("ptxc-") for p in cache_dir.iterdir())
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    # fresh executor, params reset to the same init by re-running startup
-    pt.core.reset_global_scope()
-    exe2 = pt.Executor()
-    t0 = _traces()
-    exe2.run(pt.default_startup_program(), feed={}, fetch_list=[])
-    (v2,) = exe2.run(feed=feed, fetch_list=[loss])
-    snap2 = compile_cache.stats().snapshot()
-    assert _traces() == t0                     # zero traces: disk served both
-    assert snap2["disk_hits"] - snap.get("disk_hits", 0) >= 2
-    assert v1.tobytes() == v2.tobytes()
-
-
-def test_persistent_cache_corrupt_entry_recompiles(rng, cache_dir):
-    loss, feed = _build_net(rng)
-    exe1 = pt.Executor()
-    exe1.run(pt.default_startup_program(), feed={}, fetch_list=[])
-    exe1.run(feed=feed, fetch_list=[loss])
-    for p in cache_dir.iterdir():
-        if p.name.startswith("ptxc-"):
-            p.write_bytes(b"corrupt")
-    pt.core.reset_global_scope()
-    exe2 = pt.Executor()
-    exe2.run(pt.default_startup_program(), feed={}, fetch_list=[])
-    (v,) = exe2.run(feed=feed, fetch_list=[loss])   # recompiles, no crash
-    assert np.isfinite(v)
-
-
-# ---------------------------------------------------------------------------
-# benchmark wiring (satellite: tier-1 smoke; full A/B is slow)
-# ---------------------------------------------------------------------------
-def test_benchmark_smoke_cold_warm_subprocesses():
-    """benchmark/run.py --model compile_cache --smoke: two fresh
-    subprocesses share a tmp cache; asserts the warm arm loads executables
-    (zero traces) and produces bit-identical fetches."""
-    from benchmark.compile_cache import run_smoke
-    row = run_smoke()
-    assert row["bit_identical"]
-    assert row["warm_traces"] == 0
+# one fresh process: a spy on jax.config.update, a tiny training run, then
+# what the resolver said and what JAX ended up using
+_CACHE_PROBE = r"""
+import json, sys
+import jax
+updates = []
+_orig = jax.config.update
+def _spy(name, val):
+    updates.append(name)
+    return _orig(name, val)
+jax.config.update = _spy
+import numpy as np
+import paddle_tpu as pt
+from paddle_tpu import layers
+from paddle_tpu.core import compile_cache
+x = layers.data("x", shape=[16], dtype="float32")
+y = layers.data("y", shape=[1], dtype="int64")
+pred = layers.fc(layers.fc(x, size=32, act="relu"), size=4, act="softmax")
+loss = layers.mean(layers.cross_entropy(pred, y))
+pt.optimizer.SGD(0.1).minimize(loss)
+rng = np.random.RandomState(0)
+feed = {"x": rng.rand(8, 16).astype("float32"),
+        "y": rng.randint(0, 4, (8, 1))}
+exe = pt.Executor()
+exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+vals = [float(exe.run(feed=feed, fetch_list=[loss])[0]).hex()
+        for _ in range(3)]
+print(json.dumps({
+    "resolved": compile_cache.cache_dir(),
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "dir_updates": updates.count("jax_compilation_cache_dir"),
+    "losses": vals,
+    "counters": compile_cache.stats().snapshot()}))
+"""
 
 
-@pytest.mark.slow
-def test_benchmark_full_ab_models():
-    """Full cold-vs-warm A/B on the three real models (minutes)."""
-    from benchmark.compile_cache import MODELS, run_model
-    rows = [run_model(m, quiet=True) for m in MODELS]
-    assert all(r["bit_identical"] for r in rows)
-    fast = [r for r in rows if r["speedup_engine"] >= 1.5]
-    assert len(fast) >= 2, [
-        (r["model"], r["speedup_engine"]) for r in rows]
+def _cache_probe(**env_over):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
+    env.update(JAX_PLATFORMS="cpu", **env_over)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], cwd=_ROOT,
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def _ptxc_files(*dirs):
+    return [os.path.join(dp, f) for d in dirs if os.path.isdir(d)
+            for dp, _, fs in os.walk(d) for f in fs if f.startswith("ptxc-")]
+
+
+def test_cache_dir_from_environment_is_used_untouched(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: that directory IS the cache, the code
+    never re-points jax_compilation_cache_dir, nothing appears in the
+    checkout's .jax_cache, and a second fresh process is served from it
+    (JAX's own cache-hit event) with bit-identical losses."""
+    d = str(tmp_path / "placed_from_outside")
+    repo_cache = compile_cache.REPO_CACHE_DIR
+    before = sorted(os.listdir(repo_cache)) \
+        if os.path.isdir(repo_cache) else None
+    # thresholds lowered through JAX's own env knobs so the tiny program's
+    # sub-second compiles land on disk at all
+    knobs = {"JAX_COMPILATION_CACHE_DIR": d,
+             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
+    cold = _cache_probe(**knobs)
+    assert cold["resolved"] == d and cold["jax_dir"] == d
+    assert cold["dir_updates"] == 0
+    assert cold["counters"].get("jax_cache_hits", 0) == 0
+    assert os.listdir(d)                      # the cache is in that dir
+    warm = _cache_probe(**knobs)
+    assert warm["dir_updates"] == 0
+    assert warm["counters"]["jax_cache_hits"] > 0
+    assert warm["losses"] == cold["losses"]
+    after = sorted(os.listdir(repo_cache)) \
+        if os.path.isdir(repo_cache) else None
+    assert after == before
+    assert not _ptxc_files(d, repo_cache)
+
+
+def test_cache_dir_defaults_to_fixed_path_in_checkout():
+    """Unset: the one fixed in-repo path — never a temp name, a pid or a
+    time — and JAX's cache is pointed there exactly once."""
+    got = _cache_probe()
+    want = os.path.join(_ROOT, ".jax_cache")
+    assert compile_cache.REPO_CACHE_DIR == want
+    assert got["resolved"] == want and got["jax_dir"] == want
+    assert got["dir_updates"] == 1
+    assert not _ptxc_files(want)

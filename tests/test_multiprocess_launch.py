@@ -21,7 +21,6 @@ port, pid, tmpdir = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
