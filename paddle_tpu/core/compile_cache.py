@@ -384,6 +384,61 @@ def cache_dir() -> str:
 # ---------------------------------------------------------------------------
 # The jit wrapper: explicit trace/lower/compile with telemetry + disk
 # ---------------------------------------------------------------------------
+def named_step(fn, label: Optional[str]):
+    """``fn`` under the name ``pt_<label>``, for ``jax.jit``: the HLO module
+    is then ``jit_pt_run`` / ``jit_pt_run_steps`` / ... instead of whatever
+    the closure happened to be called, so a device trace's ``XLA Modules``
+    events say which step path ran.  The module name is part of JAX's
+    persistent-cache key (op metadata is not), so it is also what keeps an
+    executable compiled before the lowering named its ops from being
+    served in place of one that carries the names."""
+    def named(feeds, state, step):
+        return fn(feeds, state, step)
+
+    named.__name__ = named.__qualname__ = f"pt_{label or 'step'}"
+    return named
+
+
+#: live CachedSteps, so that a reader who holds only a fingerprint prefix
+#: (the ``pt:<path>:<fp12>`` annotation of a trace) can ask what ran
+_live_steps: "weakref.WeakSet[CachedStep]" = weakref.WeakSet()
+
+
+#: the steps of the last few OBSERVED dispatches, held strongly: an observed
+#: dispatch writes ``pt:<path>:<fp12>`` into a profiler trace, and whoever
+#: reads that trace does so when the window is over — often after the
+#: executor, and with it every other reference to the step, is gone
+_observed_steps: "collections.deque[CachedStep]" = collections.deque(maxlen=8)
+
+
+def keep_observed(fingerprint: Optional[str]):
+    """Keep the live step(s) of ``fingerprint`` readable by
+    :func:`compiled_hlo_text` until eight newer observed fingerprints have
+    pushed them out.  Called by the executor for dispatches it annotates
+    (``observe`` on); unobserved steps die with their executor as before."""
+    if not fingerprint or any(s.fingerprint == fingerprint
+                              for s in _observed_steps):
+        return
+    _observed_steps.extend(s for s in list(_live_steps)
+                           if s.fingerprint == fingerprint)
+
+
+def compiled_hlo_text(fp_prefix: str) -> Optional[str]:
+    """The optimized HLO text of a live, compiled step whose fingerprint
+    starts with ``fp_prefix``; None when there is none (never compiled,
+    or collected with its executor: only the steps of the last observed
+    dispatches outlive it, :func:`keep_observed`).  Rendered on request
+    only."""
+    if not fp_prefix:
+        return None
+    for step in list(_live_steps):
+        if (step.fingerprint or "").startswith(fp_prefix):
+            text = step.hlo_text()
+            if text is not None:
+                return text
+    return None
+
+
 class CachedStep:
     """AOT-compiled step function for ONE fingerprint.
 
@@ -422,7 +477,7 @@ class CachedStep:
         if in_shardings is not None:
             kw["in_shardings"] = in_shardings
         self._fn = fn
-        self._jit = jax.jit(fn, **kw)
+        self._jit = jax.jit(named_step(fn, label), **kw)
         self._fp = fingerprint
         self._opts = dict(compiler_options or {})
         self._label = label
@@ -430,11 +485,26 @@ class CachedStep:
         self._mesh_step = in_shardings is not None
         self._fallback_recorded = False
         self._times: Dict[str, float] = {}
+        _live_steps.add(self)
 
     # -- public ----------------------------------------------------------
     @property
     def fingerprint(self) -> Optional[str]:
         return self._fp
+
+    def hlo_text(self) -> Optional[str]:
+        """The optimized module as XLA prints it (``compiled.as_text()``),
+        with the ``pt.<op_type>:<block>.<position>`` scopes of the lowering
+        in each instruction's ``op_name``; None before the step compiled.
+        Megabytes for a real model: rendered on each call, kept nowhere."""
+        return None if self._compiled is None else self._compiled.as_text()
+
+    def memory_analysis(self):
+        """XLA's ``memory_analysis()`` of the executable (argument, output,
+        alias, temporary and code bytes on one device); None before the
+        step compiled."""
+        return None if self._compiled is None \
+            else self._compiled.memory_analysis()
 
     @property
     def times(self) -> Dict[str, float]:
@@ -520,6 +590,17 @@ class CompiledProgram:
     @property
     def compile_times(self) -> Dict[str, float]:
         return self._step.times
+
+    def hlo_text(self) -> Optional[str]:
+        """The optimized HLO module this variant runs, as text; every
+        instruction's ``op_name`` names the Program op that lowered it
+        (``CachedStep.hlo_text``)."""
+        return self._step.hlo_text()
+
+    def memory_analysis(self):
+        """XLA's memory analysis of this variant's executable: bytes of
+        arguments, outputs, aliases, temporaries and code on one device."""
+        return self._step.memory_analysis()
 
     def run(self, feed=None, scope=None, return_numpy=True):
         if self.num_steps is not None:

@@ -185,6 +185,21 @@ class LoweringContext:
         self.op: Optional[Operator] = None
         self.env: Optional[Env] = None
         self._op_uid = 0
+        self._op_pos: Dict[int, int] = {}     # id(op) -> position in block
+
+    def op_scope(self, op: Operator):
+        """``jax.named_scope("pt.<op_type>:<block>.<position>")`` around
+        one op's lowering: the name rides into the HLO ``op_name`` of every
+        instruction the op emits (JAX adds ``jvp(...)`` /
+        ``transpose(jvp(...))`` for the direction), so a device trace can
+        be read in the Program's terms; ``<block>.<position>`` joins back
+        to ``program.blocks[block].ops[position]``."""
+        pos = self._op_pos.get(id(op))
+        if pos is None:          # once per block per trace
+            for i, o in enumerate(op.block.ops):
+                self._op_pos[id(o)] = i
+            pos = self._op_pos.get(id(op), "x")   # an op outside its block
+        return jax.named_scope(f"pt.{op.type}:{op.block.idx}.{pos}")
 
     @property
     def pp_size(self) -> int:
@@ -237,6 +252,18 @@ class LoweringContext:
 # ---------------------------------------------------------------------------
 # Interpreter
 # ---------------------------------------------------------------------------
+# jax.named_scope names for work the executor itself emits outside any op
+# (ops get "pt.<op_type>:<block>.<position>" from LoweringContext.op_scope).
+# Unconditional, like the op scopes: JAX's persistent-cache key ignores
+# metadata, so an executable compiled without them would be served to a
+# traced run of the same program.
+AMP_SCOPE = "pt.amp_cast"        # bf16 casts of state/feeds, f32 of grads
+DTYPE_SCOPE = "pt.dtype_cast"    # compute_dtype (precision-instrument) casts
+RNG_SCOPE = "pt.rng"             # the step's PRNG key
+SCAN_SCOPE = "pt.scan"           # run_steps: the K-step lax.scan's plumbing
+NAN_SCOPE = "pt.nan_check"       # check_nan_inf's per-var finite flags
+
+
 def _normalize_outputs(op: Operator, result) -> Dict[str, List]:
     if result is None:
         return {}
@@ -260,7 +287,8 @@ def run_op(op: Operator, env: Env, ctx: LoweringContext):
     ctx.op, ctx.env = op, env
     ctx._op_uid += 1
     try:
-        result = impl(ctx, ins, op.attrs)
+        with ctx.op_scope(op):
+            result = impl(ctx, ins, op.attrs)
     except Exception as e:
         # PADDLE_ENFORCE-style context (enforce.h): name the op and its
         # operand shapes so a trace-time shape error points at the graph
@@ -299,7 +327,8 @@ def run_op(op: Operator, env: Env, ctx: LoweringContext):
                 if ctx.compute_dtype is not None and hasattr(v, "dtype") \
                         and jnp.issubdtype(v.dtype, jnp.floating) \
                         and v.dtype != jnp.dtype(ctx.compute_dtype):
-                    v = v.astype(ctx.compute_dtype)
+                    with jax.named_scope(DTYPE_SCOPE):
+                        v = v.astype(ctx.compute_dtype)
                 env.set(n, v)
 
 
@@ -366,8 +395,9 @@ def _run_backward(forward_ops: Sequence[Operator], bw_op: Operator,
             # wrt leaves stay fp32 so grads come back fp32 for the master-
             # weight optimizer update.  jax.grad differentiates through the
             # cast, so this is the canonical AMP recipe at zero extra cost.
-            fenv.local.update({k: _to_bf16(v) for k, v in init.items()})
-            fenv.local.update({k: _to_bf16(v) for k, v in wrt.items()})
+            with jax.named_scope(AMP_SCOPE):
+                fenv.local.update({k: _to_bf16(v) for k, v in init.items()})
+                fenv.local.update({k: _to_bf16(v) for k, v in wrt.items()})
         else:
             fenv.local.update(init)
             fenv.local.update(wrt)
@@ -380,7 +410,8 @@ def _run_backward(forward_ops: Sequence[Operator], bw_op: Operator,
                     f"got shape {loss.shape}")
             loss = loss.reshape(())
         if amp:
-            loss = loss.astype(jnp.float32)
+            with jax.named_scope(AMP_SCOPE):
+                loss = loss.astype(jnp.float32)
         return loss, fenv.local
 
     (loss_val, fwd_vals), grads = jax.value_and_grad(f, has_aux=True)(wrt_vals)
@@ -395,7 +426,8 @@ def _run_backward(forward_ops: Sequence[Operator], bw_op: Operator,
     for n in wrt_names:
         g = grads[n]
         if amp and g.dtype != wrt_vals[n].dtype:
-            g = g.astype(wrt_vals[n].dtype)
+            with jax.named_scope(AMP_SCOPE):
+                g = g.astype(wrt_vals[n].dtype)
         env.set(grad_var_name(n), g)
 
 
@@ -705,7 +737,7 @@ class Executor:
                          wall_s: float, fetch_block_s: float,
                          feed_arrays: Dict[str, object], stacked: bool,
                          compile_before: Optional[Dict[str, int]] = None,
-                         span=None):
+                         span=None, drained: bool = True):
         """Registry writes + JSONL step event for one compiled dispatch.
         Only reached when _observing() — the off path never touches the
         registry (counter-delta tier-1 assertion).
@@ -715,7 +747,15 @@ class Executor:
         time is dominated by COMPILE, not compute —
         the dispatch is tagged cold and kept OUT of the step-time
         histogram and throughput gauge (compile cost already has its own
-        telemetry in compile_stats())."""
+        telemetry in compile_stats()).
+
+        ``drained`` False: no fetch was materialized on the host
+        (``return_numpy=False``, or nothing fetched), so the wall time is
+        the ENQUEUE, not the step — tagged ``drained: false`` and kept out
+        of the same two metrics for the same reason."""
+        # this dispatch is in the profiler's trace as pt:<path>:<fp12>:
+        # keep its step readable for whoever reads that trace afterwards
+        compile_cache.keep_observed(fp)
         cold = False
         if compile_before is not None:
             after = compile_cache.stats().snapshot()
@@ -731,7 +771,8 @@ class Executor:
         if feed_bytes:
             obs.inc_counter("executor/feed_bytes", float(feed_bytes))
         examples_per_s = None
-        if not cold:
+        timed = drained and not cold
+        if timed:
             obs.observe_hist("executor/step_time_ms", step_ms)
             lead = 1 if stacked else 0      # stacked feeds: [K, B, ...]
             for _, a in sorted(feed_arrays.items()):
@@ -746,8 +787,8 @@ class Executor:
         obs.emit_event(
             "step", path=path, fingerprint=(fp or "")[:12], steps=steps,
             wall_ms=round(wall_ms, 3),
-            step_ms=None if cold else round(step_ms, 3),
-            cold_compile=cold, feed_bytes=feed_bytes,
+            step_ms=round(step_ms, 3) if timed else None,
+            cold_compile=cold, drained=drained, feed_bytes=feed_bytes,
             fetch_block_ms=round(fetch_block_s * 1e3, 3),
             examples_per_sec=round(examples_per_s, 2)
             if examples_per_s else None,
@@ -938,7 +979,9 @@ class Executor:
                                   wall_s=now - t_start,
                                   fetch_block_s=now - t_fetch,
                                   feed_arrays=feed_arrays, stacked=False,
-                                  compile_before=c0, span=sp)
+                                  compile_before=c0, span=sp,
+                                  drained=return_numpy and any(
+                                      f is not None for f in fetches))
         return fetches
 
     def run_steps(self, num_steps: int,
@@ -1054,7 +1097,9 @@ class Executor:
                                   fetch_block_s=now - t_fetch,
                                   feed_arrays=feed_arrays,
                                   stacked=feeds_stacked,
-                                  compile_before=c0, span=sp)
+                                  compile_before=c0, span=sp,
+                                  drained=return_numpy and any(
+                                      f is not None for f in fetches))
         return fetches
 
     def run_pipelined(self, feed_iter,
@@ -1224,12 +1269,16 @@ class Executor:
                 fetches, new_s = step_fn(f, s, step)
                 return (new_s, step + 1), fetches
 
-            init = (st, jnp.asarray(step0, jnp.uint32))
-            if feeds_stacked:
-                (s_out, _), ys = jax.lax.scan(body, init, feeds)
-            else:
-                (s_out, _), ys = jax.lax.scan(body, init, None,
-                                              length=num_steps)
+            # everything the scan itself emits (the while, the carried
+            # state's copies, the stacking of each step's fetches) reads
+            # "pt.scan"; the ops inside nest their own scopes under it
+            with jax.named_scope(SCAN_SCOPE):
+                init = (st, jnp.asarray(step0, jnp.uint32))
+                if feeds_stacked:
+                    (s_out, _), ys = jax.lax.scan(body, init, feeds)
+                else:
+                    (s_out, _), ys = jax.lax.scan(body, init, None,
+                                                  length=num_steps)
             return ys, s_out
 
         multi.prog_cell = step_fn.prog_cell
@@ -1249,7 +1298,8 @@ class Executor:
         if self.auto_layout:
             return _AutoLayoutStep(multi, self._fmt_registry,
                                    self._effective_compiler_options(),
-                                   donate=not self.check_nan_inf)
+                                   donate=not self.check_nan_inf,
+                                   label="run_steps")
         return compile_cache.CachedStep(
             multi, fingerprint,
             compiler_options=self._effective_compiler_options(),
@@ -1447,7 +1497,8 @@ class Executor:
         if self.auto_layout:
             return _AutoLayoutStep(fn, self._fmt_registry,
                                    self._effective_compiler_options(),
-                                   donate=not self.check_nan_inf)
+                                   donate=not self.check_nan_inf,
+                                   label="run")
         return compile_cache.CachedStep(
             fn, fingerprint,
             compiler_options=self._effective_compiler_options(),
@@ -1493,19 +1544,23 @@ class Executor:
                     "compiled step traced after its Program was "
                     "garbage-collected (cache entry outlived every "
                     "client program)")
-            base_key = jax.random.fold_in(
-                jax.random.PRNGKey(random_seed), step)
+            with jax.named_scope(RNG_SCOPE):
+                base_key = jax.random.fold_in(
+                    jax.random.PRNGKey(random_seed), step)
             env = Env(program.global_block())
             env.local.update(state)
             env.local.update(feed_arrays)
             if compute_dtype is not None:
                 cd = jnp.dtype(compute_dtype)
-                env.local = {k: v.astype(cd) if hasattr(v, "dtype")
-                             and jnp.issubdtype(v.dtype, jnp.floating)
-                             else v for k, v in env.local.items()}
+                with jax.named_scope(DTYPE_SCOPE):
+                    env.local = {k: v.astype(cd) if hasattr(v, "dtype")
+                                 and jnp.issubdtype(v.dtype, jnp.floating)
+                                 else v for k, v in env.local.items()}
             if amp and not has_backward:
                 # pure-inference AMP: whole net computes in bf16
-                env.local = {k: _to_bf16(v) for k, v in env.local.items()}
+                with jax.named_scope(AMP_SCOPE):
+                    env.local = {k: _to_bf16(v)
+                                 for k, v in env.local.items()}
             ctx = LoweringContext(program, base_key, is_test=is_test,
                                   amp=amp, mesh=lowering_mesh,
                                   pipeline_microbatches=microbatches,
@@ -1518,11 +1573,12 @@ class Executor:
                 # per float var): the executor.cc:116-124 analog for the
                 # one-big-jit world — a NaN is localized to the op that
                 # produced it, not to the whole step (see _nan_localize)
-                finite = {
-                    k: jnp.all(jnp.isfinite(v))
-                    for k, v in env.local.items()
-                    if hasattr(v, "dtype") and
-                    jnp.issubdtype(v.dtype, jnp.floating)}
+                with jax.named_scope(NAN_SCOPE):
+                    finite = {
+                        k: jnp.all(jnp.isfinite(v))
+                        for k, v in env.local.items()
+                        if hasattr(v, "dtype") and
+                        jnp.issubdtype(v.dtype, jnp.floating)}
                 fetches = fetches + [finite]
             new_state = {k: env.get(k) for k in persistable_names
                          if env.has(k)}
@@ -1532,7 +1588,8 @@ class Executor:
                 old = state.get(k)
                 if old is not None and hasattr(old, "dtype") and \
                         hasattr(v, "dtype") and v.dtype != old.dtype:
-                    new_state[k] = v.astype(old.dtype)
+                    with jax.named_scope(AMP_SCOPE):
+                        new_state[k] = v.astype(old.dtype)
             return fetches, new_state
 
         fn.prog_cell = prog_cell
@@ -1585,12 +1642,14 @@ class _AutoLayoutStep:
     """
 
     def __init__(self, fn, fmt_registry, compiler_options=None,
-                 donate=True):
+                 donate=True, label=None):
         self._fn = fn
+        # what jit sees: the module is jit_pt_<label>, as CachedStep's
+        self._named = compile_cache.named_step(fn, label)
         # donate=False: check_nan_inf variants (same contract as
         # CachedStep) — pre-step state survives for the NaN bisect
         self._donate_kw = {"donate_argnums": (1,)} if donate else {}
-        self._plain = jax.jit(fn, **self._donate_kw)
+        self._plain = jax.jit(self._named, **self._donate_kw)
         self._compiled = None
         self._state_formats = None
         self._registry = fmt_registry  # shared across an Executor's variants
@@ -1615,7 +1674,8 @@ class _AutoLayoutStep:
         out_state = {k: self._registry.get(k, auto) for k in out_struct[1]}
         in_sh = (jax.tree.map(lambda _: dflt, feeds), in_state, dflt)
         lowered = jax.jit(
-            self._fn, in_shardings=in_sh, out_shardings=(dflt, out_state),
+            self._named, in_shardings=in_sh,
+            out_shardings=(dflt, out_state),
             **self._donate_kw,
         ).lower(feeds, state, step)
         comp = lowered.compile(

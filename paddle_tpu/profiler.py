@@ -141,6 +141,17 @@ def compile_stats():
     return compile_cache.stats()
 
 
+def compiled_hlo_text(fp_prefix: str):
+    """The optimized HLO text of the live compiled step whose fingerprint
+    starts with ``fp_prefix`` — e.g. the 12 characters of a trace's
+    ``pt:run_steps:<fp12>`` annotation — or None when no such step is
+    alive.  Each instruction's ``op_name`` carries the
+    ``pt.<op_type>:<block>.<position>`` scope of the Program op that
+    lowered it, which is how device time is read per op."""
+    from .core import compile_cache
+    return compile_cache.compiled_hlo_text(fp_prefix)
+
+
 def compile_report() -> str:
     """Human-readable compile telemetry (StatSet-style report)."""
     return compile_stats().report()

@@ -1,0 +1,240 @@
+"""The device trace speaks the Program's language: every instruction a
+compiled step emits carries ``pt.<op_type>:<block>.<position>`` in its HLO
+``op_name`` (``LoweringContext.op_scope``, unconditional), the jitted step
+functions have stable names (``jit_pt_run`` ...), and there is one public
+way from a fingerprint to the text of what ran
+(``CompiledProgram.hlo_text`` / ``profiler.compiled_hlo_text``)."""
+import gc
+import re
+
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import layers, profiler
+from paddle_tpu.core import compile_cache
+from paddle_tpu.core.compile_cache import retrace_guard
+from paddle_tpu.observability import metrics as obs
+
+
+@pytest.fixture(autouse=True)
+def _fresh_stats():
+    compile_cache.stats().reset()
+    obs.registry().reset()
+    yield
+    compile_cache.stats().reset()
+    obs.registry().reset()
+    compile_cache._observed_steps.clear()
+
+
+def _conv_net():
+    """conv + batch norm + mul + cross_entropy + Momentum, tiny."""
+    img = layers.data("img", shape=[3, 8, 8], dtype="float32")
+    label = layers.data("label", shape=[1], dtype="int64")
+    conv = layers.conv2d(img, num_filters=4, filter_size=3, padding=1,
+                         bias_attr=False)
+    bn = layers.batch_norm(conv, act="relu")
+    pred = layers.fc(bn, size=5, act="softmax")
+    loss = layers.mean(layers.cross_entropy(pred, label))
+    pt.optimizer.Momentum(0.01, momentum=0.9).minimize(loss)
+    rng = np.random.RandomState(0)
+    feed = {"img": rng.rand(2, 3, 8, 8).astype("float32"),
+            "label": rng.randint(0, 5, (2, 1))}
+    return loss, feed
+
+
+def _rnn_net():
+    seq = layers.data("seq", shape=[4], dtype="float32", lod_level=1)
+    rnn = layers.control_flow.StaticRNN()
+    with rnn.step():
+        x_t = rnn.step_input(seq)
+        acc = rnn.memory(shape=[4])
+        new = layers.elementwise_add(acc, layers.fc(x_t, size=4))
+        rnn.update_memory(acc, new)
+        rnn.step_output(new)
+    out = rnn()
+    rng = np.random.RandomState(0)
+    return out, {"seq": rng.rand(2, 3, 4).astype("float32"),
+                 "seq@LEN": np.array([3, 3])}
+
+
+def _op_names(text):
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _compile(exe, feed, fetch, **kw):
+    exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+    return exe.compile(feed=feed, fetch_list=[fetch], **kw)
+
+
+def test_forward_backward_and_optimizer_scopes_reach_the_hlo():
+    loss, feed = _conv_net()
+    text = _compile(pt.Executor(), feed, loss).hlo_text()
+    assert text.startswith("HloModule jit_pt_run,")
+    names = _op_names(text)
+    fwd = [n for n in names if re.search(r"/jvp\(pt\.conv2d:0\.\d+\)/", n)]
+    bwd = [n for n in names
+           if re.search(r"/transpose\(jvp\(pt\.conv2d:0\.\d+\)\)/", n)]
+    assert fwd and bwd
+    # the instance joins back to the Operator in the Program
+    block, pos = re.search(r"pt\.conv2d:(\d+)\.(\d+)", fwd[0]).groups()
+    op = pt.default_main_program().blocks[int(block)].ops[int(pos)]
+    assert op.type == "conv2d"
+    # an optimizer op runs after the backward pseudo-op: in neither
+    opt = [n for n in names if "pt.momentum:" in n]
+    assert opt and not any("jvp(" in n or "transpose(" in n for n in opt)
+    for other in ("pt.batch_norm:", "pt.mul:", "pt.cross_entropy:"):
+        assert any(other in n for n in names), other
+    assert not any("/" in m for n in names
+                   for m in re.findall(r"pt\.\w+:([^/()]*)", n))
+
+
+def test_step_block_ops_nest_under_their_rnn():
+    out, feed = _rnn_net()
+    names = _op_names(_compile(pt.Executor(), feed, out).hlo_text())
+    nested = [n for n in names
+              if re.search(r"pt\.rnn:0\.\d+/.*pt\.mul:1\.\d+", n)]
+    assert nested, sorted(names)
+    block, pos = re.search(r"pt\.mul:(\d+)\.(\d+)", nested[0]).groups()
+    assert pt.default_main_program().blocks[int(block)] \
+        .ops[int(pos)].type == "mul"
+
+
+def test_executor_emitted_work_has_scopes_of_its_own():
+    loss, feed = _conv_net()
+    cp = _compile(pt.Executor(amp=True), feed, loss, num_steps=3)
+    text = cp.hlo_text()
+    assert text.startswith("HloModule jit_pt_run_steps,")
+    names = _op_names(text)
+    assert any("pt.amp_cast" in n for n in names)
+    assert any(n.endswith("pt.scan/while") for n in names)
+    # ops inside the scan keep their own scope as the innermost
+    assert any(re.search(r"pt\.scan/.*pt\.conv2d:0\.\d+", n) for n in names)
+
+
+def test_scopes_do_not_depend_on_observe():
+    """Unconditional: metadata is not in JAX's persistent-cache key, so a
+    scope-less executable would be served to an observed run."""
+    loss, feed = _conv_net()
+    exe = pt.Executor(observe=False)
+    exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+    off = exe.compile(feed=feed, fetch_list=[loss])
+    on_exe = pt.Executor(observe=True)
+    on = on_exe.compile(feed=feed, fetch_list=[loss])
+    assert on.fingerprint == off.fingerprint
+    # the same instructions under the same names (the text also records
+    # the Python call sites, and the two compile() calls above differ)
+    body = [re.sub(r"stack_frame_id=\d+|source_line=\d+", "", ln)
+            for ln in on.hlo_text().splitlines() if " = " in ln]
+    assert body == [re.sub(r"stack_frame_id=\d+|source_line=\d+", "", ln)
+                    for ln in off.hlo_text().splitlines() if " = " in ln]
+    assert any("pt.conv2d:" in ln for ln in body)
+
+
+def test_fingerprints_traces_and_retrace_guard_as_before():
+    loss, feed = _conv_net()
+    exe = pt.Executor()
+    with retrace_guard():
+        cp = _compile(exe, feed, loss)
+        traces = compile_cache.stats().snapshot()["traces"]
+        for _ in range(3):
+            exe.run(feed=feed, fetch_list=[loss])
+            cp.run(feed=feed)
+    assert traces == 2                       # startup + the step
+    assert compile_cache.stats().snapshot()["traces"] == traces
+    entry = compile_cache.stats().entries[cp.fingerprint]
+    assert entry["traces"] == 1 and entry["label"] == "run"
+    assert set(entry["times"]) == {"trace_s", "lower_s", "compile_s"}
+    compile_cache.stats().assert_no_retrace()
+
+
+def test_compiled_hlo_text_by_fingerprint_prefix_and_after_collection():
+    loss, feed = _conv_net()
+    exe = pt.Executor(observe=False)
+    cp = _compile(exe, feed, loss)
+    fp12 = cp.fingerprint[:12]
+    assert profiler.compiled_hlo_text(fp12) == cp.hlo_text()
+    assert profiler.compiled_hlo_text("") is None
+    assert profiler.compiled_hlo_text("no-such-fp") is None
+    exe.close()
+    del cp, exe
+    gc.collect()
+    assert profiler.compiled_hlo_text(fp12) is None
+
+
+def test_an_observed_dispatch_keeps_its_step_readable():
+    """The reader of a trace runs when the window is over, often after the
+    executor is gone: the steps of the last eight observed fingerprints
+    (the ones a ``pt:<path>:<fp12>`` annotation names) outlive it."""
+    loss, feed = _conv_net()
+    exe = pt.Executor(observe=True)
+    exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+    exe.run_steps(2, feed=feed, fetch_list=[loss])
+    fps = [fp for fp, e in compile_cache.stats().entries.items()
+           if e["label"] == "run_steps"]
+    assert len(fps) == 1
+    exe.close()
+    del exe
+    gc.collect()
+    text = profiler.compiled_hlo_text(fps[0][:12])
+    assert text.startswith("HloModule jit_pt_run_steps,")
+    # ... until eight newer observed fingerprints have pushed it out
+    for i in range(8):
+        compile_cache._observed_steps.append(
+            compile_cache.CachedStep(lambda f, s, t: (f, s), f"fp{i}"))
+    gc.collect()
+    assert profiler.compiled_hlo_text(fps[0][:12]) is None
+    compile_cache._observed_steps.clear()
+
+
+def test_memory_analysis_is_public():
+    loss, feed = _conv_net()
+    m = _compile(pt.Executor(), feed, loss).memory_analysis()
+    assert m.argument_size_in_bytes > 0 and m.temp_size_in_bytes >= 0
+
+
+def test_sharded_steps_are_named_and_scoped():
+    from paddle_tpu.parallel import ShardedExecutor, mesh_for_axes
+    loss, feed = _conv_net()
+    exe = ShardedExecutor(mesh=mesh_for_axes({"dp": 2}), batch_axis="dp")
+    text = _compile(exe, feed, loss).hlo_text()
+    assert text.startswith("HloModule jit_pt_sharded_run,")
+    assert "pt.conv2d:" in text
+
+
+def test_auto_layout_step_is_named():
+    from paddle_tpu.core.executor import _AutoLayoutStep
+    step = _AutoLayoutStep(lambda f, s, t: (f, s), {}, label="run_steps")
+    assert step._named.__name__ == "pt_run_steps"
+
+
+@pytest.mark.parametrize("fetch,return_numpy,drained", [
+    (True, True, True), (True, False, False), (False, True, False)])
+def test_undrained_dispatch_stays_out_of_step_time(tmp_path, fetch,
+                                                   return_numpy, drained):
+    """A dispatch that materialized no fetch timed the enqueue: tagged
+    ``drained: false`` and kept out of ``executor/step_time_ms`` and
+    ``executor/examples_per_sec``, like a cold compile."""
+    import json
+
+    from paddle_tpu import flags
+    log = tmp_path / "run.jsonl"
+    flags.set_flag("metrics_log", str(log))
+    try:
+        loss, feed = _conv_net()
+        exe = pt.Executor(observe=True)
+        exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+        fetch_list = [loss] if fetch else []
+        exe.run(feed=feed, fetch_list=fetch_list)             # cold
+        exe.run(feed=feed, fetch_list=fetch_list,
+                return_numpy=return_numpy)                    # warm
+        snap = obs.registry().snapshot()
+        assert snap["executor/step_time_ms"]["count"] == int(drained)
+        assert bool(snap["executor/examples_per_sec"]["values"]) == drained
+        assert snap["executor/dispatches"]["value"] == 3
+        last = [json.loads(ln) for ln in log.read_text().splitlines()
+                if '"kind": "step"' in ln][-1]
+        assert last["drained"] is drained and not last["cold_compile"]
+        assert (last["step_ms"] is not None) == drained
+    finally:
+        flags.set_flag("metrics_log", "")
