@@ -121,6 +121,11 @@ class CompileStats:
                                     implementation an op with several
                                     lowered (e.g. flash_attention:pallas
                                     vs :reference), one bump per trace
+      rnn_ops_in_scan /           — trace-time loop fission of ``rnn`` step
+      rnn_ops_hoisted               blocks (ops/control_flow_ops.py _rnn): ops
+                                    it left under lax.scan, and ops it ran
+                                    once after the scan instead; both bumped
+                                    per lowered rnn op
       traces                      — jit traces of step functions (a trace
                                     runs the Python interpreter over the
                                     whole Program; the retrace detector

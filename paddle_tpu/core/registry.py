@@ -25,11 +25,12 @@ Implementation signature::
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, NamedTuple, Optional
 
 _OP_IMPLS: Dict[str, Callable] = {}
 _SHAPE_FNS: Dict[str, Callable] = {}
 _SHARD_FNS: Dict[str, Callable] = {}
+_ROWWISE_FNS: Dict[str, Callable] = {}
 _TUNABLES: Dict[str, dict] = {}
 
 
@@ -136,6 +137,53 @@ def register_shard_fn(*names: str):
         return fn
 
     return deco
+
+
+class Operand(NamedTuple):
+    """One input of an op as a row-wise rule sees it: ``rows`` is whether
+    its leading axis is the rows the question is about; ``shape`` is what
+    is known of its shape (always a rank; a dim may be -1)."""
+    rows: bool
+    shape: tuple
+
+
+def register_rowwise(*names: str):
+    """Declare when an op is ROW-WISE over its leading axis: row ``i`` of
+    every output is a function of row ``i`` of the operands that have rows
+    and of the operands that have none (weights, biases), so the op may be
+    given any number of rows at once — the rows of every step of a
+    recurrence, for one (``ops/control_flow_ops.py _rnn`` moves such ops
+    out of its scan).  Declaring an op also states that its lowering is a
+    pure function of its inputs and attrs: no random draw (``ctx.rng``),
+    no side effect, no ``sub_block``, no ``@LEN`` companion read or
+    written.  An op without a rule is never moved.
+
+    A rule has the signature ``fn(attrs, ins) -> bool`` where ``ins`` maps
+    input slot -> list of :class:`Operand`; it answers for these attrs and
+    these operands, and says False when it cannot tell.
+    """
+
+    def deco(fn):
+        for n in names:
+            if n in _ROWWISE_FNS:
+                raise ValueError(f"row-wise rule for op {n!r} registered "
+                                 f"twice")
+            _ROWWISE_FNS[n] = fn
+        return fn
+
+    return deco
+
+
+def get_rowwise_fn(name: str) -> Optional[Callable]:
+    return _ROWWISE_FNS.get(name)
+
+
+def rows_of_one_rank(attrs, ins):
+    """The row-wise rule of an op that is elementwise over all its inputs:
+    each has rows, and all have one rank (none is broadcast along them)."""
+    operands = [o for slot in ins.values() for o in slot]
+    return all(o.rows for o in operands) and \
+        len({len(o.shape) for o in operands}) == 1
 
 
 def register_tunable(name: str, *, side: str, space: Dict[str, tuple],

@@ -3,8 +3,11 @@ v1 demo seqToseq; generation analog of RecurrentGradientMachine.generateSequence
 gserver/gradientmachines/RecurrentGradientMachine.h:307-309).
 
 Training builds an encoder (GRU over padded+length batches) and a StaticRNN
-decoder computing dot-product attention per step — the whole thing traces to
-one lax.scan that XLA pipelines on the MXU.
+decoder computing dot-product attention per step.  The step block is written
+as the reference writes it, output layer included; the ``rnn`` lowering
+scans what the decoder state depends on (attention, gates, the GRU cell) and
+runs the dictionary head once on all steps' states
+(ops/control_flow_ops.py ``_rnn``).
 
 Inference (``seq2seq_infer``) reuses the SAME parameter names inside a
 BeamSearchDecoder (layers/generation.py) so a trained scope decodes directly

@@ -148,3 +148,18 @@ register_shard_fn(
     "hard_shrink", "soft_shrink", "softshrink", "thresholded_relu",
     "hard_sigmoid", "prelu",
 )(shard_same_as("X"))
+
+# ---------------------------------------------------------------------------
+# Row-wise rules (core.registry.register_rowwise): a unary elementwise op
+# maps row i to row i.  prelu is left out: its Alpha is a second operand.
+# ---------------------------------------------------------------------------
+from ..core.registry import register_rowwise, rows_of_one_rank  # noqa: E402
+
+register_rowwise(
+    "sigmoid", "logsigmoid", "tanh", "relu", "relu6", "abs", "sqrt",
+    "rsqrt", "square", "exp", "log", "floor", "ceil", "round",
+    "reciprocal", "softsign", "softplus", "softrelu", "sin", "cos",
+    "gelu", "silu", "swish", "brelu", "leaky_relu", "elu", "stanh",
+    "hard_shrink", "soft_shrink", "softshrink", "thresholded_relu",
+    "hard_sigmoid",
+)(rows_of_one_rank)

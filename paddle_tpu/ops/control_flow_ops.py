@@ -20,7 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.registry import register_op
+from ..core import compile_cache
+from ..core.executor import run_op
+from ..core.program import _sub_block_indices
+from ..core.registry import Operand, get_rowwise_fn, register_op
 
 
 @register_op("while")
@@ -181,15 +184,101 @@ def _rnn_memory_helper(ctx, ins, attrs):
     return {"Out": ins["X"][0]}
 
 
+def _reads(program, op):
+    """Every name ``op`` may read: its inputs and, for an op with
+    sub-blocks, what the ops of those read (they see this env)."""
+    names = list(op.input_names)
+    for idx in _sub_block_indices(op):
+        for sub in program.blocks[idx].ops:
+            names += _reads(program, sub)
+    return names
+
+
+def _split_step_block(program, block, batch, per_step, mem_update_names,
+                      env):
+    """Loop fission of an rnn step block at its recurrence.  Returns
+    ``(tail, frontier)``: the positions of the ops that need not run
+    inside the scan, and the per-step names they read from the rest.
+
+    The CORE, which stays, is every op a memory update depends on, every op
+    that may not move, and what those read, transitively.  An op may move
+    when its row-wise rule (``core.registry.register_rowwise``) holds for
+    its attrs and operands — so that it can be given the rows of all steps
+    at once — and the names it reads and writes are bound once, the written
+    ones in this block.  ``per_step`` maps the step-input and memory names
+    to their shapes; the other values of a step have the shape their var
+    declares, and rows if that is [``batch``, ...]; anything else is read
+    from ``env`` and has no rows.
+    """
+    ops = block.ops
+    writers = {}                  # name -> positions of the ops writing it
+    for i, op in enumerate(ops):
+        for n in op.output_names:
+            writers.setdefault(n, []).append(i)
+
+    def operand(n):
+        """``n`` as a rule sees it; shape None where no rule may answer."""
+        if n in per_step or n in writers:
+            # a value of the step: its rows are the batch's, or no rule
+            shape = per_step[n] if n in per_step \
+                else getattr(block.vars.get(n), "shape", None)
+            rows = shape and shape[0] in (-1, batch)
+            return Operand(True, tuple(shape) if rows else None)
+        return Operand(False, getattr(env.get(n), "shape", None)
+                       if env.has(n) else None)
+
+    def may_move(i, op):
+        rule = get_rowwise_fn(op.type)
+        # (a block built under pipeline_stage is lowered by its stage)
+        if rule is None or "pipeline_stage" in op.attrs:
+            return False
+        # a name bound twice holds the order its readers and writers have
+        if any(n in per_step or n not in block.vars or len(writers[n]) > 1
+               for n in op.output_names):
+            return False
+        if any(n in per_step or len(writers[n]) > 1 or writers[n][0] >= i
+               for n in op.input_names if n in writers):
+            return False
+        ins = {slot: [operand(n) for n in names]
+               for slot, names in op.inputs.items() if names}
+        return all(o.shape is not None for os in ins.values() for o in os) \
+            and rule(op.attrs, ins)
+
+    core = {i for i, op in enumerate(ops) if not may_move(i, op)}
+    core.update(i for n in mem_update_names if n for i in writers.get(n, ()))
+    todo = list(core)
+    while todo:
+        for n in _reads(program, ops[todo.pop()]):
+            for i in writers.get(n, ()):
+                if i not in core:
+                    core.add(i)
+                    todo.append(i)
+    tail = set(range(len(ops))) - core
+    moved = {n for i in tail for n in ops[i].output_names}
+    frontier = list(dict.fromkeys(
+        n for i in sorted(tail) for n in ops[i].input_names
+        if (n in per_step or n in writers) and n not in moved))
+    return tail, frontier
+
+
 @register_op("rnn")
 def _rnn(ctx, ins, attrs):
-    """StaticRNN/DynamicRNN lowering: run the step sub-block under lax.scan.
+    """StaticRNN/DynamicRNN lowering: the recurrence of the step sub-block
+    under lax.scan, the rest of it once on all steps.
 
     The reference RecurrentOp runs the sub-block once per step with a nested
     Executor and StepScopes (recurrent_op.cc:222-335); here the step block is
-    traced ONCE and scanned — XLA pipelines the loop and the recurrence is
+    traced ONCE — XLA pipelines the loop and the recurrence is
     differentiable (the reference needed a hand-written RecurrentGradOp).
     Finished sequences freeze their memories via the length mask.
+
+    Only what the memories depend on has to run step after step.  The ops
+    of the step block that merely turn a step's values into its outputs (an
+    output layer over the dictionary, say) are split off
+    (``_split_step_block``): the scan stacks the few values they read, and
+    they run once after it with time folded into the rows, [B*T, ...], so
+    nothing as wide as an output is stacked, sliced or re-laid-out per
+    step.  A block with no such ops lowers whole, as it always did.
     """
     sub_idx = attrs["sub_block"]
     step_in_names = attrs["step_inputs"]          # sub-block per-step vars
@@ -227,6 +316,43 @@ def _rnn(ctx, ins, attrs):
             nested_l2.append(l2)
             nested_scan.append(jnp.swapaxes(l2, 0, 1))   # [S, B]
 
+    block = ctx.block(sub_idx)
+    per_step = {nm: (B,) + s.shape[2:] for nm, s in zip(step_in_names, seqs)}
+    per_step.update((nm, v.shape) for nm, v in zip(mem_names, inits))
+    tail, frontier = set(), []
+    if not nested_names:          # step-local @LEN companions: left whole
+        tail, frontier = _split_step_block(ctx.program, block, B, per_step,
+                                           mem_update_names, env)
+    moved = {n for i in tail for n in block.ops[i].output_names}
+    # a step input is folded from its sequence; the rest the scan stacks
+    stacked = [n for n in frontier if n not in step_in_names]
+    hoist = []                    # whether the tail moved: ``step`` says
+
+    def run(part, benv):
+        """The ops of the block that are (``part``) or are not in the
+        tail, each under the PRNG number it has in the whole block
+        (``ctx.rng`` folds in ``_op_uid``, which counts ``run_op`` calls).
+        Only the core's pass may advance the count: no tail op draws."""
+        uid = ctx._op_uid
+        for i, op in enumerate(block.ops):
+            if (i in tail) == part:
+                run_op(op, benv, ctx)
+            else:
+                ctx._op_uid += 1
+        if part:
+            ctx._op_uid = uid
+
+    def scanned():
+        return [nm for nm in out_step_names
+                if not (hoist[0] and nm in moved)]
+
+    def rows_as_declared(nm, v):
+        return nm in per_step or (
+            v.ndim == len(block.vars[nm].shape) and v.shape[0] == B)
+
+    def masked(v, mask):
+        return v * mask.reshape(mask.shape + (1,) * (v.ndim - mask.ndim))
+
     def step(carry, inp):
         mems = carry
         m_t = inp[0]
@@ -240,20 +366,50 @@ def _rnn(ctx, ins, attrs):
             benv.local[nm + "@LEN"] = l2
         for nm, v in zip(mem_names, mems):
             benv.local[nm] = v
-        ctx.interpret_block(sub_idx, benv)
+        if not tail:
+            ctx.interpret_block(sub_idx, benv)
+        else:
+            run(False, benv)
+        # the rules answered for the shapes the vars declare: where a
+        # lowering gave another, the tail runs in here after all
+        hoist[:] = [bool(tail) and all(rows_as_declared(n, benv.get(n))
+                                       for n in stacked)]
+        if tail and not hoist[0]:
+            run(True, benv)
         new_mems = tuple(
             jnp.where(m_t.reshape((B,) + (1,) * (old.ndim - 1)) > 0,
                       benv.get(un), old) if un else old
             for un, old in zip(mem_update_names, mems))
-        outs = tuple(benv.get(nm) * m_t.reshape((B,) + (1,) * (benv.get(nm).ndim - 1))
-                     for nm in out_step_names)
-        return new_mems, outs
+        return new_mems, ([masked(benv.get(nm), m_t) for nm in scanned()],
+                          [benv.get(nm) for nm in stacked] if hoist[0]
+                          else [])
 
     init_mems = tuple(inits)
-    _, outs = lax.scan(step, init_mems,
-                       tuple([step_mask] + xs + nested_scan))
-    results = [jnp.swapaxes(o, 0, 1) for o in outs]
-    sub_vars = ctx.block(sub_idx).vars
+    _, (outs, stacks) = lax.scan(step, init_mems,
+                                 tuple([step_mask] + xs + nested_scan))
+    outs = {nm: jnp.swapaxes(o, 0, 1) for nm, o in zip(scanned(), outs)}
+    if hoist[0]:
+        # the tail, once, with time folded into the rows: [T,B,...] values
+        # go in as [B*T,...], and what comes out is [B,T,...] as it stands
+        tenv = ctx.child_env(sub_idx, env)
+        for nm, v in zip(stacked, stacks):
+            v = jnp.swapaxes(v, 0, 1)
+            tenv.local[nm] = v.reshape((B * T,) + v.shape[2:])
+        for nm, s in zip(step_in_names, seqs):
+            if nm in frontier:
+                tenv.local[nm] = s.reshape((B * T,) + s.shape[2:])
+        run(True, tenv)
+        for nm in out_step_names:
+            if nm in moved:
+                v = tenv.get(nm)
+                outs[nm] = masked(v.reshape((B, T) + v.shape[1:]),
+                                  step_mask.T)
+    results = [outs[nm] for nm in out_step_names]
+    n_hoisted = len(tail) if hoist[0] else 0
+    compile_cache.stats().bump("rnn_ops_hoisted", n_hoisted)
+    compile_cache.stats().bump("rnn_ops_in_scan",
+                               len(block.ops) - n_hoisted)
+    sub_vars = block.vars
     for nm, step_nm in zip(ctx.op.outputs.get("Outputs", []),
                            out_step_names):
         ctx.set_len(nm, lens)

@@ -494,3 +494,35 @@ def _sum_shard(op, ins, attrs):
     for x in ins.get("X", []):
         spec = merge_specs(spec, x.spec, "sum operands")
     return {} if spec is None else {"Out": spec}
+
+
+# ---------------------------------------------------------------------------
+# Row-wise rules (core.registry.register_rowwise).  The reductions, matmul
+# (its Y may be batched) and clip_by_norm (a norm over every row) have none.
+# ---------------------------------------------------------------------------
+from ..core.registry import register_rowwise, rows_of_one_rank  # noqa: E402
+
+register_rowwise("scale", "cast", "clip", "sign", "sum")(rows_of_one_rank)
+
+
+@register_rowwise(
+    "elementwise_add", "elementwise_sub", "elementwise_mul",
+    "elementwise_div", "elementwise_pow", "elementwise_max",
+    "elementwise_min", "elementwise_mod")
+def _elementwise_rowwise(attrs, ins):
+    """X's rows against a Y of the same rank that has rows too, or against
+    a Y without rows that ``_bcast`` lines up with X's trailing axes (a
+    bias): axis 0 would line it up with the rows."""
+    x, y = ins["X"][0], ins["Y"][0]
+    if not x.rows:
+        return False
+    if y.rows:
+        return len(y.shape) == len(x.shape)
+    return len(y.shape) < len(x.shape) and attrs.get("axis", -1) != 0
+
+
+@register_rowwise("mul")
+def _mul_rowwise(attrs, ins):
+    """The FC product: X flattened to [rows, K] times a weight."""
+    return ins["X"][0].rows and not ins["Y"][0].rows \
+        and attrs.get("x_num_col_dims", 1) == 1

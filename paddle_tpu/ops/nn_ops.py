@@ -791,3 +791,18 @@ def _softmax_ce_shard(op, ins, attrs):
     if logits.spec is None:
         return {}
     return {"Softmax": logits.spec, "Loss": (logits.entry(0), None)}
+
+
+# ---------------------------------------------------------------------------
+# Row-wise rules (core.registry.register_rowwise): a softmax along any axis
+# but the leading one.  batch_norm (statistics over the rows) and dropout
+# (a random draw) have none.
+# ---------------------------------------------------------------------------
+from ..core.registry import register_rowwise  # noqa: E402
+
+
+@register_rowwise("softmax", "log_softmax")
+def _softmax_rowwise(attrs, ins):
+    x = ins["X"][0]
+    rank = len(x.shape)
+    return x.rows and rank >= 2 and attrs.get("axis", -1) % rank != 0

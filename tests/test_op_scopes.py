@@ -100,6 +100,41 @@ def test_step_block_ops_nest_under_their_rnn():
         .ops[int(pos)].type == "mul"
 
 
+def test_op_moved_out_of_the_scan_keeps_its_scopes():
+    """The ``rnn`` lowering runs a step block's output layer once, after
+    the scan: its instructions still read ``pt.rnn/pt.mul``, forward and
+    backward, and none of them is in the rnn's ``while`` body, where the
+    recurrence's own ``mul`` stays."""
+    seq = layers.data("seq", shape=[4], dtype="float32", lod_level=1)
+    rnn = layers.control_flow.StaticRNN()
+    with rnn.step():
+        x_t = rnn.step_input(seq)
+        acc = rnn.memory(shape=[4])
+        new = layers.elementwise_add(acc, layers.fc(x_t, size=4))
+        rnn.update_memory(acc, new)
+        rnn.step_output(layers.fc(new, size=7, act="softmax"))
+    loss = layers.mean(layers.square(rnn()))
+    pt.optimizer.SGD(0.1).minimize(loss)
+    feed = {"seq": np.random.RandomState(0).rand(2, 3, 4).astype("float32"),
+            "seq@LEN": np.array([3, 2])}
+    names = _op_names(_compile(pt.Executor(), feed, loss).hlo_text())
+    step_ops = pt.default_main_program().blocks[1].ops
+    inner, head = [i for i, op in enumerate(step_ops) if op.type == "mul"]
+    assert compile_cache.stats().snapshot()["rnn_ops_hoisted"] == 3
+
+    def of(pos):
+        return [n for n in names if f"/pt.mul:1.{pos}/" in n]
+
+    assert any(re.search(r"/jvp\(pt\.rnn:0\.\d+\)/pt\.mul:1\.\d+/", n)
+               for n in of(head))
+    assert any(re.search(
+        r"/transpose\(jvp\(pt\.rnn:0\.\d+\)\)/pt\.mul:1\.\d+/", n)
+        for n in of(head))
+    assert not any("while" in n for n in of(head))
+    assert of(inner) and all(
+        re.search(r"pt\.rnn:0\.\d+\)+/while/body/", n) for n in of(inner))
+
+
 def test_executor_emitted_work_has_scopes_of_its_own():
     loss, feed = _conv_net()
     cp = _compile(pt.Executor(amp=True), feed, loss, num_steps=3)

@@ -126,7 +126,7 @@ def _registered_names(call_name: str):
 
 def test_no_duplicate_register_op_names():
     for call in ("register_op", "register_shape_fn", "register_shard_fn",
-                 "register_tunable"):
+                 "register_rowwise", "register_tunable"):
         by_name = collections.defaultdict(list)
         for name, rel, lineno in _registered_names(call):
             by_name[name].append(f"{rel}:{lineno}")
