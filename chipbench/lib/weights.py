@@ -22,10 +22,14 @@ def _is_weight(shape) -> bool:
 
 
 def _xavier_limit(shape) -> float:
-    """Glorot-uniform limit; filters are [out, in, kh, kw], matrices
-    [in, out] (the layers API's own default initializer, re-derived)."""
-    if len(shape) == 2:
-        fan_in, fan_out = shape
+    """Glorot-uniform limit (the layers API's own default initializer,
+    re-derived), by rank: a matrix is [in, out]; a rank-3 weight is a stack
+    of matrices [n, in, out], each drawn as the matrix it is used as
+    (``layers.moe``'s experts [num_experts, D, hidden] and [num_experts,
+    hidden, D], ``layers.bilinear_tensor_product``'s [size, dx, dy]); rank
+    4 and more is a filter [out, in, k...]."""
+    if len(shape) <= 3:
+        fan_in, fan_out = shape[-2:]
     else:
         receptive = math.prod(shape[2:])
         fan_in, fan_out = shape[1] * receptive, shape[0] * receptive

@@ -7,6 +7,8 @@ import pytest
 
 from conftest import BENCH, ROOT, all_cells, load_json
 
+from chipbench.lib.contract import reduced_problems
+
 NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
 LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 PLAIN_PATH = re.compile(r"^[A-Za-z0-9_./-]+$")
@@ -68,7 +70,7 @@ def test_configs_resolve():
         data = load_json(ROOT, c["file"])
         assert data["name"] == c["name"]
         assert data["source"] == c["source"]
-        assert data["reduced"] == c["reduced"] == []
+        assert reduced_problems(c, data) == []
         assert os.path.isfile(os.path.join(
             BENCH, "configs", f"{c['name']}.py"))
 
