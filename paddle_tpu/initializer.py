@@ -73,7 +73,11 @@ def _fan_in_out(var):
     receptive = 1
     for s in shape[2:]:
         receptive *= s
-    # conv weights are [out, in, kh, kw]; fc weights are [in, out]
+    # conv weights are [out, in, kh, kw]; fc weights are [in, out].  A rank-3
+    # parameter reads as a filter [out, in, k] here; a layer whose rank-3
+    # parameter is a STACK of matrices [n, in, out] (layers.moe's experts,
+    # bilinear_tensor_product) states the fans of one matrix through
+    # XavierInitializer(fan_in=, fan_out=).
     if len(shape) == 2:
         return shape[0], shape[1]
     return shape[1] * receptive, shape[0] * receptive

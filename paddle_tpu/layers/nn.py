@@ -14,6 +14,7 @@ from ..param_attr import ParamAttr
 __all__ = [
     "fc", "embedding", "dynamic_lstm", "dynamic_gru", "gru_unit", "lstm_unit",
     "conv2d", "conv2d_transpose", "pool2d", "batch_norm", "layer_norm",
+    "rms_norm", "rope",
     "dropout", "softmax", "cross_entropy", "softmax_with_cross_entropy",
     "sequence_conv", "sequence_pool", "sequence_softmax", "sequence_expand",
     "sequence_first_step", "sequence_last_step", "sequence_concat",
@@ -445,6 +446,35 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
                      attrs={"epsilon": epsilon,
                             "begin_norm_axis": begin_norm_axis})
     return helper.append_activation(out, act)
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """y = x * rsqrt(mean(x^2, -1) + epsilon) * scale over the last axis;
+    scale [D] starts at 1 (ops/nn_ops.py ``rms_norm``)."""
+    from ..initializer import ConstantInitializer
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        ParamAttr._to_attr(param_attr) or ParamAttr(),
+        shape=[input.shape[-1]], dtype=input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(
+        input.dtype, input.shape, lod_level=input.lod_level)
+    helper.append_op(type="rms_norm",
+                     inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+    if input.lod_level:
+        _copy_len(helper, input, out)
+    return out
+
+
+def rope(x, theta=10000.0, name=None):
+    """Rotary position embedding of x [B, T, H, D] at positions 0..T-1,
+    half-split pairing (ops/nn_ops.py ``rope``)."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    helper.append_op(type="rope", inputs={"X": [x]}, outputs={"Out": [out]},
+                     attrs={"theta": float(theta)})
+    return out
 
 
 def _prod(t):
@@ -892,9 +922,12 @@ def bilinear_tensor_product(x, y, size, act=None, param_attr=None,
     """bilinear_tensor_product_op.cc: out_k = x W_k y^T + b."""
     helper = LayerHelper("bilinear_tensor_product", param_attr=param_attr,
                          bias_attr=bias_attr, act=act, name=name)
+    from ..initializer import XavierInitializer
     dx, dy = x.shape[-1], y.shape[-1]
-    w = helper.create_parameter(param_attr, shape=[size, dx, dy],
-                                dtype=x.dtype)
+    # a stack of matrices [size, dx, dy], each drawn as the matrix it is
+    w = helper.create_parameter(
+        param_attr, shape=[size, dx, dy], dtype=x.dtype,
+        default_initializer=XavierInitializer(fan_in=dx, fan_out=dy))
     out = helper.create_variable_for_type_inference(
         x.dtype, (x.shape[0], size))
     ins = {"X": [x], "Y": [y], "Weight": [w]}
@@ -1443,18 +1476,28 @@ def sampling_id(x, name=None):
 
 
 def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
-        act="relu", gate_attr=None, param_attr=None, name=None):
-    """Mixture-of-Experts FFN (GShard/Switch style) — the Program-level
-    expert-parallel layer (ops/moe_ops.py).
+        act="relu", gated=False, gate_attr=None, param_attr=None, name=None):
+    """Mixture-of-Experts FFN — the Program-level expert layer
+    (ops/moe_ops.py), in one of two lowerings.
 
-    input: [B, D] or [B, T, D].  Expert weights are created stacked
-    [E, D, H]/[E, H, D] with ``sharding=('ep', None, None)``, so a
+    input: [B, D] or [B, T, D].  With a ``capacity_factor`` (GShard/Switch
+    style) each expert takes at most capacity_factor * top_k * tokens /
+    num_experts tokens and drops the rest; expert weights are created
+    stacked [E, D, H]/[E, H, D] with ``sharding=('ep', None, None)``, so a
     ShardedExecutor over a mesh with an 'ep' axis physically distributes
     the experts and GSPMD inserts the token all-to-all; a plain Executor
-    runs the identical math on one device.  Returns (out, aux_loss) —
-    add ``aux_weight * aux_loss`` to the training loss to keep experts
-    load-balanced.
+    runs the identical math on one device.  With ``capacity_factor=None``
+    no token is ever dropped: the assignments are sorted by expert and the
+    experts run as grouped products over them, on one device (a mesh with
+    ep > 1 is refused).  ``gated`` adds a second up-projection stack,
+    out = (act(x Wg) * (x Wu)) Wd, which the dropless lowering alone runs.
+
+    Returns (out, aux_loss, z_loss): add ``aux_weight * aux_loss`` to the
+    training loss to keep experts load-balanced (E * sum_e f_e P_e) and
+    ``z_weight * z_loss`` (mean squared logsumexp of the router's logits)
+    to keep them small.
     """
+    from ..initializer import XavierInitializer
     helper = LayerHelper("moe", param_attr=param_attr, name=name)
     D = input.shape[-1]
     gate_w = helper.create_parameter(
@@ -1463,24 +1506,37 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
     pa = _copy.copy(param_attr) if param_attr is not None else ParamAttr()
     if getattr(pa, "sharding", None) is None:
         pa.sharding = ("ep", None, None)
-    w1 = helper.create_parameter(
-        pa, shape=[num_experts, D, expert_hidden], dtype=input.dtype)
-    pa2 = ParamAttr(sharding=pa.sharding)
-    w2 = helper.create_parameter(
-        pa2, shape=[num_experts, expert_hidden, D], dtype=input.dtype)
+
+    def stack(attr, fan_in, fan_out):
+        # a stack of matrices [E, in, out], each drawn as the matrix it is
+        return helper.create_parameter(
+            attr, shape=[num_experts, fan_in, fan_out], dtype=input.dtype,
+            default_initializer=XavierInitializer(fan_in=fan_in,
+                                                  fan_out=fan_out))
+
+    def named(suffix):
+        # one attr, several stacks: <name>_up, <name>_down, <name>_gate
+        attr = _copy.copy(pa)
+        attr.name = pa.name and f"{pa.name}_{suffix}"
+        return attr
+
+    w1 = stack(named("up"), D, expert_hidden)
+    w2 = stack(named("down"), expert_hidden, D)
+    ins = {"X": [input], "GateW": [gate_w], "W1": [w1], "W2": [w2]}
+    if gated:
+        ins["WGate"] = [stack(named("gate"), D, expert_hidden)]
     out = helper.create_variable_for_type_inference(
         input.dtype, input.shape, lod_level=input.lod_level)
     aux = helper.create_variable_for_type_inference("float32", ())
-    helper.append_op(type="moe",
-                     inputs={"X": [input], "GateW": [gate_w],
-                             "W1": [w1], "W2": [w2]},
-                     outputs={"Out": [out], "AuxLoss": [aux]},
+    z = helper.create_variable_for_type_inference("float32", ())
+    helper.append_op(type="moe", inputs=ins,
+                     outputs={"Out": [out], "AuxLoss": [aux], "ZLoss": [z]},
                      attrs={"top_k": top_k,
                             "capacity_factor": capacity_factor,
                             "activation": act})
     if input.lod_level:
         _copy_len(helper, input, out)
-    return out, aux
+    return out, aux, z
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,16 @@ each way (exactly how GShard itself drove the XLA partitioner).  On a
 single device the same graph runs constraint-free with identical math —
 which is what the equivalence test asserts.
 
+With ``capacity_factor`` None the op takes the DROPLESS lowering instead
+(``_dropless``): no token is ever dropped and nothing has a capacity.  The
+assignments are sorted by expert, their rows gathered, the experts run as
+grouped products over the sorted rows (``ops/pallas_kernels.py
+grouped_matmul``) and the results are weighted and gathered back; work and
+memory go with the N * top_k routed rows.  It is the lowering of today's
+sparse-expert decoders (top-8 of 64 and the like), where a [T, E, C]
+dispatch tensor cannot be held.  Which of the two ran is counted at trace
+time as ``route/moe:{dropless,capacity}`` in ``profiler.compile_stats()``.
+
 Reference capability frame: the reference never shipped MoE; nearest
 ancestors are per-layer device placement (ParallelNeuralNetwork.cpp) and
 the sparse-update machinery (SelectedRows).  This is capability-forward
@@ -23,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..core import compile_cache
 from ..core.registry import register_op
 
 _ACTS = {
@@ -31,7 +42,101 @@ _ACTS = {
     "tanh": jnp.tanh,
     "sigmoid": jax.nn.sigmoid,
     "swish": jax.nn.swish,
+    "silu": jax.nn.silu,
 }
+
+# rows of one grouped-product tile (a sublane multiple): every expert's rows
+# are padded to whole tiles, half a tile on average, so small tiles waste
+# little; 128 is the MXU's own edge on the v5e
+ROW_TILE = 128
+
+
+@jax.custom_vjp
+def _gather_rows(x, index, readers):
+    """``x[index]`` (zeros where ``index`` is past the end).  ``readers``
+    [rows of x, r] lists, for each row of ``x``, the output rows that read
+    it (past the end: none), so the gradient is a gather and a sum too: no
+    scatter-add, the same bits every run."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+def _gather_rows_fwd(x, index, readers):
+    return _gather_rows(x, index, readers), readers
+
+
+def _gather_rows_bwd(readers, g):
+    return (jnp.take(g, readers, axis=0, mode="fill", fill_value=0)
+            .sum(axis=1), None, None)
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act):
+    """The sorted lowering on rows ``xt`` [N, D]: (out [N, D], aux, z).
+
+    route: router product and softmax in float32 at HIGHEST precision
+    (the choice of experts is discontinuous in the logits), the ``top_k``
+    largest probabilities as weights, NOT renormalized;
+    aux = E * sum_e f_e P_e with f_e the share of the N tokens' assignments
+    that expert e got (sum_e f_e = top_k; no gradient) and P_e its mean
+    probability; z = mean_t logsumexp(logits_t)^2.
+    dispatch: a stable sort of the N * top_k assignments by expert; each
+    expert's rows laid out in whole tiles of ``ROW_TILE`` (at least one),
+    padding rows zero.  experts: act(x Wg) * (x Wu) through Wd (no Wg:
+    act(x Wu) Wd) as grouped products.  combine: every assignment's row
+    gathered back, weighted, summed over the token's ``top_k``.
+    """
+    from .pallas_kernels import grouped_matmul
+
+    n, _ = xt.shape
+    experts = gate_w.shape[-1]
+    assignments = n * top_k
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(xt.astype(jnp.float32), gate_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weight, expert = lax.top_k(probs, top_k)              # [N, k]
+        count = jnp.bincount(expert.reshape(-1), length=experts)
+        aux = experts * jnp.sum(count.astype(jnp.float32) / n
+                                * jnp.mean(probs, axis=0))
+        z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    with jax.named_scope("moe.dispatch"):
+        tm = min(ROW_TILE, -(-assignments // experts // 8) * 8)
+        tiles = assignments // tm + experts          # the static worst case
+        order = jnp.argsort(expert.reshape(-1), stable=True)
+        first = jnp.cumsum(count) - count            # in the sorted order
+        used = jnp.maximum(-(-count // tm), 1)       # tiles of each expert
+        tile_end = jnp.cumsum(used)
+        num_tiles = tile_end[-1:]
+        tile_group = jnp.minimum(
+            jnp.searchsorted(tile_end, jnp.arange(tiles), side="right"),
+            experts - 1).astype(jnp.int32)
+        first_row = (tile_end - used) * tm           # in the tiled layout
+        row = jnp.arange(tiles * tm)
+        group = tile_group[row // tm]
+        offset = row - first_row[group]
+        held = (offset < count[group]) & (row // tm < num_tiles[0])
+        # the assignment (token * top_k + slot) a tiled row holds, and back
+        assignment = jnp.where(
+            held, order[jnp.minimum(first[group] + offset, assignments - 1)],
+            assignments)
+        sorted_at = jnp.argsort(order)               # assignment -> sorted
+        expert_flat = expert.reshape(-1)
+        row_of = (first_row[expert_flat] + sorted_at
+                  - first[expert_flat]).reshape(n, top_k)
+        rows = _gather_rows(xt, jnp.where(held, assignment // top_k, n),
+                            row_of)
+    with jax.named_scope("moe.experts"):
+        up = grouped_matmul(rows, w_up, tile_group, num_tiles)
+        hidden = act(up) if w_gate is None else \
+            act(grouped_matmul(rows, w_gate, tile_group, num_tiles)) * up
+        down = grouped_matmul(hidden, w_down, tile_group, num_tiles)
+    with jax.named_scope("moe.combine"):
+        picked = _gather_rows(down, row_of.reshape(-1), assignment[:, None])
+        out = jnp.sum(picked.reshape(n, top_k, -1)
+                      * weight[..., None].astype(picked.dtype), axis=1)
+    return out, aux, z
 
 
 @register_op("moe")
@@ -43,13 +148,32 @@ def _moe(ctx, ins, attrs):
     w1 = ins["W1"][0]          # [E, D, H], sharded P('ep', ...) on a mesh
     w2 = ins["W2"][0]          # [E, H, D]
     top_k = int(attrs.get("top_k", 2))
-    cap_f = float(attrs.get("capacity_factor", 1.25))
     act = _ACTS[attrs.get("activation", "relu")]
 
     shape = x.shape
     D = shape[-1]
     xt = x.reshape(-1, D)
     T, E = xt.shape[0], gate_w.shape[-1]
+    ep = ctx.mesh_axis_size("ep")
+
+    cap_f = attrs.get("capacity_factor", 1.25)
+    if cap_f is None:
+        if ep > 1:
+            raise NotImplementedError(
+                f"moe: the dropless lowering (capacity_factor=None) runs "
+                f"every expert on one device; this mesh has ep={ep}.  Give "
+                f"a capacity_factor for the expert-parallel dispatch.")
+        compile_cache.stats().bump("route/moe:dropless")
+        gated = ins.get("WGate")
+        out, aux, z = _dropless(xt, gate_w, gated[0] if gated else None,
+                                w1, w2, top_k, act)
+        return {"Out": out.reshape(shape).astype(x.dtype),
+                "AuxLoss": aux, "ZLoss": z}
+    if ins.get("WGate"):
+        raise NotImplementedError(
+            "moe: gated experts run in the dropless lowering only "
+            "(capacity_factor=None)")
+    compile_cache.stats().bump("route/moe:capacity")
 
     logits = xt @ gate_w
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(
@@ -57,8 +181,6 @@ def _moe(ctx, ins, attrs):
     capacity = max(1, int(cap_f * top_k * T / E))
     dispatch, combine = moe_dispatch(gates, capacity, top_k)
     aux = load_balancing_loss(gates, dispatch)
-
-    ep = ctx.mesh_axis_size("ep")
 
     def on_experts(a):
         if ep > 1:
@@ -70,8 +192,9 @@ def _moe(ctx, ins, attrs):
     h = act(jnp.einsum("ecd,edh->ech", expert_in, w1))
     out_e = on_experts(jnp.einsum("ech,ehd->ecd", h, w2))
     out = jnp.einsum("tec,ecd->td", combine, out_e)
+    z = jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) ** 2)
     return {"Out": out.reshape(shape),
-            "AuxLoss": aux.reshape(()).astype(jnp.float32)}
+            "AuxLoss": aux.reshape(()).astype(jnp.float32), "ZLoss": z}
 
 
 # ---------------------------------------------------------------------------
@@ -97,12 +220,18 @@ def _moe_shape(op, ins, attrs):
         raise ShapeError(
             f"moe: W1 expert count {w1.shape[0]} != GateW experts "
             f"{gate_w.shape[-1]}")
-    return {"Out": x, "AuxLoss": VarInfo((), "float32")}
+    gate = first(ins, "WGate")
+    if ins.get("WGate") and gate.shape is not None and \
+            w1.shape is not None and tuple(gate.shape) != tuple(w1.shape):
+        raise ShapeError(
+            f"moe: WGate {list(gate.shape)} != W1 {list(w1.shape)}")
+    return {"Out": x, "AuxLoss": VarInfo((), "float32"),
+            "ZLoss": VarInfo((), "float32")}
 
 
 # ---------------------------------------------------------------------------
 # Sharding-propagation rule (analysis.shard_prop): the fused MoE op is
-# token-preserving — Out rides X's sharding, the aux loss replicates.
+# token-preserving — Out rides X's sharding, the two losses replicate.
 # (Expert-parallel specs on W1/W2 partition the expert dim; the dispatch
 # all-to-all is GSPMD's to insert and the cost model's to charge.)
 # ---------------------------------------------------------------------------
@@ -115,4 +244,4 @@ def _moe_shard(op, ins, attrs):
     x = first_in(ins, "X")
     if x.spec is None:
         return {}
-    return {"Out": x.spec, "AuxLoss": ()}
+    return {"Out": x.spec, "AuxLoss": (), "ZLoss": ()}

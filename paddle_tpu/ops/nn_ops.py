@@ -328,6 +328,37 @@ def _layer_norm(ctx, ins, attrs):
             "Variance": var.reshape(x.shape[:begin])}
 
 
+@register_op("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """y = x * rsqrt(mean(x^2, -1) + epsilon) * scale, the statistics in
+    float32 whatever X's dtype."""
+    x = ins["X"][0]
+    x32 = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                    + attrs.get("epsilon", 1e-5))
+    y = x32 * inv * ins["Scale"][0].astype(jnp.float32)
+    return {"Y": y.astype(x.dtype)}
+
+
+@register_op("rope")
+def _rope(ctx, ins, attrs):
+    """Rotary positions on X [B, T, H, D] at positions 0..T-1, half-split
+    pairing (feature i turns with feature i + D/2):
+    angle[t, i] = t * theta^(-2i/D), i < D/2;
+    out = x * cos + concat(-x[D/2:], x[:D/2]) * sin, in float32."""
+    x = ins["X"][0]
+    t_len, dim = x.shape[1], x.shape[3]
+    half = dim // 2
+    inv_freq = float(attrs.get("theta", 10000.0)) ** (
+        -2.0 * jnp.arange(half, dtype=jnp.float32) / dim)
+    angle = jnp.arange(t_len, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angle = jnp.concatenate([angle, angle], axis=-1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return {"Out": (x32 * jnp.cos(angle)
+                    + turned * jnp.sin(angle)).astype(x.dtype)}
+
+
 @register_op("softmax")
 def _softmax(ctx, ins, attrs):
     return {"Out": jax.nn.softmax(ins["X"][0], axis=attrs.get("axis", -1))}
@@ -639,6 +670,27 @@ def _layer_norm_shape(op, ins, attrs):
     return res
 
 
+@register_shape_fn("rms_norm")
+def _rms_norm_shape(op, ins, attrs):
+    x, scale = first(ins, "X"), first(ins, "Scale")
+    if x.shape is not None and scale.shape is not None and \
+            not dim_ok(x.shape[-1], scale.shape[-1]):
+        raise ShapeError(
+            f"rms_norm: feature dim {x.shape[-1]} != Scale size "
+            f"{scale.shape[-1]}")
+    return {"Y": x}
+
+
+@register_shape_fn("rope")
+def _rope_shape(op, ins, attrs):
+    x = first(ins, "X")
+    if x.shape is not None and (len(x.shape) != 4 or (
+            x.shape[-1] >= 0 and x.shape[-1] % 2)):
+        raise ShapeError(
+            f"rope: X {list(x.shape)} is not [B, T, H, D] with an even D")
+    return {"Out": x}
+
+
 @register_shape_fn("cross_entropy")
 def _cross_entropy_shape(op, ins, attrs):
     x = first(ins, "X")
@@ -784,6 +836,12 @@ def _layer_norm_shard(op, ins, attrs):
     return res
 
 
+# rms_norm and rope keep X's layout dim for dim (rope reads its position
+# from the index along T, which a sharded T keeps global under GSPMD)
+register_shard_fn("rms_norm")(shard_same_as("X", out="Y"))
+register_shard_fn("rope")(shard_same_as("X"))
+
+
 @register_shard_fn("softmax_with_cross_entropy")
 def _softmax_ce_shard(op, ins, attrs):
     from ..analysis.shard_prop import first_in
@@ -795,8 +853,8 @@ def _softmax_ce_shard(op, ins, attrs):
 
 # ---------------------------------------------------------------------------
 # Row-wise rules (core.registry.register_rowwise): a softmax along any axis
-# but the leading one.  batch_norm (statistics over the rows) and dropout
-# (a random draw) have none.
+# but the leading one, rms_norm.  batch_norm (statistics over the rows),
+# dropout (a random draw) and rope (a row's position) have none.
 # ---------------------------------------------------------------------------
 from ..core.registry import register_rowwise  # noqa: E402
 
@@ -806,3 +864,11 @@ def _softmax_rowwise(attrs, ins):
     x = ins["X"][0]
     rank = len(x.shape)
     return x.rows and rank >= 2 and attrs.get("axis", -1) % rank != 0
+
+
+@register_rowwise("rms_norm")
+def _rms_norm_rowwise(attrs, ins):
+    """The statistics run along the last axis alone, the scale has no rows
+    (rope has no rule: a row's result depends on its position)."""
+    x = ins["X"][0]
+    return x.rows and len(x.shape) >= 2 and not ins["Scale"][0].rows

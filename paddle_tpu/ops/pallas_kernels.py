@@ -14,6 +14,10 @@ query blocks — so the [T, T] probability matrix is never materialized in
 either direction and O(T) memory holds for *training*, not just inference.
 On non-TPU backends the jnp reference runs instead (CPU tests exercise the
 kernels in interpret mode).
+
+``grouped_matmul`` is the product of rows sorted by group with each group's
+own matrix (the experts of the dropless ``moe`` lowering), forward and both
+gradients as kernels.
 """
 from __future__ import annotations
 
@@ -431,6 +435,191 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
         out = jnp.moveaxis(
             out.reshape(B, H, Tq_out, v.shape[-1]), 1, 2)
     return out
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the expert products of the dropless ``moe`` lowering)
+# ---------------------------------------------------------------------------
+# Rows sorted by group, every group padded to whole row tiles (at least
+# one), so a tile belongs to exactly one group and no store is masked:
+# ``tile_group[i]`` names tile i's group and ``num_tiles[0]`` how many
+# tiles are in use (the operands' length is the static worst case; tiles
+# past the count are skipped and read as zero).  One product is a plain
+# tiled matmul whose weight block is picked by ``tile_group``; the block
+# holds the whole contraction, stays in VMEM while consecutive tiles share
+# a group, and so is read from HBM once.
+#
+# Float32 operands are rounded to bfloat16 at the MXU and accumulated in
+# float32: one pass, what XLA's default precision does for every other
+# product of a program on the TPU.  Interpreted (any other backend) the
+# products are exact, as XLA's are there.
+# (on the v5e, the dropless lowering of 8 x 8192 rows through 64 experts of
+# [2048, 1024], forward and backward: 49.5 ms at these values and the
+# lowering's row tiles of 128; 53.3 with blocks of 1 << 20, 49.5 with
+# 1 << 22, 53.9 / 52.1 / 57.8 with tiles of 64 / 256 / 512; 75.2 with
+# lax.ragged_dot in the kernels' place.  PERF.md section 6, PR 27)
+GMM_BLOCK_ELEMS = 1 << 21        # a weight / gradient block: 8 MiB of f32
+GMM_VMEM_BYTES = 64 << 20        # scoped VMEM these kernels may take
+
+
+def _largest_tile(n, cap):
+    """The largest lane-aligned (128) divisor of ``n`` up to ``cap``; ``n``
+    itself where it fits or has none."""
+    if n <= cap:
+        return n
+    for t in range(cap - cap % 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def _mxu_dot(a, b, contract, interpret):
+    """The kernels' one product, float32 out.  Compiled: float32 operands
+    rounded to bfloat16, one MXU pass whatever ``jax_default_matmul_precision``
+    says (Mosaic has no multi-pass product of bfloat16 operands).
+    Interpreted: as XLA computes it on that backend."""
+    if not interpret:
+        a, b = (x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x
+                for x in (a, b))
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=None if interpret else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+
+
+def _tile_in_use(i, count):
+    """Row-tile index for a block spec: an unused tile (``i`` past the
+    count) re-reads the last one in use, which costs no copy."""
+    return jnp.minimum(i, count[0] - 1)
+
+
+def _gmm_call_params(interpret, *semantics):
+    if interpret:
+        return {"interpret": True}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=GMM_VMEM_BYTES)}
+
+
+def _gmm_kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref, *,
+                transpose_rhs, interpret):
+    i = pl.program_id(1)
+
+    @pl.when(i < count_ref[0])
+    def _compute():
+        out_ref[...] = _mxu_dot(
+            lhs_ref[...], rhs_ref[0], ((1,), (1 if transpose_rhs else 0,)),
+            interpret).astype(out_ref.dtype)
+
+    @pl.when(i >= count_ref[0])
+    def _unused():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _gmm(lhs, rhs, tile_group, num_tiles, transpose_rhs, interpret):
+    """out[r] = lhs[r] @ rhs[group of r's tile] (``rhs`` [G, K, N]), or
+    @ rhs[..].T with ``transpose_rhs`` (``rhs`` [G, N, K]).  Grid (column
+    blocks, row tiles), rows innermost, so the weight block changes only
+    where the group does."""
+    rows, k = lhs.shape
+    tm = rows // tile_group.shape[0]
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _largest_tile(n, max(128, GMM_BLOCK_ELEMS // k))
+
+    row = _tile_in_use
+    rhs_spec = pl.BlockSpec(
+        (1, tn, k), lambda j, i, group, count: (group[row(i, count)], j, 0)
+    ) if transpose_rhs else pl.BlockSpec(
+        (1, k, tn), lambda j, i, group, count: (group[row(i, count)], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
+                          interpret=interpret),
+        out_shape=_sds(lhs, (rows, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n // tn, rows // tm),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, i, group, count:
+                             (row(i, count), 0)),
+                rhs_spec],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, i, group, count: (i, j))),
+        **_gmm_call_params(interpret, "parallel", "arbitrary"),
+    )(tile_group, num_tiles, lhs, rhs)
+
+
+def _tgmm_kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref, *,
+                 interpret):
+    i = pl.program_id(2)
+    # (an unused tile repeats the last group: neither first nor computed)
+    first = jnp.logical_or(
+        i == 0, group_ref[i] != group_ref[jnp.maximum(i - 1, 0)])
+
+    @pl.when(first)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < count_ref[0])
+    def _compute():
+        out_ref[0] += _mxu_dot(lhs_ref[...], rhs_ref[...], ((0,), (0,)),
+                               interpret).astype(out_ref.dtype)
+
+
+def _tgmm(lhs, rhs, tile_group, num_tiles, groups, interpret):
+    """out[g] = lhs[rows of g].T @ rhs[rows of g]: [G, K, N] from [R, K] and
+    [R, N].  Grid (K blocks, N blocks, row tiles), rows innermost: a
+    group's output block stays in VMEM and accumulates over its tiles."""
+    rows, k = lhs.shape
+    n = rhs.shape[1]
+    tm = rows // tile_group.shape[0]
+    tk = _largest_tile(k, 1024)
+    tn = _largest_tile(n, max(128, GMM_BLOCK_ELEMS // tk))
+
+    row = _tile_in_use
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, interpret=interpret),
+        out_shape=_sds(lhs, (groups, k, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(k // tk, n // tn, rows // tm),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda a, b, i, group, count:
+                             (row(i, count), a)),
+                pl.BlockSpec((tm, tn), lambda a, b, i, group, count:
+                             (row(i, count), b))],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn), lambda a, b, i, group, count:
+                (group[row(i, count)], a, b))),
+        **_gmm_call_params(interpret, "parallel", "parallel", "arbitrary"),
+    )(tile_group, num_tiles, lhs, rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _grouped(lhs, rhs, tile_group, num_tiles, interpret):
+    return _gmm(lhs, rhs, tile_group, num_tiles, False, interpret)
+
+
+def _grouped_vjp_fwd(lhs, rhs, tile_group, num_tiles, interpret):
+    return (_gmm(lhs, rhs, tile_group, num_tiles, False, interpret),
+            (lhs, rhs, tile_group, num_tiles))
+
+
+def _grouped_vjp_bwd(interpret, res, g):
+    lhs, rhs, tile_group, num_tiles = res
+    return (_gmm(g, rhs, tile_group, num_tiles, True, interpret),
+            _tgmm(lhs, g, tile_group, num_tiles, rhs.shape[0], interpret),
+            None, None)
+
+
+_grouped.defvjp(_grouped_vjp_fwd, _grouped_vjp_bwd)
+
+
+def grouped_matmul(lhs, rhs, tile_group, num_tiles):
+    """``out[r] = lhs[r] @ rhs[tile_group[r // tm]]`` for rows laid out in
+    whole tiles per group (see above): ``lhs`` [R, K], ``rhs`` [G, K, N],
+    ``tile_group`` int32 [R / tm] non-decreasing with every group present,
+    ``num_tiles`` int32 [1].  Differentiable in ``lhs`` and ``rhs``.  The
+    Pallas kernels everywhere: compiled on the TPU, interpreted on any
+    other backend."""
+    return _grouped(lhs, rhs, tile_group, num_tiles,
+                    jax.default_backend() != "tpu")
 
 
 # ---------------------------------------------------------------------------

@@ -433,8 +433,6 @@ def test_cache_dir_from_environment_is_used_untouched(tmp_path):
     (JAX's own cache-hit event) with bit-identical losses."""
     d = str(tmp_path / "placed_from_outside")
     repo_cache = compile_cache.REPO_CACHE_DIR
-    before = sorted(os.listdir(repo_cache)) \
-        if os.path.isdir(repo_cache) else None
     # thresholds lowered through JAX's own env knobs so the tiny program's
     # sub-second compiles land on disk at all
     knobs = {"JAX_COMPILATION_CACHE_DIR": d,
@@ -449,9 +447,10 @@ def test_cache_dir_from_environment_is_used_untouched(tmp_path):
     assert warm["dir_updates"] == 0
     assert warm["counters"]["jax_cache_hits"] > 0
     assert warm["losses"] == cold["losses"]
-    after = sorted(os.listdir(repo_cache)) \
-        if os.path.isdir(repo_cache) else None
-    assert after == before
+    # (other workers' tests fill the shared <checkout>/.jax_cache meanwhile:
+    # what is asked is that none of THIS cache's entries landed there)
+    if os.path.isdir(repo_cache):
+        assert not set(os.listdir(d)) & set(os.listdir(repo_cache))
     assert not _ptxc_files(d, repo_cache)
 
 

@@ -135,6 +135,28 @@ def test_op_moved_out_of_the_scan_keeps_its_scopes():
         re.search(r"pt\.rnn:0\.\d+\)+/while/body/", n) for n in of(inner))
 
 
+def test_dropless_moe_names_its_four_stages_inside_its_op_scope():
+    """``moe.route`` / ``moe.dispatch`` / ``moe.experts`` / ``moe.combine``
+    sit inside ``pt.moe:<b>.<p>``, forward and backward; they do not start
+    with ``pt.``, so the op stays the innermost owner."""
+    x = layers.data("x", shape=[6, 8], dtype="float32")
+    out, aux, z = layers.moe(layers.fc(x, size=8, num_flatten_dims=2),
+                             num_experts=4, expert_hidden=5, top_k=2,
+                             capacity_factor=None, act="silu", gated=True)
+    loss = layers.elementwise_add(layers.mean(out),
+                                  layers.elementwise_add(aux, z))
+    pt.optimizer.SGD(0.1).minimize(loss)
+    feed = {"x": np.random.RandomState(0).rand(3, 6, 8).astype("float32")}
+    names = _op_names(_compile(pt.Executor(), feed, loss).hlo_text())
+    for stage in ("route", "dispatch", "experts", "combine"):
+        assert any(re.search(
+            rf"/jvp\(pt\.moe:0\.\d+\)/moe\.{stage}/", n) for n in names), stage
+        assert any(re.search(
+            rf"/transpose\(jvp\(pt\.moe:0\.\d+\)\)/moe\.{stage}/", n)
+            for n in names), stage
+    assert not any("pt.moe." in n for n in names)
+
+
 def test_executor_emitted_work_has_scopes_of_its_own():
     loss, feed = _conv_net()
     cp = _compile(pt.Executor(amp=True), feed, loss, num_steps=3)

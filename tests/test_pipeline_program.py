@@ -80,8 +80,8 @@ def test_pipeline_stage_count_mismatch_errors(rng):
 def _moe_program(rng, batch=32, experts=8, hidden=32):
     x = layers.data("x", shape=[WIDTH], dtype="float32")
     y = layers.data("y", shape=[WIDTH], dtype="float32")
-    out, aux = layers.moe(x, num_experts=experts, expert_hidden=hidden,
-                          top_k=2, capacity_factor=4.0)
+    out, aux, _ = layers.moe(x, num_experts=experts, expert_hidden=hidden,
+                             top_k=2, capacity_factor=4.0)
     loss = layers.mean(layers.square_error_cost(out, y))
     total = layers.elementwise_add(
         loss, layers.scale(aux, scale=0.01))

@@ -325,11 +325,31 @@ def test_tune_persists_winner_and_replays(tuning, knob, tmp_path):
         == {"a": 1, "b": 20}
 
 
-def test_tune_refusal_persists_nothing(tuning, knob, tmp_path):
+class _CountedClock:
+    """Stands in for the ``time`` module ``tuning.search`` reads: the clock
+    moves only when a measurement says what it cost, so equal
+    configurations cost the same by construction, whatever else the
+    machine is doing."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
+def test_tune_refusal_persists_nothing(tuning, knob, tmp_path, monkeypatch):
+    from paddle_tpu.tuning import search
+
     name, _ = knob
     base = str(tmp_path)
+    clock = _CountedClock()
+    monkeypatch.setattr(search, "time", clock)
     # distinct configs, identical cost: any "winner" is jitter
-    doc = tuning.tune(name, lambda cfg: time.sleep(0.004), reps=2,
+    doc = tuning.tune(name, lambda cfg: clock.sleep(0.004), reps=2,
                       pairs=3, warmup=0, base=base)
     assert doc["status"] in ("noise_gate_refusal", "default_is_best")
     assert doc.get("winner") is None
