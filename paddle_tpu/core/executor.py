@@ -115,6 +115,30 @@ class Env:
         self.block = block
         self.parent = parent
         self.local: Dict[str, object] = {}
+        self.softmax_of: Dict[str, tuple] = {}
+        """The note beside a value: ``name -> (value, logits, row_scale)``
+        says "the variable ``name``, while it holds ``value``, is
+        ``softmax(logits, axis=-1) * row_scale``" (``row_scale`` None for
+        1; else it broadcasts against ``logits`` with a last axis of 1).
+
+        * WRITTEN by the ``softmax`` lowering along the last axis
+          (``ops/nn_ops.py``), through :meth:`note_softmax`.
+        * CARRIED by ``reshape`` when the last axis stays
+          (``ops/tensor_ops.py``) and by ``rnn`` for a step output its tail
+          computed after the scan (``ops/control_flow_ops.py``: the length
+          mask it multiplies by becomes ``row_scale``).  Any other op drops
+          the note by not knowing it.
+        * READ by ``cross_entropy`` with hard labels, which then computes
+          the loss from the logits (``ops/nn_ops.py _nll_from_logits``) and
+          never reads the probabilities.
+
+        It lives on the ``Env`` level the value is written to, not on the
+        ``LoweringContext`` and not in ``local``: the logits are tracers
+        of the trace that level belongs to (a scan body's, a
+        ``value_and_grad``'s), the level dies with that trace, and nothing
+        that copies ``local`` out of a trace (``snapshot``, the backward's
+        aux) takes a note along.  A note whose ``value`` is no longer what
+        the name holds is stale and reads as absent."""
 
     def get(self, name: str):
         e: Optional[Env] = self
@@ -134,18 +158,39 @@ class Env:
             e = e.parent
         return False
 
-    def set(self, name: str, value):
-        # Write to the nearest env level that either already BINDS the name
+    def _level_written(self, name: str) -> "Env":
+        # The nearest env level that either already BINDS the name
         # (loop-carry bindings made by while/rnn lowerings must capture body
         # writes locally, not leak into the parent trace) or DECLARES it
         # (fluid write-through semantics for sub-blocks).
         e: Optional[Env] = self
         while e is not None:
             if name in e.local or name in e.block.vars:
-                e.local[name] = value
-                return
+                return e
             e = e.parent
-        self.local[name] = value
+        return self
+
+    def set(self, name: str, value):
+        self._level_written(name).local[name] = value
+
+    def note_softmax(self, name: str, value, logits, row_scale=None):
+        """Leave the note of ``softmax_of`` for ``value``, which the caller
+        is about to bind to ``name`` from this level."""
+        self._level_written(name).softmax_of[name] = (value, logits,
+                                                      row_scale)
+
+    def softmax_note(self, name: str, value):
+        """``(logits, row_scale)`` if ``name`` holds ``value`` and a note
+        says what softmax that is, else None."""
+        e: Optional[Env] = self
+        while e is not None:
+            if name in e.local:
+                note = e.softmax_of.get(name)
+                if note is not None and note[0] is value:
+                    return note[1], note[2]
+                return None
+            e = e.parent
+        return None
 
     def snapshot(self) -> Dict[str, object]:
         out: Dict[str, object] = {}

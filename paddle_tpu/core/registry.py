@@ -22,6 +22,12 @@ Implementation signature::
 * ``ins``  — dict slot -> list of input values (arrays / nested, per OpDesc).
 * return   — dict slot -> value or list of values; normalized by the executor.
 * ``ctx``  — LoweringContext: rng keys, sub-block interpretation, env access.
+
+What a lowering may state beyond its outputs: that it is row-wise
+(:func:`register_rowwise`, so ``rnn`` may move it out of its scan), and, of
+one output, that it is a softmax of known logits (``ctx.env.note_softmax``;
+``core.executor.Env.softmax_of`` has who writes, carries and reads that note:
+``softmax`` -> ``reshape`` / ``rnn`` -> ``cross_entropy``).
 """
 from __future__ import annotations
 

@@ -261,6 +261,24 @@ def _split_step_block(program, block, batch, per_step, mem_update_names,
     return tail, frontier
 
 
+@jax.custom_vjp
+def _cotangents_together(xs):
+    """Identity on a tuple whose cotangents are handed on only once all of
+    them exist (``lax.optimization_barrier``).  ``_rnn`` puts what its
+    tail reads through it, so the tail's backward -- the wide products of
+    an output layer and the sums for its bias -- is whole before the
+    scan's backward starts.  Left free, XLA computes only the gradient the
+    scan needs first and the weight's after the loop, and the loop's
+    accumulators then share fast memory with the layer's weight and lose
+    (seq2seq: the decoder's backward scan 2.3 ms slower, PERF.md PR 29)."""
+    return xs
+
+
+_cotangents_together.defvjp(
+    lambda xs: (xs, None),
+    lambda _, cts: (lax.optimization_barrier(cts),))
+
+
 @register_op("rnn")
 def _rnn(ctx, ins, attrs):
     """StaticRNN/DynamicRNN lowering: the recurrence of the step sub-block
@@ -398,12 +416,29 @@ def _rnn(ctx, ins, attrs):
         for nm, s in zip(step_in_names, seqs):
             if nm in frontier:
                 tenv.local[nm] = s.reshape((B * T,) + s.shape[2:])
+        # what the tail reads and differentiates: stacked values, weights
+        reads = dict.fromkeys(n for i in sorted(tail)
+                              for n in block.ops[i].input_names
+                              if n not in moved)
+        reads = [n for n in reads if jnp.issubdtype(
+            jnp.result_type(tenv.get(n)), jnp.floating)]
+        tenv.local.update(zip(reads, _cotangents_together(
+            tuple(tenv.get(n) for n in reads))))
         run(True, tenv)
-        for nm in out_step_names:
+        for nm, out_nm in zip(out_step_names,
+                              ctx.op.outputs.get("Outputs", [])):
             if nm in moved:
                 v = tenv.get(nm)
                 outs[nm] = masked(v.reshape((B, T) + v.shape[1:]),
                                   step_mask.T)
+                note = tenv.softmax_note(nm, v)
+                if note is not None and note[1] is None:
+                    # Env.softmax_of: the output is that softmax of all
+                    # steps' logits, times the length mask
+                    env.note_softmax(
+                        out_nm, outs[nm],
+                        note[0].reshape((B, T) + note[0].shape[1:]),
+                        step_mask.T.reshape((B, T) + (1,) * (v.ndim - 1)))
     results = [outs[nm] for nm in out_step_names]
     n_hoisted = len(tail) if hoist[0] else 0
     compile_cache.stats().bump("rnn_ops_hoisted", n_hoisted)

@@ -10,6 +10,8 @@ assignment re-tiles internally, so no NHWC rewrite is forced on users.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -359,9 +361,78 @@ def _rope(ctx, ins, attrs):
                     + turned * jnp.sin(angle)).astype(x.dtype)}
 
 
+_CE_EPS = 1e-8      # cross_entropy_op's clamp under the logarithm
+
+
+def _label_rows(label, rank):
+    """Hard labels as int32 with the class axis squeezed away."""
+    lab = label.astype(jnp.int32)
+    return lab.squeeze(-1) if lab.ndim == rank else lab
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _nll_and_lse(logits, lab, row_scale, clamp):
+    return _nll_and_lse_fwd(logits, lab, row_scale, clamp)[0]
+
+
+def _nll_and_lse_fwd(logits, lab, row_scale, clamp):
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1,
+                           keepdims=True)
+    z_y = jnp.take_along_axis(logits, lab[..., None], axis=-1)
+    loss, keep = lse - z_y.astype(jnp.float32), None
+    if clamp:
+        p_y = jnp.exp(-loss)
+        if row_scale is not None:
+            p_y = p_y * row_scale.astype(jnp.float32)
+        loss, keep = -jnp.log(jnp.maximum(p_y, _CE_EPS)), p_y >= _CE_EPS
+    return (loss.astype(logits.dtype), lse), (logits, lse, lab, keep)
+
+
+def _nll_and_lse_bwd(clamp, res, cts):
+    logits, lse, lab, keep = res
+    g, g_lse = cts
+    g = g.astype(jnp.float32)
+    if clamp:
+        g = jnp.where(keep, g, 0.0)
+    classes = logits.shape[-1]
+    lab = jnp.where(lab < 0, lab + classes, lab)      # as the gather reads it
+    hit = lax.broadcasted_iota(jnp.int32, logits.shape,
+                               logits.ndim - 1) == lab[..., None]
+    p = jnp.exp(logits.astype(jnp.float32) - lse)
+    dlogits = p * (g + g_lse) - jnp.where(hit, g, 0.0)
+    return dlogits.astype(logits.dtype), None, None
+
+
+_nll_and_lse.defvjp(_nll_and_lse_fwd, _nll_and_lse_bwd)
+
+
+def _nll_from_logits(logits, label, row_scale=None, clamp=True):
+    """``(loss, lse)``: the hard-label cross-entropy of
+    ``softmax(logits, -1) * row_scale`` and the float32
+    ``logsumexp(logits)`` beside it, from the logits alone.  With ``clamp``
+    the loss is ``cross_entropy``'s, ``-log(max(p[label], 1e-8))`` with
+    ``p[label] = exp(logits[label] - lse) * row_scale``; without it (and
+    without a ``row_scale``) ``softmax_with_cross_entropy``'s, ``lse -
+    logits[label]``.  Shape ``[..., 1]``.  Nothing of the size of the
+    logits is written going forward, and the residuals are the logits
+    (alive as their producer's output), ``lse``, the labels and one flag a
+    row.  Going back, ``dlogits = (exp(logits - lse) - onehot(label)) * g``
+    with the one-hot an ``iota`` comparison XLA fuses (the composition's
+    gather transposes to a scatter-add into dense zeros), zero for a row
+    the clamp caught, as the clamp's own gradient is; labels and
+    ``row_scale`` get none.  Reductions run in float32 whatever the logits'
+    dtype; the loss and ``dlogits`` come back in it."""
+    return _nll_and_lse(logits, _label_rows(label, logits.ndim), row_scale,
+                        clamp)
+
+
 @register_op("softmax")
 def _softmax(ctx, ins, attrs):
-    return {"Out": jax.nn.softmax(ins["X"][0], axis=attrs.get("axis", -1))}
+    x = ins["X"][0]
+    out = jax.nn.softmax(x, axis=attrs.get("axis", -1))
+    if attrs.get("axis", -1) % x.ndim == x.ndim - 1:
+        ctx.env.note_softmax(ctx.op.outputs["Out"][0], out, x)
+    return {"Out": out}
 
 
 @register_op("log_softmax")
@@ -372,34 +443,39 @@ def _log_softmax(ctx, ins, attrs):
 @register_op("cross_entropy")
 def _cross_entropy(ctx, ins, attrs):
     """cross_entropy_op: X is probabilities [N, D]; hard or soft labels.
-    Out is [N, 1] like the reference."""
+    Out is [N, 1] like the reference.  Where X is known to be a softmax
+    (``Env.softmax_of``) and the labels are hard, the loss is computed
+    from that softmax's logits and X is not read."""
     x, label = ins["X"][0], ins["Label"][0]
-    eps = 1e-8
-    if attrs.get("soft_label", False):
-        loss = -jnp.sum(label * jnp.log(jnp.maximum(x, eps)), axis=-1,
-                        keepdims=True)
-    else:
-        lab = label.astype(jnp.int32)
-        if lab.ndim == x.ndim:
-            lab = lab.squeeze(-1)
-        picked = jnp.take_along_axis(x, lab[..., None], axis=-1)
-        loss = -jnp.log(jnp.maximum(picked, eps))
-    return {"Y": loss}
+    soft = attrs.get("soft_label", False)
+    note = None if soft else ctx.env.softmax_note(ctx.op.inputs["X"][0], x)
+    if note is not None:
+        compile_cache.stats().bump("route/cross_entropy:from_logits")
+        return {"Y": _nll_from_logits(note[0], label, note[1])[0]}
+    compile_cache.stats().bump("route/cross_entropy:probabilities")
+    if soft:
+        return {"Y": -jnp.sum(label * jnp.log(jnp.maximum(x, _CE_EPS)),
+                              axis=-1, keepdims=True)}
+    picked = jnp.take_along_axis(x, _label_rows(label, x.ndim)[..., None],
+                                 axis=-1)
+    return {"Y": -jnp.log(jnp.maximum(picked, _CE_EPS))}
 
 
 @register_op("softmax_with_cross_entropy")
 def _softmax_with_ce(ctx, ins, attrs):
-    """Fused, numerically-stable logits->loss (softmax_with_cross_entropy_op)."""
+    """Fused, numerically-stable logits->loss (softmax_with_cross_entropy_op).
+    Hard labels share ``_nll_from_logits`` with ``cross_entropy``, without
+    its clamp: a label whose probability is under 1e-8 keeps its loss and
+    its gradient, as ``-log_softmax`` gives them."""
     logits, label = ins["Logits"][0], ins["Label"][0]
-    logp = jax.nn.log_softmax(logits, axis=-1)
     if attrs.get("soft_label", False):
-        loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
-    else:
-        lab = label.astype(jnp.int32)
-        if lab.ndim == logits.ndim:
-            lab = lab.squeeze(-1)
-        loss = -jnp.take_along_axis(logp, lab[..., None], axis=-1)
-    return {"Softmax": jnp.exp(logp), "Loss": loss}
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return {"Softmax": jnp.exp(logp),
+                "Loss": -jnp.sum(label * logp, axis=-1, keepdims=True)}
+    compile_cache.stats().bump("route/cross_entropy:from_logits")
+    loss, lse = _nll_from_logits(logits, label, clamp=False)
+    return {"Softmax": jnp.exp(logits.astype(jnp.float32) - lse)
+            .astype(logits.dtype), "Loss": loss}
 
 
 @register_op("dropout")

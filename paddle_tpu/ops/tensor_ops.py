@@ -109,7 +109,16 @@ def _reshape(ctx, ins, attrs):
     for i, s in enumerate(shape):
         if s == 0:
             shape[i] = x.shape[i]
-    return {"Out": x.reshape(tuple(shape))}
+    out = x.reshape(tuple(shape))
+    note = ctx.env.softmax_note(ctx.op.inputs["X"][0], x)
+    if note is not None and x.shape[-1:] == out.shape[-1:]:
+        # rows regrouped, classes untouched: still that softmax (Env.softmax_of)
+        logits, row_scale = note
+        ctx.env.note_softmax(
+            ctx.op.outputs["Out"][0], out, logits.reshape(out.shape),
+            None if row_scale is None
+            else row_scale.reshape(out.shape[:-1] + (1,)))
+    return {"Out": out}
 
 
 @register_op("squeeze")
