@@ -122,14 +122,12 @@ def main():
     opt = pt.optimizer.Momentum(learning_rate=0.01 / BATCH, momentum=0.9)
     opt.minimize(loss)
 
-    # bf16 compute + fp32 master weights.  auto_layout is unnecessary
-    # under run_steps: inside one scan executable XLA keeps parameters in
-    # compute layouts across iterations (measured equal, 2648 vs 2652).
-    # conv1x1_pallas stays OFF here: the Pallas 1x1 kernels
-    # (ops/pallas_conv.py) compile on the chip and match XLA
-    # (benchmark/conv_kernel.py --steps 0) but have never been timed.
-    # Flip it on in the same commit as an on-chip per-op A/B showing
-    # >=1.2x, together with the re-measured driver number.
+    # bf16 compute + fp32 master weights.  No conv here asks for the
+    # Pallas 1x1 kernels (ops/pallas_conv.py, layers.conv2d(use_pallas=)):
+    # they compile on the chip and match XLA (benchmark/conv_kernel.py
+    # --steps 0) but have never been timed.  Route to them in the same
+    # commit as an on-chip per-op A/B showing >=1.2x, together with the
+    # re-measured driver number.
     exe = pt.Executor(amp=True)
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
 

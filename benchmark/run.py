@@ -112,8 +112,7 @@ def _build_seq2seq(batch, src_len=30, tgt_len=30, vocab=30000, dim=512,
     return loss, feeds, batch * (src_len + tgt_len)
 
 
-def run_config(name, batch, amp=True, iters=None, reps=3,
-               conv1x1_pallas=None):
+def run_config(name, batch, amp=True, iters=None, reps=3):
     import statistics
 
     import jax
@@ -133,7 +132,7 @@ def run_config(name, batch, amp=True, iters=None, reps=3,
         loss, feeds, units = _build_image(name, batch)
         unit = "img/s"
 
-    exe = pt.Executor(amp=amp, conv1x1_pallas=conv1x1_pallas)
+    exe = pt.Executor(amp=amp)
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
     feeds = {k: jax.device_put(v) for k, v in feeds.items()}
     prog = pt.default_main_program()
@@ -251,13 +250,6 @@ def main():
                          "big CNNs, 300 otherwise)")
     ap.add_argument("--amp", action="store_true", default=True)
     ap.add_argument("--no-amp", dest="amp", action="store_false")
-    ap.add_argument("--conv1x1-pallas", dest="conv1x1_pallas",
-                    action="store_true", default=None,
-                    help="route eligible 1x1 convs to the hand-written "
-                         "Pallas kernels (ops/pallas_conv.py; per-op A/B: "
-                         "benchmark/conv_kernel.py)")
-    ap.add_argument("--no-conv1x1-pallas", dest="conv1x1_pallas",
-                    action="store_false")
     ap.add_argument("--all", action="store_true")
     args = ap.parse_args()
     if args.model == "input_pipeline":
@@ -281,14 +273,12 @@ def main():
     if args.all:
         for name, batch in HEADLINE:
             try:
-                run_config(name, batch, amp=args.amp, iters=args.iters,
-                           conv1x1_pallas=args.conv1x1_pallas)
+                run_config(name, batch, amp=args.amp, iters=args.iters)
             except Exception as e:
                 print(json.dumps({"model": name, "batch": batch,
                                   "error": str(e)[:200]}), flush=True)
     else:
-        run_config(args.model, args.batch, amp=args.amp, iters=args.iters,
-                   conv1x1_pallas=args.conv1x1_pallas)
+        run_config(args.model, args.batch, amp=args.amp, iters=args.iters)
 
 
 if __name__ == "__main__":

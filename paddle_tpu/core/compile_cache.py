@@ -9,7 +9,7 @@ for every (program, feed-signature) variant.  This module makes that cost
    hash of everything that determines the traced computation: the serialized
    Program (ops, attrs, var shapes/dtypes, random_seed), the feed signature
    (names/shapes/dtypes), fetch names, state keys, executor configuration
-   (amp, compute_dtype, compiler_options, conv1x1_pallas, check_nan_inf),
+   (amp, compute_dtype, compiler_options, check_nan_inf),
    mesh + sharding specs (ShardedExecutor), x64 mode, and the jax +
    paddle_tpu versions.  Content-identical programs
    (``prune().clone(for_test=True)`` slices built per evaluation call)
@@ -240,8 +240,7 @@ class retrace_guard:
     """Context manager: raise :class:`RetraceError` if any fingerprint is
     traced more than once while active.  Tests wrap training loops in this
     to pin the compile-once contract; note that cache eviction (LRU
-    overflow) and ``auto_layout`` (which compiles probe variants)
-    legitimately re-trace."""
+    overflow) legitimately re-traces."""
 
     def __enter__(self):
         self._window: Dict[str, int] = {}

@@ -24,11 +24,12 @@ into a single ``jax.jit`` function per (program-version, feed-signature):
 """
 from __future__ import annotations
 
-import logging
 import time
 import warnings
 import weakref
-from contextlib import nullcontext
+from collections import namedtuple
+from contextlib import contextmanager, nullcontext
+from types import SimpleNamespace
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -37,13 +38,13 @@ import numpy as np
 
 from . import compile_cache
 from .. import faults as _faults
+from .. import flags as _flags
 from .. import observability as obs
 from ..testing import faultinject as _fi
-from .program import Block, Operator, Program, Variable, grad_var_name
+from .program import (Block, Operator, Program, Variable,
+                      default_main_program, grad_var_name)
 from .registry import get_op_impl, register_tunable, resolve_tuned
 from .scope import Scope, global_scope
-
-logger = logging.getLogger("paddle_tpu")
 
 # ---------------------------------------------------------------------------
 # Autotuner knob declarations (paddle_tpu.tuning) — declared HERE, next to
@@ -211,7 +212,7 @@ class LoweringContext:
     def __init__(self, program: Program, base_key, is_test: bool = False,
                  amp: bool = False, mesh=None,
                  pipeline_microbatches: Optional[int] = None,
-                 compute_dtype=None, conv1x1_pallas=None):
+                 compute_dtype=None):
         self.program = program
         self.base_key = base_key      # traced PRNG key folding in the step
         self.is_test = is_test
@@ -224,9 +225,6 @@ class LoweringContext:
         # sharding constraints (moe) or lower staged regions (pipeline)
         self.mesh = mesh
         self.pipeline_microbatches = pipeline_microbatches
-        # tri-state 1x1-conv Pallas routing (None = defer to the
-        # conv1x1_pallas flag); consulted by ops.nn_ops._conv2d
-        self.conv1x1_pallas = conv1x1_pallas
         self.op: Optional[Operator] = None
         self.env: Optional[Env] = None
         self._op_uid = 0
@@ -342,17 +340,7 @@ def run_op(op: Operator, env: Env, ctx: LoweringContext):
                   for slot, vals in ins.items()}
         note = (f"[paddle_tpu] while lowering op {op.type!r} "
                 f"(outputs {op.outputs}) with input shapes {shapes}")
-        if hasattr(e, "add_note"):        # PEP 678, python 3.11+
-            e.add_note(note)
-        else:                             # 3.10 shim: same __notes__ slot
-            try:
-                notes = getattr(e, "__notes__", None)
-                if notes is None:
-                    notes = e.__notes__ = []
-                notes.append(note)
-            except (AttributeError, TypeError):   # slotted exception:
-                e.args = (f"{e.args[0] if e.args else e}\n{note}",) \
-                    + e.args[1:]          # at least don't mask the error
+        e.add_note(note)        # PEP 678 (jax itself needs python 3.11)
         raise
     finally:
         ctx.op, ctx.env = prev_op, prev_env
@@ -580,8 +568,25 @@ def _validation_ctx_key(mesh, param_specs, feed_specs):
 _STATE_KEYS_CACHE_MAX = 32
 
 
+def _option(value, flag: str) -> bool:
+    """An executor option left at None defers to the process flag."""
+    return bool(_flags.get_flag(flag) if value is None else value)
+
+
+# one resolved call (Executor._enter): what run, run_steps and compile need
+# of it; `feeds` and `state` hold arrays, or ShapeDtypeStructs for compile
+_StepEntry = namedtuple(
+    "_StepEntry", "program scope fetch_names feeds state_keys state is_test "
+                  "fp fn")
+
+
 class Executor:
     """Compile-and-run a Program (reference: fluid/executor.py:56-119).
+
+    ``run``, ``run_steps`` and ``compile`` resolve a call through one entry
+    (``_enter``); the first two dispatch what it built inside one observed
+    dispatch (``_observed``), and every step, compiled or replayed eagerly,
+    starts from one prologue (``_step_prologue``).
 
     ``use_jit=False`` runs the interpreter eagerly op-by-op — the debugging
     analog of the reference's serial executor (and of jax.disable_jit).
@@ -589,10 +594,8 @@ class Executor:
 
     def __init__(self, place: Optional[Place] = None, use_jit: bool = True,
                  check_nan_inf: bool = False, amp: bool = False,
-                 auto_layout: bool = False,
                  compiler_options: Optional[Dict[str, object]] = None,
                  compute_dtype: Optional[str] = None,
-                 conv1x1_pallas: Optional[bool] = None,
                  validate: Optional[bool] = None,
                  observe: Optional[bool] = None,
                  retry_policy=None,
@@ -607,19 +610,10 @@ class Executor:
         # autodiff compare at double precision; persistable state keeps its
         # declared dtype across steps via the existing dtype-restore pass
         self.compute_dtype = compute_dtype
-        # XLA-chosen parameter layouts (see _AutoLayoutStep).  Opt-in: a few
-        # % on conv nets, but best used with a single compiled step variant
-        # (run the same fetch_list every call) — some PJRT backends reject
-        # executables whose parameters carry another compile's exotic layout.
-        self.auto_layout = auto_layout
         # XLA backend knobs passed to Compiled (e.g. xla_tpu_scoped_vmem_
         # limit_kib); the FLAGS-registry analog of the reference's gflags
         # runtime switches, but scoped to one executor
         self.compiler_options = dict(compiler_options or {})
-        # opt-in hand-written Pallas 1x1-conv kernels (ops/pallas_conv.py);
-        # None defers to the conv1x1_pallas flag, a per-op use_pallas attr
-        # (layers.conv2d(use_pallas=...)) overrides both
-        self.conv1x1_pallas = conv1x1_pallas
         # static program verification (paddle_tpu.analysis) before trace
         # AND before compile-cache fingerprinting, so an invalid program
         # never enters the cache; None defers to the `validate` flag
@@ -659,17 +653,14 @@ class Executor:
         # compiled step variants keyed by CONTENT fingerprint (survives
         # process restarts via the persistent layer; content-identical
         # programs share an entry), LRU-bounded with dead-program sweeping
-        self._cache = compile_cache.ExecCache(self._cache_capacity())
-        self._fmt_registry: Dict = {}  # state var name -> pinned Format
+        self._cache = compile_cache.ExecCache(
+            int(_flags.get_flag("executor_cache_entries")))
+        # what a ShardedExecutor sets: the mesh reaches op lowerings
+        # through the LoweringContext (moe sharding constraints, pipeline
+        # regions), with the GPipe microbatch count beside it
+        self.mesh = None
+        self.num_microbatches: Optional[int] = None
         self._step = 0
-
-    @staticmethod
-    def _cache_capacity() -> int:
-        try:
-            from .. import flags
-            return int(flags.get_flag("executor_cache_entries"))
-        except Exception:
-            return 64
 
     def _validation_context(self):
         """(mesh, param_specs, feed_specs) for the sharding lints; the
@@ -684,14 +675,7 @@ class Executor:
         installed in (or persisted to) the compilation cache.  Successful
         validations memoize; error reports re-raise on every call.
         """
-        want = self.validate
-        if want is None:
-            try:
-                from .. import flags
-                want = bool(flags.get_flag("validate"))
-            except Exception:
-                want = False
-        if not want:
+        if not _option(self.validate, "validate"):
             return
         mesh, param_specs, feed_specs = self._validation_context()
         seen = self._validated.get(program)
@@ -722,13 +706,7 @@ class Executor:
     # -- autotuner replay ----------------------------------------------------
     def _autotuning(self) -> bool:
         """Resolved autotune switch: per-executor override, else flag."""
-        if self.autotune is not None:
-            return bool(self.autotune)
-        try:
-            from .. import flags
-            return bool(flags.get_flag("autotune"))
-        except KeyError:
-            return False
+        return _option(self.autotune, "autotune")
 
     def _tuned(self, name: str, default: Dict[str, object]):
         """Tunable config for a call site: the persisted winner under the
@@ -740,7 +718,7 @@ class Executor:
         """compiler_options with device-side tuned winners folded in.
 
         Feeds BOTH the compile-cache fingerprint (_config_sig) and the
-        actual compile (CachedStep/_AutoLayoutStep), so a replayed XLA
+        actual compile (CachedStep), so a replayed XLA
         flag can never produce a fingerprint/executable mismatch.  An
         explicit user-set option always wins; with autotune off, or no
         record, or a record equal to XLA's own default, this returns
@@ -762,9 +740,7 @@ class Executor:
     # -- observability -------------------------------------------------------
     def _observing(self) -> bool:
         """Resolved observe switch: per-executor override, else flag."""
-        if self.observe is not None:
-            return bool(self.observe)
-        return obs.enabled()
+        return _option(self.observe, "observe")
 
     def _observe_label(self) -> str:
         """Extra context folded into trace annotations and step events
@@ -916,93 +892,135 @@ class Executor:
             f"{err}\n[paddle_tpu] NaN provenance (eager re-run of step "
             f"{step}): {nanprov.format_diagnosis(diag)}")
 
-    # -- public ------------------------------------------------------------
-    def run(self, program: Optional[Program] = None,
-            feed: Optional[Dict[str, object]] = None,
-            fetch_list: Optional[Sequence] = None,
-            scope: Optional[Scope] = None,
-            return_numpy: bool = True,
-            is_test: bool = False):
-        from .program import default_main_program
-        program = program or default_main_program()
-        feed = feed or {}
-        fetch_list = list(fetch_list or [])
-        scope = global_scope() if scope is None else scope
+    # -- the one way into a compiled step -------------------------------------
+    def _call_context(self, program: Optional[Program]):
+        """What a whole run/run_steps/compile call runs under: nothing
+        here, the mesh for a ShardedExecutor."""
+        return nullcontext()
 
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-
-        # normalize feeds to arrays with declared dtypes
+    def _coerce_feeds(self, program: Program, feed: Dict[str, object],
+                      steps, abstract: bool) -> Dict[str, object]:
+        """Feeds at the dtypes the Program declares.  ``abstract`` (compile)
+        reads only shapes and dtypes — of example arrays, ``(shape, dtype)``
+        tuples or ShapeDtypeStructs — and returns ShapeDtypeStructs."""
         gb = program.global_block()
-        feed_arrays: Dict[str, jnp.ndarray] = {}
+        # device-resident arrays stay on device (no host round-trip)
+        as_is = (jax.Array, jax.ShapeDtypeStruct) if abstract else jax.Array
+        out: Dict[str, object] = {}
         for name, val in feed.items():
-            # keep device-resident arrays on device (no host round-trip)
-            arr = val if isinstance(val, jax.Array) else np.asarray(val)
+            if abstract and (isinstance(val, tuple) and len(val) == 2
+                             and not hasattr(val, "dtype")
+                             and isinstance(val[0], (tuple, list))):
+                val = jax.ShapeDtypeStruct(tuple(int(d) for d in val[0]),
+                                           np.dtype(val[1]))
+            elif not isinstance(val, as_is):
+                val = np.asarray(val)
+            if steps is not None and steps[1] \
+                    and tuple(val.shape[:1]) != (steps[0],):
+                raise ValueError(
+                    f"run_steps(feeds_stacked=True): feed {name!r} must "
+                    f"have leading dim {steps[0]}, got {tuple(val.shape)}")
+            dtype = val.dtype
             if gb.has_var(name):
-                want = jax.dtypes.canonicalize_dtype(gb.var(name).dtype)
-                if arr.dtype != want:
-                    arr = arr.astype(want)
-            feed_arrays[name] = arr
+                dtype = jax.dtypes.canonicalize_dtype(gb.var(name).dtype)
+            if abstract:
+                val = jax.ShapeDtypeStruct(tuple(val.shape), dtype)
+            elif val.dtype != dtype:
+                val = val.astype(dtype)
+            out[name] = val
         if not self.use_jit:
             # eager interpreting: op lowerings expect jax arrays (.at etc.)
-            feed_arrays = {k: jnp.asarray(v) for k, v in feed_arrays.items()}
+            out = {k: jnp.asarray(v) for k, v in out.items()}
+        return out
 
+    def _enter(self, program: Optional[Program], feed, fetch_list, scope,
+               is_test: bool, steps=None, abstract: bool = False):
+        """Resolve a call into what run, run_steps and compile all need (a
+        :class:`_StepEntry`): defaults, coerced feeds, the state the step
+        threads, the validated program's fingerprint, and the cached-or-
+        built step — one step, or the K-step scan for ``steps =
+        (num_steps, feeds_stacked)``.  ``abstract``: feeds and state as
+        ShapeDtypeStructs."""
+        program = program or default_main_program()
+        scope = global_scope() if scope is None else scope
+        fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                       for v in (fetch_list or [])]
+        feeds = self._coerce_feeds(program, feed or {}, steps, abstract)
         state_keys = self._state_keys(program, scope)
         state = {k: scope.get(k) for k in state_keys}
-        # check_nan_inf step variants compile WITHOUT donation (_build,
-        # CachedStep/_AutoLayoutStep donate=False), so `state` itself
-        # survives the dispatch for the provenance bisect at zero
-        # per-step cost on the success path
-
-        self._maybe_validate(program, fetch_names)
+        if abstract:
+            state = {k: jax.ShapeDtypeStruct(
+                np.shape(v), v.dtype if hasattr(v, "dtype")
+                else np.asarray(v).dtype) for k, v in state.items()}
+        self._maybe_validate(program, fetch_names)   # before fingerprinting
         fp = compile_cache.fingerprint_hex(self._entry_sig(
-            program, feed_arrays, fetch_names, state_keys, is_test))
+            program, feeds, fetch_names, state_keys, is_test, steps=steps))
         fn = self._cache.get(fp, program)
         if fn is None:
-            fn = self._build(program, sorted(feed_arrays), fetch_names,
-                             sorted(state_keys), is_test, fingerprint=fp)
+            if steps is None:
+                fn = self._build(program, sorted(feeds), fetch_names,
+                                 sorted(state_keys), is_test, fingerprint=fp)
+            else:
+                fn = self._build_steps(
+                    program, self._make_multi(program, fetch_names, is_test,
+                                              *steps),
+                    steps[1], fingerprint=fp)
             self._cache.put(fp, fn, program)
+        return _StepEntry(program, scope, fetch_names, feeds, state_keys,
+                          state, is_test, fp, fn)
 
+    @contextmanager
+    def _observed(self, entry: "_StepEntry", path: str, annotation: str,
+                  steps: int, stacked: bool, return_numpy: bool):
+        """Everything around the dispatch of a built step, observed when
+        observing: the ``executor/step`` root span, the profiler's
+        ``annotation`` and ``pt:<path>:<fp12>`` around the with-body, then
+        the new state written back to the scope, the NaN check
+        (check_nan_inf; run_steps refuses it), the fetches converted under
+        ``executor/fetch_block``, and the dispatch recorded.  Off: no span,
+        no timer, no registry write.
+
+        Yields ``call``: its ``step`` and ``span`` go into the with-body,
+        which is the caller's ONE line ``call.out = self._dispatch(...)``;
+        the caller then returns ``call.fetches``.  A context manager and
+        not a method around ``_dispatch``: the first dispatch traces and
+        lowers the step, and JAX's lowering time moves by seconds with the
+        Python stack that stands above it (PERF.md section 6, PR 30), so
+        ``run`` and ``run_steps`` call ``_dispatch`` themselves."""
+        program, scope, fetch_names, feed_arrays, _, state, is_test, fp, \
+            _ = entry
         obs_on = self._observing()
         t_start = time.perf_counter() if obs_on else 0.0
         c0 = compile_cache.stats().snapshot() if obs_on else None
-        sp = obs.tracing.start_span(
-            "executor/step", path="run", steps=1,
-            fingerprint=(fp or "")[:12]) if obs_on else None
-        step = self._step
-        self._step += 1
+        sp = obs.tracing.start_span("executor/step", path=path, steps=steps,
+                                    fingerprint=fp[:12]) if obs_on else None
+        call = SimpleNamespace(step=self._step, span=None)
+        self._step += steps
         try:
             if obs_on:
-                with jax.profiler.StepTraceAnnotation("paddle_tpu/step",
-                                                      step_num=step), \
+                with jax.profiler.StepTraceAnnotation(annotation,
+                                                      step_num=call.step), \
                         jax.profiler.TraceAnnotation(
-                            self._trace_name("run", fp)), \
+                            self._trace_name(path, fp)), \
                         obs.tracing.span("executor/dispatch",
-                                         parent=sp) as dsp:
-                    fetches, new_state = self._dispatch(
-                        fn, feed_arrays, state, step, "run",
-                        trace_span=dsp)
+                                         parent=sp) as call.span:
+                    yield call
             else:
-                fetches, new_state = self._dispatch(fn, feed_arrays,
-                                                    state, step, "run")
-
-            finite_map = None
-            if self.check_nan_inf and fetches \
-                    and isinstance(fetches[-1], dict):
-                finite_map = fetches[-1]
-                fetches = fetches[:-1]
-
+                yield call
+            fetches, new_state = call.out
+            fetches = list(fetches)
             for k, v in new_state.items():
                 scope.set(k, v)
 
             if self.check_nan_inf:
                 try:
-                    if finite_map is not None:
-                        self._nan_localize(program, finite_map)
+                    # the per-var finite flags ride behind the fetches
+                    if fetches and isinstance(fetches[-1], dict):
+                        self._nan_localize(program, fetches.pop())
                     self._nan_check(fetch_names, fetches)
                 except FloatingPointError as e:
                     raise self._nan_diagnose(program, feed_arrays, state,
-                                             step, is_test, e) from e
+                                             call.step, is_test, e) from e
 
             t_fetch = time.perf_counter() if obs_on else 0.0
             if return_numpy:
@@ -1020,14 +1038,29 @@ class Executor:
         if obs_on:
             now = time.perf_counter()
             sp.end()
-            self._record_dispatch("run", fp, steps=1,
+            self._record_dispatch(path, fp, steps=steps,
                                   wall_s=now - t_start,
                                   fetch_block_s=now - t_fetch,
-                                  feed_arrays=feed_arrays, stacked=False,
+                                  feed_arrays=feed_arrays, stacked=stacked,
                                   compile_before=c0, span=sp,
                                   drained=return_numpy and any(
                                       f is not None for f in fetches))
-        return fetches
+        call.fetches = fetches
+
+    # -- public ------------------------------------------------------------
+    def run(self, program: Optional[Program] = None,
+            feed: Optional[Dict[str, object]] = None,
+            fetch_list: Optional[Sequence] = None,
+            scope: Optional[Scope] = None,
+            return_numpy: bool = True,
+            is_test: bool = False):
+        with self._call_context(program):
+            e = self._enter(program, feed, fetch_list, scope, is_test)
+            with self._observed(e, "run", "paddle_tpu/step", 1, False,
+                                return_numpy) as call:
+                call.out = self._dispatch(e.fn, e.feeds, e.state, call.step,
+                                          "run", trace_span=call.span)
+            return call.fetches
 
     def run_steps(self, num_steps: int,
                   program: Optional[Program] = None,
@@ -1056,96 +1089,19 @@ class Executor:
 
         Fetches come back stacked with a leading ``num_steps`` axis.
         """
-        from .program import default_main_program
         if self.check_nan_inf:
             raise ValueError(
                 "run_steps: check_nan_inf needs per-step host inspection; "
                 "use run() for NaN hunts")
-        program = program or default_main_program()
-        feed = feed or {}
-        fetch_list = list(fetch_list or [])
-        scope = global_scope() if scope is None else scope
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-
-        gb = program.global_block()
-        feed_arrays: Dict[str, jnp.ndarray] = {}
-        for name, val in feed.items():
-            arr = val if isinstance(val, jax.Array) else np.asarray(val)
-            if feeds_stacked and arr.shape[:1] != (num_steps,):
-                raise ValueError(
-                    f"run_steps(feeds_stacked=True): feed {name!r} must "
-                    f"have leading dim {num_steps}, got {arr.shape}")
-            if gb.has_var(name):
-                want = jax.dtypes.canonicalize_dtype(gb.var(name).dtype)
-                if arr.dtype != want:
-                    arr = arr.astype(want)
-            feed_arrays[name] = arr
-
-        state_keys = self._state_keys(program, scope)
-        state = {k: scope.get(k) for k in state_keys}
-
-        self._maybe_validate(program, fetch_names)
-        fp = compile_cache.fingerprint_hex(self._entry_sig(
-            program, feed_arrays, fetch_names, state_keys, is_test,
-            steps=(num_steps, feeds_stacked)))
-        jfn = self._cache.get(fp, program)
-        if jfn is None:
-            multi = self._make_multi(program, fetch_names, is_test,
-                                     num_steps, feeds_stacked)
-            jfn = self._build_steps(program, multi, feeds_stacked,
-                                    fingerprint=fp)
-            self._cache.put(fp, jfn, program)
-
-        obs_on = self._observing()
-        t_start = time.perf_counter() if obs_on else 0.0
-        c0 = compile_cache.stats().snapshot() if obs_on else None
-        sp = obs.tracing.start_span(
-            "executor/step", path="run_steps", steps=num_steps,
-            fingerprint=(fp or "")[:12]) if obs_on else None
-        step0 = self._step
-        self._step += num_steps
-        try:
-            if obs_on:
-                with jax.profiler.StepTraceAnnotation(
-                        "paddle_tpu/dispatch", step_num=step0), \
-                        jax.profiler.TraceAnnotation(
-                            self._trace_name("run_steps", fp)), \
-                        obs.tracing.span("executor/dispatch",
-                                         parent=sp) as dsp:
-                    fetches, new_state = self._dispatch(
-                        jfn, feed_arrays, state, step0, "run_steps",
-                        trace_span=dsp)
-            else:
-                fetches, new_state = self._dispatch(
-                    jfn, feed_arrays, state, step0, "run_steps")
-            fetches = list(fetches)
-            for k, v in new_state.items():
-                scope.set(k, v)
-            t_fetch = time.perf_counter() if obs_on else 0.0
-            if return_numpy:
-                with (obs.tracing.span("executor/fetch_block", parent=sp)
-                      if sp is not None else nullcontext()):
-                    fetches = [np.asarray(f) if f is not None else None
-                               for f in fetches]
-        except BaseException as e:
-            # see run(): a failed dispatch must not leave an orphaned
-            # dispatch child — the root span ends with the typed status
-            if sp is not None:
-                sp.end(status=type(e).__name__)
-            raise
-        if obs_on:
-            now = time.perf_counter()
-            sp.end()
-            self._record_dispatch("run_steps", fp, steps=num_steps,
-                                  wall_s=now - t_start,
-                                  fetch_block_s=now - t_fetch,
-                                  feed_arrays=feed_arrays,
-                                  stacked=feeds_stacked,
-                                  compile_before=c0, span=sp,
-                                  drained=return_numpy and any(
-                                      f is not None for f in fetches))
-        return fetches
+        with self._call_context(program):
+            e = self._enter(program, feed, fetch_list, scope, is_test,
+                            steps=(num_steps, feeds_stacked))
+            with self._observed(e, "run_steps", "paddle_tpu/dispatch",
+                                num_steps, feeds_stacked,
+                                return_numpy) as call:
+                call.out = self._dispatch(e.fn, e.feeds, e.state, call.step,
+                                          "run_steps", trace_span=call.span)
+            return call.fetches
 
     def run_pipelined(self, feed_iter,
                       program: Optional[Program] = None,
@@ -1193,7 +1149,6 @@ class Executor:
             raise ValueError(
                 "run_pipelined: check_nan_inf needs per-step host "
                 "inspection; use run() for NaN hunts")
-        from .program import default_main_program
         program = program or default_main_program()
         if steps_per_dispatch is None or prefetch_depth is None:
             cfg = self._tuned("executor/run_pipelined",
@@ -1332,30 +1287,31 @@ class Executor:
     def _build_steps(self, program: Program, multi, feeds_stacked: bool,
                      fingerprint: Optional[str] = None):
         """jit wrapper for the K-step scan fn (ShardedExecutor overrides
-        this to pin mesh shardings).  auto_layout executors route through
-        _AutoLayoutStep — the shared format registry keeps run() and
-        run_steps() variants agreeing on the donated state's layouts
-        (mixing pinned-AUTO and default layouts on the same donated
-        buffers is the InvalidArgument ping-pong the methodology notes
-        describe)."""
+        this to pin mesh shardings)."""
+        return self._as_step(multi, fingerprint, "run_steps")
+
+    def _as_step(self, fn, fingerprint: Optional[str], label: str):
+        """``fn`` itself when interpreting eagerly, else a CachedStep named
+        ``pt_<label>``; a check_nan_inf variant (run only) does not donate
+        its pre-step state, which the NaN bisect replays from."""
         if not self.use_jit:
-            return multi
-        if self.auto_layout:
-            return _AutoLayoutStep(multi, self._fmt_registry,
-                                   self._effective_compiler_options(),
-                                   donate=not self.check_nan_inf,
-                                   label="run_steps")
+            return fn
         return compile_cache.CachedStep(
-            multi, fingerprint,
+            fn, fingerprint,
             compiler_options=self._effective_compiler_options(),
-            label="run_steps")
+            label=label, donate=not self.check_nan_inf)
 
     # -- fingerprinting ------------------------------------------------------
+    def _lowering_options(self) -> Dict[str, object]:
+        """The constructor arguments that change what a step TRACES to:
+        ``_step_options`` hands them to the lowerings, ``_config_sig``
+        fingerprints them, ``Executor(**them)`` replays this executor."""
+        return {"amp": self.amp, "compute_dtype": self.compute_dtype}
+
     def _config_sig(self):
         """Executor-configuration component of every cache fingerprint —
-        everything on `self` that changes the traced computation."""
-        return (self.use_jit, self.amp, self.auto_layout,
-                str(self.compute_dtype), self.conv1x1_pallas,
+        everything on `self` that changes the compiled computation."""
+        return (self.use_jit, _specs_sig(self._lowering_options()),
                 _specs_sig(self._effective_compiler_options()))
 
     def _fingerprint_extras(self, program: Program):
@@ -1373,9 +1329,8 @@ class Executor:
         head = ("run",) if steps is None else ("steps",) + tuple(steps)
         return head + (
             compile_cache.program_content_digest(program),
-            tuple(sorted((n, tuple(np.shape(a)), str(a.dtype))
-                         for n, a in feed_arrays.items())),
-            tuple(fetch_names), tuple(sorted(state_keys)), bool(is_test),
+            _feed_signature(feed_arrays), tuple(fetch_names),
+            tuple(sorted(state_keys)), bool(is_test),
             self.check_nan_inf,   # changes the compiled fn's output arity
             bool(jax.config.jax_enable_x64),
             self._config_sig(), self._fingerprint_extras(program))
@@ -1406,14 +1361,8 @@ class Executor:
         compile lands in JAX's persistent compilation cache
         (``compile_cache.cache_dir()``) for warm process starts.
         """
-        from .program import default_main_program
         if not self.use_jit:
             raise ValueError("Executor.compile requires use_jit=True")
-        if self.auto_layout:
-            raise ValueError(
-                "Executor.compile: auto_layout compiles lazily (AUTO "
-                "layouts are chosen from concrete arrays); drop "
-                "auto_layout or warm up with a real first step")
         if self.check_nan_inf and num_steps is not None:
             raise ValueError("run_steps: check_nan_inf needs per-step host "
                              "inspection")
@@ -1423,61 +1372,16 @@ class Executor:
                 "(stacked [K, ...] specs describe the run_steps scan "
                 "variant; without num_steps the single-step variant would "
                 "silently compile against the wrong shapes)")
-        program = program or default_main_program()
-        feed = feed or {}
-        fetch_list = list(fetch_list or [])
-        scope = global_scope() if scope is None else scope
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-
-        gb = program.global_block()
-        feeds_abs: Dict[str, jax.ShapeDtypeStruct] = {}
-        for name, val in feed.items():
-            if isinstance(val, jax.ShapeDtypeStruct):
-                shape, dtype = tuple(val.shape), val.dtype
-            elif (isinstance(val, tuple) and len(val) == 2
-                    and not hasattr(val, "dtype")
-                    and isinstance(val[0], (tuple, list))):
-                shape, dtype = tuple(int(s) for s in val[0]), \
-                    np.dtype(val[1])
-            else:
-                a = val if isinstance(val, jax.Array) else np.asarray(val)
-                shape, dtype = tuple(a.shape), a.dtype
-            if gb.has_var(name):
-                dtype = jax.dtypes.canonicalize_dtype(gb.var(name).dtype)
-            feeds_abs[name] = jax.ShapeDtypeStruct(shape, dtype)
-
-        state_keys = self._state_keys(program, scope)
-        state_abs = {k: jax.ShapeDtypeStruct(
-            tuple(np.shape(scope.get(k))),
-            getattr(scope.get(k), "dtype", np.asarray(scope.get(k)).dtype))
-            for k in state_keys}
-
-        self._maybe_validate(program, fetch_names)
         steps = None if num_steps is None else (num_steps, feeds_stacked)
-        fp = compile_cache.fingerprint_hex(self._entry_sig(
-            program, feeds_abs, fetch_names, state_keys, is_test,
-            steps=steps))
-        fn = self._cache.get(fp, program)
-        if fn is None:
-            if num_steps is None:
-                fn = self._build(program, sorted(feeds_abs), fetch_names,
-                                 sorted(state_keys), is_test, fingerprint=fp)
-            else:
-                multi = self._make_multi(program, fetch_names, is_test,
-                                         num_steps, feeds_stacked)
-                fn = self._build_steps(program, multi, feeds_stacked,
-                                       fingerprint=fp)
-            self._cache.put(fp, fn, program)
-        prepare = getattr(fn, "prepare", None)
-        if prepare is None:
-            raise ValueError("Executor.compile: this step variant does not "
-                             "support AOT preparation")
-        step = prepare(feeds_abs, state_abs, 0)
+        with self._call_context(program):
+            entry = self._enter(program, feed, fetch_list, scope, is_test,
+                                steps=steps, abstract=True)
+            # every jitted step (CachedStep, the sharded wrapper) prepares
+            step = entry.fn.prepare(entry.feeds, entry.state, 0)
         return compile_cache.CompiledProgram(
-            self, program, fp, step, fetch_names, state_keys,
-            num_steps=num_steps, feeds_stacked=feeds_stacked,
-            is_test=is_test)
+            self, entry.program, entry.fp, step, entry.fetch_names,
+            entry.state_keys, num_steps=num_steps,
+            feeds_stacked=feeds_stacked, is_test=is_test)
 
     # -- internals ---------------------------------------------------------
     def _state_keys(self, program: Program, scope: Scope) -> List[str]:
@@ -1536,18 +1440,53 @@ class Executor:
     def _build(self, program: Program, feed_names: List[str],
                fetch_names: List[str], state_keys: List[str], is_test: bool,
                fingerprint: Optional[str] = None):
-        fn = self._make_fn(program, fetch_names, is_test)
-        if not self.use_jit:
-            return fn
-        if self.auto_layout:
-            return _AutoLayoutStep(fn, self._fmt_registry,
-                                   self._effective_compiler_options(),
-                                   donate=not self.check_nan_inf,
-                                   label="run")
-        return compile_cache.CachedStep(
-            fn, fingerprint,
-            compiler_options=self._effective_compiler_options(),
-            label="run", donate=not self.check_nan_inf)
+        return self._as_step(self._make_fn(program, fetch_names, is_test),
+                             fingerprint, "run")
+
+    def _step_options(self) -> Dict[str, object]:
+        """A snapshot of what this executor hands the op lowerings (the
+        LoweringContext's keywords).  ``_step_prologue`` is a function of
+        it and not of the executor, so a traced fn outlives its executor
+        as it always has (``__graft_entry__.entry``, ``export_model``)."""
+        return dict(self._lowering_options(), mesh=self.mesh,
+                    pipeline_microbatches=self.num_microbatches)
+
+    @staticmethod
+    def _lowering_context(options: Dict[str, object], program: Program,
+                          base_key, is_test: bool) -> LoweringContext:
+        """The ONE construction site (tests/test_repo_lint.py), so that a
+        replay (``observability.opprof``) lowers as the compiled step did."""
+        return LoweringContext(program, base_key, is_test=is_test, **options)
+
+    @staticmethod
+    def _step_prologue(options: Dict[str, object], program: Program,
+                       feed_arrays, state, step, is_test: bool):
+        """``(env, ctx)`` one step starts from under ``options`` (an
+        executor's ``_step_options()``): state and feeds in the env, the
+        ``compute_dtype`` upcast, the pure-inference AMP cast, the step's
+        PRNG key, the LoweringContext.  The traced fn of ``_make_fn``
+        calls it, and so do the eager replays (``nanprov.
+        make_eager_context``): they cannot start from another precision."""
+        with jax.named_scope(RNG_SCOPE):
+            base_key = jax.random.fold_in(
+                jax.random.PRNGKey(program.random_seed), step)
+        env = Env(program.global_block())
+        env.local.update(state)
+        env.local.update(feed_arrays)
+        if options["compute_dtype"] is not None:
+            cd = jnp.dtype(options["compute_dtype"])
+            with jax.named_scope(DTYPE_SCOPE):
+                env.local = {k: v.astype(cd) if hasattr(v, "dtype")
+                             and jnp.issubdtype(v.dtype, jnp.floating)
+                             else v for k, v in env.local.items()}
+        if options["amp"] and not any(op.type == "backward"
+                                      for op in program.global_block().ops):
+            # pure-inference AMP: whole net computes in bf16 (a training
+            # step casts inside value_and_grad, _run_backward)
+            with jax.named_scope(AMP_SCOPE):
+                env.local = {k: _to_bf16(v) for k, v in env.local.items()}
+        return env, Executor._lowering_context(options, program, base_key,
+                                               is_test)
 
     def _make_fn(self, program: Program, fetch_names: List[str],
                  is_test: bool):
@@ -1562,25 +1501,15 @@ class Executor:
         cache can refresh it when a content-identical client Program hits
         the entry (the fingerprint guarantees any client traces the same
         computation); a re-trace after the original program died then uses
-        the live client instead of failing.
+        the live client instead of failing.  The executor's options are
+        snapshotted here: the fn holds no executor and outlives its own.
         """
         persistable_names = sorted(
             {v.name for b in program.blocks for v in b.vars.values()
              if v.persistable})
-
-        amp = self.amp
         check_nan = self.check_nan_inf
-        # ShardedExecutor sets these: the mesh reaches op lowerings through
-        # the LoweringContext (moe sharding constraints, pipeline regions)
-        lowering_mesh = getattr(self, "mesh", None)
-        microbatches = getattr(self, "num_microbatches", None)
-        has_backward = any(op.type == "backward"
-                           for op in program.global_block().ops)
-
-        compute_dtype = self.compute_dtype
-        conv1x1_pallas_opt = self.conv1x1_pallas
+        options = self._step_options()
         prog_cell = [weakref.ref(program)]
-        random_seed = program.random_seed
 
         def fn(feed_arrays, state, step):
             program = prog_cell[0]()
@@ -1589,28 +1518,8 @@ class Executor:
                     "compiled step traced after its Program was "
                     "garbage-collected (cache entry outlived every "
                     "client program)")
-            with jax.named_scope(RNG_SCOPE):
-                base_key = jax.random.fold_in(
-                    jax.random.PRNGKey(random_seed), step)
-            env = Env(program.global_block())
-            env.local.update(state)
-            env.local.update(feed_arrays)
-            if compute_dtype is not None:
-                cd = jnp.dtype(compute_dtype)
-                with jax.named_scope(DTYPE_SCOPE):
-                    env.local = {k: v.astype(cd) if hasattr(v, "dtype")
-                                 and jnp.issubdtype(v.dtype, jnp.floating)
-                                 else v for k, v in env.local.items()}
-            if amp and not has_backward:
-                # pure-inference AMP: whole net computes in bf16
-                with jax.named_scope(AMP_SCOPE):
-                    env.local = {k: _to_bf16(v)
-                                 for k, v in env.local.items()}
-            ctx = LoweringContext(program, base_key, is_test=is_test,
-                                  amp=amp, mesh=lowering_mesh,
-                                  pipeline_microbatches=microbatches,
-                                  compute_dtype=compute_dtype,
-                                  conv1x1_pallas=conv1x1_pallas_opt)
+            env, ctx = Executor._step_prologue(options, program, feed_arrays,
+                                               state, step, is_test)
             interpret_block_with_backward(program.global_block(), env, ctx)
             fetches = [env.get(n) if env.has(n) else None for n in fetch_names]
             if check_nan:
@@ -1670,99 +1579,6 @@ class Executor:
 
     def close(self):
         self._cache.clear()
-
-
-class _AutoLayoutStep:
-    """Single-device jitted step with XLA-chosen ("AUTO") layouts for the
-    persistable state.
-
-    Default jit gives every parameter the default layout at the step
-    function's boundary, but because the state is donated (input buffer
-    aliased to output), XLA must materialize a layout-normalizing ``copy``
-    for every parameter whose compute layout differs — measured 289 copies
-    and ~3-4% step time on ResNet-50.  Compiling with AUTO layouts on the
-    state lets XLA keep parameters in their compute layouts across steps
-    (feeds/fetches stay default so host IO is unsurprising).  Falls back to
-    plain jit if the layout API is unavailable.
-    """
-
-    def __init__(self, fn, fmt_registry, compiler_options=None,
-                 donate=True, label=None):
-        self._fn = fn
-        # what jit sees: the module is jit_pt_<label>, as CachedStep's
-        self._named = compile_cache.named_step(fn, label)
-        # donate=False: check_nan_inf variants (same contract as
-        # CachedStep) — pre-step state survives for the NaN bisect
-        self._donate_kw = {"donate_argnums": (1,)} if donate else {}
-        self._plain = jax.jit(self._named, **self._donate_kw)
-        self._compiled = None
-        self._state_formats = None
-        self._registry = fmt_registry  # shared across an Executor's variants
-        self._opts = dict(compiler_options or {})
-        self._failed = False
-
-    def _compile(self, feeds, state, step):
-        from jax.experimental.layout import Format, Layout
-        auto = Format(Layout.AUTO)
-        dflt = Format()
-        # State formats are pinned executor-wide: the first variant to
-        # compile lets XLA choose (AUTO), every later variant (e.g. the
-        # fetch-nothing vs fetch-loss steps a training loop alternates
-        # between) reuses those exact formats — otherwise each variant picks
-        # its own AUTO layouts and the state would be layout-copied on every
-        # alternation.
-        in_state = {k: self._registry.get(k, auto) for k in state}
-        # the output state can have MORE keys than the input (a startup
-        # program creates every parameter from an empty scope) — size the
-        # out_shardings spec to the output pytree, not the input
-        out_struct = jax.eval_shape(self._fn, feeds, state, step)
-        out_state = {k: self._registry.get(k, auto) for k in out_struct[1]}
-        in_sh = (jax.tree.map(lambda _: dflt, feeds), in_state, dflt)
-        lowered = jax.jit(
-            self._named, in_shardings=in_sh,
-            out_shardings=(dflt, out_state),
-            **self._donate_kw,
-        ).lower(feeds, state, step)
-        comp = lowered.compile(
-            compiler_options=self._opts if self._opts else None)
-        # input_formats mirrors the arg pytree: (feeds, state, step);
-        # donated buffers alias in->out, so input formats ARE the steady
-        # state formats — record them for later variants
-        self._state_formats = comp.input_formats[0][1]
-        for k, f in self._state_formats.items():
-            self._registry.setdefault(k, f)
-        return comp
-
-    def __call__(self, feeds, state, step):
-        if self._failed:
-            return self._plain(feeds, state, step)
-        step = np.int64(step)
-        if self._compiled is None:
-            # Only the compile/layout-API phase may fall back: a failure here
-            # means AUTO layouts are unavailable, not that the program is
-            # broken.  Execution errors below must propagate — the state has
-            # been donated, so a silent plain-jit re-run would operate on
-            # deleted buffers and mask the real error.
-            try:
-                self._compiled = self._compile(feeds, state, step)
-                state = jax.tree.map(jax.device_put, state,
-                                     self._state_formats)
-            except Exception as e:
-                logger.warning(
-                    "auto_layout: AUTO-layout compilation failed (%s: %s); "
-                    "this executor falls back to plain jit permanently",
-                    type(e).__name__, e)
-                self._failed = True
-                return self._plain(feeds, state, step)
-        try:
-            return self._compiled(feeds, state, step)
-        except ValueError:
-            # state arrays in foreign layouts (first step after a
-            # checkpoint restore etc.): this is raised at argument-check
-            # time, before donation — normalize and retry
-            state = jax.tree.map(jax.device_put, state,
-                                 self._state_formats)
-            return self._compiled(feeds, state, step)
 
 
 def _nan_check_impl(names, fetches):

@@ -106,9 +106,3 @@ define_flag("autotune", False,
             "replay never searches.  Per-executor override: "
             "Executor(autotune=...); search via `python -m paddle_tpu "
             "tune <target>`.  (PADDLE_TPU_AUTOTUNE=1)")
-define_flag("conv1x1_pallas", False,
-            "route eligible 1x1 conv2d ops (groups=1, pad 0, dil 1, "
-            "128-divisible dims) to the hand-written Pallas dot kernels "
-            "(ops/pallas_conv.py) instead of XLA's conv emitter; "
-            "per-executor override: Executor(conv1x1_pallas=...), "
-            "per-layer override: layers.conv2d(use_pallas=...)")

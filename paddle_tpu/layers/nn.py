@@ -271,10 +271,13 @@ def conv2d(input, num_filters, filter_size, stride=1, padding=0, dilation=1,
     """fluid/layers/nn.py:562 (use_cudnn accepted+ignored: XLA owns conv
     algorithm selection).
 
-    ``use_pallas``: tri-state per-layer override of the ``conv1x1_pallas``
-    routing (flags.py / Executor(conv1x1_pallas=...)): True forces the
-    hand-written Pallas dot kernel on eligible 1x1 shapes, False pins this
-    layer to XLA's emitter, None (default) defers to the executor/flag."""
+    ``use_pallas``: the one way to select the hand-written Pallas 1x1
+    kernel (``ops/pallas_conv.py``).  True writes the op attribute
+    ``use_pallas``, and the conv2d lowering then takes the kernel on
+    eligible 1x1 shapes (single device, TPU backend); False and None
+    (default) leave this layer to XLA's emitter.  The choice lives in the
+    Program, so it is part of the content digest; no executor option and
+    no process flag is consulted."""
     helper = LayerHelper("conv2d", param_attr=param_attr, bias_attr=bias_attr,
                          act=act, name=name)
     dtype = input.dtype

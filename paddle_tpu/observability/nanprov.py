@@ -28,41 +28,22 @@ __all__ = ["bisect_step", "format_diagnosis", "make_eager_context"]
 
 def make_eager_context(executor, program, feed_arrays, state, step: int,
                        is_test: bool = False):
-    """``(env, ctx, bw_idx)`` for an eager per-op replay of one step,
-    replicating the compiled step's input dtype coercion EXACTLY
-    (core/executor.py ``_make_fn``): compute_dtype upcast first, then
-    pure-inference AMP bf16.  Shared by the NaN bisect here and the
-    per-op profiler (``observability.opprof``) so both replay at the
-    SAME precision the compiled step computed at — a diagnosis or a
-    per-op timing taken at another precision would describe a different
+    """``(env, ctx, bw_idx)`` for an eager per-op replay of one step: the
+    executor's own prologue (core/executor.py ``_step_prologue``, the one
+    the compiled step traces) on concrete arrays, plus the position of the
+    ``backward`` op.  Shared by the NaN bisect here and the per-op
+    profiler (``observability.opprof``) so both replay at the SAME
+    precision the compiled step computed at — a diagnosis or a per-op
+    timing taken at another precision would describe a different
     computation."""
-    import jax
     import jax.numpy as jnp
 
-    from ..core.executor import Env, LoweringContext, _to_bf16
-
-    ops = program.global_block().ops
-    bw_idx = next((i for i, op in enumerate(ops)
+    env, ctx = executor._step_prologue(
+        executor._step_options(), program,
+        {k: jnp.asarray(v) for k, v in feed_arrays.items()},
+        {k: jnp.asarray(v) for k, v in state.items()}, step, is_test)
+    bw_idx = next((i for i, op in enumerate(program.global_block().ops)
                    if op.type == "backward"), None)
-
-    env = Env(program.global_block())
-    env.local.update({k: jnp.asarray(v) for k, v in state.items()})
-    env.local.update({k: jnp.asarray(v) for k, v in feed_arrays.items()})
-    if executor.compute_dtype is not None:
-        cd = jnp.dtype(executor.compute_dtype)
-        env.local = {k: v.astype(cd) if hasattr(v, "dtype")
-                     and jnp.issubdtype(v.dtype, jnp.floating)
-                     else v for k, v in env.local.items()}
-    if executor.amp and bw_idx is None:
-        env.local = {k: _to_bf16(v) for k, v in env.local.items()}
-
-    base_key = jax.random.fold_in(
-        jax.random.PRNGKey(program.random_seed), step)
-    ctx = LoweringContext(
-        program, base_key, is_test=is_test, amp=executor.amp,
-        mesh=getattr(executor, "mesh", None),
-        compute_dtype=executor.compute_dtype,
-        conv1x1_pallas=executor.conv1x1_pallas)
     return env, ctx, bw_idx
 
 

@@ -248,8 +248,7 @@ def profile_program(program, *, executor=None, feed=None, state=None,
     import numpy as np
 
     from ..core import compile_cache
-    from ..core.executor import (Env, LoweringContext, _run_backward,
-                                 _to_bf16, run_op)
+    from ..core.executor import Env, _run_backward, _to_bf16, run_op
     from .nanprov import make_eager_context
 
     if executor is None:
@@ -260,16 +259,10 @@ def profile_program(program, *, executor=None, feed=None, state=None,
         scope = global_scope()
 
     gb = program.global_block()
-    feed_arrays = dict(feed) if feed is not None \
-        else synth_feeds(program, batch=batch, seq_len=seq_len)
-    # the same declared-dtype coercion Executor.run applies to feeds
-    for name, val in list(feed_arrays.items()):
-        arr = val if isinstance(val, jax.Array) else np.asarray(val)
-        if gb.has_var(name):
-            want = jax.dtypes.canonicalize_dtype(gb.var(name).dtype)
-            if arr.dtype != want:
-                arr = arr.astype(want)
-        feed_arrays[name] = arr
+    # feeds at their declared dtypes, as Executor.run coerces them
+    feed_arrays = executor._coerce_feeds(
+        program, feed if feed is not None
+        else synth_feeds(program, batch=batch, seq_len=seq_len), None, False)
     if state is None:
         state = synth_state(program, scope=scope, batch=batch)
 
@@ -376,11 +369,8 @@ def profile_program(program, *, executor=None, feed=None, state=None,
             fenv2 = Env(gb)
             fenv2.local.update(
                 {k: _to_bf16(v) for k, v in initial.items()})
-        ctx2 = LoweringContext(
-            program, ctx.base_key, is_test=is_test, amp=executor.amp,
-            mesh=getattr(executor, "mesh", None),
-            compute_dtype=executor.compute_dtype,
-            conv1x1_pallas=executor.conv1x1_pallas)
+        ctx2 = executor._lowering_context(executor._step_options(), program,
+                                          ctx.base_key, is_test)
         for i, op in enumerate(ops):
             # same per-op env discipline as the measured walk, so the
             # per-op table can sum to this total
@@ -630,9 +620,7 @@ def _compiled_facts(executor, program, feed_arrays, state, is_test):
         sc = Scope()
         for k, v in state.items():
             sc.set(k, v)
-        exe = Executor(amp=executor.amp,
-                       compute_dtype=executor.compute_dtype,
-                       conv1x1_pallas=executor.conv1x1_pallas)
+        exe = Executor(**executor._lowering_options())
         compiled = exe.compile(program, feed=feed_arrays,
                                fetch_list=[], scope=sc,
                                is_test=is_test)

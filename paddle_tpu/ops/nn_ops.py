@@ -47,19 +47,6 @@ def _conv2d_space_to_depth(x, w, pads):
         dimension_numbers=("NCHW", "OIHW", "NCHW"))
 
 
-def _conv1x1_pallas_wanted(ctx, attrs) -> bool:
-    """Tri-state opt-in resolution for the hand-written 1x1 Pallas path:
-    per-op attr (layers.conv2d(use_pallas=...)) > per-executor setting
-    (Executor(conv1x1_pallas=...)) > process flag (conv1x1_pallas)."""
-    v = attrs.get("use_pallas")
-    if v is None:
-        v = getattr(ctx, "conv1x1_pallas", None)
-    if v is None:
-        from ..flags import get_flag
-        v = get_flag("conv1x1_pallas")
-    return bool(v)
-
-
 @register_op("conv2d", "depthwise_conv2d")
 def _conv2d(ctx, ins, attrs):
     """conv_op.cc / conv_cudnn_op: Input [N,C,H,W], Filter [M,C/g,kh,kw]."""
@@ -68,7 +55,10 @@ def _conv2d(ctx, ins, attrs):
     pads = _pair(attrs.get("paddings", [0, 0]))
     dil = _pair(attrs.get("dilations", [1, 1]))
     groups = int(attrs.get("groups", 1) or 1)
-    if _conv1x1_pallas_wanted(ctx, attrs):
+    # the hand-written 1x1 Pallas path is the OP's choice (the use_pallas
+    # attribute layers.conv2d writes): it lives in the Program, hence in
+    # the content digest, and no executor or process setting selects it
+    if attrs.get("use_pallas"):
         from . import pallas_conv
         interpret = bool(attrs.get("pallas_interpret", False))
         # single-device only: GSPMD treats pallas_call as opaque, so under

@@ -31,8 +31,8 @@ epilogues ride the streams for free (the data is already in VMEM):
 ``pallas_matmul`` carries a custom VJP whose backward runs the same
 kernels, so ``conv2d_1x1`` is fully differentiable end-to-end and the
 executor's autodiff pass routes conv gradients through the hand-written
-path automatically.  Everything here is opt-in behind the
-``conv1x1_pallas`` flag / ``Executor(conv1x1_pallas=True)`` — see
+path automatically.  Everything here is opt-in behind the conv2d op's
+``use_pallas`` attribute (``layers.conv2d(use_pallas=True)``) — see
 ``ops/nn_ops._conv2d`` for the routing and ``benchmark/conv_kernel.py``
 for the per-op A/B against XLA's emitters.
 

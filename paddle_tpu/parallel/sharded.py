@@ -133,22 +133,11 @@ class ShardedExecutor(Executor):
         return P()
 
     # -- overrides ----------------------------------------------------------
-    def run(self, program: Optional[Program] = None, feed=None,
-            fetch_list=None, **kw):
+    def _call_context(self, program: Optional[Program]):
+        # every run/run_steps/compile call plans first (auto_shard), then
+        # resolves, traces and dispatches under the mesh
         self._ensure_auto_plan(program)
-        with self.mesh:
-            return super().run(program, feed=feed, fetch_list=fetch_list,
-                               **kw)
-
-    def run_steps(self, num_steps, program=None, feed=None, **kw):
-        self._ensure_auto_plan(program)
-        with self.mesh:
-            return super().run_steps(num_steps, program, feed=feed, **kw)
-
-    def compile(self, program=None, *args, **kw):
-        self._ensure_auto_plan(program)
-        with self.mesh:
-            return super().compile(program, *args, **kw)
+        return self.mesh
 
     def _fingerprint_extras(self, program: Program):
         """Mesh + sharding-spec fingerprint components: the same program/

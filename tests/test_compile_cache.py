@@ -205,7 +205,7 @@ def test_shared_entry_retargets_to_live_client(rng):
     """A shared entry's step fn must not depend on its CREATOR program
     staying alive: when a content-identical client hits the entry, the
     fn's program weakref cell retargets to the client, so a later
-    re-trace (lazy-jit fallback, auto_layout re-jit) uses the live
+    re-trace (lazy-jit fallback) uses the live
     program instead of raising."""
     loss, feed = _build_net(rng)
     exe = pt.Executor()

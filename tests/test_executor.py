@@ -259,3 +259,103 @@ def test_run_steps_matches_per_step_run(rng):
     with pytest.raises(ValueError):
         exe.run_steps(3, feed={"x": xb, "y": yb}, fetch_list=[loss],
                       feeds_stacked=True)      # missing leading K axis
+
+
+def _removed(what):
+    from paddle_tpu import flags
+    from paddle_tpu.parallel import ShardedExecutor, mesh_for_axes
+    if what == "flag":
+        return flags.get_flag("conv1x1_pallas")
+    if what == "sharded_auto_layout":
+        return ShardedExecutor(mesh=mesh_for_axes({"dp": 2}),
+                               auto_layout=True)
+    return pt.Executor(**{what: True})
+
+
+@pytest.mark.parametrize("what,error", [
+    ("auto_layout", TypeError), ("sharded_auto_layout", TypeError),
+    ("conv1x1_pallas", TypeError), ("flag", KeyError)])
+def test_removed_executor_forks_are_refused(what, error):
+    """The two forks that never won on the chip are gone, not ignored: an
+    executor given either option raises like any unknown keyword (a
+    ShardedExecutor used to accept auto_layout and never look at it), and
+    the process flag no longer exists."""
+    with pytest.raises(error):
+        _removed(what)
+
+
+def _prologue_net(kind):
+    x = layers.data("x", shape=[8], dtype="float32")
+    y = layers.data("y", shape=[1], dtype="int64")
+    h = layers.dropout(layers.fc(x, size=16, act="relu"), dropout_prob=0.3)
+    pred = layers.fc(h, size=3, act="softmax")
+    loss = layers.mean(layers.cross_entropy(pred, y))
+    if kind != "amp_inference":
+        pt.optimizer.Momentum(learning_rate=0.1, momentum=0.9).minimize(loss)
+    return loss
+
+
+@pytest.mark.parametrize("kind", ["f32_train", "amp_inference", "amp_train"])
+def test_nanprov_replay_starts_from_the_executors_prologue(rng, kind,
+                                                           monkeypatch):
+    """The NaN bisect's eager replay (observability.nanprov) and the
+    executor start one step from the same env: every entry at the dtype
+    the ``use_jit=False`` executor gives it, and the same first-step loss
+    (dropout mask included: the same PRNG key)."""
+    from paddle_tpu.core import executor as ex
+    from paddle_tpu.observability import nanprov
+    loss = _prologue_net(kind)
+    prog = pt.default_main_program()
+    exe = pt.Executor(use_jit=False, amp=kind != "f32_train")
+    exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+    scope = pt.global_scope()
+    state0 = {k: scope.get(k) for k in exe._state_keys(prog, scope)}
+    feed = {"x": rng.rand(4, 8).astype("float64"),     # coerced to f32
+            "y": rng.randint(0, 3, (4, 1))}
+    is_test = kind == "amp_inference"
+
+    seen = []
+    real = ex.interpret_block_with_backward
+
+    def spy(block, env, ctx):
+        seen.append({k: str(v.dtype) for k, v in env.local.items()})
+        return real(block, env, ctx)
+    monkeypatch.setattr(ex, "interpret_block_with_backward", spy)
+    step = exe._step
+    (want,) = exe.run(feed=feed, fetch_list=[loss], is_test=is_test)
+    monkeypatch.undo()
+    (executors_env,) = seen
+
+    feeds = exe._coerce_feeds(prog, feed, None, False)
+    env, ctx, bw_idx = nanprov.make_eager_context(exe, prog, feeds, state0,
+                                                  step, is_test)
+    assert {k: str(v.dtype) for k, v in env.local.items()} == executors_env
+    assert executors_env["x"] == ("bfloat16" if is_test else "float32")
+    assert (bw_idx is None) == is_test
+    real(prog.global_block(), env, ctx)
+    np.testing.assert_array_equal(np.asarray(env.get(loss.name)), want)
+
+
+def test_traced_fn_outlives_its_executor():
+    """``_make_fn`` snapshots the executor's options: the fn it returns
+    holds no executor, so it still traces after its executor is gone
+    (``__graft_entry__.entry`` returns a closure over it alone and the
+    driver lowers that; ``export_model`` does the same)."""
+    import gc
+    import weakref
+
+    import jax
+    x = layers.data("x", shape=[8], dtype="float32")
+    pred = layers.fc(x, size=3, act="softmax")
+    prog = pt.default_main_program()
+    exe = pt.Executor(use_jit=False, amp=True)
+    exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+    scope = pt.global_scope()
+    state = {k: scope.get(k) for k in exe._state_keys(prog, scope)}
+    fn = exe._make_fn(prog, [pred.name], is_test=True)
+    gone = weakref.ref(exe)
+    del exe
+    gc.collect()
+    assert gone() is None
+    lowered = jax.jit(fn).lower({"x": np.zeros((4, 8), np.float32)}, state, 0)
+    assert "bf16" in lowered.as_text()       # amp=True rode the snapshot

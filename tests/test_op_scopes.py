@@ -259,12 +259,6 @@ def test_sharded_steps_are_named_and_scoped():
     assert "pt.conv2d:" in text
 
 
-def test_auto_layout_step_is_named():
-    from paddle_tpu.core.executor import _AutoLayoutStep
-    step = _AutoLayoutStep(lambda f, s, t: (f, s), {}, label="run_steps")
-    assert step._named.__name__ == "pt_run_steps"
-
-
 @pytest.mark.parametrize("fetch,return_numpy,drained", [
     (True, True, True), (True, False, False), (False, True, False)])
 def test_undrained_dispatch_stays_out_of_step_time(tmp_path, fetch,

@@ -138,7 +138,8 @@ def _bn_conv_program(use_pallas):
 def test_executor_routing_end_to_end(rng, monkeypatch):
     """Same program trained 3 steps through XLA's conv emitter and through
     the Pallas route (interpret mode): losses must track, proving the
-    opt-in switch routes the forward AND the autodiff gradients.  A
+    op's ``use_pallas`` attribute alone (no executor option, no flag)
+    routes the forward AND the autodiff gradients.  A
     counting wrapper on ``conv2d_1x1`` proves the route was actually
     taken — nn_ops has four silent fall-through gates, and without the
     probe a routing regression would make this test pass vacuously
@@ -168,7 +169,7 @@ def test_executor_routing_end_to_end(rng, monkeypatch):
     monkeypatch.setattr(
         pallas_conv, "conv2d_1x1",
         lambda *a, **kw: (calls.append(1), real(*a, **kw))[1])
-    exe = pt.Executor(conv1x1_pallas=True)
+    exe = pt.Executor()
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
     pallas = [float(exe.run(prog, feed=feeds, fetch_list=[loss])[0])
               for _ in range(3)]
@@ -177,14 +178,22 @@ def test_executor_routing_end_to_end(rng, monkeypatch):
     np.testing.assert_allclose(base, pallas, rtol=2e-4, atol=2e-5)
 
 
-def test_executor_flag_off_is_default_path(rng):
-    """conv1x1_pallas defaults OFF: without the opt-in nothing routes to
-    Pallas (the attr-free program must not consult the kernel at all on a
-    CPU backend — no interpret attr set, would raise if routed)."""
+def test_executor_flag_off_is_default_path(rng, monkeypatch):
+    """The kernel is OFF unless the op asks: a conv2d without the
+    ``use_pallas`` attribute never consults it, eligible shape or not
+    (``conv1x1_eligible`` and the kernel would both raise here)."""
+    from paddle_tpu.ops import pallas_conv
+
+    def refuse(*a, **kw):
+        raise AssertionError("attr-free conv2d consulted the Pallas route")
+    monkeypatch.setattr(pallas_conv, "conv1x1_eligible", refuse)
+    monkeypatch.setattr(pallas_conv, "conv2d_1x1", refuse)
     feeds = {"img": rng.rand(4, 128, 8, 8).astype("float32") * 0.1,
              "label": rng.randint(0, 10, (4, 1))}
     loss = _bn_conv_program(use_pallas=None)
     prog = pt.default_main_program()
+    assert all("use_pallas" not in op.attrs
+               for op in prog.global_block().ops)
     exe = pt.Executor()
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
     v = float(exe.run(prog, feed=feeds, fetch_list=[loss])[0])

@@ -104,6 +104,19 @@ def test_no_new_broad_except_swallows():
     assert not problems, "\n".join(problems)
 
 
+def test_lowering_context_is_constructed_in_the_executor_only():
+    """How executor options become a LoweringContext is written ONCE
+    (``Executor._lowering_context``): an eager replay that built its own
+    (observability.nanprov and opprof each did) would lower under another
+    configuration than the compiled step the day an option is added."""
+    sites = [rel for rel, tree in _iter_sources()
+             for node in ast.walk(tree)
+             if isinstance(node, ast.Call)
+             and getattr(node.func, "id", getattr(node.func, "attr", None))
+             == "LoweringContext"]
+    assert sites == ["paddle_tpu/core/executor.py"], sites
+
+
 def _registered_names(call_name: str):
     """(name, file, lineno) for every string literal passed to
     register_op(...) / register_shape_fn(...) decorator calls."""

@@ -440,9 +440,11 @@ def test_rejected_request_span_carries_typed_status(tmp_path):
     assert len(reqs) == len(admitted) + rejected
 
 
-def test_failed_dispatch_still_emits_step_span(tmp_path):
+@pytest.mark.parametrize("path", ["run", "run_steps"])
+def test_failed_dispatch_still_emits_step_span(tmp_path, path):
     """A fatally failing dispatch ends the executor/step root with the
-    typed status instead of leaving its dispatch child orphaned."""
+    typed status instead of leaving its dispatch child orphaned — on
+    both paths through the one observed dispatch."""
     from paddle_tpu.testing import faultinject
     log = tmp_path / "fail.jsonl"
     flags.set_flag("observe", True)
@@ -453,14 +455,18 @@ def test_failed_dispatch_still_emits_step_span(tmp_path):
     faultinject.configure("executor.dispatch@*=error")
     try:
         with pytest.raises(Exception, match="injected"):
-            exe.run(feed=_batches(1)[0], fetch_list=[loss])
+            if path == "run":
+                exe.run(feed=_batches(1)[0], fetch_list=[loss])
+            else:
+                exe.run_steps(2, feed=_batches(1)[0], fetch_list=[loss])
     finally:
         faultinject.clear()
         flags.set_flag("metrics_log", "")
     spans = _spans(_read_events(log))
     _assert_tree_invariants(spans)
     failed = [e for e in spans if e["name"] == "executor/step"
-              and (e.get("labels") or {}).get("status") == "InjectedFault"]
+              and (e.get("labels") or {}).get("status") == "InjectedFault"
+              and (e.get("labels") or {}).get("path") == path]
     assert failed, f"no failed step span in {[e['name'] for e in spans]}"
 
 
