@@ -15,8 +15,8 @@ With ``capacity_factor`` None the op takes the DROPLESS lowering instead
 (``_dropless``): no token is ever dropped and nothing has a capacity.  The
 assignments are sorted by expert, their rows gathered, the experts run as
 grouped products over the sorted rows (``ops/pallas_kernels.py
-grouped_matmul``) and the results are weighted and gathered back; work and
-memory go with the N * top_k routed rows.  It is the lowering of today's
+grouped_matmul``, ``gated_grouped_matmul``) and the results are weighted
+and gathered back; work and memory go with the N * top_k routed rows.  It is the lowering of today's
 sparse-expert decoders (top-8 of 64 and the like), where a [T, E, C]
 dispatch tensor cannot be held.  Which of the two ran is counted at trace
 time as ``route/moe:{dropless,capacity}`` in ``profiler.compile_stats()``.
@@ -84,10 +84,15 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act):
     dispatch: a stable sort of the N * top_k assignments by expert; each
     expert's rows laid out in whole tiles of ``ROW_TILE`` (at least one),
     padding rows zero.  experts: act(x Wg) * (x Wu) through Wd (no Wg:
-    act(x Wu) Wd) as grouped products.  combine: every assignment's row
-    gathered back, weighted, summed over the token's ``top_k``.
+    act(x Wu) Wd) as grouped products; with Wg, gate and up are ONE paired
+    product a direction (``gated_grouped_matmul``: the rows read once for
+    both stacks, ``act(gate) * up`` in the forward kernel's epilogue, the
+    two gradients of the rows summed inside one kernel), counted at trace
+    time as ``route/moe:gated_pair``, so the stage is six kernels forward
+    and backward.  combine: every assignment's row gathered back, weighted,
+    summed over the token's ``top_k``.
     """
-    from .pallas_kernels import grouped_matmul
+    from .pallas_kernels import gated_grouped_matmul, grouped_matmul
 
     n, _ = xt.shape
     experts = gate_w.shape[-1]
@@ -128,9 +133,12 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act):
         rows = _gather_rows(xt, jnp.where(held, assignment // top_k, n),
                             row_of)
     with jax.named_scope("moe.experts"):
-        up = grouped_matmul(rows, w_up, tile_group, num_tiles)
-        hidden = act(up) if w_gate is None else \
-            act(grouped_matmul(rows, w_gate, tile_group, num_tiles)) * up
+        if w_gate is None:
+            hidden = act(grouped_matmul(rows, w_up, tile_group, num_tiles))
+        else:
+            compile_cache.stats().bump("route/moe:gated_pair")
+            hidden = gated_grouped_matmul(rows, w_gate, w_up, tile_group,
+                                          num_tiles, act)
         down = grouped_matmul(hidden, w_down, tile_group, num_tiles)
     with jax.named_scope("moe.combine"):
         picked = _gather_rows(down, row_of.reshape(-1), assignment[:, None])

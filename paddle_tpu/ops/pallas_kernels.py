@@ -17,11 +17,13 @@ kernels in interpret mode).
 
 ``grouped_matmul`` is the product of rows sorted by group with each group's
 own matrix (the experts of the dropless ``moe`` lowering), forward and both
-gradients as kernels.
+gradients as kernels; ``gated_grouped_matmul`` runs the gate and up stacks of
+gated experts through the same three kernels as a pair.
 """
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -449,6 +451,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 # holds the whole contraction, stays in VMEM while consecutive tiles share
 # a group, and so is read from HBM once.
 #
+# Every kernel takes a TUPLE of stacks that share the layout (the gate and
+# up stacks of gated experts; one stack otherwise): a tile of rows is read
+# and cast once for all of them, and the gradient of the rows is summed
+# over the stacks before its one store.  A weight block is capped at
+# ``GMM_BLOCK_ELEMS`` whatever the number of stacks: two stacks' blocks,
+# double-buffered, are 32 MiB of ``GMM_VMEM_BYTES``.
+#
 # Float32 operands are rounded to bfloat16 at the MXU and accumulated in
 # float32: one pass, what XLA's default precision does for every other
 # product of a program on the TPU.  Interpreted (any other backend) the
@@ -457,7 +466,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 # [2048, 1024], forward and backward: 49.5 ms at these values and the
 # lowering's row tiles of 128; 53.3 with blocks of 1 << 20, 49.5 with
 # 1 << 22, 53.9 / 52.1 / 57.8 with tiles of 64 / 256 / 512; 75.2 with
-# lax.ragged_dot in the kernels' place.  PERF.md section 6, PR 27)
+# lax.ragged_dot in the kernels' place.  PERF.md section 6, PR 27.  With
+# gate and up as a pair, PR 31: the ``moe`` op 47.6 -> 42.6 ms a step, its
+# experts stage 28.7 -> 23.7 in six kernels for nine: of the pair's three,
+# forward with ``act(gate) * up`` in its epilogue 4.64 ms (two single ones
+# and the XLA fusion: 6.1), the rows' gradient 4.54 (two and XLA's add:
+# 7.5), both stacks' gradients 5.02 (two: 5.6).  PERF.md section 6, PR 31)
 GMM_BLOCK_ELEMS = 1 << 21        # a weight / gradient block: 8 MiB of f32
 GMM_VMEM_BYTES = 64 << 20        # scoped VMEM these kernels may take
 
@@ -473,16 +487,22 @@ def _largest_tile(n, cap):
     return n
 
 
+def _mxu_operand(x, interpret):
+    """A product's operand as the MXU takes it: compiled, float32 rounded
+    to bfloat16; interpreted, as it is."""
+    if not interpret and x.dtype == jnp.float32:
+        return x.astype(jnp.bfloat16)
+    return x
+
+
 def _mxu_dot(a, b, contract, interpret):
     """The kernels' one product, float32 out.  Compiled: float32 operands
     rounded to bfloat16, one MXU pass whatever ``jax_default_matmul_precision``
     says (Mosaic has no multi-pass product of bfloat16 operands).
     Interpreted: as XLA computes it on that backend."""
-    if not interpret:
-        a, b = (x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x
-                for x in (a, b))
     return jax.lax.dot_general(
-        a, b, (contract, ((), ())),
+        _mxu_operand(a, interpret), _mxu_operand(b, interpret),
+        (contract, ((), ())),
         precision=None if interpret else jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.float32)
 
@@ -500,54 +520,77 @@ def _gmm_call_params(interpret, *semantics):
         dimension_semantics=semantics, vmem_limit_bytes=GMM_VMEM_BYTES)}
 
 
-def _gmm_kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref, *,
-                transpose_rhs, interpret):
+def _gmm_kernel(group_ref, count_ref, *refs, stacks, transpose_rhs, act,
+                interpret):
+    """``refs``: the row tile, the stacks' weight blocks, their outputs and,
+    with ``act``, one more for ``act(first output) * second``.  With
+    ``transpose_rhs``: the stacks' row tiles, their weight blocks, the ONE
+    output that sums the stacks' products."""
     i = pl.program_id(1)
+    lhs_refs = refs[:stacks if transpose_rhs else 1]
+    rhs_refs = refs[len(lhs_refs):len(lhs_refs) + stacks]
+    out_refs = refs[len(lhs_refs) + stacks:]
 
     @pl.when(i < count_ref[0])
     def _compute():
-        out_ref[...] = _mxu_dot(
-            lhs_ref[...], rhs_ref[0], ((1,), (1 if transpose_rhs else 0,)),
-            interpret).astype(out_ref.dtype)
+        if transpose_rhs:
+            outs = [functools.reduce(operator.add, (
+                _mxu_dot(lhs_ref[...], rhs_ref[0], ((1,), (1,)), interpret)
+                for lhs_ref, rhs_ref in zip(lhs_refs, rhs_refs)))]
+        else:
+            lhs = _mxu_operand(lhs_refs[0][...], interpret)
+            outs = [_mxu_dot(lhs, rhs_ref[0], ((1,), (0,)), interpret)
+                    .astype(out_refs[0].dtype) for rhs_ref in rhs_refs]
+            if act is not None:      # of the values as stored, which the
+                outs.append(act(outs[0]) * outs[1])   # backward reads
+        for out_ref, out in zip(out_refs, outs):
+            out_ref[...] = out.astype(out_ref.dtype)
 
     @pl.when(i >= count_ref[0])
     def _unused():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        for out_ref in out_refs:
+            out_ref[...] = jnp.zeros_like(out_ref)
 
 
-def _gmm(lhs, rhs, tile_group, num_tiles, transpose_rhs, interpret):
-    """out[r] = lhs[r] @ rhs[group of r's tile] (``rhs`` [G, K, N]), or
-    @ rhs[..].T with ``transpose_rhs`` (``rhs`` [G, N, K]).  Grid (column
-    blocks, row tiles), rows innermost, so the weight block changes only
-    where the group does."""
-    rows, k = lhs.shape
+def _gmm(lhs, rhs, tile_group, num_tiles, transpose_rhs, interpret,
+         act=None):
+    """``outs[s][r] = lhs[0][r] @ rhs[s][group of r's tile]`` for the stacks
+    ``rhs`` (a tuple of [G, K, N]) and the one ``lhs`` (a 1-tuple), and with
+    ``act`` (two stacks) a third, ``act(outs[0]) * outs[1]``, from the
+    kernel's epilogue.  With ``transpose_rhs`` the 1-tuple of
+    ``sum_s lhs[s][r] @ rhs[s][..].T`` (``rhs[s]`` [G, N, K], one ``lhs`` a
+    stack).  Grid (column blocks, row tiles), rows innermost, so a weight
+    block changes only where the group does."""
+    rows, k = lhs[0].shape
     tm = rows // tile_group.shape[0]
-    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    n = rhs[0].shape[1] if transpose_rhs else rhs[0].shape[2]
     tn = _largest_tile(n, max(128, GMM_BLOCK_ELEMS // k))
 
     row = _tile_in_use
+    lhs_spec = pl.BlockSpec((tm, k), lambda j, i, group, count:
+                            (row(i, count), 0))
     rhs_spec = pl.BlockSpec(
         (1, tn, k), lambda j, i, group, count: (group[row(i, count)], j, 0)
     ) if transpose_rhs else pl.BlockSpec(
         (1, k, tn), lambda j, i, group, count: (group[row(i, count)], 0, j))
+    outs = 1 if transpose_rhs else len(rhs) + (act is not None)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
+        functools.partial(_gmm_kernel, stacks=len(rhs),
+                          transpose_rhs=transpose_rhs, act=act,
                           interpret=interpret),
-        out_shape=_sds(lhs, (rows, n), lhs.dtype),
+        out_shape=[_sds(lhs[0], (rows, n), lhs[0].dtype)] * outs,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(n // tn, rows // tm),
-            in_specs=[
-                pl.BlockSpec((tm, k), lambda j, i, group, count:
-                             (row(i, count), 0)),
-                rhs_spec],
-            out_specs=pl.BlockSpec((tm, tn),
-                                   lambda j, i, group, count: (i, j))),
+            in_specs=[lhs_spec] * len(lhs) + [rhs_spec] * len(rhs),
+            out_specs=[pl.BlockSpec(
+                (tm, tn), lambda j, i, group, count: (i, j))] * outs),
         **_gmm_call_params(interpret, "parallel", "arbitrary"),
-    )(tile_group, num_tiles, lhs, rhs)
+    )(tile_group, num_tiles, *lhs, *rhs)
 
 
-def _tgmm_kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref, *,
-                 interpret):
+def _tgmm_kernel(group_ref, count_ref, lhs_ref, *refs, interpret):
+    """``refs``: the stacks' row tiles (cotangents), their output blocks."""
+    stacks = len(refs) // 2
     i = pl.program_id(2)
     # (an unused tile repeats the last group: neither first nor computed)
     first = jnp.logical_or(
@@ -555,20 +598,24 @@ def _tgmm_kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref, *,
 
     @pl.when(first)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        for out_ref in refs[stacks:]:
+            out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(i < count_ref[0])
     def _compute():
-        out_ref[0] += _mxu_dot(lhs_ref[...], rhs_ref[...], ((0,), (0,)),
-                               interpret).astype(out_ref.dtype)
+        lhs = _mxu_operand(lhs_ref[...], interpret)
+        for rhs_ref, out_ref in zip(refs[:stacks], refs[stacks:]):
+            out_ref[0] += _mxu_dot(lhs, rhs_ref[...], ((0,), (0,)),
+                                   interpret).astype(out_ref.dtype)
 
 
 def _tgmm(lhs, rhs, tile_group, num_tiles, groups, interpret):
-    """out[g] = lhs[rows of g].T @ rhs[rows of g]: [G, K, N] from [R, K] and
-    [R, N].  Grid (K blocks, N blocks, row tiles), rows innermost: a
-    group's output block stays in VMEM and accumulates over its tiles."""
+    """``outs[s][g] = lhs[rows of g].T @ rhs[s][rows of g]``: a [G, K, N] for
+    each of the tuple ``rhs`` of [R, N], from the one [R, K].  Grid (K
+    blocks, N blocks, row tiles), rows innermost: a group's output blocks
+    stay in VMEM and accumulate over its tiles."""
     rows, k = lhs.shape
-    n = rhs.shape[1]
+    n = rhs[0].shape[1]
     tm = rows // tile_group.shape[0]
     tk = _largest_tile(k, 1024)
     tn = _largest_tile(n, max(128, GMM_BLOCK_ELEMS // tk))
@@ -576,35 +623,35 @@ def _tgmm(lhs, rhs, tile_group, num_tiles, groups, interpret):
     row = _tile_in_use
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, interpret=interpret),
-        out_shape=_sds(lhs, (groups, k, n), lhs.dtype),
+        out_shape=[_sds(lhs, (groups, k, n), lhs.dtype)] * len(rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(k // tk, n // tn, rows // tm),
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda a, b, i, group, count:
-                             (row(i, count), a)),
+                             (row(i, count), a))] + [
                 pl.BlockSpec((tm, tn), lambda a, b, i, group, count:
-                             (row(i, count), b))],
-            out_specs=pl.BlockSpec(
+                             (row(i, count), b))] * len(rhs),
+            out_specs=[pl.BlockSpec(
                 (1, tk, tn), lambda a, b, i, group, count:
-                (group[row(i, count)], a, b))),
+                (group[row(i, count)], a, b))] * len(rhs)),
         **_gmm_call_params(interpret, "parallel", "parallel", "arbitrary"),
-    )(tile_group, num_tiles, lhs, rhs)
+    )(tile_group, num_tiles, lhs, *rhs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _grouped(lhs, rhs, tile_group, num_tiles, interpret):
-    return _gmm(lhs, rhs, tile_group, num_tiles, False, interpret)
+    return _gmm((lhs,), (rhs,), tile_group, num_tiles, False, interpret)[0]
 
 
 def _grouped_vjp_fwd(lhs, rhs, tile_group, num_tiles, interpret):
-    return (_gmm(lhs, rhs, tile_group, num_tiles, False, interpret),
+    return (_gmm((lhs,), (rhs,), tile_group, num_tiles, False, interpret)[0],
             (lhs, rhs, tile_group, num_tiles))
 
 
 def _grouped_vjp_bwd(interpret, res, g):
     lhs, rhs, tile_group, num_tiles = res
-    return (_gmm(g, rhs, tile_group, num_tiles, True, interpret),
-            _tgmm(lhs, g, tile_group, num_tiles, rhs.shape[0], interpret),
+    return (*_gmm((g,), (rhs,), tile_group, num_tiles, True, interpret),
+            *_tgmm(lhs, (g,), tile_group, num_tiles, rhs.shape[0], interpret),
             None, None)
 
 
@@ -620,6 +667,43 @@ def grouped_matmul(lhs, rhs, tile_group, num_tiles):
     other backend."""
     return _grouped(lhs, rhs, tile_group, num_tiles,
                     jax.default_backend() != "tpu")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gated(rows, w_gate, w_up, tile_group, num_tiles, act, interpret):
+    return _gated_vjp_fwd(rows, w_gate, w_up, tile_group, num_tiles, act,
+                          interpret)[0]
+
+
+def _gated_vjp_fwd(rows, w_gate, w_up, tile_group, num_tiles, act,
+                   interpret):
+    gate, up, hidden = _gmm((rows,), (w_gate, w_up), tile_group, num_tiles,
+                            False, interpret, act)
+    return hidden, (rows, w_gate, w_up, tile_group, num_tiles, gate, up)
+
+
+def _gated_vjp_bwd(act, interpret, res, g):
+    rows, w_gate, w_up, tile_group, num_tiles, gate, up = res
+    d_pre = jax.vjp(lambda a, b: act(a) * b, gate, up)[1](g)
+    return (*_gmm(d_pre, (w_gate, w_up), tile_group, num_tiles, True,
+                  interpret),
+            *_tgmm(rows, d_pre, tile_group, num_tiles, w_up.shape[0],
+                   interpret),
+            None, None)
+
+
+_gated.defvjp(_gated_vjp_fwd, _gated_vjp_bwd)
+
+
+def gated_grouped_matmul(rows, w_gate, w_up, tile_group, num_tiles, act):
+    """``act(rows @ w_gate[g]) * (rows @ w_up[g])`` in ``grouped_matmul``'s
+    layout, gate and up as ONE pass a direction: a tile of ``rows`` is read
+    once for both stacks, forward and in the stacks' gradients, and the
+    gradient of ``rows`` is summed over the two stacks inside its kernel.
+    ``act`` is an elementwise function; differentiable in ``rows`` and both
+    stacks."""
+    return _gated(rows, w_gate, w_up, tile_group, num_tiles, act,
+                  jax.default_backend() != "tpu")
 
 
 # ---------------------------------------------------------------------------

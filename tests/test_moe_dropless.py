@@ -164,11 +164,13 @@ def _program(capacity_factor, **kw):
                   .astype("float32")}
 
 
-@pytest.mark.parametrize("capacity_factor,route", [(None, "dropless"),
-                                                   (1.25, "capacity")])
-def test_the_lowering_that_ran_is_counted(capacity_factor, route):
+@pytest.mark.parametrize("capacity_factor,gated,ran", [
+    (None, True, {"dropless", "gated_pair"}),
+    (None, False, {"dropless"}),
+    (1.25, False, {"capacity"})])
+def test_the_lowering_that_ran_is_counted(capacity_factor, gated, ran):
     loss, feed = _program(capacity_factor,
-                          **({"gated": True, "act": "silu"}
+                          **({"gated": gated, "act": "silu"}
                              if capacity_factor is None else {}))
     exe = pt.Executor()
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
@@ -180,7 +182,7 @@ def test_the_lowering_that_ran_is_counted(capacity_factor, route):
     after = profiler.compile_stats().snapshot()
     routes = {k.split(":", 1)[1]: after[k] - before.get(k, 0)
               for k in after if k.startswith("route/moe:")}
-    assert {k: v for k, v in routes.items() if v} == {route: 1}
+    assert {k: v for k, v in routes.items() if v} == dict.fromkeys(ran, 1)
 
 
 def test_dropless_refuses_expert_parallelism():

@@ -157,6 +157,51 @@ def test_dropless_moe_names_its_four_stages_inside_its_op_scope():
     assert not any("pt.moe." in n for n in names)
 
 
+def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
+    """Lowered for the TPU (no chip needed to LOWER), the experts stage of a
+    gated ``moe`` op is six Pallas custom calls: the gate/up pair forward
+    (gate, up and ``act(gate) * up``), ``down`` forward, and backward the
+    pair's gradient of the rows (two cotangents and two stacks in), the
+    pair's gradient of both stacks (two results) and ``down``'s two.  All carry
+    ``pt.moe:<b>.<p>/moe.experts`` in their location, which is how
+    ``moe_step_ms``, ``moe_experts_roofline_pct`` and the stages' table of
+    a traced run find them."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import moe_ops
+
+    n, d, h, e = 64, 128, 256, 4
+    rng = np.random.RandomState(0)
+    args = [jnp.asarray(rng.randn(*shape).astype("float32")) for shape in (
+        (n, d), (d, e), (e, d, h), (e, d, h), (e, h, d))]
+
+    def loss(xt, router, w_gate, w_up, w_down):
+        with jax.named_scope("pt.moe:0.3"):
+            out, aux, z = moe_ops._dropless(xt, router, w_gate, w_up, w_down,
+                                            2, jax.nn.silu)
+        return jnp.sum(out) + aux + z
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(jax.grad(loss, argnums=(0, 2, 3, 4))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            operands, results, loc = re.search(
+                r" : \((.*)\) -> (.*) loc\((#loc\d+)\)$", line).groups()
+            calls.append((operands.count("tensor<"), results.count("tensor<"),
+                          locs[loc][locs[loc].index("/"):]))
+    fwd = "/jvp(pt.moe:0.3)/moe.experts/pallas_call"
+    bwd = "/transpose(jvp(pt.moe:0.3))/moe.experts/pallas_call"
+    # (operands with the layout's two, results, where)
+    assert sorted(calls) == sorted([
+        (5, 3, fwd), (4, 1, fwd), (6, 1, bwd), (5, 2, bwd), (4, 1, bwd),
+        (4, 1, bwd)])
+    assert compile_cache.stats().snapshot()["route/moe:gated_pair"] == 1
+
+
 def test_executor_emitted_work_has_scopes_of_its_own():
     loss, feed = _conv_net()
     cp = _compile(pt.Executor(amp=True), feed, loss, num_steps=3)
