@@ -274,10 +274,23 @@ class Block:
         return v
 
     def create_parameter(self, name, shape, dtype, **kwargs) -> Parameter:
-        p = Parameter(self, name, shape, dtype, **kwargs)
+        """The parameter ``name`` of the program.  A name that stands
+        already IS that parameter: it is returned as it stands (the ops
+        that hold it keep reading the same object, and their gradients
+        sum), provided the shape and dtype asked for are its own."""
         # parameters always live in block 0 (reference: framework.py
         # global_block parameter creation)
         gb = self.program.global_block()
+        standing = gb.vars.get(name)
+        if isinstance(standing, Parameter):
+            asked = (tuple(int(s) for s in shape), convert_dtype(dtype))
+            if asked != (standing.shape, standing.dtype):
+                raise ValueError(
+                    f"parameter {name!r} stands as {standing.shape} "
+                    f"{standing.dtype.name}; it cannot be shared as "
+                    f"{asked[0]} {asked[1].name}")
+            return standing
+        p = Parameter(self, name, shape, dtype, **kwargs)
         gb.vars[name] = p
         return p
 

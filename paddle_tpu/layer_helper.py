@@ -51,8 +51,12 @@ class LayerHelper:
                        attr.trainable, attr.gradient_clip,
                        attr.sharding).to_kwargs()
         kw.pop("name", None)
+        shared = isinstance(self.main_program.global_block().vars.get(name),
+                            Parameter)
         p = self.block.create_parameter(name=name, shape=shape, dtype=dtype,
                                         **kw)
+        if shared:      # a name that stands: drawn once, where it was made
+            return p
         # ... and emit its initializer into the startup program
         sb = self.startup_program.global_block()
         sv = sb.create_var(name=name, shape=shape, dtype=dtype,

@@ -8,6 +8,19 @@ TPU-native notes:
   executor runs it under lax.scan via the ``rnn`` op — differentiable, unlike
   a raw while loop, and pipelined by XLA.  DynamicRNN masks finished
   sequences instead of shrinking the batch (shrink_rnn_memory_op analog).
+* ``Repeat`` runs a sub-block a fixed number of times over carried values,
+  with no sequence: the ops and their weights stand ONCE in the Program
+  (a looped, weight-shared stack of layers), every pass reads the same
+  parameters, and their gradients sum over the passes.  What a pass leaves
+  (``output``) comes out stacked along a new leading axis.  The ``rnn``
+  lowering walks a SEQUENCE (its length, its masks, the per-step slices);
+  ``repeat`` has none of that and shares none of its code.
+* ``recompute`` marks a stretch of the Program whose intermediate values
+  the backward pass computes again instead of keeping them
+  (``jax.checkpoint`` where the forward slice is differentiated): only what
+  the stretch reads stays alive between the two passes.  The values are the
+  same with and without it; what changes is the memory a step needs and
+  the forward work it does twice.
 """
 from __future__ import annotations
 
@@ -22,7 +35,7 @@ __all__ = [
     "less_than", "equal", "array_read", "array_write", "array_length",
     "create_array", "lod_rank_table", "max_sequence_len",
     "lod_tensor_to_array", "array_to_lod_tensor", "shrink_memory",
-    "reorder_lod_tensor_by_rank", "ConditionalBlock",
+    "reorder_lod_tensor_by_rank", "ConditionalBlock", "Repeat", "recompute",
 ]
 
 
@@ -125,6 +138,129 @@ class ConditionalBlock:
                 inputs={"Cond": [self.inputs[0]]},
                 outputs={"Out": written},
                 attrs={"sub_block": sub.idx})
+
+
+def _names_once(names):
+    return list(dict.fromkeys(names))
+
+
+def _reads_from_outside(program, ops, visible_from):
+    """The names ``ops`` (and the ops of their sub-blocks) read that no
+    earlier one of them wrote and that ``visible_from`` can see: what a
+    stretch of the Program takes from its surroundings."""
+    from ..ops.control_flow_ops import _reads
+
+    reads, written = [], set()
+    for op in ops:
+        reads += [n for n in _reads(program, op) if n not in written]
+        written.update(op.output_names)
+    return [n for n in _names_once(reads) if visible_from.has_var(n)]
+
+
+@contextlib.contextmanager
+def recompute():
+    """``with layers.recompute(): ...``: the ops appended inside are a
+    stretch whose intermediate values are NOT kept for the backward pass;
+    it computes them again from what the stretch read (the executor lowers
+    the stretch under ``jax.checkpoint``).  Same loss, same gradients;
+    less memory, and the stretch's forward work done twice.
+
+    The stretch becomes one ``recompute`` op over a sub-block.  It declares
+    nothing of its own: the variables made inside belong to the enclosing
+    block, so whatever follows reads them as if the stretch were not
+    marked."""
+    program = default_main_program()
+    parent = program.current_block()
+    sub = program.create_block()
+    try:
+        yield
+    finally:
+        program.rollback()
+    for var in sub.vars.values():
+        var.block = parent
+    parent.vars.update(sub.vars)
+    sub.vars = {}
+    parent.append_op(
+        "recompute",
+        inputs={"X": _reads_from_outside(program, sub.ops, parent)},
+        outputs={"Out": _names_once(n for op in sub.ops
+                                    for n in op.output_names)},
+        attrs={"sub_block": sub.idx})
+
+
+class Repeat:
+    """A block run ``times`` times over carried values, its ops and weights
+    standing once in the Program::
+
+        loop = layers.Repeat(times=4)
+        with loop.block():
+            h = loop.carry(h0)        # the value a pass starts from
+            new = stack_of_layers(h)  # ONE set of weights, read every pass
+            loop.update(h, new)       # ... and the next pass starts from
+            loop.output(new)
+        hs = loop()                   # [4, ...]: what each pass left
+
+    Differentiable; a parameter read inside gets the sum of the passes'
+    gradients.  Lowered by the ``repeat`` op (ops/control_flow_ops.py)."""
+
+    def __init__(self, times, name=None):
+        if int(times) < 1:
+            raise ValueError(f"Repeat: times must be at least 1, got {times}")
+        self.helper = LayerHelper("repeat", name=name)
+        self.program = self.helper.main_program
+        self.times = int(times)
+        self.carries = {}         # carried var name -> [init var, update name]
+        self.pass_outputs = []
+        self.sub_block = None
+        self.outputs = []
+
+    @contextlib.contextmanager
+    def block(self):
+        parent_block = self.program.current_block()
+        self.sub_block = self.program.create_block()
+        try:
+            yield
+        finally:
+            self.program.rollback()
+        self._complete(parent_block)
+
+    def carry(self, init):
+        """The carried value as a pass sees it at its start: ``init`` in
+        the first pass, what ``update`` named in the pass before after."""
+        var = self.sub_block.create_var(
+            name=unique_name.generate("repeat_carry"), dtype=init.dtype,
+            shape=init.shape)
+        self.carries[var.name] = [init, None]
+        return var
+
+    def update(self, carried, new):
+        self.carries[carried.name][1] = new.name
+
+    def output(self, *values):
+        self.pass_outputs.extend(values)
+
+    def _complete(self, parent_block):
+        missing = [n for n, (_, new) in self.carries.items() if new is None]
+        if missing:
+            raise ValueError(f"Repeat: no update for carried {missing}")
+        self.outputs = [
+            parent_block.create_var(
+                name=unique_name.generate("repeat_out"), dtype=o.dtype,
+                shape=(self.times,) + tuple(o.shape)
+                if o.shape is not None else None)
+            for o in self.pass_outputs]
+        names = list(self.carries)
+        parent_block.append_op(
+            "repeat",
+            inputs={"Init": [self.carries[n][0] for n in names]},
+            outputs={"Outputs": self.outputs},
+            attrs={"sub_block": self.sub_block.idx, "times": self.times,
+                   "carry_names": names,
+                   "update_names": [self.carries[n][1] for n in names],
+                   "output_names": [o.name for o in self.pass_outputs]})
+
+    def __call__(self):
+        return self.outputs if len(self.outputs) != 1 else self.outputs[0]
 
 
 class StaticRNN:

@@ -16,10 +16,11 @@ from .lstm_textcls import lstm_text_classification
 from .seq2seq import seq2seq_attention, seq2seq_infer
 from .wide_deep import wide_deep
 from .olmoe import olmoe
+from .ouro import ouro, ouro_loss
 
 __all__ = [
     "mnist_mlp", "mnist_lenet", "alexnet", "vgg16", "vgg19", "vgg_cifar",
     "resnet_imagenet", "resnet50", "resnet_cifar", "googlenet",
     "lstm_text_classification", "seq2seq_attention", "seq2seq_infer", "wide_deep",
-    "olmoe",
+    "olmoe", "ouro", "ouro_loss",
 ]

@@ -21,8 +21,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core import compile_cache
-from ..core.executor import run_op
-from ..core.program import _sub_block_indices
+from ..core.executor import Env, run_op
+from ..core.program import LEN2_SUFFIX, LEN_SUFFIX, _sub_block_indices
 from ..core.registry import Operand, get_rowwise_fn, register_op
 
 
@@ -455,6 +455,67 @@ def _rnn(ctx, ins, attrs):
         if nested_l2 and sv is not None and sv.lod_level >= 1:
             ctx.set_len2(nm, nested_l2[0])
     return {"Outputs": results}
+
+
+@register_op("repeat")
+def _repeat(ctx, ins, attrs):
+    """``layers.Repeat``: the sub-block ``times`` times over the carried
+    values, every pass reading the same weights from the enclosing env (so
+    their gradients sum); what a pass leaves comes out stacked [times, ...].
+    attrs: sub_block, times, carry_names (sub-block vars bound to the
+    carried values), update_names (what each becomes), output_names.
+
+    The passes are INLINED: ``times`` traces of the one sub-block.  A
+    ``lax.scan`` over the passes was measured beside it on the chip at the
+    benchmark's looped decoder (PERF.md section 6, PR 32): 8 % faster and a
+    third of the compile, but 2.9 GB more at the peak (the backward scan
+    carries every weight's gradient accumulator, and XLA keeps bfloat16
+    copies of the loop's weights), which put the one-sequence step over
+    the 90 % of the chip that a cell may take."""
+    sub_idx, times = attrs["sub_block"], int(attrs["times"])
+    carry_names, update_names = attrs["carry_names"], attrs["update_names"]
+    env = ctx.env
+
+    def one_pass(carried):
+        benv = ctx.child_env(sub_idx, env)
+        benv.local.update(zip(carry_names, carried))
+        ctx.interpret_block(sub_idx, benv)
+        return (tuple(benv.get(n) for n in update_names),
+                [benv.get(n) for n in attrs["output_names"]])
+
+    carried, left = tuple(ins.get("Init", [])), []
+    for _ in range(times):
+        carried, outs = one_pass(carried)
+        left.append(outs)
+    compile_cache.stats().bump("route/repeat:inlined")
+    compile_cache.stats().bump("repeat_passes", times)
+    return {"Outputs": [jnp.stack(vals) for vals in zip(*left)]}
+
+
+@register_op("recompute")
+def _recompute(ctx, ins, attrs):
+    """``layers.recompute``: the stretch of ops in the sub-block under
+    ``jax.checkpoint``, so that a backward pass keeps what the stretch READ
+    (X, and the length companions of those) and computes the rest again.
+    The stretch runs in an env of its own with no parent: everything it
+    reads is an argument of the checkpointed function, and everything it
+    binds (Out, and companions its ops set) is a result."""
+    env = ctx.env
+    read = [n + suffix for n in ctx.op.inputs.get("X", [])
+            for suffix in ("", LEN_SUFFIX, LEN2_SUFFIX) if env.has(n + suffix)]
+
+    def stretch(values):
+        benv = Env(ctx.block(attrs["sub_block"]))
+        benv.local.update(values)
+        ctx.interpret_block(attrs["sub_block"], benv)
+        return {n: v for n, v in benv.local.items()
+                if values.get(n) is not v}
+
+    bound = jax.checkpoint(stretch)({n: env.get(n) for n in read})
+    compile_cache.stats().bump("route/recompute:checkpoint")
+    out_names = ctx.op.outputs.get("Out", [])
+    env.local.update((n, v) for n, v in bound.items() if n not in out_names)
+    return {"Out": [bound.get(n) for n in out_names]}
 
 
 @register_op("print")
