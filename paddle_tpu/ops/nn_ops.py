@@ -18,6 +18,7 @@ from jax import lax
 
 from ..core import compile_cache
 from ..core.registry import register_op
+from . import pallas_kernels
 
 
 def _pair(v):
@@ -337,18 +338,37 @@ def _rope(ctx, ins, attrs):
     """Rotary positions on X [B, T, H, D] at positions 0..T-1, half-split
     pairing (feature i turns with feature i + D/2):
     angle[t, i] = t * theta^(-2i/D), i < D/2;
-    out = x * cos + concat(-x[D/2:], x[:D/2]) * sin, in float32."""
+    out = x * cos + concat(-x[D/2:], x[:D/2]) * sin, in float32.
+
+    The formula below runs where ``pallas_kernels.rope_route`` says
+    ``reference``: every backend but the TPU, a D that is not whole lane
+    tiles, a T off the kernel's row block, a mesh of more than one device
+    (GSPMD would have to make the kernel's operand whole on each).  On the
+    TPU the other shapes take ``pallas_kernels.rope_turn``: the same two
+    products and one sum an element, the half-rotation done in fast memory
+    (XLA writes both halves to HBM, lane-padded, and reads them back).
+    Counted at trace time as ``route/rope:{pallas,interpret,reference}``."""
     x = ins["X"][0]
     t_len, dim = x.shape[1], x.shape[3]
     half = dim // 2
     inv_freq = float(attrs.get("theta", 10000.0)) ** (
         -2.0 * jnp.arange(half, dtype=jnp.float32) / dim)
     angle = jnp.arange(t_len, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angle = jnp.concatenate([angle, angle], axis=-1)[None, :, None, :]
+    angle = jnp.concatenate([angle, angle], axis=-1)          # [T, D]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    single = ctx.mesh is None or getattr(ctx.mesh, "size", 1) == 1
+    route = pallas_kernels.rope_route(x.shape, x.dtype) if single \
+        else "reference"
+    compile_cache.stats().bump("route/rope:" + route)
+    if route != "reference":
+        # concat(-x[D/2:], x[:D/2]) * sin = roll(x, D/2) * (sign * sin)
+        signed = jnp.concatenate([-sin[:, :half], sin[:, half:]], axis=-1)
+        return {"Out": pallas_kernels.rope_turn(
+            x, cos, signed, interpret=route == "interpret")}
     x32 = x.astype(jnp.float32)
     turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return {"Out": (x32 * jnp.cos(angle)
-                    + turned * jnp.sin(angle)).astype(x.dtype)}
+    return {"Out": (x32 * cos[None, :, None, :]
+                    + turned * sin[None, :, None, :]).astype(x.dtype)}
 
 
 _CE_EPS = 1e-8      # cross_entropy_op's clamp under the logarithm

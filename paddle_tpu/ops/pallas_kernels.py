@@ -19,6 +19,9 @@ kernels in interpret mode).
 own matrix (the experts of the dropless ``moe`` lowering), forward and both
 gradients as kernels; ``gated_grouped_matmul`` runs the gate and up stacks of
 gated experts through the same three kernels as a pair.
+
+``rope_turn`` is the half-rotation of rotary positions as a lane rotation
+in fast memory, one read and one write a direction.
 """
 from __future__ import annotations
 
@@ -704,6 +707,97 @@ def gated_grouped_matmul(rows, w_gate, w_up, tile_group, num_tiles, act):
     stacks."""
     return _gated(rows, w_gate, w_up, tile_group, num_tiles, act,
                   jax.default_backend() != "tpu")
+
+
+# ---------------------------------------------------------------------------
+# rope (the half-rotation of the ``rope`` lowering, ops/nn_ops.py)
+# ---------------------------------------------------------------------------
+# ``concat(-x[D/2:], x[:D/2])`` is ``roll(x, D/2)`` times a sign per lane,
+# and the sign goes into the sine table.  XLA writes the two halves to HBM
+# (a minor dimension of D/2 = 64 in tiles of 128 lanes: each half as large
+# as ``x``) and reads them back, 3.5 times the bytes of one read and one
+# write; here the rotation happens on the block in VMEM.  The kernel works on
+# the head-major view [B, H, T, D], which is ``flash_attention``'s: the
+# transposes around it are logical, and XLA's layout assignment gives them to
+# the projection before and the attention kernels after, so no copy is left.
+ROPE_ROWS = 256                  # positions a block
+ROPE_BLOCK_BYTES = 2 << 20       # a block of X as float32: in and out,
+#                                  double-buffered, stay under half of the
+#                                  16 MiB of scoped VMEM
+
+
+def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref):
+    x = x_ref[...].astype(jnp.float32)                 # [heads, rows, D]
+    turned = pltpu.roll(x, x.shape[2] // 2, axis=2)
+    o_ref[...] = (x * cos_ref[...] + turned * sin_ref[...]).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _rope_call(x, cos, sin, interpret):
+    """``x * cos + roll(x, D/2) * sin`` for ``x`` [B, H, T, D] and float32
+    tables [T, D]: ``x`` read once, the result written once.  Jitted, so a
+    program that calls it many times lowers the kernel once."""
+    b, h, t, d = x.shape
+    heads = max(n for n in range(1, h + 1) if h % n == 0
+                and n * ROPE_ROWS * d * 4 <= ROPE_BLOCK_BYTES)
+    block = pl.BlockSpec((None, heads, ROPE_ROWS, d),
+                         lambda i, j, k: (i, k, j, 0))
+    table = pl.BlockSpec((ROPE_ROWS, d), lambda i, j, k: (j, 0))
+    kwargs = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"))}
+    # heads innermost: a table block is fetched once for all of them
+    return pl.pallas_call(
+        _rope_kernel, out_shape=_sds(x, x.shape, x.dtype),
+        grid=(b, t // ROPE_ROWS, h // heads),
+        in_specs=[block, table, table], out_specs=block,
+        interpret=interpret, **kwargs)(x, cos, sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rope_turn(x, cos, sin, interpret):
+    return _rope_call(x, cos, sin, interpret)
+
+
+def _rope_turn_fwd(x, cos, sin, interpret):
+    return _rope_call(x, cos, sin, interpret), (cos, sin)
+
+
+def _rope_turn_bwd(interpret, res, g):
+    # d/dx of x * cos + roll(x, D/2) * sin with a sine table whose halves
+    # differ in sign only: the same kernel with that table negated.  The
+    # tables are the only residuals; nothing of x is kept.
+    cos, sin = res
+    return _rope_call(g, cos, -sin, interpret), None, None
+
+
+_rope_turn.defvjp(_rope_turn_fwd, _rope_turn_bwd)
+
+
+def rope_route(shape, dtype, interpret=False):
+    """Which lowering a ``rope`` of X [B, T, H, D] takes: the kernel
+    (``pallas`` on a TPU; ``interpret``, which only a test asks for) where
+    D is whole lane tiles (one head's rows fitting a block), T whole row
+    blocks and X float32 or bfloat16; ``reference``, the op's formula
+    through XLA, for every other shape and backend."""
+    eligible = (len(shape) == 4 and shape[3] % 128 == 0
+                and ROPE_ROWS * shape[3] * 4 <= ROPE_BLOCK_BYTES
+                and shape[1] % ROPE_ROWS == 0
+                and dtype in (jnp.float32, jnp.bfloat16))
+    if eligible and interpret:
+        return "interpret"
+    if eligible and jax.default_backend() == "tpu":
+        return "pallas"
+    return "reference"
+
+
+def rope_turn(x, cos, sin, interpret=False):
+    """``x * cos + roll(x, D/2, axis=-1) * sin`` for ``x`` [B, T, H, D] that
+    ``rope_route`` takes and float32 tables [T, D], in ``x``'s dtype, computed
+    in float32.  Differentiable in ``x``."""
+    out = _rope_turn(jnp.moveaxis(x, 2, 1), cos, sin, interpret)
+    return jnp.moveaxis(out, 1, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -202,6 +202,44 @@ def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
     assert compile_cache.stats().snapshot()["route/moe:gated_pair"] == 1
 
 
+def test_rope_kernel_call_sites_carry_the_ops_scope(monkeypatch):
+    """Lowered for the TPU (no chip needed to LOWER), a ``rope`` op of a
+    shape its kernel takes is a call into the jitted ``_rope_call`` (the
+    Pallas custom call inside it, lowered once for a module), forward and
+    backward, and each call site carries ``pt.rope:<b>.<p>`` with its
+    direction.  XLA inlines the calls and joins the names, which is how
+    ``attention_step_ms``, the looped stack's table and the class metrics
+    of a traced run find the kernels (the compiled module's names:
+    tests/test_tpu_compile.py)."""
+    import jax
+
+    x = layers.data("x", shape=[256, 256], dtype="float32")
+    q = layers.fc(x, size=256, num_flatten_dims=2, bias_attr=False)
+    q = layers.rope(layers.reshape(q, [0, 256, 2, 128]), theta=1e4)
+    loss = layers.mean(q)
+    pt.optimizer.SGD(0.1).minimize(loss)
+    exe = pt.Executor()
+    exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+    position = [op.type for op in
+                pt.default_main_program().global_block().ops].index("rope")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with exe._call_context(None):
+        entry = exe._enter(None, {"x": ((1, 256, 256), "float32")}, [loss],
+                           None, False, abstract=True)
+    text = entry.fn._jit.trace(entry.feeds, entry.state, 0).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert compile_cache.stats().snapshot()["route/rope:pallas"] == 1
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    sites = sorted(locs[loc] for loc in re.findall(
+        r"call @_rope_call\w*\(.* loc\((#loc\d+)\)$", text, re.M))
+    assert sites == [
+        f"jit(pt_run)/jvp(pt.rope:0.{position})/jit(_rope_call)",
+        f"jit(pt_run)/transpose(jvp(pt.rope:0.{position}))/jit(_rope_call)"]
+    # nothing of the rotation is left outside the kernel
+    assert text.count("tpu_custom_call") == 2 and "x2x64xf32" not in text
+
+
 def test_executor_emitted_work_has_scopes_of_its_own():
     loss, feed = _conv_net()
     cp = _compile(pt.Executor(amp=True), feed, loss, num_steps=3)

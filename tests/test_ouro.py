@@ -1,11 +1,14 @@
 """``models.ouro`` at the benchmark rehearsal's size: against the
 configuration's plain float32 reference (loss and EVERY gradient), the
 looped Program against the same layers written out pass by pass with
-shared names, recomputation on against off, and the exit distribution."""
+shared names, recomputation on against off, the exit distribution, and
+``rope``'s kernel (interpreted) under the recomputed, inlined passes."""
+import functools
 import importlib
 import importlib.util
 import json
 import os
+import sys
 from contextlib import contextmanager
 
 import numpy as np
@@ -13,6 +16,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import layers, models, profiler
+from paddle_tpu.ops import pallas_kernels
 
 # the module: ``models.ouro`` is the function of the same name
 ouro_model = importlib.import_module("paddle_tpu.models.ouro")
@@ -34,13 +38,13 @@ def _data():
             layers.data("lbl", shape=[T_LEN], dtype="int64"))
 
 
-def _seeded(program, seed=3):
+def _seeded(program, seed=3, matrix_scale=0.3):
     """Every parameter drawn anew, so that norm scales, the gate and its
     bias are not at the values the startup program gives them."""
     rng = np.random.RandomState(seed)
     values = {}
     for p in program.all_parameters():
-        scale = 0.3 if len(p.shape) > 1 else 0.1
+        scale = matrix_scale if len(p.shape) > 1 else 0.1
         values[p.name] = (rng.standard_normal(p.shape) * scale
                           + (1.0 if p.name.endswith("norm") else 0.0)
                           ).astype(np.float32)
@@ -69,9 +73,10 @@ def _reference_config():
     with open(os.path.join(ROOT, "chipbench", "configs",
                            "ouro_2_6b.json")) as fh:
         sizes = json.load(fh)
-    sizes.update(hidden_size=32, num_hidden_layers=2, num_attention_heads=2,
-                 num_key_value_heads=2, intermediate_size=48,
-                 total_ut_steps=3, vocab_size=VOCAB, seq_len=T_LEN)
+    sizes.update(hidden_size=MODEL["hidden_size"], num_hidden_layers=2,
+                 num_attention_heads=2, num_key_value_heads=2,
+                 intermediate_size=48, total_ut_steps=3, vocab_size=VOCAB,
+                 seq_len=T_LEN)
     return config, sizes
 
 
@@ -93,6 +98,46 @@ def test_ouro_equals_its_reference_on_the_loss_and_every_gradient():
     got = _run(loss, _grads(program), values, feed)
     config, sizes = _reference_config()
     assert sorted(config._parameter_names(sizes)) == sorted(names)
+    sizes["check_params"] = names
+    ref_loss, ref_grads = config.reference("train", values, feed, sizes)
+    np.testing.assert_allclose(got[0], ref_loss, rtol=1e-5)
+    for name, grad in zip(names, got[1:]):
+        assert np.linalg.norm(grad) > 0, name
+        np.testing.assert_allclose(
+            grad, ref_grads[name], rtol=2e-4,
+            atol=2e-5 * float(np.abs(ref_grads[name]).max()), err_msg=name)
+
+
+def test_rope_kernel_under_recomputed_inlined_passes_equals_the_reference(
+        monkeypatch):
+    """2 heads of 128 at 256 positions, which ``rope``'s kernel takes
+    (interpreted here; the test steers the route): q and k of 2 layers in 3
+    inlined passes turn in the kernel, its custom_vjp under every layer's
+    ``jax.checkpoint``, and the loss and EVERY gradient are still the
+    reference's."""
+    monkeypatch.setitem(MODEL, "hidden_size", 256)
+    monkeypatch.setattr(sys.modules[__name__], "T_LEN", 256)
+    monkeypatch.setattr(pallas_kernels, "rope_route", functools.partial(
+        pallas_kernels.rope_route, interpret=True))
+
+    def routes():
+        snap = profiler.compile_stats().snapshot()
+        return [snap.get(k, 0) for k in ("route/rope:interpret",
+                                         "route/rope:reference",
+                                         "route/recompute:checkpoint")]
+
+    ids, lbl = _data()
+    loss, _ = models.ouro_loss(ids, lbl, VOCAB, **MODEL)
+    program = pt.default_main_program()
+    names = [p.name for p in program.all_parameters()]
+    # (matrices 8 times as wide as this file's other tests': a smaller draw)
+    values, feed = _seeded(program, matrix_scale=0.1), _feed(4, batch=1)
+    before = routes()
+    got = _run(loss, _grads(program), values, feed)
+    assert [a - b for a, b in zip(routes(), before)] == [
+        MODEL["num_layers"] * MODEL["total_ut_steps"] * 2, 0,
+        MODEL["num_layers"] * MODEL["total_ut_steps"] + 3]
+    config, sizes = _reference_config()
     sizes["check_params"] = names
     ref_loss, ref_grads = config.reference("train", values, feed, sizes)
     np.testing.assert_allclose(got[0], ref_loss, rtol=1e-5)

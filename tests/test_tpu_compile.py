@@ -9,13 +9,14 @@ in THIS file."""
 import functools
 import os
 import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops import moe_ops, pallas_kernels
+from paddle_tpu.ops import moe_ops, nn_ops, pallas_kernels
 
 
 @pytest.fixture(scope="module")
@@ -105,3 +106,88 @@ def test_gated_forward_takes_every_activation_in_its_epilogue(spec, layout,
     ).lower(spec((ROWS, D)), spec((EXPERTS, D, H)), spec((EXPERTS, D, H)),
             *layout).compile().as_text()
     assert _kernels(text) == 1
+
+
+# rope at the two cells' shapes: [B, 4096 positions, 16 heads of 128]
+T_LEN, HEADS, HEAD_DIM = 4096, 16, 128
+
+
+def _rope_op(x, backend, scope="pt.rope:0.0"):
+    """The ``rope`` lowering as a step traces it on ``backend``, under the
+    scope the executor gives an op."""
+    with pytest.MonkeyPatch.context() as patch, jax.named_scope(scope):
+        patch.setattr(jax, "default_backend", lambda: backend)
+        return nn_ops._rope(SimpleNamespace(mesh=None), {"X": [x]},
+                            {"theta": 1e6})["Out"]
+
+
+def _half_width_arrays(text):
+    """Arrays whose minor dimension is D/2: the two halves of the rotation
+    as XLA writes them to HBM, each padded to whole 128-lane tiles."""
+    return len(re.findall(rf"f32\[\d+,\d+,\d+,{HEAD_DIM // 2}\]", text))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_rope_is_one_kernel_a_direction_and_no_half_width_array(spec, batch):
+    """Value and gradient of ``rope`` on the head-major view the kernel
+    works on: two custom calls (the backward is the forward's kernel with
+    the sine table negated), next to no temporaries, nothing of width D/2.
+    The formula it replaces on the TPU, which is the reason the kernel
+    exists: both halves written lane-padded, in both directions."""
+    def loss(backend, x, mix):           # x, mix: [B, H, T, D]
+        out = _rope_op(jnp.moveaxis(x, 1, 2), backend)
+        return jnp.sum(jnp.moveaxis(out, 2, 1) * mix)
+
+    head_major = spec((batch, HEADS, T_LEN, HEAD_DIM))
+    kernel = jax.jit(jax.value_and_grad(functools.partial(loss, "tpu"))
+                     ).lower(head_major, head_major).compile()
+    assert _kernels(kernel.as_text()) == 2
+    assert _half_width_arrays(kernel.as_text()) == 0
+    assert kernel.memory_analysis().temp_size_in_bytes < 1 << 20
+
+    formula = jax.jit(jax.value_and_grad(functools.partial(loss, "cpu"))
+                      ).lower(head_major, head_major).compile()
+    assert _kernels(formula.as_text()) == 0
+    assert _half_width_arrays(formula.as_text()) >= 4
+    # at least one array of X's size in temporaries a direction
+    assert formula.memory_analysis().temp_size_in_bytes \
+        >= 4 * batch * T_LEN * HEADS * HEAD_DIM
+
+
+def test_rope_between_projection_and_attention_leaves_no_copy(spec):
+    """Where Ouro has the op (a projection reshaped to heads in front,
+    ``flash_attention`` behind; one sequence), gradient and all: the
+    transposes to and from the kernel's head-major view are XLA's to
+    assign, the projection writes that layout and the attention kernels
+    read the result as it is, so the module holds the seven kernels and no
+    copy or transpose of an array of q's size in float32.  Each ``rope`` kernel
+    carries its op's scope and direction in its ``op_name`` (the calls into
+    the jitted ``_rope_call`` are inlined, the names joined): what the
+    per-layer metrics of a traced run find it by."""
+    batch, width = 1, HEADS * HEAD_DIM
+
+    def loss(x, wq, wk, wv):
+        def heads(w):
+            return (x @ w).reshape(batch, T_LEN, HEADS, HEAD_DIM)
+        q = _rope_op(heads(wq), "tpu", "pt.rope:0.3")
+        k = _rope_op(heads(wk), "tpu", "pt.rope:0.5")
+        out = pallas_kernels.flash_attention(
+            q, k, heads(wv), causal=True, block_q=1024, block_k=1024,
+            use_pallas=True)
+        return jnp.sum(out ** 2)
+
+    # (products as a step on the chip has them: under conftest's 'highest'
+    # the attention kernels' multi-pass products overrun their VMEM)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+            spec((batch, T_LEN, width)), *[spec((width, width))] * 3
+        ).compile().as_text()
+    assert _kernels(text) == 2 * 2 + 3
+    assert _half_width_arrays(text) == 0
+    q_sized = rf"= f32\[{batch},(?:{T_LEN},{HEADS}|{HEADS},{T_LEN}),{HEAD_DIM}\]"
+    assert re.findall(q_sized + r"\S* (?:copy|transpose)\(", text) == []
+    assert sorted(re.findall(
+        r'custom-call\(.*op_name="jit\(loss\)/([^"]*)/jit\(_rope_call\)'
+        r'/pallas_call"', text)) == sorted(
+        way.format(op) for op in ("pt.rope:0.3", "pt.rope:0.5")
+        for way in ("jvp({})", "transpose(jvp({}))"))

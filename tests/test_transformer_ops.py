@@ -1,7 +1,10 @@
 """``rms_norm`` and ``rope`` (ops/nn_ops.py) against their equations: values
 and gradients through the Program path, their shape rules, rope's
-relative-position property, and ``rms_norm``'s row-wise rule moving it out
-of an ``rnn`` step."""
+relative-position property, ``rope``'s kernel (interpreted) against the
+formula it replaces on the TPU and the shapes each route takes, and
+``rms_norm``'s row-wise rule moving it out of an ``rnn`` step."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +15,7 @@ from paddle_tpu import layers, profiler
 from paddle_tpu.analysis.shape_infer import ShapeError, VarInfo
 from paddle_tpu.core.registry import get_rowwise_fn, get_shape_fn
 from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.ops import pallas_kernels
 
 
 def _rms(x, g, eps):
@@ -95,6 +99,123 @@ def test_rope_values_and_gradients(t_len):
     # d/dw mean(rope(x * w) * mix): rope is linear, so rope(x e_i) * mix
     ref = [np.mean(_rope(x * np.eye(8)[i], 50.0) * mix) for i in range(8)]
     np.testing.assert_allclose(g_w, ref, rtol=1e-4, atol=1e-6)
+
+
+def _rope_tables(t_len, dim, theta):
+    inv_freq = float(theta) ** (
+        -2.0 * jnp.arange(dim // 2, dtype=jnp.float32) / dim)
+    angle = jnp.arange(t_len, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angle = jnp.concatenate([angle, angle], axis=-1)[None, :, None, :]
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rope_formula(x, theta):
+    """The lowering as it stood before the kernel (the kernel's oracle):
+    out = x * cos + concat(-x[D/2:], x[:D/2]) * sin."""
+    half = x.shape[3] // 2
+    cos, sin = _rope_tables(x.shape[1], x.shape[3], theta)
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + turned * sin
+
+
+def _rope_routes():
+    return {k.split(":", 1)[1]: v for k, v in
+            profiler.compile_stats().snapshot().items()
+            if k.startswith("route/rope:")}
+
+
+def _rope_program(monkeypatch, shape, theta, seed=0):
+    """(out, dX of mean(out * mix), x, mix, routes taken) of a ``rope`` op
+    through Program + Executor, with the kernel interpreted wherever its
+    shapes are eligible (the test steers the route; the program has no
+    option for it)."""
+    monkeypatch.setattr(pallas_kernels, "rope_route", functools.partial(
+        pallas_kernels.rope_route, interpret=True))
+    rng = np.random.RandomState(seed)
+    x = rng.randn(*shape).astype("float32")
+    mix = rng.randn(*shape).astype("float32")
+
+    def build():
+        # (X is a parameter, so that its gradient can be fetched)
+        xv = LayerHelper("x").create_parameter(
+            pt.ParamAttr(name="x",
+                         initializer=pt.initializer.NumpyArrayInitializer(x)),
+            shape=list(shape), dtype="float32")
+        return layers.rope(xv, theta=theta), layers.data(
+            "mix", shape=list(shape[1:]), dtype="float32")
+
+    before = _rope_routes()
+    out, d_x = _run(build, {"mix": mix}, "x")
+    taken = {k: v - before.get(k, 0) for k, v in _rope_routes().items()
+             if v - before.get(k, 0)}
+    return out, d_x, x, mix, taken
+
+
+@pytest.mark.parametrize("shape,theta", [((1, 256, 16, 128), 1e6),
+                                         ((2, 512, 4, 128), 1e4),
+                                         ((1, 256, 2, 128), 50.0)])
+def test_rope_kernel_equals_the_formula_it_replaces(monkeypatch, shape,
+                                                    theta):
+    """The kernel computes the formula's two products and one sum an
+    element, so its values are the formula's BIT FOR BIT.  Its gradient,
+    ``g * cos + roll(g, D/2) * -(sin * sign)``, is bit for bit that closed
+    form, whose terms are autodiff's of the formula: against autodiff it is
+    held to one rounding of either product and not to the bit, because
+    XLA's CPU backend contracts one product of each sum into a fused
+    multiply-add and picks another one in the formula's backward pass
+    (``g * cos - g' * sin`` in the upper half) than in the kernel."""
+    out, d_x, x, mix, taken = _rope_program(monkeypatch, shape, theta)
+    assert taken == {"interpret": 1}
+    formula = functools.partial(_rope_formula, theta=theta)
+    np.testing.assert_array_equal(out, jax.jit(formula)(x))
+
+    half = shape[3] // 2
+    sign = jnp.where(jnp.arange(shape[3]) < half, -1.0, 1.0)
+
+    @jax.jit
+    def closed_form(mix):
+        g = mix / mix.size                    # d mean(out * mix) / d out
+        cos, sin = _rope_tables(shape[1], shape[3], theta)
+        straight, crossed = g * cos, jnp.roll(g, half, -1) * -(sin * sign)
+        return (straight + crossed,
+                jnp.finfo(jnp.float32).eps * (abs(straight) + abs(crossed)))
+
+    closed, one_rounding = closed_form(mix)
+    np.testing.assert_array_equal(d_x, closed)
+    autodiff = jax.jit(jax.grad(lambda x: jnp.mean(formula(x) * mix)))(x)
+    assert (np.abs(d_x - autodiff) <= one_rounding).all()
+
+
+@pytest.mark.parametrize("shape", [(2, 256, 2, 8),       # D off the lanes
+                                   (1, 384, 2, 128),     # T off the block
+                                   (1, 100, 2, 128)])
+def test_rope_shapes_the_kernel_does_not_take_run_the_formula(monkeypatch,
+                                                              shape):
+    out, d_x, x, mix, taken = _rope_program(monkeypatch, shape, 1e4)
+    assert taken == {"reference": 1}
+    np.testing.assert_array_equal(
+        out, jax.jit(functools.partial(_rope_formula, theta=1e4))(x))
+    autodiff = jax.jit(jax.grad(lambda x: jnp.mean(
+        _rope_formula(x, 1e4) * mix)))(x)
+    np.testing.assert_allclose(                   # the products' rounding
+        d_x, autodiff, rtol=0, atol=2 * np.finfo("float32").eps
+        * float(np.abs(autodiff).max()))
+
+
+def test_rope_route_by_shape_dtype_and_backend(monkeypatch):
+    route = pallas_kernels.rope_route
+    ok = (2, 4096, 16, 128)
+    assert route(ok, jnp.float32) == "reference"          # the CPU
+    assert route(ok, jnp.float32, interpret=True) == "interpret"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert route(ok, jnp.float32) == route(ok, jnp.bfloat16) == "pallas"
+    assert route((2, 4096, 16, 256), jnp.float32) == "pallas"
+    for shape, dtype in [((2, 4096, 16, 64), jnp.float32),
+                         ((2, 4000, 16, 128), jnp.float32),
+                         ((2, 128, 16, 128), jnp.float32),
+                         ((2, 4096, 1, 4096), jnp.float32),
+                         (ok, jnp.float16), ((4096, 16, 128), jnp.float32)]:
+        assert route(shape, dtype) == "reference", (shape, dtype)
 
 
 def test_rope_scores_depend_on_the_distance_only():
