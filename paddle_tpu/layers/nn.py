@@ -38,6 +38,7 @@ __all__ = [
     "lambda_rank",
     "margin_rank_loss", "squared_l2_distance", "squared_l2_norm",
     "kldiv_loss", "modified_huber_loss", "bilinear_tensor_product",
+    "short_conv",
 ]
 
 
@@ -477,6 +478,26 @@ def rope(x, theta=10000.0, name=None):
     out = helper.create_variable_for_type_inference(x.dtype, x.shape)
     helper.append_op(type="rope", inputs={"X": [x]}, outputs={"Out": [out]},
                      attrs={"theta": float(theta)})
+    return out
+
+
+def short_conv(x, num_taps=3, param_attr=None, interpret=False, name=None):
+    """The gated short convolution (ops/nn_ops.py ``short_conv``): ``x``
+    [B, T, 3C] holds an input gate, an output gate and the input side by
+    side; out [B, T, C] = gate_out * causal_depthwise_conv(gate_in * input)
+    with a filter [C, num_taps], no bias.  The projections before and after
+    are the caller's ``fc``.  ``interpret`` runs the TPU kernels through
+    the Pallas interpreter (CPU tests)."""
+    helper = LayerHelper("short_conv", name=name)
+    channels = x.shape[-1] // 3
+    w = helper.create_parameter(
+        ParamAttr._to_attr(param_attr) or ParamAttr(),
+        shape=[channels, num_taps], dtype=x.dtype)
+    out = helper.create_variable_for_type_inference(
+        x.dtype, tuple(x.shape[:-1]) + (channels,))
+    helper.append_op(type="short_conv", inputs={"X": [x], "Filter": [w]},
+                     outputs={"Out": [out]},
+                     attrs={"interpret": True} if interpret else {})
     return out
 
 
@@ -1479,7 +1500,9 @@ def sampling_id(x, name=None):
 
 
 def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
-        act="relu", gated=False, gate_attr=None, param_attr=None, name=None):
+        act="relu", gated=False, gate_attr=None, param_attr=None, name=None,
+        scoring="softmax", select_bias_attr=None, renormalize=False,
+        routed_scale=1.0, experts_held=None, expert_offset=0):
     """Mixture-of-Experts FFN — the Program-level expert layer
     (ops/moe_ops.py), in one of two lowerings.
 
@@ -1495,12 +1518,24 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
     ep > 1 is refused).  ``gated`` adds a second up-projection stack,
     out = (act(x Wg) * (x Wu)) Wd, which the dropless lowering alone runs.
 
+    The dropless lowering alone also takes: ``scoring`` ``'sigmoid'`` (the
+    experts' scores are sigmoid(logits) and not a softmax over them);
+    ``select_bias_attr``, a float32 [num_experts] parameter without a
+    gradient that is added to the scores for the CHOICE of the ``top_k``
+    and not for their weights; ``renormalize`` (a token's weights divided
+    by their sum + 1e-6) and ``routed_scale`` (then multiplied by it); and
+    **one chip's share of an expert-parallel layer**: with ``experts_held``
+    the stacks hold that many experts, ``expert_offset`` the first, under
+    a router that keeps its ``num_experts`` outputs.  The choice and the
+    renormalisation run over all of them; what the experts held add to the
+    result is computed, what the others would add is left out.
+
     Returns (out, aux_loss, z_loss): add ``aux_weight * aux_loss`` to the
     training loss to keep experts load-balanced (E * sum_e f_e P_e) and
     ``z_weight * z_loss`` (mean squared logsumexp of the router's logits)
     to keep them small.
     """
-    from ..initializer import XavierInitializer
+    from ..initializer import ConstantInitializer, XavierInitializer
     helper = LayerHelper("moe", param_attr=param_attr, name=name)
     D = input.shape[-1]
     gate_w = helper.create_parameter(
@@ -1509,11 +1544,12 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
     pa = _copy.copy(param_attr) if param_attr is not None else ParamAttr()
     if getattr(pa, "sharding", None) is None:
         pa.sharding = ("ep", None, None)
+    stacked = num_experts if experts_held is None else experts_held
 
     def stack(attr, fan_in, fan_out):
         # a stack of matrices [E, in, out], each drawn as the matrix it is
         return helper.create_parameter(
-            attr, shape=[num_experts, fan_in, fan_out], dtype=input.dtype,
+            attr, shape=[stacked, fan_in, fan_out], dtype=input.dtype,
             default_initializer=XavierInitializer(fan_in=fan_in,
                                                   fan_out=fan_out))
 
@@ -1528,15 +1564,32 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
     ins = {"X": [input], "GateW": [gate_w], "W1": [w1], "W2": [w2]}
     if gated:
         ins["WGate"] = [stack(named("gate"), D, expert_hidden)]
+    attrs = {"top_k": top_k, "capacity_factor": capacity_factor,
+             "activation": act}
+    if select_bias_attr is not None:
+        bias_attr = _copy.copy(ParamAttr._to_attr(select_bias_attr))
+        bias_attr.trainable = False
+        ins["SelectBias"] = [helper.create_parameter(
+            bias_attr, shape=[num_experts], dtype="float32",
+            default_initializer=ConstantInitializer(0.0))]
+    # (the defaults stay out of the attrs: a program that asks for none of
+    # this keeps its content digest, and so its compile-cache entry)
+    if scoring != "softmax":
+        attrs["scoring"] = scoring
+    if renormalize:
+        attrs["renormalize"] = True
+    if routed_scale != 1.0:
+        attrs["routed_scale"] = float(routed_scale)
+    if experts_held is not None:
+        attrs.update(experts_held=int(experts_held),
+                     expert_offset=int(expert_offset))
     out = helper.create_variable_for_type_inference(
         input.dtype, input.shape, lod_level=input.lod_level)
     aux = helper.create_variable_for_type_inference("float32", ())
     z = helper.create_variable_for_type_inference("float32", ())
     helper.append_op(type="moe", inputs=ins,
                      outputs={"Out": [out], "AuxLoss": [aux], "ZLoss": [z]},
-                     attrs={"top_k": top_k,
-                            "capacity_factor": capacity_factor,
-                            "activation": act})
+                     attrs=attrs)
     if input.lod_level:
         _copy_len(helper, input, out)
     return out, aux, z

@@ -371,6 +371,47 @@ def _rope(ctx, ins, attrs):
                     + turned * sin[None, :, None, :]).astype(x.dtype)}
 
 
+@register_op("short_conv")
+def _short_conv(ctx, ins, attrs):
+    """The gated short convolution of a hybrid decoder's ``conv`` layers
+    (LFM2), between its two projections: X [B, T, 3C] holds the gates and
+    the input side by side, [Bg | Cg | u]; Filter [C, L] is a depthwise
+    causal filter of L taps.
+
+        v = Bg * u;   c[t] = sum_j Filter[:, j] * v[t - (L-1) + j]
+        Out = Cg * c                      (v before position 0 is 0)
+
+    ONE lowering for the two gates and the taps, computed in float32.
+    Where ``pallas_kernels.short_conv_route`` says so (a TPU, C whole lane
+    tiles, T whole row blocks, one device) the Pallas kernels run: one read
+    of X and one write of Out forward, one read of X and the cotangent and
+    one write of dX backward.  Everywhere else the formula below: tap j
+    reads ``v`` shifted down by L-1-j positions (a slice of ``v`` behind as
+    many zero rows), and XLA makes of it two passes forward and five
+    backward.  Counted at trace time as
+    ``route/short_conv:{pallas,interpret,xla}``."""
+    x, w = ins["X"][0], ins["Filter"][0]
+    c, taps = w.shape
+    single = ctx.mesh is None or getattr(ctx.mesh, "size", 1) == 1
+    route = pallas_kernels.short_conv_route(
+        x.shape, taps, x.dtype, attrs.get("interpret", False)) \
+        if single else "xla"
+    compile_cache.stats().bump("route/short_conv:" + route)
+    if route != "xla":
+        return {"Out": pallas_kernels.short_conv(
+            x, w, interpret=route == "interpret")}
+    x32, w32 = x.astype(jnp.float32), w.astype(jnp.float32)
+    gate_in, gate_out, u = (x32[..., :c], x32[..., c:2 * c], x32[..., 2 * c:])
+    v = gate_in * u
+    acc = v * w32[:, taps - 1]
+    for j in range(taps - 1):
+        back = taps - 1 - j
+        behind = jnp.concatenate(
+            [jnp.zeros_like(v[:, :back]), v[:, :v.shape[1] - back]], axis=1)
+        acc = acc + behind * w32[:, j]
+    return {"Out": (gate_out * acc).astype(x.dtype)}
+
+
 _CE_EPS = 1e-8      # cross_entropy_op's clamp under the logarithm
 
 
@@ -777,6 +818,24 @@ def _rope_shape(op, ins, attrs):
     return {"Out": x}
 
 
+@register_shape_fn("short_conv")
+def _short_conv_shape(op, ins, attrs):
+    x, w = first(ins, "X"), first(ins, "Filter")
+    if x.shape is None:
+        return {"Out": x}
+    if len(x.shape) != 3 or (x.shape[-1] >= 0 and x.shape[-1] % 3):
+        raise ShapeError(
+            f"short_conv: X {list(x.shape)} is not [B, T, 3C] (the two "
+            f"gates and the input side by side)")
+    if w.shape is not None and (len(w.shape) != 2 or not dim_ok(
+            3 * w.shape[0], x.shape[-1])):
+        raise ShapeError(
+            f"short_conv: Filter {list(w.shape)} is not [C, taps] for X "
+            f"{list(x.shape)} = [B, T, 3C]")
+    return {"Out": x.with_shape(
+        x.shape[:-1] + (-1 if x.shape[-1] < 0 else x.shape[-1] // 3,))}
+
+
 @register_shape_fn("cross_entropy")
 def _cross_entropy_shape(op, ins, attrs):
     x = first(ins, "X")
@@ -926,6 +985,22 @@ def _layer_norm_shard(op, ins, attrs):
 # from the index along T, which a sharded T keeps global under GSPMD)
 register_shard_fn("rms_norm")(shard_same_as("X", out="Y"))
 register_shard_fn("rope")(shard_same_as("X"))
+
+
+@register_shard_fn("short_conv")
+def _short_conv_shard(op, ins, attrs):
+    """Out keeps X's batch sharding.  The filter runs along T and the
+    feature axis is cut in three inside the op, so a sharded T (it would
+    need the L-1 rows before each shard) or feature axis is a conflict."""
+    from ..analysis.shard_prop import ShardConflict, first_in
+    x = first_in(ins, "X")
+    if x.spec is None:
+        return {}
+    if x.entry(1) or x.entry(2):
+        raise ShardConflict(
+            "short_conv: X sharded along T or the features: the filter "
+            "needs a halo along T, the split whole features")
+    return {"Out": (x.entry(0), None, None)}
 
 
 @register_shard_fn("softmax_with_cross_entropy")

@@ -21,7 +21,9 @@ gradients as kernels; ``gated_grouped_matmul`` runs the gate and up stacks of
 gated experts through the same three kernels as a pair.
 
 ``rope_turn`` is the half-rotation of rotary positions as a lane rotation
-in fast memory, one read and one write a direction.
+in fast memory, one read and one write a direction.  ``short_conv`` is the
+gated short convolution of a hybrid decoder's ``conv`` layers, gates and
+taps in one pass a direction.
 """
 from __future__ import annotations
 
@@ -106,13 +108,24 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = m_ref[...] + jnp.log(l_safe)
 
 
+def _kv_of(group):
+    """Which K / V batch-head a Q batch-head reads: with ``group`` query
+    heads to a K / V head (heads innermost in the leading dim), head i
+    reads i // group; the block specs' index maps go there, so no repeated
+    K or V is ever written.  Equal head counts: i itself."""
+    return (lambda i: i) if group == 1 else (lambda i: i // group)
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    """Returns (out, lse); lse is [BH, Tq, 1] float32."""
+    """Returns (out, lse); lse is [BH, Tq, 1] float32.  ``k`` and ``v`` may
+    hold fewer batch-heads than ``q``, a whole divisor (grouped-query
+    heads)."""
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     Dv = v.shape[2]
     nk = Tk // block_k
     grid = (BH, Tq // block_q, nk)
+    kv = _kv_of(BH // k.shape[0])
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -128,8 +141,8 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda i, j, kb: (i, kb, 0)),
+            pl.BlockSpec((1, block_k, D), lambda i, j, kb: (kv(i), kb, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda i, j, kb: (kv(i), kb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda i, j, kb: (i, j, 0)),
@@ -148,6 +161,9 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 # backward kernels (FlashAttention-2: recompute p from (q, k, lse) per block)
 # ---------------------------------------------------------------------------
+FLASH_BWD_VMEM_BYTES = 32 << 20    # scoped VMEM a grouped backward may take
+
+
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, acc_ref, *, block_q, block_k, num_k_blocks,
                          causal, sm_scale):
@@ -195,13 +211,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                          block_k, num_q_blocks, causal, sm_scale):
-    """Grid (bh, k_blocks, q_blocks), q innermost: dk/dv for one key block
-    accumulate over streamed Q/dO blocks in VMEM scratches."""
+                          block_k, num_q_blocks, causal, sm_scale, group=1):
+    """Grid (K/V batch-heads, k_blocks, group * q_blocks), the last
+    innermost: dk/dv for one key block accumulate in VMEM scratches over
+    the streamed Q/dO blocks of every query head of the group, one head
+    after the other."""
     kb = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
+    j = step if group == 1 else step % num_q_blocks
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -238,7 +257,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         _compute()
 
-    @pl.when(j == num_q_blocks - 1)
+    @pl.when(step == group * num_q_blocks - 1)
     def _write():
         # q32 already carried sm_scale, so dk_acc is fully scaled
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
@@ -252,6 +271,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     Dv = v.shape[2]
     nq = Tq // block_q
     nk = Tk // block_k
+    group = BH // k.shape[0]
+    kv = _kv_of(group)
     # delta_i = sum_d dO_i · O_i  (rescaling term of dsoftmax); O(T·Dv) work,
     # fused by XLA — not worth a kernel.  A cotangent on lse folds in here:
     # dL/ds_ij = p_ij (dp_ij - delta_i + g_lse_i), so delta_eff = delta -
@@ -262,8 +283,16 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         delta = delta - g_lse.astype(jnp.float32).reshape(delta.shape)
     kwargs = {}
     if not interpret:
+        # grouped calls ask for the scoped VMEM they need: the kernels'
+        # four [block_q, block_k] float32 temporaries are the whole default
+        # 16 MiB at 1024 x 1024 (dQ's asked for 16.46 MB at head size 64).
+        # Equal head counts keep the parent's parameters, and its edge,
+        # until Ouro and OLMoE are measured with the limit (ROADMAP A13)
+        limit = {"vmem_limit_bytes": FLASH_BWD_VMEM_BYTES} if group > 1 \
+            else {}
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **limit)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
@@ -273,8 +302,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda i, j, kb: (i, kb, 0)),
+            pl.BlockSpec((1, block_k, D), lambda i, j, kb: (kv(i), kb, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda i, j, kb: (kv(i), kb, 0)),
             pl.BlockSpec((1, block_q, Dv), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kb: (i, j, 0)),
@@ -285,22 +314,29 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         **kwargs,
     )(q, k, v, g, lse, delta)
 
+    if group == 1:
+        def of_q(i, kb, j):
+            return (i, j, 0)
+    else:
+        def of_q(i, kb, step):       # the group's query heads in turn
+            return (i * group + step // nq, step % nq, 0)
+
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                           block_k=block_k, num_q_blocks=nq, causal=causal,
-                          sm_scale=sm_scale),
+                          sm_scale=sm_scale, group=group),
         out_shape=[
-            _sds(k, (BH, Tk, D), k.dtype),
-            _sds(v, (BH, Tk, Dv), v.dtype),
+            _sds(k, k.shape[:2] + (D,), k.dtype),
+            _sds(v, v.shape, v.dtype),
         ],
-        grid=(BH, nk, nq),
+        grid=(k.shape[0], nk, group * nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda i, kb, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, D), of_q),
             pl.BlockSpec((1, block_k, D), lambda i, kb, j: (i, kb, 0)),
             pl.BlockSpec((1, block_k, Dv), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_q, Dv), lambda i, kb, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, kb, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, kb, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, Dv), of_q),
+            pl.BlockSpec((1, block_q, 1), of_q),
+            pl.BlockSpec((1, block_q, 1), of_q),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda i, kb, j: (i, kb, 0)),
@@ -317,6 +353,9 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
 
 
 def _reference_attention(q, k, v, causal, sm_scale):
+    group = q.shape[0] // k.shape[0]
+    if group > 1:                 # grouped-query heads: K, V head i // group
+        k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     s = jnp.einsum("bqd,bkd->bqk", q * sm_scale, k)
     if causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
@@ -392,7 +431,11 @@ def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None,
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
                     block_k=128, use_pallas=None, interpret=None):
-    """Fused attention.  q,k,v: [B, T, H, D] (or [BH, T, D]).
+    """Fused attention.  q,k,v: [B, T, H, D] (or [BH, T, D]).  K and V may
+    have fewer heads than Q, a whole divisor (grouped-query heads): query
+    head h reads K / V head h // (H / H_kv), through the kernels' index
+    maps, and dK / dV sum over a group's query heads inside the backward
+    kernel; counted as ``route/flash_attention:grouped``.
 
     use_pallas=None auto-selects the Pallas kernel on TPU only; every other
     backend gets the exact jnp reference.  interpret=True (explicit, as the
@@ -418,6 +461,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
         raise ValueError(
             f"flash_attention: q feature dim {q3.shape[-1]} != k feature "
             f"dim {k3.shape[-1]}")
+    if k3.shape[0] != v3.shape[0] or q3.shape[0] % k3.shape[0]:
+        raise ValueError(
+            f"flash_attention: {k3.shape[0]} K and {v3.shape[0]} V "
+            f"batch-heads do not divide Q's {q3.shape[0]}")
+    if q3.shape[0] != k3.shape[0]:
+        compile_cache.stats().bump("route/flash_attention:grouped")
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     interpret = bool(interpret)
@@ -801,6 +850,191 @@ def rope_turn(x, cos, sin, interpret=False):
 
 
 # ---------------------------------------------------------------------------
+# short_conv (the gates and taps of the ``short_conv`` lowering, ops/nn_ops.py)
+# ---------------------------------------------------------------------------
+# X [B, T, 3C] = [Bg | Cg | u], Filter [C, L]: Out = Cg * c with
+# c[t] = sum_j Filter[:, j] * v[t - (L-1) + j] and v = Bg * u.  Through XLA
+# the forward is two fusions (v goes to memory and comes back) and the
+# backward five and the pads that put the three gradients side by side: 2.3
+# and 3.4 times the bytes of one pass.  Here a block is ``SHORT_CONV_ROWS``
+# positions of ALL the features, so the three parts of X are lane-aligned
+# slices of one block and dX is written as one array; a tap reads the block
+# rotated along the positions in fast memory, its first rows patched from the
+# 8 rows before the block (the backward's from the 8 rows after: a second
+# and third view of the same arrays).  The filter's gradient accumulates in
+# a block that stays resident over the whole grid.
+SHORT_CONV_ROWS = 64             # positions a block: X, dX and the cotangent,
+#                                  double-buffered, are 7 MiB at C = 2048
+SHORT_CONV_LANES = 512           # features a pass inside a block
+_HALO = 8                        # rows of a halo view (a sublane tile)
+
+
+def _shifted(v, edge, shift, row, down):
+    """``v`` moved ``shift`` rows down the positions (``down``: row t takes
+    v[t - shift], the first rows from the last of ``edge``, the 8 rows
+    before the block) or up (row t takes v[t + shift], the last rows from
+    the first of ``edge``, the 8 rows after)."""
+    rows = v.shape[0]
+    out = pltpu.roll(v, shift if down else rows - shift, axis=0)
+    for r in range(shift):
+        at, src = (r, _HALO - shift + r) if down else (rows - shift + r, r)
+        out = jnp.where(row == at, edge[src:src + 1], out)
+    return out
+
+
+def _lane_passes(channels):
+    lanes = SHORT_CONV_LANES if channels % SHORT_CONV_LANES == 0 else 128
+    return [(at, lanes) for at in range(0, channels, lanes)]
+
+
+def _short_conv_kernel(x_ref, before_ref, w_ref, o_ref, *, channels, taps):
+    i = pl.program_id(1)
+    c = channels
+    for at, lanes in _lane_passes(c):
+        def part(ref, k):
+            return ref[:, k * c + at:k * c + at + lanes].astype(jnp.float32)
+
+        row = lax.broadcasted_iota(jnp.int32, (x_ref.shape[0], lanes), 0)
+        w = w_ref[:, at:at + lanes].astype(jnp.float32)        # [L, lanes]
+        v = part(x_ref, 0) * part(x_ref, 2)
+        before = jnp.where(i > 0, part(before_ref, 0) * part(before_ref, 2),
+                           0.0)
+        acc = v * w[taps - 1:taps]
+        for shift in range(1, taps):
+            acc = acc + _shifted(v, before, shift, row, True) \
+                * w[taps - 1 - shift:taps - shift]
+        o_ref[:, at:at + lanes] = (part(x_ref, 1) * acc).astype(o_ref.dtype)
+
+
+def _short_conv_bwd_kernel(x_ref, before_ref, after_ref, g_ref, g_after_ref,
+                           w_ref, dx_ref, dw_ref, *, channels, taps,
+                           num_blocks):
+    b, i = pl.program_id(0), pl.program_id(1)
+    c = channels
+
+    @pl.when(jnp.logical_and(b == 0, i == 0))
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    for at, lanes in _lane_passes(c):
+        def part(ref, k):
+            return ref[:, k * c + at:k * c + at + lanes].astype(jnp.float32)
+
+        row = lax.broadcasted_iota(jnp.int32, (x_ref.shape[0], lanes), 0)
+        w = w_ref[:, at:at + lanes].astype(jnp.float32)
+        gate_in, gate_out, u = part(x_ref, 0), part(x_ref, 1), part(x_ref, 2)
+        g = g_ref[:, at:at + lanes].astype(jnp.float32)
+        v = gate_in * u
+        before = jnp.where(i > 0, part(before_ref, 0) * part(before_ref, 2),
+                           0.0)
+        d_acc = g * gate_out                   # cotangent of the filter's sum
+        after = jnp.where(
+            i < num_blocks - 1,
+            g_after_ref[:, at:at + lanes].astype(jnp.float32)
+            * part(after_ref, 1), 0.0)
+        acc = v * w[taps - 1:taps]
+        d_v = d_acc * w[taps - 1:taps]
+        dw_ref[taps - 1:taps, at:at + lanes] += jnp.sum(
+            d_acc * v, axis=0, keepdims=True)
+        for shift in range(1, taps):
+            tap = slice(taps - 1 - shift, taps - shift)
+            behind = _shifted(v, before, shift, row, True)
+            acc = acc + behind * w[tap]
+            d_v = d_v + _shifted(d_acc, after, shift, row, False) * w[tap]
+            dw_ref[tap, at:at + lanes] += jnp.sum(d_acc * behind, axis=0,
+                                                   keepdims=True)
+        for k, value in enumerate((d_v * u, g * acc, d_v * gate_in)):
+            dx_ref[:, k * c + at:k * c + at + lanes] = value.astype(
+                dx_ref.dtype)
+
+
+def _short_conv_specs(x):
+    """(grid, block of X, the 8 rows before it, the 8 rows after it, their
+    likes for an array [B, T, C])."""
+    b, t, _ = x.shape
+    per, last = SHORT_CONV_ROWS // _HALO, t // _HALO - 1
+
+    def views(width):
+        return (pl.BlockSpec((None, SHORT_CONV_ROWS, width),
+                             lambda b, i: (b, i, 0)),
+                pl.BlockSpec((None, _HALO, width), lambda b, i:
+                             (b, jnp.maximum(i * per - 1, 0), 0)),
+                pl.BlockSpec((None, _HALO, width), lambda b, i:
+                             (b, jnp.minimum((i + 1) * per, last), 0)))
+    return (b, t // SHORT_CONV_ROWS), views
+
+
+def _short_conv_params(interpret):
+    return {"interpret": True} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"))}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _short_conv(x, w, interpret):
+    channels, taps = w.shape
+    grid, views = _short_conv_specs(x)
+    block, before, _ = views(3 * channels)
+    return pl.pallas_call(
+        functools.partial(_short_conv_kernel, channels=channels, taps=taps),
+        out_shape=_sds(x, x.shape[:2] + (channels,), x.dtype), grid=grid,
+        in_specs=[block, before,
+                  pl.BlockSpec((taps, channels), lambda b, i: (0, 0))],
+        out_specs=views(channels)[0],
+        **_short_conv_params(interpret))(x, x, w.T)
+
+
+def _short_conv_fwd(x, w, interpret):
+    return _short_conv(x, w, interpret), (x, w)
+
+
+def _short_conv_bwd(interpret, res, g):
+    x, w = res
+    channels, taps = w.shape
+    grid, views = _short_conv_specs(x)
+    g_block, _, g_after = views(channels)
+    filter_spec = pl.BlockSpec((taps, channels), lambda b, i: (0, 0))
+    dx, dw = pl.pallas_call(
+        functools.partial(_short_conv_bwd_kernel, channels=channels,
+                          taps=taps, num_blocks=grid[1]),
+        out_shape=[_sds(x, x.shape, x.dtype),
+                   _sds(w, (taps, channels), jnp.float32)],
+        grid=grid,
+        in_specs=[*views(3 * channels), g_block, g_after, filter_spec],
+        out_specs=[views(3 * channels)[0], filter_spec],
+        **_short_conv_params(interpret))(x, x, x, g, g, w.T)
+    return dx, dw.T.astype(w.dtype)
+
+
+_short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
+def short_conv_route(shape, taps, dtype, interpret=False):
+    """Which lowering a ``short_conv`` of X [B, T, 3C] takes: the kernels
+    (``pallas`` on a TPU; ``interpret``, which only a test asks for) where C
+    is whole lane tiles, T whole blocks of ``SHORT_CONV_ROWS`` positions,
+    the taps fit a halo and X is float32 or bfloat16; ``xla``, the op's
+    formula, for every other shape and backend."""
+    eligible = (len(shape) == 3 and shape[2] % (3 * 128) == 0
+                and shape[1] % SHORT_CONV_ROWS == 0 and 1 <= taps <= _HALO
+                and dtype in (jnp.float32, jnp.bfloat16))
+    if eligible and interpret:
+        return "interpret"
+    if eligible and jax.default_backend() == "tpu":
+        return "pallas"
+    return "xla"
+
+
+def short_conv(x, w, interpret=False):
+    """``Cg * causal_depthwise_filter(Bg * u)`` for ``x`` [B, T, 3C] = [Bg |
+    Cg | u] that ``short_conv_route`` takes and the filter ``w`` [C, L]: X
+    read once and Out [B, T, C] written once; the backward reads X and the
+    cotangent once and writes dX once.  In ``x``'s dtype, computed in
+    float32.  Differentiable in both."""
+    return _short_conv(x, w, interpret)
+
+
+# ---------------------------------------------------------------------------
 # op registration (layer: layers.flash_attention)
 # ---------------------------------------------------------------------------
 from ..core.registry import register_op, register_tunable  # noqa: E402
@@ -865,7 +1099,9 @@ def _flash_attention_op(ctx, ins, attrs):
             # entering another shard_map with a concrete mesh is an error
             and not manual_axes()
             and q.ndim in (3, 4) and q.shape[1] == k.shape[1]
-            and q.shape[1] % sp == 0):
+            and q.shape[1] % sp == 0
+            # grouped-query heads run the device-global kernels
+            and q.shape == k.shape):
         from ..parallel.ring_attention import ring_attention_sharded
         q4, k4, v4 = (x[:, :, None, :] if x.ndim == 3 else x
                       for x in (q, k, v))
@@ -900,6 +1136,14 @@ def _flash_attention_shape(op, ins, attrs):
                 raise ShapeError(
                     f"flash_attention: Q {list(q.shape)} vs {name} "
                     f"{list(o.shape)} (rank or head dim mismatch)")
+            # grouped-query heads: K and V heads divide Q's ([B, T, H, D]:
+            # H; [BH, T, D]: the leading dim)
+            at = 2 if len(q.shape) == 4 else 0
+            if len(q.shape) in (3, 4) and q.shape[at] >= 0 and \
+                    o.shape[at] > 0 and q.shape[at] % o.shape[at]:
+                raise ShapeError(
+                    f"flash_attention: {name}'s {o.shape[at]} heads do "
+                    f"not divide Q's {q.shape[at]}")
     return {"Out": q}
 
 

@@ -191,3 +191,53 @@ def test_rope_between_projection_and_attention_leaves_no_copy(spec):
         r'/pallas_call"', text)) == sorted(
         way.format(op) for op in ("pt.rope:0.3", "pt.rope:0.5")
         for way in ("jvp({})", "transpose(jvp({}))"))
+
+
+# LFM2-8B-A1B's operators at the cell's shapes: 8192 positions of 2048
+# features, 32 query heads over 8 key / value heads of 64
+LFM2_T, LFM2_D = 8192, 2048
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_short_conv_is_one_kernel_a_direction(spec, batch):
+    """The gated short convolution, value and gradient: one custom call a
+    direction and next to no temporaries (the formula through XLA keeps v
+    and four shifted products)."""
+    def loss(x, w, mix):
+        return jnp.sum(pallas_kernels._short_conv(x, w, False) * mix)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        spec((batch, LFM2_T, 3 * LFM2_D)), spec((LFM2_D, 3)),
+        spec((batch, LFM2_T, LFM2_D))).compile()
+    assert _kernels(compiled.as_text()) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.5 * batch * LFM2_T * LFM2_D * 4
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_grouped_query_attention_compiles_at_lfm2_heads(spec, batch):
+    """32 query heads reading 8 K / V heads of 64 through the index maps,
+    from the [B, T, H, D] arrays a model hands the op, at the blocks the
+    cell runs (the layer's default 1024 x 1024): three kernels, dK and dV
+    at the 8 heads they have, and no K or V repeated to 32 heads anywhere
+    in the module.  (The backward kernels' four [1024, 1024] float32
+    temporaries are the default 16 MiB of scoped VMEM; a grouped call asks
+    for ``FLASH_BWD_VMEM_BYTES``.)"""
+    heads, kv_heads, d = 32, 8, 64
+
+    def loss(q, k, v, mix):
+        return jnp.sum(pallas_kernels.flash_attention(
+            q, k, v, causal=True, block_q=1024, block_k=1024,
+            use_pallas=True) * mix)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec((batch, LFM2_T, heads, d)), spec((batch, LFM2_T, kv_heads, d)),
+        spec((batch, LFM2_T, kv_heads, d)),
+        spec((batch, LFM2_T, heads, d))).compile().as_text()
+    assert _kernels(text) == 3
+    kv_results = re.findall(
+        rf"\(f32\[{batch * kv_heads},{LFM2_T},{d}\]\S*, "
+        rf"f32\[{batch * kv_heads},{LFM2_T},{d}\]\S*\) custom-call\(", text)
+    assert len(kv_results) == 1
+    assert not re.search(rf"f32\[{batch},{LFM2_T},{heads},{d}\]\S* "
+                         r"broadcast\(", text)

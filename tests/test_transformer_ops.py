@@ -284,3 +284,77 @@ def test_rms_norm_is_row_wise_and_leaves_an_rnn_step_and_rope_is_not():
         state = state + feed["seq"][:, t] @ w + (b[0].reshape(-1) if b else 0)
         np.testing.assert_allclose(
             got[:, t], _rms(state, 1.0, 1e-5), rtol=1e-5, atol=1e-6)
+
+
+def test_moe_shape_rule_takes_a_share_only_where_it_is_declared():
+    moe = get_shape_fn("moe")
+    x = VarInfo((-1, 16, 32), "float32")
+
+    def ins(held, width=32, bias=None):
+        stacks = {"W1": [VarInfo((held, 32, 24), "float32")],
+                  "W2": [VarInfo((held, 24, 32), "float32")],
+                  "WGate": [VarInfo((held, 32, 24), "float32")]}
+        if bias is not None:
+            stacks["SelectBias"] = [VarInfo((bias,), "float32")]
+        return {"X": [x], "GateW": [VarInfo((32, width), "float32")],
+                **stacks}
+
+    assert moe(None, ins(32), {})["Out"] == x
+    # fewer stacks than the router is wide: the parent's error, unless the
+    # op says it holds a share
+    with pytest.raises(ShapeError, match="W1 expert count 8 != GateW"):
+        moe(None, ins(8), {})
+    share = {"experts_held": 8, "expert_offset": 24}
+    assert moe(None, ins(8, bias=32), share)["Out"] == x
+    with pytest.raises(ShapeError, match="a share of 8 experts from 25"):
+        moe(None, ins(8), {"experts_held": 8, "expert_offset": 25})
+    with pytest.raises(ShapeError, match="a share of 8"):
+        moe(None, ins(16), share)
+    with pytest.raises(ShapeError, match="SelectBias"):
+        moe(None, ins(8, bias=8), share)
+
+
+def test_flash_attention_shape_rule_takes_heads_that_divide():
+    rule = get_shape_fn("flash_attention")
+
+    def qkv(q, k, v=None):
+        return {"Q": [VarInfo(q, "float32")], "K": [VarInfo(k, "float32")],
+                "V": [VarInfo(v or k, "float32")]}
+
+    q = (-1, 64, 32, 64)
+    assert rule(None, qkv(q, (-1, 64, 8, 64)), {})["Out"].shape == q
+    assert rule(None, qkv(q, q), {})["Out"].shape == q
+    assert rule(None, qkv((32, 64, 16), (8, 64, 16)), {})["Out"].shape \
+        == (32, 64, 16)
+    with pytest.raises(ShapeError, match="K's 5 heads do not divide Q's 32"):
+        rule(None, qkv(q, (-1, 64, 5, 64)), {})
+    with pytest.raises(ShapeError, match="V's 3 heads do not divide"):
+        rule(None, qkv(q, (-1, 64, 8, 64), (-1, 64, 3, 64)), {})
+    with pytest.raises(ShapeError, match="head dim mismatch"):
+        rule(None, qkv(q, (-1, 64, 8, 32)), {})
+
+
+def test_short_conv_shape_and_sharding_rules():
+    from paddle_tpu.analysis.shard_prop import ShardConflict, ShardInfo
+    from paddle_tpu.core.registry import get_shard_fn
+
+    rule = get_shape_fn("short_conv")
+    x = VarInfo((-1, 64, 96), "float32")
+    w = VarInfo((32, 3), "float32")
+    assert rule(None, {"X": [x], "Filter": [w]}, {})["Out"].shape \
+        == (-1, 64, 32)
+    with pytest.raises(ShapeError, match=r"not \[B, T, 3C\]"):
+        rule(None, {"X": [VarInfo((-1, 64, 97), "float32")],
+                    "Filter": [w]}, {})
+    with pytest.raises(ShapeError, match=r"not \[B, T, 3C\]"):
+        rule(None, {"X": [VarInfo((64, 96), "float32")], "Filter": [w]}, {})
+    with pytest.raises(ShapeError, match=r"not \[C, taps\]"):
+        rule(None, {"X": [x], "Filter": [VarInfo((33, 3), "float32")]}, {})
+
+    shard = get_shard_fn("short_conv")
+    on_batch = {"X": [ShardInfo(("dp", None, None), (-1, 64, 96))]}
+    assert shard(None, on_batch, {})["Out"] == ("dp", None, None)
+    for spec in ((None, "sp", None), (None, None, "mp")):
+        with pytest.raises(ShardConflict, match="short_conv"):
+            shard(None, {"X": [ShardInfo(spec, (-1, 64, 96))]}, {})
+    assert shard(None, {"X": [ShardInfo(None, (-1, 64, 96))]}, {}) == {}
