@@ -13,13 +13,18 @@ which is what the equivalence test asserts.
 
 With ``capacity_factor`` None the op takes the DROPLESS lowering instead
 (``_dropless``): no token is ever dropped and nothing has a capacity.  The
-assignments are sorted by expert, their rows gathered, the experts run as
-grouped products over the sorted rows (``ops/pallas_kernels.py
-grouped_matmul``, ``gated_grouped_matmul``) and the results are weighted
-and gathered back; work and memory go with the N * top_k routed rows.  It is the lowering of today's
-sparse-expert decoders (top-8 of 64 and the like), where a [T, E, C]
-dispatch tensor cannot be held.  Which of the two ran is counted at trace
-time as ``route/moe:{dropless,capacity}`` in ``profiler.compile_stats()``.
+assignments are sorted by expert and laid out in whole row tiles, the
+experts run as grouped products over the tiled rows (``ops/pallas_kernels.py
+grouped_matmul``, ``gated_grouped_matmul``), and every movement of rows
+between the token order and the tiled order is driven from the tiled side,
+over the tiles in use: rows-from-tokens (``rows_from_tokens``, a kernel)
+and tokens-from-rows (``_tokens_from_rows``), each the other's transpose.
+Memory goes with the N * top_k routed rows, time with the rows a call
+really holds.  It is the lowering of today's sparse-expert decoders (top-8
+of 64 and the like), where a [T, E, C] dispatch tensor cannot be held.
+Which of the two ran is counted at trace time as
+``route/moe:{dropless,capacity}`` in ``profiler.compile_stats()``, and which
+form the tokens' sums took as ``route/moe_rows:{tiles,take}``.
 
 Reference capability frame: the reference never shipped MoE; nearest
 ancestors are per-layer device placement (ParallelNeuralNetwork.cpp) and
@@ -27,6 +32,8 @@ the sparse-update machinery (SelectedRows).  This is capability-forward
 surface the ep mesh axis exists for.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,27 +58,116 @@ _ACTS = {
 ROW_TILE = 128
 
 
-@jax.custom_vjp
-def _gather_rows(x, index, readers):
-    """``x[index]`` (zeros where ``index`` is past the end).  ``readers``
-    [rows of x, r] lists, for each row of ``x``, the output rows that read
-    it (past the end: none), so the gradient is a gather and a sum too: no
-    scatter-add, the same bits every run."""
-    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+# row tiles one pass of ``_tokens_from_rows`` spans (on the v5e a scatter-add
+# costs ~45 us a call whatever it moves and 80-115 ns a row: at LFM2's shape
+# 4.04 ms a tile a pass, 1.60 / 1.71 at 4 / 8 tiles; PERF.md section 6, PR 35)
+TILE_SPAN = 8
 
 
-def _gather_rows_fwd(x, index, readers):
-    return _gather_rows(x, index, readers), readers
+def _tokens_from_rows(tiled, token, num_tiles, tm, n, scale=None):
+    """``out[token[r]] += scale[r] * tiled[r]`` over the rows of the tiles in
+    use, [n, D]: a loop whose trip count is ``num_tiles`` (a value, as the
+    grouped kernels' is), ``TILE_SPAN`` tiles a pass.  The order of a
+    token's sum is fixed: pass after pass in tile order, and inside a pass
+    in the order XLA's scatter applies its updates, the same every run (a
+    pass spans several experts' tiles, so a token may occur twice in it and
+    ``unique_indices`` cannot be promised).  A padding row (``token[r]``
+    past the end) adds nothing, and no row past ``num_tiles`` is read for
+    its value."""
+    rows, d = tiled.shape
+    span = min(TILE_SPAN * tm, rows)
+
+    def one(i, out):
+        at = jnp.minimum(i * span, rows - span)
+        index = lax.dynamic_slice(token, (at,), (span,))
+        # the last pass ends at the bound: the rows the pass before took
+        # are dropped from it
+        index = jnp.where(at + jnp.arange(span) < i * span, n, index)
+        values = lax.dynamic_slice(tiled, (at, 0), (span, d))
+        if scale is not None:
+            values = values * lax.dynamic_slice(scale, (at,), (span,))[:, None]
+        return out.at[index].add(values, mode="drop")
+
+    return lax.fori_loop(0, -(-(num_tiles[0] * tm) // span), one,
+                         jnp.zeros((n, d), tiled.dtype))
 
 
-def _gather_rows_bwd(readers, g):
-    # the r readers outermost, [r, rows, D]: a second-minor dimension of 4
-    # would pad to a sublane tile of 8 and move twice the bytes
-    return (jnp.take(g, readers.T, axis=0, mode="fill", fill_value=0)
-            .sum(axis=0), None, None)
+def _tokens_from_rows_held(tiled, row_of, weight=None):
+    """The same sum from the token side, for a call that holds EVERY expert
+    (no assignment is absent, so nothing can be skipped): each of the
+    ``n * top_k`` assignments' rows gathered (``row_of`` [n, top_k]) and
+    summed over the slots, the slots outermost ([top_k, n, D]: a
+    second-minor dimension of 4 would pad to a sublane tile of 8)."""
+    n, top_k = row_of.shape
+    picked = jnp.take(tiled, row_of.T.reshape(-1), axis=0, mode="fill",
+                      fill_value=0).reshape(top_k, n, -1)
+    if weight is not None:
+        picked = picked * weight.T[..., None].astype(picked.dtype)
+    return jnp.sum(picked, axis=0)
 
 
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+def _weight_of_rows(weight, assignment, dtype):
+    return jnp.take(weight.reshape(-1), assignment, mode="fill",
+                    fill_value=0).astype(dtype)
+
+
+# ``index`` of the two stages: (assignment of a tiled row, its token, tiled
+# row of an assignment [n, top_k], ``num_tiles``), past the end where none
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dispatch(xt, index, tm, all_held):
+    """rows-from-tokens, and tokens-from-rows as its transpose: the tokens'
+    rows in the tiled order, [tiles * tm, D], those of the tiles in use
+    (the rest is never written)."""
+    from .pallas_kernels import rows_from_tokens
+
+    _, token, _, num_tiles = index
+    return rows_from_tokens(xt, token, num_tiles, tm)
+
+
+def _dispatch_fwd(xt, index, tm, all_held):
+    return _dispatch(xt, index, tm, all_held), index
+
+
+def _dispatch_bwd(tm, all_held, index, g):
+    _, token, row_of, num_tiles = index
+    return (_tokens_from_rows_held(g, row_of) if all_held else
+            _tokens_from_rows(g, token, num_tiles, tm, row_of.shape[0])), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(down, weight, index, tm, all_held):
+    """tokens-from-rows with the weights, and rows-from-tokens as its
+    transpose: ``out[t] = sum over t's assignments held here of weight *
+    its row of down``, [n, D], the weights applied in ``down``'s dtype.  No
+    [top_k, n, D] array of picked rows is kept: the backward pass reads
+    ``down`` itself (``d_down[r] = w(r) * g[token(r)]`` and ``d_w(r) =
+    <g[token(r)], down[r]>`` on the same tile)."""
+    assignment, token, row_of, num_tiles = index
+    if all_held:
+        return _tokens_from_rows_held(down, row_of, weight)
+    return _tokens_from_rows(down, token, num_tiles, tm, weight.shape[0],
+                             _weight_of_rows(weight, assignment, down.dtype))
+
+
+def _combine_fwd(down, weight, index, tm, all_held):
+    return _combine(down, weight, index, tm, all_held), (down, weight, index)
+
+
+def _combine_bwd(tm, all_held, res, g):
+    from .pallas_kernels import rows_from_tokens
+
+    down, weight, (assignment, token, row_of, num_tiles) = res
+    d_down, dots = rows_from_tokens(
+        g, token, num_tiles, tm,
+        _weight_of_rows(weight, assignment, g.dtype), down)
+    d_weight = jnp.take(dots, row_of, mode="fill", fill_value=0)
+    return d_down, d_weight.astype(weight.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
@@ -92,27 +188,44 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
     z = mean_t logsumexp(logits_t)^2.
     dispatch: a stable sort of the N * top_k assignments by expert; each
     expert's rows laid out in whole tiles of ``ROW_TILE`` (at least one),
-    padding rows zero.  experts: act(x Wg) * (x Wu) through Wd (no Wg:
-    act(x Wu) Wd) as grouped products; with Wg, gate and up are ONE paired
-    product a direction (``gated_grouped_matmul``: the rows read once for
-    both stacks, ``act(gate) * up`` in the forward kernel's epilogue, the
-    two gradients of the rows summed inside one kernel), counted at trace
-    time as ``route/moe:gated_pair``, so the stage is six kernels forward
-    and backward.  combine: every assignment's row gathered back, weighted,
-    summed over the token's ``top_k`` (the slots outermost, [top_k, N, D]:
-    a second-minor dimension of 4 would pad to a sublane tile of 8).
+    padding rows zero.  A tiled row in use knows its token (``assignment //
+    top_k``); a tile belongs to ONE expert and the sort is stable over
+    token-major assignments, so within a tile (within an expert) the tokens
+    are distinct and increasing.  The rows come by rows-from-tokens,
+    ``tiled[r] = xt[token(r)]``, a tile a step of a kernel that stops at
+    ``num_tiles``; the gradient is tokens-from-rows, ``d_xt[token(r)] +=
+    d_tiled[r]`` over the same tiles.  experts: act(x Wg) * (x Wu) through
+    Wd (no Wg: act(x Wu) Wd) as grouped products; with Wg, gate and up are
+    ONE paired product a direction (``gated_grouped_matmul``: the rows read
+    once for both stacks, ``act(gate) * up`` in the forward kernel's
+    epilogue, the two gradients of the rows summed inside one kernel),
+    counted at trace time as ``route/moe:gated_pair``, so the stage is six
+    kernels forward and backward.  combine: tokens-from-rows with the
+    weights, ``out[token(r)] += w(r) * down[r]``, its gradient
+    rows-from-tokens of the cotangent with ``w(r)`` and the weights' own
+    gradient made on the same tile.  Where a call holds a share,
+    tokens-from-rows is a scatter-add over the tiles in use, in tile order
+    (``route/moe_rows:tiles``); where it holds EVERY expert the bound is the
+    rows in use, nothing can be skipped, and a gather from the token side
+    costs half as much a row: each assignment's row gathered ([top_k, N, D],
+    the slots outermost) and summed over the slots (``route/moe_rows:take``;
+    a static fact of the operands, ``held == experts``).
 
     **One chip's share of an expert-parallel layer**: the stacks hold fewer
     experts than the router has outputs, those from ``expert_offset`` on.
     Scores, choice and renormalisation run over ALL the router's experts,
     as on every chip of the deployment; an assignment to an expert that is
-    not held sorts behind the held ones, gets no row and no product, and in
-    ``combine`` reads past the end (the gather's zero): what the absent
-    experts would have added is left out, and nothing stands in for their
-    chips.  The tile count stays the static worst case (every assignment
-    lands here; no token is dropped): the kernels skip the tiles past
-    ``num_tiles``, so time goes with the rows held and memory with the
-    bound.
+    not held sorts behind the held ones, gets no row and no product, and no
+    tile holds it, so ``combine`` never meets it: what the absent experts
+    would have added is left out, and nothing stands in for their chips.
+    The tile count stays the static worst case (every assignment lands
+    here; no token is dropped), and every stage stops at ``num_tiles``: the
+    grouped kernels skip the tiles past it, rows-from-tokens starts no copy
+    for them and tokens-from-rows' loop ends before them, so time goes with
+    the rows held and memory with the bound, in dispatch and combine as in
+    the experts.  Past ``num_tiles`` the tiled arrays hold nothing anyone
+    may read (``rows`` and the cotangent of ``down`` are not even written
+    there; the grouped kernels' own outputs are zero).
     """
     from .pallas_kernels import gated_grouped_matmul, grouped_matmul
 
@@ -171,12 +284,18 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
             in_use,
             order[jnp.minimum(first[group] + offset, assignments - 1)],
             assignments)
+        token = jnp.where(in_use, assignment // top_k, n)
         sorted_at = jnp.argsort(order)               # assignment -> sorted
         at = jnp.minimum(key, held - 1)
         row_of = jnp.where(here, first_row[at] + sorted_at - first[at],
                            tiles * tm).reshape(n, top_k)
-        rows = _gather_rows(xt, jnp.where(in_use, assignment // top_k, n),
-                            row_of)
+        # tokens-from-rows runs from the tiled side unless every expert is
+        # held: then the bound IS the rows in use and nothing can be skipped
+        all_held = held == experts
+        compile_cache.stats().bump(
+            "route/moe_rows:" + ("take" if all_held else "tiles"))
+        index = (assignment, token, row_of, num_tiles)
+        rows = _dispatch(xt, index, tm, all_held)
     with jax.named_scope("moe.experts"):
         if w_gate is None:
             hidden = act(grouped_matmul(rows, w_up, tile_group, num_tiles))
@@ -186,13 +305,7 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
                                           num_tiles, act)
         down = grouped_matmul(hidden, w_down, tile_group, num_tiles)
     with jax.named_scope("moe.combine"):
-        # slot-major: assignment (token, slot) at slot * n + token
-        reader = jnp.where(
-            in_use, assignment % top_k * n + assignment // top_k,
-            assignments)
-        picked = _gather_rows(down, row_of.T.reshape(-1), reader[:, None])
-        out = jnp.sum(picked.reshape(top_k, n, -1)
-                      * weight.T[..., None].astype(picked.dtype), axis=0)
+        out = _combine(down, weight, index, tm, all_held)
     return out, aux, z
 
 

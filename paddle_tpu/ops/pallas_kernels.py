@@ -495,13 +495,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 # grouped matmul (the expert products of the dropless ``moe`` lowering)
 # ---------------------------------------------------------------------------
 # Rows sorted by group, every group padded to whole row tiles (at least
-# one), so a tile belongs to exactly one group and no store is masked:
-# ``tile_group[i]`` names tile i's group and ``num_tiles[0]`` how many
-# tiles are in use (the operands' length is the static worst case; tiles
-# past the count are skipped and read as zero).  One product is a plain
-# tiled matmul whose weight block is picked by ``tile_group``; the block
-# holds the whole contraction, stays in VMEM while consecutive tiles share
-# a group, and so is read from HBM once.
+# one), so a tile belongs to one group and no store is masked:
+# ``tile_group[i]`` names tile i's group, ``num_tiles[0]`` the tiles in use
+# (the length is the static worst case).  Past the count an OPERAND's tiles
+# are never read (``rows_from_tokens`` does not write them) and a RESULT's
+# are stored as zeros (XLA's elementwise work between kernels reads those).
+# A product is a tiled matmul whose weight block, the whole contraction, is
+# picked by ``tile_group`` and stays in VMEM over a group: one read from HBM.
 #
 # Every kernel takes a TUPLE of stacks that share the layout (the gate and
 # up stacks of gated experts; one stack otherwise): a tile of rows is read
@@ -1153,3 +1153,132 @@ from ..analysis.shard_prop import shard_same_as  # noqa: E402
 from ..core.registry import register_shard_fn  # noqa: E402
 
 register_shard_fn("flash_attention")(shard_same_as("Q"))
+
+
+# ---------------------------------------------------------------------------
+# rows_from_tokens (the row movement of the dropless ``moe`` lowering,
+# ops/moe_ops.py: its dispatch, and the transpose of its combine)
+# ---------------------------------------------------------------------------
+# ``tiled[r] = src[token[r]]`` in ``grouped_matmul``'s layout, a row tile a
+# grid step, for the tiles in use alone: a step past ``num_tiles`` starts no
+# copy and its output block is the last one in use, so nothing is written
+# there and the rows past the count are NEVER WRITTEN (they hold whatever
+# the buffer held: no reader may look there, and the grouped kernels do
+# not).  A row of a [N, D] array is not something a DMA can take (eight rows
+# interleave in a tile of the (8, 128) layout), so the source is viewed as
+# [N, D / 128, 128]: a row is then D / 128 whole sublanes, contiguous, and
+# lands in a [tm, D / 128, 128] scratch; the output block [tm, D] takes it
+# 128 lanes at a time (a load with a sublane stride).  XLA's own gather
+# would do for the rows, but a loop of it over the tiles in use leaves a
+# while loop's result, which the TPU compiler holds twice where a kernel
+# reads it (1.44 GB in LFM2's step; PERF.md section 6, PR 35).
+ROW_LANES = 128
+
+
+def _rows_kernel(token_ref, count_ref, *refs, tm, n, weighted):
+    """``refs``: the source in HBM, the output tile, the scratch the rows
+    land in, the DMA semaphore; ``weighted``: the rows' weights (in SMEM)
+    first, the partner tile [tm, D] after the source, and a second output
+    [1, 1, tm] for the dots."""
+    if weighted:
+        weight_ref, src_ref, partner_ref, out_ref, dots_ref, landed, sem = refs
+    else:
+        src_ref, out_ref, landed, sem = refs
+    i = pl.program_id(0)
+
+    @pl.when(i < count_ref[0])
+    def _tile():
+        base = i * tm
+
+        def start(r, carry):
+            t = token_ref[base + r]
+
+            @pl.when(t < n)
+            def _row():
+                pltpu.make_async_copy(src_ref.at[t], landed.at[r],
+                                      sem).start()
+
+            @pl.when(t >= n)             # a padding row: zeros, as a
+            def _padding():              # product reads it
+                landed[r] = jnp.zeros(landed.shape[1:], landed.dtype)
+            return carry
+
+        lax.fori_loop(0, tm, start, 0)
+
+        def wait(r, carry):
+            @pl.when(token_ref[base + r] < n)
+            def _row():
+                pltpu.make_async_copy(src_ref.at[0], landed.at[r],
+                                      sem).wait()
+            return carry
+
+        lax.fori_loop(0, tm, wait, 0)
+        groups, lanes = landed.shape[1:]
+        if weighted:
+            dots = jnp.zeros((tm, 1), jnp.float32)
+            for c in range(groups):      # of the rows as they came
+                beside = partner_ref[:, c * lanes:(c + 1) * lanes]
+                dots += jnp.sum(landed[:, c, :] * beside, axis=1,
+                                keepdims=True)
+            dots_ref[0] = dots.reshape(1, tm).astype(dots_ref.dtype)
+
+            def scale(r, carry):         # a row is whole sublanes here
+                landed[r] = landed[r] * weight_ref[base + r]
+                return carry
+
+            lax.fori_loop(0, tm, scale, 0)
+        for c in range(groups):
+            out_ref[:, c * lanes:(c + 1) * lanes] = landed[:, c, :]
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _rows_call(token, num_tiles, src, weight, partner, tm, interpret):
+    """The ``pallas_call`` of ``rows_from_tokens`` (``weight`` and
+    ``partner`` both given or both None).  Jitted, so a program whose
+    layers call it at one shape lowers the kernel once."""
+    n, d = src.shape
+    rows = token.shape[0]
+    weighted = weight is not None
+    lanes = d if d % ROW_LANES else ROW_LANES
+
+    def tile(i, token, count, *weight):
+        return _tile_in_use(i, count), 0
+
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    out_shape = [_sds(src, (rows, d), src.dtype)]
+    out_specs = [pl.BlockSpec((tm, d), tile)]
+    if weighted:
+        in_specs.append(pl.BlockSpec((tm, d), tile))
+        out_shape.append(_sds(src, (rows // tm, 1, tm), src.dtype))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, tm), lambda i, token, count, weight:
+            (_tile_in_use(i, count), 0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_rows_kernel, tm=tm, n=n, weighted=weighted),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2 + weighted, grid=(rows // tm,),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((tm, d // lanes, lanes), src.dtype),
+                pltpu.SemaphoreType.DMA(())]),
+        **({"interpret": True} if interpret else
+           {"compiler_params": pltpu.CompilerParams(
+               dimension_semantics=("arbitrary",))}),
+    )(token, num_tiles, *([weight] if weighted else []),
+      src.reshape(n, d // lanes, lanes), *([partner] if weighted else []))
+    return (out[0], out[1].reshape(rows)) if weighted else out[0]
+
+
+def rows_from_tokens(src, token, num_tiles, tm, weight=None, partner=None):
+    """``tiled[r] = src[token[r]]`` for the rows of the ``num_tiles`` (int32
+    [1]) row tiles of ``tm`` in use: ``src`` [N, D] (a D that is no multiple
+    of 128 moves as one group of lanes), ``token`` int32 [R] (past the end,
+    ``>= N``: a padding row, zeros).  The rows of the tiles past the count
+    are never written.  With ``weight`` [R] and ``partner`` [R, D], of the
+    same rows: ``(weight[r] * src[token[r]], <src[token[r]], partner[r]>)``,
+    the second [R].  Not differentiable (``_dropless`` holds the
+    transposes).  The Pallas kernel everywhere: compiled on the TPU,
+    interpreted on any other backend."""
+    return _rows_call(token, num_tiles, src, weight, partner, tm=tm,
+                      interpret=jax.default_backend() != "tpu")

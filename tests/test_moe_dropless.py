@@ -2,8 +2,10 @@
 ops/moe_ops.py ``_dropless``): values, the two router losses and every
 gradient against an oracle that loops over tokens and their experts; that
 nothing it builds has a capacity; the refusal under expert parallelism; the
-route counter.  The grouped products are the Pallas kernels, interpreted
-here (ops/pallas_kernels.py ``grouped_matmul``)."""
+route counter; the two primitives of its row movement against the gathers
+they replaced, and that nothing reads a tiled array past the tiles in use.
+The grouped products and the row gather are the Pallas kernels, interpreted
+here (ops/pallas_kernels.py ``grouped_matmul``, ``rows_from_tokens``)."""
 import functools
 
 import jax
@@ -202,3 +204,196 @@ def test_gated_experts_need_the_dropless_lowering():
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
     with pytest.raises(NotImplementedError, match="dropless"):
         exe.run(feed=feed, fetch_list=[loss])
+
+
+# ---------------------------------------------------------------------------
+# the row movement between the token order and the tiled order
+# ---------------------------------------------------------------------------
+ROWS_CASES = {
+    # name: (tokens, router outputs, experts held, first held, top_k, skew)
+    "a-share-8-of-32-from-0": (24, 32, 8, 0, 4, None),
+    "a-share-8-of-32-from-8": (24, 32, 8, 8, 4, None),
+    "every-expert-held-top8": (12, 16, 16, 0, 8, None),
+    # the second expert held gets a large negative logit from every token
+    "an-expert-with-no-row": (24, 32, 8, 8, 4, "starve"),
+    # every expert held does: num_tiles is the experts held, all padding
+    "no-assignment-lands-here": (24, 32, 8, 8, 4, "none"),
+    # four of the held get a large positive one: every slot of every token
+    # is here, and the tiles in use reach the bound
+    "every-slot-of-a-token-here": (24, 32, 8, 8, 4, "all"),
+}
+ROWS_TILE, ROWS_SPAN, ROWS_D = 8, 3, 16
+
+
+def _logits(case):
+    n, e, held, first, top_k, skew = ROWS_CASES[case]
+    logits = np.random.RandomState(sorted(ROWS_CASES).index(case)).randn(
+        n, e).astype("float32")
+    if skew == "starve":
+        logits[:, first + 1] = -40.0
+    elif skew == "none":
+        logits[:, first:first + held] = -40.0
+    elif skew == "all":
+        logits[:, first + 2:first + 6] += 40.0
+    return logits
+
+
+def _tiled_order(expert, held, first, tm):
+    """By numpy, what ``_dropless`` computes with sorts and cumulative
+    sums: (assignment of a tiled row, its token, tiled row of an
+    assignment [n, top_k], num_tiles [1]); past the end where there is
+    none."""
+    n, top_k = expert.shape
+    rows = (n * top_k // tm + held) * tm
+    token = np.full(rows, n, np.int32)
+    assignment = np.full(rows, n * top_k, np.int32)
+    row_of = np.full(n * top_k, rows, np.int32)
+    at = 0
+    for e in range(first, first + held):
+        mine = np.flatnonzero(expert.reshape(-1) == e)   # token-major
+        token[at:at + len(mine)] = mine // top_k
+        assignment[at:at + len(mine)] = mine
+        row_of[mine] = at + np.arange(len(mine))
+        at += max(-(-len(mine) // tm), 1) * tm
+    return tuple(map(jnp.asarray, (assignment, token,
+                                   row_of.reshape(n, top_k),
+                                   np.array([at // tm], np.int32))))
+
+
+def _past_the_count(a, num_tiles, value=np.nan):
+    """``a`` with ``value`` in every row of the tiles past the count."""
+    past = jnp.arange(a.shape[0]) >= num_tiles[0] * ROWS_TILE
+    return jnp.where(past.reshape((-1,) + (1,) * (a.ndim - 1)), value, a)
+
+
+def _take(x, index):
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+def _index(case):
+    """``_dropless``'s index tuple of a case, by numpy."""
+    _, e, held, first, top_k, _ = ROWS_CASES[case]
+    return _tiled_order(
+        _choice({"x": _logits(case), "router": np.eye(e, dtype="float32")},
+                top_k), held, first, ROWS_TILE)
+
+
+def _check_dispatch(case):
+    """rows-from-tokens forward, tokens-from-rows as its transpose."""
+    n, e, held, _, top_k, _ = ROWS_CASES[case]
+    rng = np.random.RandomState(7)
+    index = _, token, row_of, num_tiles = _index(case)
+    x = jnp.asarray(rng.randn(n, ROWS_D).astype("float32"))
+    rows, vjp = jax.vjp(lambda x: moe_ops._dispatch(
+        x, index, ROWS_TILE, held == e), x)
+    used = int(num_tiles[0]) * ROWS_TILE     # past it nothing is written
+    np.testing.assert_array_equal(rows[:used], _take(x, token)[:used])
+    g = _past_the_count(jnp.asarray(
+        rng.randn(*rows.shape).astype("float32")), num_tiles)
+    # (the sum over a token's rows runs in tile order, not slot order)
+    np.testing.assert_allclose(
+        vjp(g)[0],
+        _take(g, row_of.T.reshape(-1)).reshape(top_k, n, -1).sum(0),
+        rtol=1e-6, atol=1e-6)
+
+
+def _check_combine(case):
+    """tokens-from-rows with the weights forward, rows-from-tokens with
+    the weights and the weights' own gradient as its transpose."""
+    n, e, held, _, top_k, _ = ROWS_CASES[case]
+    rng = np.random.RandomState(8)
+    index = assignment, token, row_of, num_tiles = _index(case)
+    down = _past_the_count(jnp.asarray(rng.randn(
+        token.shape[0], ROWS_D).astype("float32")), num_tiles)
+    weight = jnp.asarray(rng.rand(n, top_k).astype("float32"))
+    out, vjp = jax.vjp(lambda down, weight: moe_ops._combine(
+        down, weight, index, ROWS_TILE, held == e), down, weight)
+    picked = _take(down, row_of.T.reshape(-1)).reshape(top_k, n, -1)
+    np.testing.assert_allclose(
+        out, jnp.sum(picked * weight.T[..., None], axis=0),
+        rtol=1e-6, atol=1e-6)
+    g = jnp.asarray(rng.randn(n, ROWS_D).astype("float32"))
+    d_down, d_weight = vjp(g)
+    reader = jnp.where(assignment < n * top_k,
+                       assignment % top_k * n + assignment // top_k,
+                       n * top_k)
+    used = int(num_tiles[0]) * ROWS_TILE     # past it nothing is written
+    np.testing.assert_array_equal(                           # bit for bit
+        d_down[:used], _take((g[None] * weight.T[..., None]).reshape(
+            top_k * n, -1), reader)[:used])
+    np.testing.assert_allclose(d_weight, jnp.sum(picked * g[None], -1).T,
+                               rtol=1e-6, atol=1e-6)
+
+
+def _check_poison(case, monkeypatch):
+    """The op with NaN in every tiled array past ``num_tiles`` (the rows
+    handed to the experts, what they hand back, and the cotangents the
+    other way): output and every gradient are the bits of the clean run,
+    so nothing reads past the count."""
+    from paddle_tpu.ops import pallas_kernels
+
+    n, e, held, first, top_k, _ = ROWS_CASES[case]
+
+    clean = {"single": pallas_kernels.grouped_matmul,
+             "gated": pallas_kernels.gated_grouped_matmul}
+
+    def run(value):
+        """Through the same program twice: the rows past the count set to
+        ``value`` on the way into the experts and out of them, forward and
+        backward."""
+        @jax.custom_vjp
+        def past(a, num_tiles):
+            return _past_the_count(a, num_tiles, value)
+
+        past.defvjp(lambda a, num_tiles: (past(a, num_tiles), num_tiles),
+                    lambda num_tiles, g: (past(g, num_tiles), None))
+
+        def single(lhs, rhs, tile_group, num_tiles):
+            return past(clean["single"](
+                past(lhs, num_tiles), rhs, tile_group, num_tiles), num_tiles)
+
+        def gated(rows, w_gate, w_up, tile_group, num_tiles, act):
+            return past(clean["gated"](
+                past(rows, num_tiles), w_gate, w_up, tile_group, num_tiles,
+                act), num_tiles)
+
+        with monkeypatch.context() as m:
+            m.setattr(pallas_kernels, "grouped_matmul", single)
+            m.setattr(pallas_kernels, "gated_grouped_matmul", gated)
+            rng = np.random.RandomState(9)
+            here = slice(first, first + held)
+            w = _weights(rng, n, e, 6, e, True)
+            w.update(x=_logits(case), router=np.eye(e, dtype="float32"))
+            mix = jnp.asarray(rng.randn(n, e).astype("float32"))
+
+            def system(w, top_k):
+                return moe_ops._dropless(
+                    *(jnp.asarray(w[k]) for k in ("x", "router")),
+                    *(jnp.asarray(w[k])[here] for k in ("gate", "up",
+                                                        "down")),
+                    top_k, jax.nn.silu, expert_offset=first)
+            return _loss_and_grads(system, w, top_k, mix)
+
+    (out, aux, z), grads = run(0.0)        # what the tiles hold anyway
+    (p_out, p_aux, p_z), p_grads = run(np.nan)
+    assert np.isfinite(out).all() and (
+        bool(np.any(out)) or ROWS_CASES[case][5] == "none")
+    for a, b in [(out, p_out), (aux, p_aux), (z, p_z)] + [
+            (grads[k], p_grads[k]) for k in grads]:
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("check", ["dispatch", "combine", "poison"])
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+def test_rows_move_with_the_tiles_in_use(case, check, monkeypatch):
+    """The two primitives of ``_dropless``'s row movement and their
+    gradients against the ``jnp.take`` formulas they replaced (bit for bit
+    where no sum changed its order, to 1e-6 where one did), in tiles of 8
+    rows and passes of 3 tiles so that a last pass overlaps the one before;
+    every tiled array holds NaN past ``num_tiles``."""
+    monkeypatch.setattr(moe_ops, "ROW_TILE", ROWS_TILE)
+    monkeypatch.setattr(moe_ops, "TILE_SPAN", ROWS_SPAN)
+    if check == "poison":
+        _check_poison(case, monkeypatch)
+    else:
+        {"dispatch": _check_dispatch, "combine": _check_combine}[check](case)

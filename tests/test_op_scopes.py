@@ -165,7 +165,9 @@ def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
     pair's gradient of both stacks (two results) and ``down``'s two.  All carry
     ``pt.moe:<b>.<p>/moe.experts`` in their location, which is how
     ``moe_step_ms``, ``moe_experts_roofline_pct`` and the stages' table of
-    a traced run find them."""
+    a traced run find them.  The two calls of ``rows_from_tokens`` stay
+    OUTSIDE that stage, under ``moe.dispatch`` forward and ``moe.combine``
+    backward: the rooflines divide by the experts' time alone."""
     import jax
     import jax.numpy as jnp
 
@@ -192,13 +194,21 @@ def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
             operands, results, loc = re.search(
                 r" : \((.*)\) -> (.*) loc\((#loc\d+)\)$", line).groups()
             calls.append((operands.count("tensor<"), results.count("tensor<"),
-                          locs[loc][locs[loc].index("/"):]))
+                          locs[loc].removeprefix("jit(loss)")))
     fwd = "/jvp(pt.moe:0.3)/moe.experts/pallas_call"
     bwd = "/transpose(jvp(pt.moe:0.3))/moe.experts/pallas_call"
+    inner = "pallas_call"      # inside the jitted ``_rows_call``
     # (operands with the layout's two, results, where)
     assert sorted(calls) == sorted([
         (5, 3, fwd), (4, 1, fwd), (6, 1, bwd), (5, 2, bwd), (4, 1, bwd),
-        (4, 1, bwd)])
+        (4, 1, bwd), (3, 1, inner), (5, 2, inner)])
+    # the row kernels' call sites carry the stage (XLA inlines the calls
+    # and joins the names, as for ``rope``)
+    sites = sorted(locs[loc][locs[loc].index("/"):] for loc in re.findall(
+        r"call @_rows_call\w*\(.* loc\((#loc\d+)\)$", text, re.M))
+    assert sites == [
+        "/jvp(pt.moe:0.3)/moe.dispatch/jit(_rows_call)",
+        "/transpose(jvp(pt.moe:0.3))/moe.combine/jit(_rows_call)"]
     assert compile_cache.stats().snapshot()["route/moe:gated_pair"] == 1
 
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.core import compile_cache
 from paddle_tpu.ops import moe_ops, nn_ops, pallas_kernels
 
 
@@ -241,3 +242,57 @@ def test_grouped_query_attention_compiles_at_lfm2_heads(spec, batch):
     assert len(kv_results) == 1
     assert not re.search(rf"f32\[{batch},{LFM2_T},{heads},{d}\]\S* "
                          r"broadcast\(", text)
+
+
+# the dropless ``moe`` lowering at the two cells' shapes: 8192 tokens of 2048
+# features; LFM2 top-4 of a 32-wide router over the 8 experts of 1792 a chip
+# holds, OLMoE top-8 of 64 experts of 1024, all held
+@pytest.mark.parametrize(
+    "experts,held,top_k,width,route,parent_temp,allowed", [
+        (32, 8, 4, 1792, {"scoring": "sigmoid", "renormalize": True},
+         1_553_075_712, 8_388_608),
+        (64, 64, 8, 1024, {}, 1_823_088_640, 302_514_176)],
+    ids=["lfm2-share", "olmoe"])
+def test_dropless_moves_rows_with_the_tiles_in_use(
+        spec, monkeypatch, experts, held, top_k, width, route, parent_temp,
+        allowed):
+    """``_dropless``, value and every gradient, compiles for the v5e at
+    both cells' shapes, its row movement driven from the tiled side: no XLA
+    gather makes a bound-sized array ([33792, 2048], [73728, 2048]; the
+    rows come from ``rows_from_tokens``, two more kernels than the six of
+    the experts), and where a share is held (LFM2) ``moe.combine`` holds no
+    [top_k, N, D] array either way ([4, 8192, 2048], [32768, 2048]: the
+    tokens' sum is a scatter-add over the tiles in use, counted
+    ``route/moe_rows:tiles``; every expert held: ``:take``).  Temporaries
+    against the parent's (PR 34) for the same function: LFM2's share
+    1 560 958 976 bytes for 1 553 075 712 (``down`` [33792, 2048] is kept
+    for the backward pass where ``picked`` [4, 8192, 2048] was: 8 388 608
+    more, and nothing else); OLMoE 2 125 192 192 for 1 823 088 640 (the
+    same exchange, 67 MB, and that ``picked``'s 537 MB had found room in a
+    gradient's output buffer where ``down``'s 604 MB do not: in the cell's
+    whole step the difference is the 67 MB, 12.233 GB for 12.161)."""
+    n, d = 8192, LFM2_D
+    bound = (n * top_k // TILE + held) * TILE
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(x, router, gate, up, down, mix):
+        out, aux, z = moe_ops._dropless(x, router, gate, up, down, top_k,
+                                        jax.nn.silu, **route)
+        return jnp.sum(out * mix) + aux + z
+
+    counted = compile_cache.stats().snapshot()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        spec((n, d)), spec((d, experts)), spec((held, d, width)),
+        spec((held, d, width)), spec((held, width, d)),
+        spec((n, d))).compile()
+    form = "route/moe_rows:" + ("tiles" if held < experts else "take")
+    assert compile_cache.stats().snapshot()[form] == counted.get(form, 0) + 1
+    text = compiled.as_text()
+    assert _kernels(text) == 8
+    assert not re.search(rf"f32\[{bound},{d}\]\S* gather\(", text)
+    if held < experts:
+        picked = rf"f32\[({top_k},{n}|{top_k * n}),{d}\]"
+        assert not [line for line in text.splitlines()
+                    if "moe.combine" in line and re.search(picked, line)]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= parent_temp + allowed, temp
