@@ -26,6 +26,13 @@ Public API intentionally mirrors the reference's fluid Python surface
 ``Program``, ``default_main_program`` ...
 """
 
+# the phase log's ``process/import`` (core/compile_cache.py PHASE_NAMES):
+# from here to the last statement of this file, on time.perf_counter()
+import sys as _sys
+import time as _time
+_IMPORT_T0 = _time.perf_counter()
+_JAX_PREIMPORTED = "jax" in _sys.modules    # a benchmark imports jax first
+
 from . import compat
 from . import core
 from .core import (
@@ -108,3 +115,7 @@ __all__ = [
     "faults", "EXIT_PREEMPTED", "Preempted", "RetryPolicy",
     "train_state", "TrainState", "testing",
 ]
+
+core.compile_cache.stats().record_phase(
+    "process/import", _IMPORT_T0, _time.perf_counter(),
+    jax_preimported=_JAX_PREIMPORTED)

@@ -27,8 +27,11 @@ for every (program, feed-signature) variant.  This module makes that cost
 
 3. **Telemetry** — per-fingerprint trace/lower/compile wall times, cache
    hit/miss/eviction counters, trace-time kernel-routing counters
-   (``route/<op>:<path>``) and a retrace detector
-   (:func:`retrace_guard` / :meth:`CompileStats.assert_no_retrace`), all
+   (``route/<op>:<path>``), a retrace detector
+   (:func:`retrace_guard` / :meth:`CompileStats.assert_no_retrace`) and
+   the **phase log**: what made a process's start cold, one record a piece
+   of work (:data:`PHASE_NAMES`), written where the work happens, always
+   on, on ``time.perf_counter()``; a warm dispatch writes nothing.  All
    surfaced through ``paddle_tpu.profiler.compile_stats()``.
 
 The deploy-time entry point is ``Executor.compile(...) -> CompiledProgram``
@@ -108,8 +111,43 @@ class RetraceError(AssertionError):
     """Raised by :func:`retrace_guard` when a fingerprint traces twice."""
 
 
+#: the phase log's names, frozen as ``SPAN_NAMES`` / ``METRIC_NAMES`` are:
+#: every ``record_phase`` call passes one of these as a string literal
+#: (tests/test_repo_lint.py holds call sites and table to each other)
+PHASE_NAMES = (
+    ("process/import",
+     "first to last statement of paddle_tpu/__init__.py; fact "
+     "jax_preimported"),
+    ("step/enter",
+     "Executor._enter when the entry cache had no step: feeds coerced, "
+     "state keys, validation, the Program's fingerprint, the step built"),
+    ("step/trace",
+     "CachedStep._compile: jit.trace, Python over the Program's ops"),
+    ("step/lower",
+     "CachedStep._compile: traced.lower, jaxpr to StableHLO"),
+    ("step/xla",
+     "CachedStep._compile: lowered.compile, a read of JAX's persistent "
+     "cache (fact cache_hit) or an XLA compile"),
+    ("step/first_call",
+     "CachedStep.__call__, the first call of a compiled step: argument "
+     "check, the executable's load, the enqueue (host time, not device)"),
+    ("state/place",
+     "ShardedExecutor.place_state: device_put of every persistable under "
+     "its sharding; fact bytes"),
+)
+_PHASE_NAME_SET = frozenset(n for n, _help in PHASE_NAMES)
+#: the three phases of CachedStep._compile -> their key in
+#: ``entries[fp]["times"]``: record_phase writes both, so the per-fingerprint
+#: times and the log cannot disagree
+_STEP_TIME_KEYS = {"step/trace": "trace_s", "step/lower": "lower_s",
+                   "step/xla": "compile_s"}
+#: records kept; beyond it a record only bumps ``phases_dropped``
+PHASE_LOG_CAP = 512
+
+
 class CompileStats:
-    """Compile-time telemetry: counters + per-fingerprint phase records.
+    """Compile-time telemetry: counters, per-fingerprint phase times, and
+    the phase log (:meth:`record_phase`).
 
     Counters:
       hits/misses/evictions       — in-process entry cache (ExecCache)
@@ -136,13 +174,32 @@ class CompileStats:
                                     memoizes per (program, version,
                                     fetches), so this stays flat across
                                     steps — tests/test_analysis.py pins it
+      jax_trace_s / jax_lower_s / — seconds (floats) of JAX's tracing,
+      jax_backend_compile_s         lowering and backend compiles OUTSIDE a
+                                    CachedStep's compile (seeded draws,
+                                    device_puts, jnp glue): its jaxpr_trace
+                                    / jaxpr_to_mlir_module / backend_compile
+                                    duration events once :func:`cache_dir`
+                                    has hooked them, an event nested in
+                                    another counted once
+                                    (:func:`_on_jax_duration`)
+      phases_dropped              — phase records beyond PHASE_LOG_CAP
+
+    The phase log answers what ``entries[fp]["times"]`` cannot: WHEN each
+    piece of a cold start ran (``t0`` on ``time.perf_counter()``, the clock
+    of a benchmark's own marks and of the host side of a profiler trace),
+    what came before the trace and after the compile, and whether the XLA
+    phase was a cache read.  Records of one cold call share ``fp``; its
+    ``step/enter`` names in ``cause`` the public call that made the work
+    necessary.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.counters: Dict[str, int] = collections.defaultdict(int)
+        self.counters: Dict[str, float] = collections.defaultdict(int)
         self.entries: Dict[str, dict] = {}
         self._guards: List[Dict[str, int]] = []
+        self._phases: List[dict] = []
 
     # -- recording -------------------------------------------------------
     def entry(self, fp: str) -> dict:
@@ -179,19 +236,65 @@ class CompileStats:
             e["hits"] += 1
             self.counters["hits"] += 1
 
-    def record_times(self, fp: str, source: str, label: Optional[str] = None,
-                     **times):
-        e = self.entry(fp)
+    def record_phase(self, name: str, t0: float, t1: float,
+                     fp: Optional[str] = None, label: Optional[str] = None,
+                     cause: Optional[str] = None, **facts):
+        """Append one record to the phase log: ``name`` (a literal member
+        of :data:`PHASE_NAMES`) ran from ``t0`` to ``t1`` on
+        ``time.perf_counter()``, for the step ``fp`` / ``label``, because
+        of the public call ``cause`` (``run``, ``run_steps``, ``compile``:
+        ``Executor._enter`` knows it and writes it on ``step/enter``; a
+        step's other records find it through ``fp``).  A phase of
+        ``CachedStep._compile`` is also that fingerprint's
+        ``entries[fp]["times"]`` (kept beyond the log's cap).  With a
+        metrics log set the record is also one ``phase`` event, whatever
+        ``observe`` says: a cold start's handful, none from a warm
+        dispatch."""
+        if name not in _PHASE_NAME_SET:
+            raise ValueError(
+                f"record_phase: {name!r} is not in PHASE_NAMES "
+                f"({sorted(_PHASE_NAME_SET)})")
+        rec = {"name": name, "t0": t0, "dur_s": t1 - t0, "fp": fp,
+               "label": label, "cause": cause, **facts}
+        key = _STEP_TIME_KEYS.get(name)
+        e = self.entry(fp) if key and fp else None
         with self._lock:
-            e["times"].update({k: round(v, 6) for k, v in times.items()})
-            e["source"] = source
-            if label:
-                e["label"] = label
+            if e is not None:
+                e["times"][key] = round(rec["dur_s"], 6)
+                e["source"] = "compile"
+                if label:
+                    e["label"] = label
+            if len(self._phases) >= PHASE_LOG_CAP:
+                self.counters["phases_dropped"] += 1
+                return
+            self._phases.append(rec)
+        from ..observability import export
+        export.emit_event("phase", **rec)       # a no-op without a log
 
     # -- queries ---------------------------------------------------------
-    def snapshot(self) -> Dict[str, int]:
+    def snapshot(self) -> Dict[str, float]:
         with self._lock:
             return dict(self.counters)
+
+    def phases(self) -> List[dict]:
+        """The phase log, oldest first (copies)."""
+        with self._lock:
+            return [dict(r) for r in self._phases]
+
+    def phase_totals(self, before: Optional[float] = None
+                     ) -> Dict[str, dict]:
+        """``{name: {"seconds", "count"}}`` over the phase log, or over
+        its records that started before ``before`` (the end of a set-up on
+        ``time.perf_counter()``)."""
+        out: Dict[str, dict] = {}
+        with self._lock:
+            for r in self._phases:
+                if before is not None and r["t0"] >= before:
+                    continue
+                t = out.setdefault(r["name"], {"seconds": 0.0, "count": 0})
+                t["seconds"] += r["dur_s"]
+                t["count"] += 1
+        return out
 
     def total_compile_seconds(self) -> float:
         """Wall time spent in trace/lower/compile phases (with a warm
@@ -213,7 +316,9 @@ class CompileStats:
         lines = ["======= CompileStats ======="]
         with self._lock:
             for k in sorted(self.counters):
-                lines.append(f"  {k}: {self.counters[k]}")
+                v = self.counters[k]
+                lines.append(f"  {k}: {v:.3f}" if isinstance(v, float)
+                             else f"  {k}: {v}")
             for fp, e in self.entries.items():
                 t = " ".join(f"{k}={v * 1e3:.1f}ms"
                              for k, v in e["times"].items())
@@ -221,12 +326,31 @@ class CompileStats:
                     f"  [{fp[:12]}] traces={e['traces']} hits={e['hits']} "
                     f"source={e['source']} {t}"
                     + (f" ({e['label']})" if e.get("label") else ""))
+        totals = self.phase_totals()
+        if totals:
+            lines.append("  phases (seconds, count):")
+            for name, _help in PHASE_NAMES:
+                if name in totals:
+                    t = totals[name]
+                    lines.append(f"    {name}: {t['seconds']:.3f}s "
+                                 f"x{t['count']}")
+            for r in sorted(self.phases(), key=lambda r: -r["dur_s"])[:5]:
+                said = " ".join(
+                    f"{k}={v}" for k, v in r.items()
+                    if v is not None
+                    and k not in ("name", "t0", "dur_s", "fp"))
+                lines.append(
+                    f"    longest: {r['name']} {r['dur_s']:.3f}s "
+                    f"[{(r['fp'] or '-')[:12]}] {said}".rstrip())
         return "\n".join(lines)
 
     def reset(self):
         with self._lock:
             self.counters.clear()
             self.entries.clear()
+            # a process has one import: it outlives a reset
+            self._phases = [r for r in self._phases
+                            if r["name"] == "process/import"]
 
 
 _stats = CompileStats()
@@ -356,12 +480,48 @@ REPO_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: JAX's duration events, fired at the END of every trace, lowering and
+#: backend compile of the process -> the float counter each is summed into
+_JAX_DURATION_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_s",
+    "/jax/core/compile/backend_compile_duration": "jax_backend_compile_s",
+}
 _hit_listener_on = False
+#: per thread: ``in_step``, the depth of CachedStep._compile (its trace,
+#: lowering and compile are the step phases'), and ``spans``, the newest
+#: disjoint intervals already counted
+_jit_local = threading.local()
+_JIT_SPANS_KEPT = 1024
 
 
 def _on_jax_event(event: str, **_):
     if event == _CACHE_HIT_EVENT:
         _stats.bump("jax_cache_hits")
+
+
+def _on_jax_duration(event: str, secs: float, **_):
+    """Sum JAX's own seconds of work OUTSIDE a CachedStep's compile, each
+    second once.  A jit called inside a step's trace fires its own trace
+    event there, and an eager op inside any trace fires a whole compile:
+    an event ends when it is reported, so it ran over the ``secs`` before
+    now, the events it contains were reported before it, and it adds what
+    they left uncovered."""
+    counter = _JAX_DURATION_COUNTERS.get(event)
+    if counter is None or getattr(_jit_local, "in_step", 0):
+        return
+    end = time.perf_counter()
+    start = end - float(secs)
+    spans = _jit_local.__dict__.setdefault("spans", [])
+    inner = 0.0
+    while spans and spans[-1][0] >= start:
+        s, e = spans.pop()
+        inner += e - s
+    if spans and spans[-1][1] > start:
+        start = spans[-1][1]
+    spans.append((start, end))
+    del spans[:-_JIT_SPANS_KEPT]
+    _stats.bump(counter, end - start - inner)
 
 
 def cache_dir() -> str:
@@ -371,12 +531,16 @@ def cache_dir() -> str:
     directory is used as-is and this function makes NO
     ``jax_compilation_cache_dir`` update.  Unset: JAX's cache is pointed
     (once) at the fixed :data:`REPO_CACHE_DIR`.  The autotuner's winner
-    store lives under ``<dir>/tuning``.  Also hooks JAX's cache-hit
-    monitoring event into :class:`CompileStats` (``jax_cache_hits``)."""
+    store lives under ``<dir>/tuning``.  Also hooks JAX's monitoring events
+    into :class:`CompileStats`: the cache hits (``jax_cache_hits``) and its
+    own trace / lower / backend-compile seconds outside the steps' compiles
+    (``jax_trace_s``, ``jax_lower_s``, ``jax_backend_compile_s``)."""
     global _hit_listener_on
     if not _hit_listener_on:
         _hit_listener_on = True
         _jax_monitoring.register_event_listener(_on_jax_event)
+        _jax_monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -489,12 +653,17 @@ class CachedStep:
         self._mesh_step = in_shardings is not None
         self._fallback_recorded = False
         self._times: Dict[str, float] = {}
+        self._called = False         # the phase log's step/first_call
         _live_steps.add(self)
 
     # -- public ----------------------------------------------------------
     @property
     def fingerprint(self) -> Optional[str]:
         return self._fp
+
+    @property
+    def label(self) -> Optional[str]:
+        return self._label
 
     def hlo_text(self) -> Optional[str]:
         """The optimized module as XLA prints it (``compiled.as_text()``),
@@ -522,9 +691,14 @@ class CachedStep:
         return self
 
     def __call__(self, feeds, state, step):
+        # prepare() FIRST, as before the phase log: this frame stands above
+        # trace() and lower() while a step compiles (PERF.md section 6,
+        # PR 36 (d2))
         self.prepare(feeds, state, step)
+        first = not self._called    # a warm call: this flag, nothing else
+        t0 = time.perf_counter() if first else 0.0
         try:
-            return self._compiled(feeds, state, step)
+            out = self._compiled(feeds, state, step)
         except ValueError:
             # the argument check runs before donation; a deleted state
             # buffer means execution STARTED and the error is real
@@ -542,27 +716,54 @@ class CachedStep:
                     (self._fp or "?")[:12])
                 # the jit trace is an honest retrace of this fingerprint
                 _stats.record_trace(self._fp)
-            return self._jit(feeds, state, step)
+            out = self._jit(feeds, state, step)
+        if first:
+            self._called = True
+            _stats.record_phase("step/first_call", t0, time.perf_counter(),
+                                fp=self._fp, label=self._label)
+        return out
 
     # -- internals -------------------------------------------------------
     def _compile(self, feeds, state, step):
+        # Timestamps and ``with`` blocks IN PLACE, no helper around the
+        # three calls: JAX's lowering time moves by seconds with the Python
+        # stack above it, and a kernel's cache key holds that stack
+        # (PERF.md section 6, PR 30).  The annotations cost nothing without
+        # a profiler session; inside one they name the compile, and the
+        # step, that an idle gap of the device was spent in.
+        # JAX's own duration events of this thread are the step's until
+        # the three phases end (_on_jax_duration).
         cache_dir()                  # JAX's persistent cache, placed once
-        t0 = time.perf_counter()
-        traced = self._jit.trace(feeds, state, step)
-        t1 = time.perf_counter()
-        lowered = traced.lower()
-        t2 = time.perf_counter()
-        # the trace happened inside trace(): record it now (the retrace
-        # detector fires here if this fingerprint already traced)
-        _stats.record_trace(self._fp)
-        compiled = lowered.compile(
-            compiler_options=self._opts if self._opts else None)
-        t3 = time.perf_counter()
+        fp12 = (self._fp or "")[:12]
+        _jit_local.in_step = getattr(_jit_local, "in_step", 0) + 1
+        try:
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(f"pt:compile:trace:{fp12}"):
+                traced = self._jit.trace(feeds, state, step)
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(f"pt:compile:lower:{fp12}"):
+                lowered = traced.lower()
+            t2 = time.perf_counter()
+            # the trace happened inside trace(): record it now (the retrace
+            # detector fires here if this fingerprint already traced)
+            _stats.record_trace(self._fp)
+            hits = _stats.snapshot().get("jax_cache_hits", 0)
+            with jax.profiler.TraceAnnotation(f"pt:compile:xla:{fp12}"):
+                compiled = lowered.compile(
+                    compiler_options=self._opts if self._opts else None)
+            t3 = time.perf_counter()
+        finally:
+            _jit_local.in_step -= 1
         self._times = {"trace_s": t1 - t0, "lower_s": t2 - t1,
                        "compile_s": t3 - t2}
-        if self._fp:
-            _stats.record_times(self._fp, source="compile",
-                                label=self._label, **self._times)
+        # the three records are also entries[fp]["times"]; a cache read
+        # where JAX's hit counter moved during the compile
+        who = {"fp": self._fp, "label": self._label}
+        _stats.record_phase("step/trace", t0, t1, **who)
+        _stats.record_phase("step/lower", t1, t2, **who)
+        _stats.record_phase(
+            "step/xla", t2, t3, **who,
+            cache_hit=_stats.snapshot().get("jax_cache_hits", 0) > hits)
         return compiled
 
 

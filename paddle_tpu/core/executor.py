@@ -940,7 +940,14 @@ class Executor:
         threads, the validated program's fingerprint, and the cached-or-
         built step — one step, or the K-step scan for ``steps =
         (num_steps, feeds_stacked)``.  ``abstract``: feeds and state as
-        ShapeDtypeStructs."""
+        ShapeDtypeStructs.
+
+        A call that finds no cached step writes ``step/enter`` into the
+        phase log (``compile_cache.PHASE_NAMES``): everything from here to
+        the built step.  The fingerprint that says whether the call is cold
+        is known only after that work, so every call takes the one
+        timestamp; a warm one writes nothing."""
+        t_enter = time.perf_counter()
         program = program or default_main_program()
         scope = global_scope() if scope is None else scope
         fetch_names = [v.name if isinstance(v, Variable) else str(v)
@@ -966,6 +973,11 @@ class Executor:
                                               *steps),
                     steps[1], fingerprint=fp)
             self._cache.put(fp, fn, program)
+            compile_cache.stats().record_phase(
+                "step/enter", t_enter, time.perf_counter(), fp=fp,
+                label=getattr(fn, "label", None),
+                cause="compile" if abstract
+                else "run" if steps is None else "run_steps")
         return _StepEntry(program, scope, fetch_names, feeds, state_keys,
                           state, is_test, fp, fn)
 

@@ -90,11 +90,16 @@ define_flag("observe", False,
             "pipeline telemetry into the metrics registry, XProf trace "
             "annotations on dispatches, and JSONL export when metrics_log "
             "is set.  Zero overhead and zero retraces when off "
-            "(tier-1-enforced).  Per-executor override: "
+            "(tier-1-enforced; a metrics log still gets the `phase` events "
+            "of a cold start, see metrics_log).  Per-executor override: "
             "Executor(observe=...).  (PADDLE_TPU_OBSERVE=1)")
 define_flag("metrics_log", "",
             "JSONL structured metrics/event log path "
-            "(PADDLE_TPU_METRICS_LOG); empty = off.  Summarize with "
+            "(PADDLE_TPU_METRICS_LOG); empty = off.  Spans and step events "
+            "need observe on; what made a step COLD is written whatever "
+            "observe says (one `phase` event a record of "
+            "profiler.compile_stats().phases(): a handful a step variant, "
+            "none from a warm dispatch).  Summarize with "
             "`python -m paddle_tpu stats <log.jsonl>`")
 define_flag("autotune", False,
             "replay persisted autotuner winners (paddle_tpu.tuning) at the "

@@ -17,6 +17,7 @@ Parallelism taxonomy (mesh axes, see parallel.mesh):
 """
 from __future__ import annotations
 
+import time
 import weakref
 from typing import Dict, Optional, Sequence
 
@@ -219,6 +220,7 @@ class ShardedExecutor(Executor):
             return get_step(feed_arrays, state)(feed_arrays, state, step)
 
         wrapper.prog_cell = prog_cell
+        wrapper.label = label        # as CachedStep.label (the phase log)
         # AOT hook for Executor.compile: prepare (and return) the inner
         # CachedStep from abstract avals
         wrapper.prepare = lambda feeds, state, step: \
@@ -247,10 +249,17 @@ class ShardedExecutor(Executor):
         program ran — the analog of MultiGradientMachine's value dispatch."""
         from ..core.scope import global_scope
         scope = global_scope() if scope is None else scope
+        t0, nbytes = time.perf_counter(), 0
         for name in list(scope.keys()):
             v = self._find_var(program, name)
             if v is None or not v.persistable:
                 continue
             spec = self._state_spec(program, name)
+            value = scope.get(name)
+            nbytes += int(getattr(value, "nbytes", 0))
             scope.set(name, jax.device_put(
-                scope.get(name), NamedSharding(self.mesh, spec)))
+                value, NamedSharding(self.mesh, spec)))
+        # host time of the placement (device_put returns before the copies
+        # land); bytes: what was handed over, once, not times the replicas
+        compile_cache.stats().record_phase(
+            "state/place", t0, time.perf_counter(), bytes=nbytes)

@@ -8,6 +8,15 @@ traces (viewable in TensorBoard/XProf) + host-side step timers.
 This module is the human-facing surface of the observability layer
 (paddle_tpu.observability): :func:`report` renders the merged StatSet +
 CompileStats + Metrics view, :func:`metrics_snapshot` the structured one.
+
+The StatSet (:class:`Stat`, :func:`timer`, :func:`global_stat`) is for a
+user's own code, as ``REGISTER_TIMER`` was.  The program itself times
+nothing into it: what a cold start costs (the package's import, a step's
+enter / trace / lower / XLA-or-cache-read / first call, a mesh's state
+placement) goes into the phase log of :func:`compile_stats`
+(``compile_stats().phases()``, ``phase_totals()``; names in
+``core/compile_cache.py PHASE_NAMES``), and a dispatch's time into the
+metrics registry.
 """
 from __future__ import annotations
 
@@ -168,34 +177,14 @@ def metrics_snapshot() -> dict:
 
 
 def report() -> str:
-    """ONE merged human-readable view: host-side StatSet timers, compile
-    telemetry, and the observability metrics registry — the v1
+    """ONE merged human-readable view: host-side StatSet timers (when any
+    ran), compile telemetry with the phase log's totals, and the
+    observability metrics registry — the v1
     ``printAllStatus`` every ``log_period`` analog (the trainer emits this
     via observability.maybe_periodic_report)."""
     from . import observability
-    return "\n".join([_global_stat.report(), compile_report(),
-                      observability.report()])
-
-
-class StepTimer:
-    """Per-step wall-clock with warmup discard, for benchmarks."""
-
-    def __init__(self, warmup: int = 2):
-        self.warmup = warmup
-        self.times = []
-        self._t = None
-        self._step = 0
-
-    def start(self):
-        self._t = time.perf_counter()
-
-    def stop(self):
-        dt = time.perf_counter() - self._t
-        self._step += 1
-        if self._step > self.warmup:
-            self.times.append(dt)
-        return dt
-
-    @property
-    def mean(self):
-        return sum(self.times) / max(len(self.times), 1)
+    parts = [compile_report(), observability.report()]
+    timers = _global_stat.report()
+    if "\n" in timers:            # more than the header: a timer ran
+        parts.insert(0, timers)
+    return "\n".join(parts)

@@ -238,12 +238,17 @@ def test_faultinject_rpc_transient_is_retried(fleet2):
             "t", addrs=_addrs(fleet2), io_timeout_s=IO_TO,
             retry=RetryPolicy(max_attempts=4, backoff_base_s=0.01,
                               jitter=0.0), **kw) as rt:
+        # both shards dialled before the fault is armed: the site counts
+        # every frame, a connection's ``hello`` too, and a transient error
+        # in answer to a hello is a wiring mismatch, not a retry (which
+        # frame came third was the servers' threads' to decide)
+        allids = np.arange(32, dtype=np.int64)
+        assert rt.pull(allids).tobytes() == oracle.pull(allids).tobytes()
         faultinject.configure("pserver.rpc@3=transient")
         try:
             _train_rounds(rt, oracle, rounds=3, vocab=32, dim=4, seed=6)
         finally:
             faultinject.clear()
-        allids = np.arange(32, dtype=np.int64)
         assert rt.pull(allids).tobytes() == oracle.pull(allids).tobytes()
 
 

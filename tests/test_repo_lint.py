@@ -26,6 +26,8 @@ import ast
 import collections
 import os
 
+import pytest
+
 ROOT = os.path.join(os.path.dirname(__file__), os.pardir, "paddle_tpu")
 
 # ---------------------------------------------------------------------------
@@ -437,43 +439,59 @@ def test_tunable_registry_matches_ast_scan():
 # ---------------------------------------------------------------------------
 # Span-name gate (paddle_tpu.observability.tracing.SPAN_NAMES) — the
 # tracing mirror of the metric gate: every span name passed to span()/
-# start_span() must be a string literal frozen in SPAN_NAMES.
+# start_span() must be a string literal frozen in SPAN_NAMES.  The SAME
+# gate holds the phase log's names: every record_phase() call passes a
+# literal frozen in core.compile_cache.PHASE_NAMES.
 # ---------------------------------------------------------------------------
-_SPAN_HELPERS = ("span", "start_span")
-# the tracing module itself passes names through variables by
-# construction (its SPAN_NAMES table is what the gate checks against)
-_SPAN_DEFINING_FILE = "paddle_tpu/observability/tracing.py"
+# (table, file that holds its literal, helpers whose first argument is a
+# name of it, file left out of the scan).  The tracing module itself
+# passes names through variables by construction (its SPAN_NAMES table is
+# what the gate checks against); compile_cache.py's own record_phase calls
+# pass literals like everyone's, so nothing is left out there.
+_NAME_TABLES = {
+    "SPAN_NAMES": ("observability/tracing.py", ("span", "start_span"),
+                   "paddle_tpu/observability/tracing.py"),
+    "PHASE_NAMES": ("core/compile_cache.py", ("record_phase",), None),
+}
+_name_tables = pytest.mark.parametrize("table", sorted(_NAME_TABLES))
 
 
-def _span_names_table():
-    """Names parsed from the SPAN_NAMES literal — no import, so the gate
+def _names_table(table):
+    """Names parsed from the table's literal — no import, so the gate
     also covers a syntactically valid but unimportable state."""
-    path = os.path.join(ROOT, "observability", "tracing.py")
+    path = os.path.join(ROOT, *_NAME_TABLES[table][0].split("/"))
     with open(path) as fh:
         tree = ast.parse(fh.read(), filename=path)
     for node in ast.walk(tree):
         if isinstance(node, ast.Assign) and any(
-                isinstance(t, ast.Name) and t.id == "SPAN_NAMES"
+                isinstance(t, ast.Name) and t.id == table
                 for t in node.targets):
             rows = ast.literal_eval(node.value)
             return [name for name, _help in rows]
-    raise AssertionError("SPAN_NAMES literal not found in tracing.py")
+    raise AssertionError(f"{table} literal not found in {path}")
 
 
-def test_span_names_table_well_formed():
-    names = _span_names_table()
+def _span_names_table():
+    return _names_table("SPAN_NAMES")
+
+
+@_name_tables
+def test_span_names_table_well_formed(table):
+    names = _names_table(table)
     dupes = {n for n in names if names.count(n) > 1}
-    assert not dupes, f"duplicate SPAN_NAMES entries: {sorted(dupes)}"
-    assert names, "SPAN_NAMES is empty — the gate has nothing to check"
+    assert not dupes, f"duplicate {table} entries: {sorted(dupes)}"
+    assert names, f"{table} is empty — the gate has nothing to check"
     for name in names:
-        assert "/" in name, f"span {name!r} is not namespaced (sub/name)"
+        assert "/" in name, f"{table}: {name!r} is not namespaced (sub/name)"
 
 
-def test_span_helper_names_are_registered_literals():
-    registered = set(_span_names_table())
+@_name_tables
+def test_span_helper_names_are_registered_literals(table):
+    _file, helpers, defining_file = _NAME_TABLES[table]
+    registered = set(_names_table(table))
     problems, used = [], set()
     for rel, tree in _iter_lint_sources():
-        if rel == _SPAN_DEFINING_FILE:
+        if rel == defining_file:
             continue
         for node in ast.walk(tree):
             if not isinstance(node, ast.Call):
@@ -481,40 +499,42 @@ def test_span_helper_names_are_registered_literals():
             fn = node.func
             target = fn.id if isinstance(fn, ast.Name) else (
                 fn.attr if isinstance(fn, ast.Attribute) else None)
-            if target not in _SPAN_HELPERS:
+            if target not in helpers:
                 continue
             if not node.args:
                 problems.append(f"{rel}:{node.lineno}: {target} without a "
-                                f"positional span name")
+                                f"positional name")
                 continue
             arg = node.args[0]
             if not (isinstance(arg, ast.Constant)
                     and isinstance(arg.value, str)):
                 problems.append(
-                    f"{rel}:{node.lineno}: {target} span name must be a "
+                    f"{rel}:{node.lineno}: {target} name must be a "
                     f"string literal (free-form names defeat the typo "
                     f"gate)")
                 continue
             used.add(arg.value)
             if arg.value not in registered:
                 problems.append(
-                    f"{rel}:{node.lineno}: span {arg.value!r} is not in "
-                    f"observability.tracing.SPAN_NAMES — register it "
-                    f"there (typo?)")
+                    f"{rel}:{node.lineno}: {arg.value!r} is not in "
+                    f"{table} — register it there (typo?)")
     assert not problems, "\n".join(problems)
-    assert used, "AST scan found no span-helper calls — lint is broken"
+    assert used, f"AST scan found no {helpers} calls — lint is broken"
     # the full causal chain is instrumented: every frozen name is LIVE
     # at some call site (a dead table row is a removed instrumentation
     # point, which deserves a conscious table edit)
     assert used == registered, (
-        f"SPAN_NAMES and call sites disagree: "
+        f"{table} and call sites disagree: "
         f"unused={sorted(registered - used)} "
         f"unregistered={sorted(used - registered)}")
 
 
-def test_span_gate_matches_live_registry():
-    from paddle_tpu.observability.tracing import SPAN_NAMES
-    assert [n for n, _ in SPAN_NAMES] == _span_names_table()
+@_name_tables
+def test_span_gate_matches_live_registry(table):
+    import importlib
+    module = importlib.import_module(
+        "paddle_tpu." + _NAME_TABLES[table][0][:-3].replace("/", "."))
+    assert [n for n, _ in getattr(module, table)] == _names_table(table)
 
 
 def test_attribution_module_only_imported_lazily():

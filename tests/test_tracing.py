@@ -141,24 +141,40 @@ def _batches(n, batch=16):
 
 def test_tracing_off_zero_events_zero_writes_zero_retrace(tmp_path):
     """observe off + metrics_log SET: the training path emits NO JSONL
-    events (spans included), touches NO metrics, and cannot retrace."""
+    events (spans included), writes NOT A BYTE to the log, touches NO
+    metrics, and cannot retrace.  The one thing a metrics log gets whatever
+    ``observe`` says is what made a step COLD (one ``phase`` event a record
+    of the phase log, core/compile_cache.py; ``metrics_log``'s help says
+    so): exactly five for each of the three steps here, all of them
+    written before the first warm dispatch."""
     log = tmp_path / "off.jsonl"
     flags.set_flag("observe", False)
     flags.set_flag("metrics_log", str(log))
     loss = _build_net()
+    prog = pt.default_main_program()
     exe = pt.Executor()
     exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
-    feeds = _batches(9)
+    feeds = _batches(17)
     before = obs.registry().snapshot()
     exe.run(feed=feeds[0], fetch_list=[loss])       # pays the one trace
     with retrace_guard():
-        outs = list(exe.run_pipelined(
-            iter(feeds[1:]), pt.default_main_program(),
-            fetch_list=[loss], steps_per_dispatch=4))
-    assert len(outs) == 8
-    after = obs.registry().snapshot()
-    assert after == before
-    assert not log.exists() or log.read_text() == ""
+        cold = list(exe.run_pipelined(      # the K-step scan's one trace
+            iter(feeds[1:9]), prog, fetch_list=[loss],
+            steps_per_dispatch=4))
+        written = log.read_bytes()
+        # every step is warm from here on: zero events, zero writes
+        for feed in feeds[1:9]:
+            exe.run(feed=feed, fetch_list=[loss])
+        warm = list(exe.run_pipelined(
+            iter(feeds[9:]), prog, fetch_list=[loss],
+            steps_per_dispatch=4))
+    assert len(cold) == len(warm) == 8
+    assert obs.registry().snapshot() == before
+    assert log.read_bytes() == written
+    events = [json.loads(ln) for ln in written.decode().splitlines()]
+    assert {e["kind"] for e in events} <= {"identity", "phase"}
+    # startup, run and the K-step scan went cold once each
+    assert len([e for e in events if e["kind"] == "phase"]) == 15
 
 
 # ---------------------------------------------------------------------------
