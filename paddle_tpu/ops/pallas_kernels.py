@@ -8,10 +8,12 @@ available to models directly.
 
 Both directions are fused kernels.  The forward computes exact attention and
 saves only the per-row logsumexp; the backward (FlashAttention-2 style)
-recomputes block-local probabilities from (q, k, lse) inside two Pallas
-kernels — one accumulating dq over key blocks, one accumulating dk/dv over
-query blocks — so the [T, T] probability matrix is never materialized in
-either direction and O(T) memory holds for *training*, not just inference.
+recomputes block-local probabilities from (q, k, lse) inside Pallas kernels
+— ONE that visits each score tile once and makes dq, dk and dv from it where
+dk / dv of a whole sequence fit VMEM, else two (one accumulating dq over key
+blocks, one accumulating dk/dv over query blocks), a rule on the static
+shapes — so the [T, T] probability matrix is never materialized in either
+direction and O(T) memory holds for *training*, not just inference.
 On non-TPU backends the jnp reference runs instead (CPU tests exercise the
 kernels in interpret mode).
 
@@ -161,7 +163,32 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 # backward kernels (FlashAttention-2: recompute p from (q, k, lse) per block)
 # ---------------------------------------------------------------------------
-FLASH_BWD_VMEM_BYTES = 32 << 20    # scoped VMEM a grouped backward may take
+# Two routes, chosen by ``_one_pass_fits`` from the static shapes and the
+# blocks alone and counted at trace time as
+# ``route/flash_attention_bwd:{one_pass,two_pass}``:
+#
+# * ONE kernel (``_flash_bwd_one_pass_kernel``) that visits each (query block,
+#   key block) tile once and makes dq, dk and dv from one recomputation of
+#   the scores: five products, one exponential and one mask a tile.  dk and
+#   dv of a K/V head's whole sequence stay in VMEM, so it runs where they fit
+#   ``FLASH_BWD_VMEM_BYTES`` beside a tile's temporaries (at 1024 x 1024
+#   blocks: up to 5 120 keys of 128 features, 13 312 of 64; at 512 x 512,
+#   12 800 of 128).
+# * TWO kernels (``_flash_bwd_dq_kernel``, ``_flash_bwd_dkv_kernel``), each
+#   recomputing the scores (seven products, two exponentials a tile) with
+#   O(block) VMEM: every longer sequence, and blocks a row of lse cannot be
+#   cut into.
+#
+# Every call asks for ``FLASH_BWD_VMEM_BYTES`` of scoped VMEM: four [1024,
+# 1024] float32 temporaries alone are the default 16 MiB, and the one kernel
+# at 8192 keys of 64 features takes 19.6 MB inside a step.  No more than
+# that: asked for 64 MiB the same kernel ran 6 % slower, alone and in a step.
+# (on the v5e, the kernels alone, ms forward / two kernels / one, causal,
+# 1024 x 1024 blocks: 32 query over 8 K/V heads of 64 at T 8192 4.9 / 15.8 /
+# 9.0; 2 x 16 heads of 128 at T 4096 1.79 / 4.63 / 2.84; 16 heads 0.71 /
+# 2.25 / 1.34.  With the tile not transposed the one kernel read 10.0, 3.13,
+# 1.50; with queries innermost and dq resident 11.8, 3.27, 1.64.)
+FLASH_BWD_VMEM_BYTES = 32 << 20    # scoped VMEM a backward kernel may take
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -264,8 +291,151 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
-               interpret, g_lse=None):
+def _flash_bwd_one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                               delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
+                               block_q, block_k, num_q_blocks, num_k_blocks,
+                               causal, sm_scale):
+    """Grid (K/V batch-heads, group * q_blocks, k_blocks), k innermost: one
+    visit a (query block, key block) tile, all three gradients from one
+    recomputation of the scores.  dq for one query block accumulates in a
+    VMEM scratch over the streamed K/V blocks; dk and dv of the WHOLE
+    sequence of one K/V head are the resident float32 output blocks, summed
+    over the query blocks of every query head of the group in turn, and
+    leave VMEM once a K/V head.
+
+    The tile is held TRANSPOSED, [block_k, block_q] (lse and delta come as
+    rows): dv += p^T do and dk += ds^T q are then plain products and dq +=
+    ds k the one transposed contraction, where the [block_q, block_k]
+    orientation of the two kernels above has two."""
+    step = pl.program_id(1)
+    kb = pl.program_id(2)
+    j = step % num_q_blocks
+
+    @pl.when((step == 0) & (kb == 0))
+    def _init_kv():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(kb == 0)
+    def _init_q():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def _compute():
+        q32 = q_ref[0].astype(jnp.float32) * sm_scale      # [bq, D]
+        kblk = k_ref[0].astype(jnp.float32)                # [bk, D]
+        vblk = v_ref[0].astype(jnp.float32)                # [bk, Dv]
+        do = do_ref[0].astype(jnp.float32)                 # [bq, Dv]
+        lse = lse_ref[0]                                   # [1, bq]
+        delta = delta_ref[0]                               # [1, bq]
+        st = jax.lax.dot_general(
+            kblk, q32, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bk, bq]
+        if causal:
+            kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, st.shape, 0)
+            qpos = j * block_q + lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            st = jnp.where(kpos <= qpos, st, NEG_INF)
+        pt = jnp.exp(st - lse)                             # normalized probs
+        rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        dv_ref[0, rows, :] += jax.lax.dot_general(
+            pt, do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bk, Dv]
+        dpt = jax.lax.dot_general(
+            vblk, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bk, bq]
+        dst = pt * (dpt - delta)
+        # q32 carries sm_scale, so dk is fully scaled
+        dk_ref[0, rows, :] += jax.lax.dot_general(
+            dst, q32, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bk, D]
+        dq_acc[...] += jax.lax.dot_general(
+            dst, kblk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bq, D]
+
+    if causal:
+        pl.when(kb * block_k <= (j + 1) * block_q - 1)(_compute)
+    else:
+        _compute()
+
+    @pl.when(kb == num_k_blocks - 1)
+    def _write():
+        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _one_pass_fits(Tq, Tk, D, Dv, block_q, block_k):
+    """Whether the one-pass backward takes this call, from the static shapes
+    and the blocks alone: what it holds in VMEM (dk and dv of one K/V head's
+    whole sequence in both pipeline buffers; four [block_q, block_k] float32
+    temporaries; the streamed blocks q, do, dq and k, v, double-buffered)
+    within ``FLASH_BWD_VMEM_BYTES``, and a row of lse that Mosaic can cut
+    into blocks."""
+    resident = 2 * Tk * (D + Dv) * 4
+    tile = 4 * block_q * block_k * 4
+    streamed = 2 * (block_q * (2 * D + Dv) + block_k * (D + Dv)) * 4
+    return (resident + tile + streamed <= FLASH_BWD_VMEM_BYTES
+            and (block_q % 128 == 0 or block_q == Tq))
+
+
+def _flash_bwd_params(interpret, *semantics):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=FLASH_BWD_VMEM_BYTES)}
+
+
+def _flash_bwd_one_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
+                        block_k, interpret):
+    BH, Tq, D = q.shape
+    Tk = k.shape[1]
+    Dv = v.shape[2]
+    nq = Tq // block_q
+    nk = Tk // block_k
+    group = BH // k.shape[0]
+
+    def of_q(i, step, kb):           # the group's query heads in turn
+        return (i * group + step // nq, step % nq, 0)
+
+    def of_row(i, step, kb):
+        return (i * group + step // nq, 0, step % nq)
+
+    def of_k(i, step, kb):
+        return (i, kb, 0)
+
+    def whole(i, step, kb):
+        return (i, 0, 0)
+
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_one_pass_kernel, block_q=block_q,
+                          block_k=block_k, num_q_blocks=nq, num_k_blocks=nk,
+                          causal=causal, sm_scale=sm_scale),
+        out_shape=[
+            _sds(q, (BH, Tq, D), q.dtype),
+            _sds(k, k.shape[:2] + (D,), jnp.float32),
+            _sds(v, v.shape, jnp.float32),
+        ],
+        grid=(k.shape[0], group * nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), of_q),
+            pl.BlockSpec((1, block_k, D), of_k),
+            pl.BlockSpec((1, block_k, Dv), of_k),
+            pl.BlockSpec((1, block_q, Dv), of_q),
+            pl.BlockSpec((1, 1, block_q), of_row),
+            pl.BlockSpec((1, 1, block_q), of_row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, D), of_q),
+            pl.BlockSpec((1, Tk, D), whole),
+            pl.BlockSpec((1, Tk, Dv), whole),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        interpret=interpret,
+        **_flash_bwd_params(interpret, "parallel", "arbitrary", "arbitrary"),
+    )(q, k, v, g, lse.reshape(BH, 1, Tq), delta.reshape(BH, 1, Tq))
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _flash_bwd_two_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
+                        block_k, interpret):
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     Dv = v.shape[2]
@@ -273,26 +443,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     nk = Tk // block_k
     group = BH // k.shape[0]
     kv = _kv_of(group)
-    # delta_i = sum_d dO_i · O_i  (rescaling term of dsoftmax); O(T·Dv) work,
-    # fused by XLA — not worth a kernel.  A cotangent on lse folds in here:
-    # dL/ds_ij = p_ij (dp_ij - delta_i + g_lse_i), so delta_eff = delta -
-    # g_lse and the kernels run unchanged.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # [BH, Tq, 1]
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32).reshape(delta.shape)
-    kwargs = {}
-    if not interpret:
-        # grouped calls ask for the scoped VMEM they need: the kernels'
-        # four [block_q, block_k] float32 temporaries are the whole default
-        # 16 MiB at 1024 x 1024 (dQ's asked for 16.46 MB at head size 64).
-        # Equal head counts keep the parent's parameters, and its edge,
-        # until Ouro and OLMoE are measured with the limit (ROADMAP A13)
-        limit = {"vmem_limit_bytes": FLASH_BWD_VMEM_BYTES} if group > 1 \
-            else {}
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            **limit)
+    kwargs = _flash_bwd_params(interpret, "parallel", "parallel", "arbitrary")
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
@@ -350,6 +501,27 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         **kwargs,
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
+
+
+def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
+               interpret, g_lse=None):
+    """dq, dk, dv by the route ``_one_pass_fits`` gives the static shapes."""
+    # delta_i = sum_d dO_i · O_i  (rescaling term of dsoftmax); O(T·Dv) work,
+    # fused by XLA — not worth a kernel.  A cotangent on lse folds in here:
+    # dL/ds_ij = p_ij (dp_ij - delta_i + g_lse_i), so delta_eff = delta -
+    # g_lse and the kernels run unchanged.
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)                # [BH, Tq, 1]
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32).reshape(delta.shape)
+    one_pass = _one_pass_fits(q.shape[1], k.shape[1], q.shape[2], v.shape[2],
+                              block_q, block_k)
+    compile_cache.stats().bump(
+        "route/flash_attention_bwd:" + ("one_pass" if one_pass
+                                        else "two_pass"))
+    bwd = _flash_bwd_one_pass if one_pass else _flash_bwd_two_pass
+    return bwd(q, k, v, g, lse, delta, causal, sm_scale, block_q, block_k,
+               interpret)
 
 
 def _reference_attention(q, k, v, causal, sm_scale):
@@ -436,6 +608,14 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     head h reads K / V head h // (H / H_kv), through the kernels' index
     maps, and dK / dV sum over a group's query heads inside the backward
     kernel; counted as ``route/flash_attention:grouped``.
+
+    The backward is one kernel that visits each (query block, key block)
+    tile once where dK and dV of a K/V head's whole sequence fit VMEM
+    beside a tile's temporaries (``_one_pass_fits``: from Tq, Tk, the
+    feature sizes and the blocks alone; at 1024 x 1024 blocks up to 5 120
+    keys of 128 features or 13 312 of 64), and the two kernels that each
+    recompute the scores for longer sequences; counted at trace time as
+    ``route/flash_attention_bwd:{one_pass,two_pass}``.
 
     use_pallas=None auto-selects the Pallas kernel on TPU only; every other
     backend gets the exact jnp reference.  interpret=True (explicit, as the

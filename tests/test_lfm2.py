@@ -312,15 +312,22 @@ def test_short_conv_route_by_shape_dtype_and_backend(monkeypatch):
 # ---------------------------------------------------------------------------
 # grouped-query heads
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("heads,kv_heads,causal", [
-    (8, 2, True), (4, 1, True), (6, 3, False), (4, 4, True)])
-def test_grouped_query_flash_attention_equals_repeated_k_and_v(heads,
-                                                              kv_heads,
-                                                              causal):
+@pytest.mark.parametrize("heads,kv_heads,causal,t_len,d,blocks,bwd", [
+    (8, 2, True, 64, 16, (16, 32), "two_pass"),
+    (4, 1, True, 64, 16, (16, 32), "two_pass"),
+    (6, 3, False, 64, 16, (16, 32), "two_pass"),
+    (4, 4, True, 64, 16, (16, 32), "two_pass"),
+    # the cell's heads in small (4 query heads to a K/V head of 64), at
+    # blocks the one-pass backward takes
+    (8, 2, True, 256, 64, (128, 128), "one_pass"),
+    (4, 1, False, 256, 64, (128, 128), "one_pass")])
+def test_grouped_query_flash_attention_equals_repeated_k_and_v(
+        heads, kv_heads, causal, t_len, d, blocks, bwd):
     """The kernels (interpreted), K and V read through the index maps,
     against ``_reference_attention`` on K and V repeated to Q's heads:
-    output, dQ, and dK / dV summed over each group."""
-    b, t_len, d = 2, 64, 16
+    output, dQ, and dK / dV summed over each group, by either route of the
+    backward."""
+    b = 2
     rng = np.random.RandomState(heads)
     q = jnp.asarray(rng.randn(b, t_len, heads, d), jnp.float32)
     k, v = (jnp.asarray(rng.randn(b, t_len, kv_heads, d), jnp.float32)
@@ -330,7 +337,8 @@ def test_grouped_query_flash_attention_equals_repeated_k_and_v(heads,
 
     def kernels(q, k, v):
         return pallas_kernels.flash_attention(
-            q, k, v, causal=causal, block_q=16, block_k=32, interpret=True)
+            q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
 
     def repeated(q, k, v):
         def rows(x):
@@ -346,7 +354,13 @@ def test_grouped_query_flash_attention_equals_repeated_k_and_v(heads,
         == (group > 1)
     want, ref_vjp = jax.vjp(repeated, q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    for a, r in zip(vjp(ct), ref_vjp(ct)):
+    before = _routes()
+    grads = vjp(ct)
+    seen = {r: n - before.get(r, 0) for r, n in _routes().items()
+            if r.startswith("route/flash_attention_bwd:")}
+    assert {r: n for r, n in seen.items() if n} \
+        == {"route/flash_attention_bwd:" + bwd: 1}
+    for a, r in zip(grads, ref_vjp(ct)):
         np.testing.assert_allclose(a, r, rtol=1e-4, atol=1e-4)
     # the reference route takes grouped heads too (every other backend)
     np.testing.assert_allclose(

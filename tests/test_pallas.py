@@ -5,8 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu import profiler
+from paddle_tpu.ops import pallas_kernels
 from paddle_tpu.ops.pallas_kernels import (_reference_attention,
-                                           flash_attention)
+                                           flash_attention,
+                                           flash_attention_with_lse)
 
 R = np.random.RandomState(4)
 
@@ -152,3 +155,110 @@ def test_flash_gradient_cross_attention():
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the backward's two routes (one kernel a tile visit; the two kernels)
+# ---------------------------------------------------------------------------
+def _bwd_routes():
+    return {k.rsplit(":", 1)[1]: v
+            for k, v in profiler.compile_stats().snapshot().items()
+            if k.startswith("route/flash_attention_bwd:")}
+
+
+def _attention_and_lse(q, k, v, causal, scale):
+    """``_reference_attention`` with the rows' logsumexp beside it."""
+    group = q.shape[0] // k.shape[0]
+    k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
+    s = jnp.einsum("bqd,bkd->bqk", q * scale, k)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool))[None], s,
+                      pallas_kernels.NEG_INF)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    return jnp.einsum("bqk,bkd->bqd", jnp.exp(s - lse), v), lse
+
+
+# heads, K/V heads, Tq, Tk, D, Dv, causal, (block_q, block_k), a cotangent
+# on lse, the route the rule gives the shape
+BWD_CASES = {
+    "causal": (2, 2, 256, 256, 16, 16, True, (128, 128), False, "one_pass"),
+    "full": (2, 2, 256, 256, 16, 16, False, (128, 128), False, "one_pass"),
+    # the cell's shape in small: 4 query heads to a K/V head of 64
+    "grouped_causal": (8, 2, 384, 384, 64, 64, True, (128, 128), False,
+                       "one_pass"),
+    "grouped_full": (4, 1, 256, 256, 64, 64, False, (128, 128), False,
+                     "one_pass"),
+    "blocks_differ": (2, 1, 512, 512, 16, 16, True, (128, 256), False,
+                      "one_pass"),
+    "cross": (2, 2, 128, 384, 16, 32, False, (128, 128), False, "one_pass"),
+    "one_block": (3, 3, 64, 64, 16, 16, True, (64, 64), False, "one_pass"),
+    "lse_causal": (2, 2, 256, 256, 16, 16, True, (128, 128), True,
+                   "one_pass"),
+    "lse_grouped_full": (4, 2, 128, 256, 16, 32, False, (128, 128), True,
+                         "one_pass"),
+    # a row of lse cannot be cut into blocks of 32: the two kernels
+    "small_blocks": (4, 2, 128, 128, 16, 16, True, (32, 32), False,
+                     "two_pass"),
+    "small_blocks_lse": (2, 2, 64, 128, 16, 32, False, (32, 64), True,
+                         "two_pass"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BWD_CASES))
+def test_flash_backward_routes_match_reference(case):
+    """dQ, dK and dV of both routes (interpreted) against autodiff of the
+    plain formula: several blocks in both directions, so accumulation
+    across blocks, over a group's query heads and the causal skip are all
+    exercised; the route is the one the rule gives the static shape."""
+    heads, kv_heads, Tq, Tk, D, Dv, causal, blocks, with_lse, route = \
+        BWD_CASES[case]
+    rng = np.random.RandomState(len(case))
+    q = jnp.asarray(rng.randn(heads, Tq, D), jnp.float32)
+    k = jnp.asarray(rng.randn(kv_heads, Tk, D), jnp.float32)
+    v = jnp.asarray(rng.randn(kv_heads, Tk, Dv), jnp.float32)
+    w = jnp.asarray(rng.randn(heads, Tq, Dv), jnp.float32)
+    u = jnp.asarray(rng.randn(heads, Tq, 1), jnp.float32)
+    scale = D ** -0.5
+
+    def kernels(q, k, v):
+        if not with_lse:
+            return jnp.sum(w * flash_attention(
+                q, k, v, causal=causal, block_q=blocks[0],
+                block_k=blocks[1], use_pallas=True, interpret=True))
+        out, lse = flash_attention_with_lse(
+            q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
+        return jnp.sum(w * out) + jnp.sum(u * lse)
+
+    def formula(q, k, v):
+        out, lse = _attention_and_lse(q, k, v, causal, scale)
+        return jnp.sum(w * out) + (jnp.sum(u * lse) if with_lse else 0.0)
+
+    before = _bwd_routes()
+    got = jax.grad(kernels, argnums=(0, 1, 2))(q, k, v)
+    seen = {r: n - before.get(r, 0) for r, n in _bwd_routes().items()}
+    assert seen.get(route) == 1 and sum(seen.values()) == 1
+    want = jax.grad(formula, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("Tq,Tk,D,Dv,blocks,fits", [
+    (8192, 8192, 64, 64, (1024, 1024), True),        # lfm2-train-scan
+    (4096, 4096, 128, 128, (1024, 1024), True),      # olmoe-, ouro-
+    (5120, 5120, 128, 128, (1024, 1024), True),
+    (8192, 8192, 128, 128, (1024, 1024), False),
+    (8192, 8192, 128, 128, (512, 512), True),
+    (32768, 32768, 128, 128, (1024, 1024), False),   # benchmark/longctx.py
+    (65536, 65536, 128, 128, (1024, 1024), False),
+    (65536, 65536, 64, 64, (512, 512), False),
+    (4096, 4096, 128, 128, (2048, 1024), False),     # a tile's temporaries
+    (1024, 16384, 128, 128, (512, 512), False),      # Tk alone is what counts
+    (256, 256, 16, 16, (32, 32), False),             # no row blocks of 32
+    (32, 256, 16, 16, (32, 32), True),               # one query block
+])
+def test_flash_backward_route_is_a_rule_on_static_shape(Tq, Tk, D, Dv,
+                                                       blocks, fits):
+    assert pallas_kernels._one_pass_fits(Tq, Tk, D, Dv, *blocks) is fits

@@ -160,7 +160,7 @@ def test_rope_between_projection_and_attention_leaves_no_copy(spec):
     ``flash_attention`` behind; one sequence), gradient and all: the
     transposes to and from the kernel's head-major view are XLA's to
     assign, the projection writes that layout and the attention kernels
-    read the result as it is, so the module holds the seven kernels and no
+    read the result as it is, so the module holds the six kernels and no
     copy or transpose of an array of q's size in float32.  Each ``rope`` kernel
     carries its op's scope and direction in its ``op_name`` (the calls into
     the jitted ``_rope_call`` are inlined, the names joined): what the
@@ -183,7 +183,7 @@ def test_rope_between_projection_and_attention_leaves_no_copy(spec):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
             spec((batch, T_LEN, width)), *[spec((width, width))] * 3
         ).compile().as_text()
-    assert _kernels(text) == 2 * 2 + 3
+    assert _kernels(text) == 2 * 2 + 2
     assert _half_width_arrays(text) == 0
     q_sized = rf"= f32\[{batch},(?:{T_LEN},{HEADS}|{HEADS},{T_LEN}),{HEAD_DIM}\]"
     assert re.findall(q_sized + r"\S* (?:copy|transpose)\(", text) == []
@@ -215,33 +215,69 @@ def test_short_conv_is_one_kernel_a_direction(spec, batch):
         < 1.5 * batch * LFM2_T * LFM2_D * 4
 
 
-@pytest.mark.parametrize("batch", [1, 2])
-def test_grouped_query_attention_compiles_at_lfm2_heads(spec, batch):
-    """32 query heads reading 8 K / V heads of 64 through the index maps,
-    from the [B, T, H, D] arrays a model hands the op, at the blocks the
-    cell runs (the layer's default 1024 x 1024): three kernels, dK and dV
-    at the 8 heads they have, and no K or V repeated to 32 heads anywhere
-    in the module.  (The backward kernels' four [1024, 1024] float32
-    temporaries are the default 16 MiB of scoped VMEM; a grouped call asks
-    for ``FLASH_BWD_VMEM_BYTES``.)"""
-    heads, kv_heads, d = 32, 8, 64
-
+def _attention_grads(spec, batch, seq, heads, kv_heads, d, with_value=False):
+    """The compiled module text of ``flash_attention``'s gradients (and the
+    value) from the [B, T, H, D] arrays a model hands the op, at the blocks
+    the cells run (the layer's default 1024 x 1024)."""
     def loss(q, k, v, mix):
         return jnp.sum(pallas_kernels.flash_attention(
             q, k, v, causal=True, block_q=1024, block_k=1024,
             use_pallas=True) * mix)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        spec((batch, LFM2_T, heads, d)), spec((batch, LFM2_T, kv_heads, d)),
-        spec((batch, LFM2_T, kv_heads, d)),
-        spec((batch, LFM2_T, heads, d))).compile().as_text()
-    assert _kernels(text) == 3
-    kv_results = re.findall(
-        rf"\(f32\[{batch * kv_heads},{LFM2_T},{d}\]\S*, "
+    grad = jax.value_and_grad if with_value else jax.grad
+    # (products as a step on the chip has them: under conftest's 'highest'
+    # the one-pass backward's multi-pass products ask for 32.70 MB at two of
+    # LFM2's sequences, 0.7 over what the kernel may take)
+    with jax.default_matmul_precision("default"):
+        return jax.jit(grad(loss, argnums=(0, 1, 2))).lower(
+            spec((batch, seq, heads, d)), spec((batch, seq, kv_heads, d)),
+            spec((batch, seq, kv_heads, d)),
+            spec((batch, seq, heads, d))).compile().as_text()
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_grouped_query_attention_compiles_at_lfm2_heads(spec, batch):
+    """32 query heads reading 8 K / V heads of 64 through the index maps:
+    TWO kernels, the forward and the one-pass backward (three before PR 37,
+    when dQ and dK / dV each recomputed the scores), dK and dV at the 8
+    heads they have, and no K or V repeated to 32 heads anywhere in the
+    module.  (Four [1024, 1024] float32 temporaries are the default 16 MiB
+    of scoped VMEM; every backward call asks for
+    ``FLASH_BWD_VMEM_BYTES``.)"""
+    heads, kv_heads, d = 32, 8, 64
+    before = dict(compile_cache.stats().snapshot())
+    text = _attention_grads(spec, batch, LFM2_T, heads, kv_heads, d)
+    assert compile_cache.stats().snapshot().get(
+        "route/flash_attention_bwd:one_pass", 0) - before.get(
+        "route/flash_attention_bwd:one_pass", 0) == 1
+    assert _kernels(text) == 2
+    results = re.findall(
+        rf"\(f32\[{batch * heads},{LFM2_T},{d}\]\S*, "
+        rf"f32\[{batch * kv_heads},{LFM2_T},{d}\]\S*, "
         rf"f32\[{batch * kv_heads},{LFM2_T},{d}\]\S*\) custom-call\(", text)
-    assert len(kv_results) == 1
+    assert len(results) == 1
     assert not re.search(rf"f32\[{batch},{LFM2_T},{heads},{d}\]\S* "
                          r"broadcast\(", text)
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_attention_compiles_at_ouro_and_olmoe_heads(spec, batch):
+    """16 heads of 128 over 4096 positions, value and gradient, at 16
+    batch-heads (Ouro's step) and at 32 (OLMoE's; in Ouro's program the
+    parent's dQ kernel overran the default 16 MiB by 52 KB there): two
+    kernels.  Only that it compiles: no cell's batch changes with it."""
+    assert _kernels(_attention_grads(spec, batch, 4096, 16, 16, 128,
+                                     with_value=True)) == 2
+
+
+@pytest.mark.parametrize("seq,kernels", [(5120, 2), (8192, 3), (32768, 3)])
+def test_long_sequences_keep_the_two_backward_kernels(spec, seq, kernels):
+    """Where dK and dV of a whole sequence no longer fit the one-pass
+    kernel's VMEM beside a tile's temporaries (``benchmark/longctx.py``:
+    32k and 64k positions of 128 features) the backward is the two
+    kernels; the longest sequence the rule admits at these blocks
+    compiles."""
+    assert _kernels(_attention_grads(spec, 1, seq, 2, 2, 128)) == kernels
 
 
 # the dropless ``moe`` lowering at the two cells' shapes: 8192 tokens of 2048
