@@ -39,6 +39,7 @@ __all__ = [
     "margin_rank_loss", "squared_l2_distance", "squared_l2_norm",
     "kldiv_loss", "modified_huber_loss", "bilinear_tensor_product",
     "short_conv",
+    "ssd_scan",
 ]
 
 
@@ -481,23 +482,66 @@ def rope(x, theta=10000.0, name=None):
     return out
 
 
-def short_conv(x, num_taps=3, param_attr=None, interpret=False, name=None):
-    """The gated short convolution (ops/nn_ops.py ``short_conv``): ``x``
-    [B, T, 3C] holds an input gate, an output gate and the input side by
-    side; out [B, T, C] = gate_out * causal_depthwise_conv(gate_in * input)
-    with a filter [C, num_taps], no bias.  The projections before and after
-    are the caller's ``fc``.  ``interpret`` runs the TPU kernels through
-    the Pallas interpreter (CPU tests)."""
+def short_conv(x, num_taps=3, param_attr=None, interpret=False, name=None,
+               gated=True, bias_attr=None, act=None):
+    """The short causal depthwise convolution (ops/nn_ops.py
+    ``short_conv``), a filter [C, num_taps] along T; the projections before
+    and after are the caller's ``fc``.  Two forms:
+
+    * ``gated=True`` (LFM2's ``conv`` layers): ``x`` [B, T, 3C] holds an
+      input gate, an output gate and the input side by side, and the
+      channel count must divide by 3; out [B, T, C] = gate_out *
+      filter(gate_in * input); no bias, no activation;
+    * ``gated=False`` (a Mamba-2 layer's filter): ``x`` [B, T, C]; out
+      [B, T, C] = act(filter(x) + bias), the bias [C] (starting at 0) unless
+      ``bias_attr`` is False, ``act`` ``"silu"`` or None.
+
+    ``interpret`` runs the TPU kernels through the Pallas interpreter (CPU
+    tests)."""
     helper = LayerHelper("short_conv", name=name)
-    channels = x.shape[-1] // 3
+    if gated and ((x.shape[-1] >= 0 and x.shape[-1] % 3) or act
+                  or bias_attr not in (None, False)):
+        raise ValueError(
+            f"short_conv: the gated form takes [B, T, 3C] (got "
+            f"{list(x.shape)}), no bias and no activation")
+    channels = x.shape[-1] // 3 if gated else x.shape[-1]
     w = helper.create_parameter(
         ParamAttr._to_attr(param_attr) or ParamAttr(),
         shape=[channels, num_taps], dtype=x.dtype)
     out = helper.create_variable_for_type_inference(
         x.dtype, tuple(x.shape[:-1]) + (channels,))
-    helper.append_op(type="short_conv", inputs={"X": [x], "Filter": [w]},
-                     outputs={"Out": [out]},
-                     attrs={"interpret": True} if interpret else {})
+    inputs = {"X": [x], "Filter": [w]}
+    # attributes stay out of the op where they say the default: a gated
+    # program keeps its content digest
+    attrs = {"interpret": True} if interpret else {}
+    if not gated:
+        attrs["gated"] = False
+        if act:
+            attrs["activation"] = act
+        if bias_attr is not False:
+            inputs["Bias"] = [helper.create_parameter(
+                ParamAttr._to_attr(bias_attr) or ParamAttr(),
+                shape=[channels], dtype=x.dtype, is_bias=True)]
+    helper.append_op(type="short_conv", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def ssd_scan(u, delta, a, bm, cm, d, chunk=256, name=None):
+    """The selective state-space recurrence of a Mamba-2 layer, chunked
+    (ops/ssd_ops.py ``ssd_scan``): ``u`` [B, T, H, P], ``delta`` [B, T, H]
+    (positive: the caller's softplus), ``a`` [H] (negative: the caller's
+    -exp(A_log)), ``bm`` and ``cm`` [B, T, G, N] with G a divisor of H,
+    ``d`` [H]; every head's state [P, N] starts at 0,
+    S[t] = exp(delta[t] a) S[t-1] + delta[t] u[t] (x) bm[t],
+    out[t] = S[t] cm[t] + d u[t], [B, T, H, P].  ``chunk`` positions a
+    chunk; it must divide T."""
+    helper = LayerHelper("ssd_scan", name=name)
+    out = helper.create_variable_for_type_inference(u.dtype, u.shape)
+    helper.append_op(type="ssd_scan",
+                     inputs={"U": [u], "Delta": [delta], "A": [a],
+                             "Bm": [bm], "Cm": [cm], "D": [d]},
+                     outputs={"Out": [out]}, attrs={"chunk": int(chunk)})
     return out
 
 
@@ -1347,6 +1391,12 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     ring attention over the sp axis — K/V circulate on ICI, O(T/sp)
     memory per device; pass ``sequence_parallel=False`` to force the
     device-global kernel.
+
+    The kernels scale the scores by 1/sqrt(D), D the head size, and take
+    no other scale: a model whose scale is s multiplies q by s * sqrt(D)
+    before the call (``layers.scale``; XLA fuses it behind the projection).
+    K and V may have fewer heads than Q, a whole divisor (query head h
+    reads K / V head h // group).
 
     ``block_q``/``block_k`` default to the swept 1024x1024 tiles — or,
     when the ``autotune`` flag is on, to the persisted

@@ -23,6 +23,7 @@ from . import crf_ops         # noqa: F401
 from . import generation_ops  # noqa: F401
 from . import pallas_kernels  # noqa: F401
 from . import moe_ops         # noqa: F401
+from . import ssd_ops         # noqa: F401
 
 
 @register_op("backward")

@@ -373,43 +373,61 @@ def _rope(ctx, ins, attrs):
 
 @register_op("short_conv")
 def _short_conv(ctx, ins, attrs):
-    """The gated short convolution of a hybrid decoder's ``conv`` layers
-    (LFM2), between its two projections: X [B, T, 3C] holds the gates and
-    the input side by side, [Bg | Cg | u]; Filter [C, L] is a depthwise
-    causal filter of L taps.
+    """The short causal depthwise convolution of a hybrid decoder, between
+    its two projections.  Filter [C, L] is a depthwise causal filter of L
+    taps: c[t] = sum_j Filter[:, j] * v[t - (L-1) + j], v before position 0
+    is 0.  Two forms, by the attribute ``gated`` (default true):
 
-        v = Bg * u;   c[t] = sum_j Filter[:, j] * v[t - (L-1) + j]
-        Out = Cg * c                      (v before position 0 is 0)
+    * gated (LFM2's ``conv`` layers): X [B, T, 3C] holds the gates and the
+      input side by side, [Bg | Cg | u];  v = Bg * u,  Out = Cg * c;
+    * ungated (a Mamba-2 layer's filter): X [B, T, C];  v = X,
+      Out = act(c + Bias), with Bias [C] (optional) and ``activation``
+      (``silu`` or none).
 
-    ONE lowering for the two gates and the taps, computed in float32.
-    Where ``pallas_kernels.short_conv_route`` says so (a TPU, C whole lane
-    tiles, T whole row blocks, one device) the Pallas kernels run: one read
-    of X and one write of Out forward, one read of X and the cotangent and
-    one write of dX backward.  Everywhere else the formula below: tap j
-    reads ``v`` shifted down by L-1-j positions (a slice of ``v`` behind as
-    many zero rows), and XLA makes of it two passes forward and five
-    backward.  Counted at trace time as
-    ``route/short_conv:{pallas,interpret,xla}``."""
+    ONE lowering for the gates, taps, bias and activation, computed in
+    float32.  Where ``pallas_kernels.short_conv_route`` says so (a TPU, C
+    whole lane tiles, T whole row blocks, one device) the Pallas kernels
+    run, one pair for both forms: one read of X and one write of Out
+    forward, one read of X and the cotangent and one write of dX backward.
+    Everywhere else the formula below: tap j reads ``v`` shifted down by
+    L-1-j positions (a slice of ``v`` behind as many zero rows), and XLA
+    makes of the gated form two passes forward and five backward.  Counted
+    at trace time as ``route/short_conv:{pallas,interpret,xla}``, both
+    forms."""
     x, w = ins["X"][0], ins["Filter"][0]
     c, taps = w.shape
+    gated = attrs.get("gated", True)
+    act = attrs.get("activation")
+    bias = ins["Bias"][0] if ins.get("Bias") else None
+    if gated and (bias is not None or act):
+        raise ValueError("short_conv: the gated form takes no bias and no "
+                         "activation")
+    if act not in (None, "silu"):
+        raise ValueError(f"short_conv: activation {act!r} is neither "
+                         f"'silu' nor None")
     single = ctx.mesh is None or getattr(ctx.mesh, "size", 1) == 1
+    interpret = attrs.get("interpret", False)
     route = pallas_kernels.short_conv_route(
-        x.shape, taps, x.dtype, attrs.get("interpret", False)) \
-        if single else "xla"
+        x.shape, taps, x.dtype, interpret, gated, act) if single else "xla"
     compile_cache.stats().bump("route/short_conv:" + route)
     if route != "xla":
+        if not gated and bias is None:
+            bias = jnp.zeros((c,), w.dtype)
         return {"Out": pallas_kernels.short_conv(
-            x, w, interpret=route == "interpret")}
+            x, w, bias, act, interpret=route == "interpret")}
     x32, w32 = x.astype(jnp.float32), w.astype(jnp.float32)
-    gate_in, gate_out, u = (x32[..., :c], x32[..., c:2 * c], x32[..., 2 * c:])
-    v = gate_in * u
+    v = x32[..., :c] * x32[..., 2 * c:] if gated else x32
     acc = v * w32[:, taps - 1]
     for j in range(taps - 1):
         back = taps - 1 - j
         behind = jnp.concatenate(
             [jnp.zeros_like(v[:, :back]), v[:, :v.shape[1] - back]], axis=1)
         acc = acc + behind * w32[:, j]
-    return {"Out": (gate_out * acc).astype(x.dtype)}
+    if gated:
+        return {"Out": (x32[..., c:2 * c] * acc).astype(x.dtype)}
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
+    return {"Out": (jax.nn.silu(acc) if act else acc).astype(x.dtype)}
 
 
 _CE_EPS = 1e-8      # cross_entropy_op's clamp under the logarithm
@@ -823,6 +841,26 @@ def _short_conv_shape(op, ins, attrs):
     x, w = first(ins, "X"), first(ins, "Filter")
     if x.shape is None:
         return {"Out": x}
+    if not attrs.get("gated", True):
+        bias = first(ins, "Bias")
+        if len(x.shape) != 3:
+            raise ShapeError(
+                f"short_conv: X {list(x.shape)} is not [B, T, C] (the "
+                f"ungated form)")
+        if w.shape is not None and (len(w.shape) != 2 or not dim_ok(
+                w.shape[0], x.shape[-1])):
+            raise ShapeError(
+                f"short_conv: Filter {list(w.shape)} is not [C, taps] for "
+                f"X {list(x.shape)} = [B, T, C]")
+        if ins.get("Bias") and bias.shape is not None and not (
+                len(bias.shape) == 1 and dim_ok(bias.shape[0], x.shape[-1])):
+            raise ShapeError(
+                f"short_conv: Bias {list(bias.shape)} is not [C] for X "
+                f"{list(x.shape)} = [B, T, C]")
+        return {"Out": x}
+    if ins.get("Bias") or attrs.get("activation"):
+        raise ShapeError("short_conv: the gated form takes no Bias and no "
+                         "activation")
     if len(x.shape) != 3 or (x.shape[-1] >= 0 and x.shape[-1] % 3):
         raise ShapeError(
             f"short_conv: X {list(x.shape)} is not [B, T, 3C] (the two "
@@ -990,8 +1028,9 @@ register_shard_fn("rope")(shard_same_as("X"))
 @register_shard_fn("short_conv")
 def _short_conv_shard(op, ins, attrs):
     """Out keeps X's batch sharding.  The filter runs along T and the
-    feature axis is cut in three inside the op, so a sharded T (it would
-    need the L-1 rows before each shard) or feature axis is a conflict."""
+    feature axis is cut in three inside the gated op (the kernels of
+    either form take whole features), so a sharded T (it would need the
+    L-1 rows before each shard) or feature axis is a conflict."""
     from ..analysis.shard_prop import ShardConflict, first_in
     x = first_in(ins, "X")
     if x.spec is None:

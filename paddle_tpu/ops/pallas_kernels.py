@@ -24,8 +24,8 @@ gated experts through the same three kernels as a pair.
 
 ``rope_turn`` is the half-rotation of rotary positions as a lane rotation
 in fast memory, one read and one write a direction.  ``short_conv`` is the
-gated short convolution of a hybrid decoder's ``conv`` layers, gates and
-taps in one pass a direction.
+short causal depthwise convolution of a hybrid decoder, gated (two gates
+and the taps) or not (taps, bias and SiLU), in one pass a direction.
 """
 from __future__ import annotations
 
@@ -1030,22 +1030,29 @@ def rope_turn(x, cos, sin, interpret=False):
 
 
 # ---------------------------------------------------------------------------
-# short_conv (the gates and taps of the ``short_conv`` lowering, ops/nn_ops.py)
+# short_conv (the gates, taps, bias and activation of the ``short_conv``
+# lowering, ops/nn_ops.py)
 # ---------------------------------------------------------------------------
-# X [B, T, 3C] = [Bg | Cg | u], Filter [C, L]: Out = Cg * c with
-# c[t] = sum_j Filter[:, j] * v[t - (L-1) + j] and v = Bg * u.  Through XLA
-# the forward is two fusions (v goes to memory and comes back) and the
-# backward five and the pads that put the three gradients side by side: 2.3
-# and 3.4 times the bytes of one pass.  Here a block is ``SHORT_CONV_ROWS``
-# positions of ALL the features, so the three parts of X are lane-aligned
-# slices of one block and dX is written as one array; a tap reads the block
-# rotated along the positions in fast memory, its first rows patched from the
-# 8 rows before the block (the backward's from the 8 rows after: a second
-# and third view of the same arrays).  The filter's gradient accumulates in
-# a block that stays resident over the whole grid.
+# Filter [C, L]: c[t] = sum_j Filter[:, j] * v[t - (L-1) + j].  Gated: X [B,
+# T, 3C] = [Bg | Cg | u], v = Bg * u, Out = Cg * c.  Ungated: X [B, T, C],
+# v = X, Out = act(c + Bias), act SiLU or nothing.  ONE pair of kernels, told
+# the two static facts ``gated`` and ``act``.  Through XLA the gated forward
+# is two fusions (v goes to memory and comes back) and the backward five and
+# the pads that put the three gradients side by side: 2.3 and 3.4 times the
+# bytes of one pass.  Here a block is ``SHORT_CONV_ROWS`` positions of ALL the
+# features, so the parts of X are lane-aligned slices of one block and dX is
+# written as one array; a tap reads the block rotated along the positions in
+# fast memory, its first rows patched from the 8 rows before the block (the
+# backward's from the 8 rows after: a second and third view of the same
+# arrays).  The cotangent of c at the rows after a block is one product where
+# gated; ungated it wants the activation's slope there, so the filter runs
+# over that halo too (the rows before ITS first are the block's last).  The
+# filter's and the bias's gradients accumulate in blocks that stay resident
+# over the whole grid.
 SHORT_CONV_ROWS = 64             # positions a block: X, dX and the cotangent,
 #                                  double-buffered, are 7 MiB at C = 2048
-SHORT_CONV_LANES = 512           # features a pass inside a block
+SHORT_CONV_LANES = 512           # features a pass inside a block, where that
+#                                  divides C (else 256, else 128)
 _HALO = 8                        # rows of a halo view (a sublane tile)
 
 
@@ -1063,67 +1070,113 @@ def _shifted(v, edge, shift, row, down):
 
 
 def _lane_passes(channels):
-    lanes = SHORT_CONV_LANES if channels % SHORT_CONV_LANES == 0 else 128
+    lanes = next(n for n in (SHORT_CONV_LANES, 256, 128) if channels % n == 0)
     return [(at, lanes) for at in range(0, channels, lanes)]
 
 
-def _short_conv_kernel(x_ref, before_ref, w_ref, o_ref, *, channels, taps):
-    i = pl.program_id(1)
-    c = channels
-    for at, lanes in _lane_passes(c):
-        def part(ref, k):
-            return ref[:, k * c + at:k * c + at + lanes].astype(jnp.float32)
+def _behind(v, edge, taps):
+    """[v[t - s] for s < taps]: ``v`` and its copies moved down the
+    positions, the rows before its first from the last of ``edge``."""
+    row = lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    return [v] + [_shifted(v, edge, s, row, True) for s in range(1, taps)]
 
-        row = lax.broadcasted_iota(jnp.int32, (x_ref.shape[0], lanes), 0)
+
+def _filtered(behind, w):
+    """sum_s w[L-1-s] * v[t - s] from ``_behind``'s list."""
+    taps = len(behind)
+    acc = behind[0] * w[taps - 1:taps]
+    for s in range(1, taps):
+        acc = acc + behind[s] * w[taps - 1 - s:taps - s]
+    return acc
+
+
+def _act_and_slope(pre, act):
+    """(act(pre), d act / d pre)."""
+    if act is None:
+        return pre, jnp.ones_like(pre)
+    s = jax.nn.sigmoid(pre)
+    return pre * s, s * (1.0 + pre * (1.0 - s))
+
+
+def _part(ref, k, c, at, lanes):
+    """Lanes ``at`` .. ``at + lanes`` of the ``k``-th of the C-wide parts of
+    a block, in float32."""
+    return ref[:, k * c + at:k * c + at + lanes].astype(jnp.float32)
+
+
+def _filter_input(ref, c, at, lanes, gated):
+    """v of the rows of a block or a halo: Bg * u, or X itself."""
+    if gated:
+        return _part(ref, 0, c, at, lanes) * _part(ref, 2, c, at, lanes)
+    return _part(ref, 0, c, at, lanes)
+
+
+def _short_conv_kernel(x_ref, before_ref, w_ref, *rest, taps, gated, act):
+    """``rest``: the bias (ungated only), then Out."""
+    o_ref = rest[-1]
+    i = pl.program_id(1)
+    c = o_ref.shape[1]
+    for at, lanes in _lane_passes(c):
         w = w_ref[:, at:at + lanes].astype(jnp.float32)        # [L, lanes]
-        v = part(x_ref, 0) * part(x_ref, 2)
-        before = jnp.where(i > 0, part(before_ref, 0) * part(before_ref, 2),
-                           0.0)
-        acc = v * w[taps - 1:taps]
-        for shift in range(1, taps):
-            acc = acc + _shifted(v, before, shift, row, True) \
-                * w[taps - 1 - shift:taps - shift]
-        o_ref[:, at:at + lanes] = (part(x_ref, 1) * acc).astype(o_ref.dtype)
+        before = jnp.where(
+            i > 0, _filter_input(before_ref, c, at, lanes, gated), 0.0)
+        acc = _filtered(_behind(_filter_input(x_ref, c, at, lanes, gated),
+                                before, taps), w)
+        if gated:
+            out = _part(x_ref, 1, c, at, lanes) * acc
+        else:
+            out = _act_and_slope(acc + _part(rest[0], 0, c, at, lanes),
+                                 act)[0]
+        o_ref[:, at:at + lanes] = out.astype(o_ref.dtype)
 
 
 def _short_conv_bwd_kernel(x_ref, before_ref, after_ref, g_ref, g_after_ref,
-                           w_ref, dx_ref, dw_ref, *, channels, taps,
-                           num_blocks):
+                           w_ref, *rest, taps, gated, act, num_blocks):
+    """``rest``: the bias (ungated only), then dX, dFilter and (ungated
+    only) dBias."""
+    dx_ref, dw_ref = rest[-2:] if gated else rest[1:3]
     b, i = pl.program_id(0), pl.program_id(1)
-    c = channels
+    rows, c = g_ref.shape
 
     @pl.when(jnp.logical_and(b == 0, i == 0))
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
+        if not gated:
+            rest[3][...] = jnp.zeros_like(rest[3])
 
     for at, lanes in _lane_passes(c):
-        def part(ref, k):
-            return ref[:, k * c + at:k * c + at + lanes].astype(jnp.float32)
-
-        row = lax.broadcasted_iota(jnp.int32, (x_ref.shape[0], lanes), 0)
+        row = lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
         w = w_ref[:, at:at + lanes].astype(jnp.float32)
-        gate_in, gate_out, u = part(x_ref, 0), part(x_ref, 1), part(x_ref, 2)
-        g = g_ref[:, at:at + lanes].astype(jnp.float32)
-        v = gate_in * u
-        before = jnp.where(i > 0, part(before_ref, 0) * part(before_ref, 2),
-                           0.0)
-        d_acc = g * gate_out                   # cotangent of the filter's sum
-        after = jnp.where(
-            i < num_blocks - 1,
-            g_after_ref[:, at:at + lanes].astype(jnp.float32)
-            * part(after_ref, 1), 0.0)
-        acc = v * w[taps - 1:taps]
+        g = _part(g_ref, 0, c, at, lanes)
+        g_after = _part(g_after_ref, 0, c, at, lanes)
+        v = _filter_input(x_ref, c, at, lanes, gated)
+        before = jnp.where(
+            i > 0, _filter_input(before_ref, c, at, lanes, gated), 0.0)
+        behind = _behind(v, before, taps)
+        acc = _filtered(behind, w)
+        # d_acc: the cotangent of the filter's sum, here and at the 8 rows
+        # after the block
+        if gated:
+            d_acc = g * _part(x_ref, 1, c, at, lanes)
+            d_after = g_after * _part(after_ref, 1, c, at, lanes)
+        else:
+            bias = _part(rest[0], 0, c, at, lanes)
+            d_acc = g * _act_and_slope(acc + bias, act)[1]
+            d_after = g_after * _act_and_slope(_filtered(_behind(
+                _part(after_ref, 0, c, at, lanes), v[rows - _HALO:], taps),
+                w) + bias, act)[1]
+            rest[3][:, at:at + lanes] += jnp.sum(d_acc, axis=0, keepdims=True)
+        d_after = jnp.where(i < num_blocks - 1, d_after, 0.0)
         d_v = d_acc * w[taps - 1:taps]
-        dw_ref[taps - 1:taps, at:at + lanes] += jnp.sum(
-            d_acc * v, axis=0, keepdims=True)
-        for shift in range(1, taps):
-            tap = slice(taps - 1 - shift, taps - shift)
-            behind = _shifted(v, before, shift, row, True)
-            acc = acc + behind * w[tap]
-            d_v = d_v + _shifted(d_acc, after, shift, row, False) * w[tap]
-            dw_ref[tap, at:at + lanes] += jnp.sum(d_acc * behind, axis=0,
+        for s in range(taps):
+            tap = slice(taps - 1 - s, taps - s)
+            if s:
+                d_v = d_v + _shifted(d_acc, d_after, s, row, False) * w[tap]
+            dw_ref[tap, at:at + lanes] += jnp.sum(d_acc * behind[s], axis=0,
                                                    keepdims=True)
-        for k, value in enumerate((d_v * u, g * acc, d_v * gate_in)):
+        parts = (d_v * _part(x_ref, 2, c, at, lanes), g * acc,
+                 d_v * _part(x_ref, 0, c, at, lanes)) if gated else (d_v,)
+        for k, value in enumerate(parts):
             dx_ref[:, k * c + at:k * c + at + lanes] = value.astype(
                 dx_ref.dtype)
 
@@ -1150,54 +1203,83 @@ def _short_conv_params(interpret):
             dimension_semantics=("arbitrary", "arbitrary"))}
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _short_conv(x, w, interpret):
+def _resident(rows, channels):
+    return pl.BlockSpec((rows, channels), lambda b, i: (0, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+def _short_conv_call(x, w, bias, act, interpret):
+    """(jitted, as ``_rope_call`` is: a module traces and lowers the kernel
+    once however many layers call it; its body is 17 unrolled lane passes at
+    4352 channels, a second of tracing a call)"""
     channels, taps = w.shape
+    gated = bias is None
     grid, views = _short_conv_specs(x)
-    block, before, _ = views(3 * channels)
+    block, before, _ = views(x.shape[2])
+    more = () if gated else (bias[None],)
     return pl.pallas_call(
-        functools.partial(_short_conv_kernel, channels=channels, taps=taps),
+        functools.partial(_short_conv_kernel, taps=taps, gated=gated,
+                          act=act),
         out_shape=_sds(x, x.shape[:2] + (channels,), x.dtype), grid=grid,
-        in_specs=[block, before,
-                  pl.BlockSpec((taps, channels), lambda b, i: (0, 0))],
+        in_specs=[block, before, _resident(taps, channels)]
+        + [_resident(1, channels) for _ in more],
         out_specs=views(channels)[0],
-        **_short_conv_params(interpret))(x, x, w.T)
+        **_short_conv_params(interpret))(x, x, w.T, *more)
 
 
-def _short_conv_fwd(x, w, interpret):
-    return _short_conv(x, w, interpret), (x, w)
-
-
-def _short_conv_bwd(interpret, res, g):
-    x, w = res
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+def _short_conv_bwd_call(x, w, bias, g, act, interpret):
     channels, taps = w.shape
+    gated = bias is None
     grid, views = _short_conv_specs(x)
     g_block, _, g_after = views(channels)
-    filter_spec = pl.BlockSpec((taps, channels), lambda b, i: (0, 0))
-    dx, dw = pl.pallas_call(
-        functools.partial(_short_conv_bwd_kernel, channels=channels,
-                          taps=taps, num_blocks=grid[1]),
+    more = () if gated else (bias[None],)
+    dx, dw, *db = pl.pallas_call(
+        functools.partial(_short_conv_bwd_kernel, taps=taps, gated=gated,
+                          act=act, num_blocks=grid[1]),
         out_shape=[_sds(x, x.shape, x.dtype),
-                   _sds(w, (taps, channels), jnp.float32)],
+                   _sds(w, (taps, channels), jnp.float32)]
+        + [_sds(m, m.shape, jnp.float32) for m in more],
         grid=grid,
-        in_specs=[*views(3 * channels), g_block, g_after, filter_spec],
-        out_specs=[views(3 * channels)[0], filter_spec],
-        **_short_conv_params(interpret))(x, x, x, g, g, w.T)
-    return dx, dw.T.astype(w.dtype)
+        in_specs=[*views(x.shape[2]), g_block, g_after,
+                  _resident(taps, channels)]
+        + [_resident(1, channels) for _ in more],
+        out_specs=[views(x.shape[2])[0], _resident(taps, channels)]
+        + [_resident(1, channels) for _ in more],
+        **_short_conv_params(interpret))(x, x, x, g, g, w.T, *more)
+    return dx, dw.T.astype(w.dtype), \
+        None if gated else db[0][0].astype(bias.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _short_conv(x, w, bias, act, interpret):
+    return _short_conv_call(x, w, bias, act=act, interpret=interpret)
+
+
+def _short_conv_fwd(x, w, bias, act, interpret):
+    return _short_conv(x, w, bias, act, interpret), (x, w, bias)
+
+
+def _short_conv_bwd(act, interpret, res, g):
+    return _short_conv_bwd_call(*res, g, act=act, interpret=interpret)
 
 
 _short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
 
 
-def short_conv_route(shape, taps, dtype, interpret=False):
-    """Which lowering a ``short_conv`` of X [B, T, 3C] takes: the kernels
-    (``pallas`` on a TPU; ``interpret``, which only a test asks for) where C
-    is whole lane tiles, T whole blocks of ``SHORT_CONV_ROWS`` positions,
-    the taps fit a halo and X is float32 or bfloat16; ``xla``, the op's
-    formula, for every other shape and backend."""
-    eligible = (len(shape) == 3 and shape[2] % (3 * 128) == 0
+def short_conv_route(shape, taps, dtype, interpret=False, gated=True,
+                     act=None):
+    """Which lowering a ``short_conv`` of X [B, T, 3C] (gated) or [B, T, C]
+    (ungated) takes: the kernels (``pallas`` on a TPU; ``interpret``, which
+    only a test asks for) where C is whole lane tiles, T whole blocks of
+    ``SHORT_CONV_ROWS`` positions, the taps fit a halo, X is float32 or
+    bfloat16 and the activation is SiLU or none; ``xla``, the op's formula,
+    for every other shape and backend."""
+    parts = 3 if gated else 1
+    eligible = (len(shape) == 3 and shape[2] % (parts * 128) == 0
                 and shape[1] % SHORT_CONV_ROWS == 0 and 1 <= taps <= _HALO
-                and dtype in (jnp.float32, jnp.bfloat16))
+                and dtype in (jnp.float32, jnp.bfloat16)
+                and act in (None, "silu") and not (gated and act))
     if eligible and interpret:
         return "interpret"
     if eligible and jax.default_backend() == "tpu":
@@ -1205,13 +1287,15 @@ def short_conv_route(shape, taps, dtype, interpret=False):
     return "xla"
 
 
-def short_conv(x, w, interpret=False):
-    """``Cg * causal_depthwise_filter(Bg * u)`` for ``x`` [B, T, 3C] = [Bg |
-    Cg | u] that ``short_conv_route`` takes and the filter ``w`` [C, L]: X
-    read once and Out [B, T, C] written once; the backward reads X and the
-    cotangent once and writes dX once.  In ``x``'s dtype, computed in
-    float32.  Differentiable in both."""
-    return _short_conv(x, w, interpret)
+def short_conv(x, w, bias=None, act=None, interpret=False):
+    """The short causal depthwise filter ``w`` [C, L] over ``x`` that
+    ``short_conv_route`` takes.  ``bias`` None, the gated form: ``Cg *
+    filter(Bg * u)`` for ``x`` [B, T, 3C] = [Bg | Cg | u].  ``bias`` [C], the
+    ungated form: ``act(filter(x) + bias)`` for ``x`` [B, T, C], ``act`` None
+    or ``"silu"``.  X read once and Out [B, T, C] written once; the backward
+    reads X and the cotangent once and writes dX once.  In ``x``'s dtype,
+    computed in float32.  Differentiable in ``x``, ``w`` and ``bias``."""
+    return _short_conv(x, w, bias, act, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -157,6 +157,43 @@ def test_dropless_moe_names_its_four_stages_inside_its_op_scope():
     assert not any("pt.moe." in n for n in names)
 
 
+def test_ssd_scan_names_its_five_stages_inside_its_op_scope():
+    """``ssd.decay`` / ``ssd.intra`` / ``ssd.states`` / ``ssd.pass`` /
+    ``ssd.out`` sit inside ``pt.ssd_scan:<b>.<p>``, forward and backward;
+    they do not start with ``pt.``, so the op stays the innermost owner."""
+    t_len, heads, p, n = 16, 2, 4, 3
+    x = layers.data("x", shape=[t_len, heads * p + heads + 2 * n],
+                    dtype="float32")
+    h = layers.fc(x, size=heads * p + heads + 2 * n, num_flatten_dims=2)
+
+    def cut(start, stop, shape):
+        return layers.reshape(layers.slice(h, axes=[2], starts=[start],
+                                           ends=[stop]), shape)
+
+    at = heads * p
+    vec = layers.fc(layers.reduce_mean(x, dim=[1]), size=heads)
+    out = layers.ssd_scan(
+        cut(0, at, [-1, t_len, heads, p]),
+        layers.softplus(cut(at, at + heads, [-1, t_len, heads])),
+        layers.scale(layers.exp(layers.reduce_mean(vec, dim=[0])), -1.0),
+        cut(at + heads, at + heads + n, [-1, t_len, 1, n]),
+        cut(at + heads + n, at + heads + 2 * n, [-1, t_len, 1, n]),
+        layers.reduce_mean(vec, dim=[0]), chunk=4)
+    loss = layers.mean(out)
+    pt.optimizer.SGD(0.1).minimize(loss)
+    feed = {"x": np.random.RandomState(0).rand(
+        3, t_len, heads * p + heads + 2 * n).astype("float32")}
+    names = _op_names(_compile(pt.Executor(), feed, loss).hlo_text())
+    for stage in ("decay", "intra", "states", "pass", "out"):
+        assert any(re.search(
+            rf"/jvp\(pt\.ssd_scan:0\.\d+\)/ssd\.{stage}/", n)
+            for n in names), stage
+        assert any(re.search(
+            rf"/transpose\(jvp\(pt\.ssd_scan:0\.\d+\)\)/ssd\.{stage}/", n)
+            for n in names), stage
+    assert not any("pt.ssd." in n for n in names)
+
+
 def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
     """Lowered for the TPU (no chip needed to LOWER), the experts stage of a
     gated ``moe`` op is six Pallas custom calls: the gate/up pair forward

@@ -205,7 +205,7 @@ def test_short_conv_is_one_kernel_a_direction(spec, batch):
     direction and next to no temporaries (the formula through XLA keeps v
     and four shifted products)."""
     def loss(x, w, mix):
-        return jnp.sum(pallas_kernels._short_conv(x, w, False) * mix)
+        return jnp.sum(pallas_kernels.short_conv(x, w) * mix)
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         spec((batch, LFM2_T, 3 * LFM2_D)), spec((LFM2_D, 3)),
@@ -332,3 +332,87 @@ def test_dropless_moves_rows_with_the_tiles_in_use(
                     if "moe.combine" in line and re.search(picked, line)]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= parent_temp + allowed, temp
+
+
+# granite-4.0-h-micro's Mamba-2 layers at the cell's shapes: the filter over
+# 4096 + 2 x 128 = 4352 channels (34 lane tiles) of 4 taps
+GRANITE_C, GRANITE_TAPS = 4352, 4
+
+
+@pytest.mark.parametrize("seq", [4096, 8192])
+def test_ungated_short_conv_is_one_kernel_a_direction(spec, seq):
+    """The ungated short convolution (bias, SiLU), value and the three
+    gradients: one custom call a direction and next to no temporaries."""
+    def loss(x, w, bias, mix):
+        return jnp.sum(pallas_kernels.short_conv(x, w, bias, "silu") * mix)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec((1, seq, GRANITE_C)), spec((GRANITE_C, GRANITE_TAPS)),
+        spec((GRANITE_C,)), spec((1, seq, GRANITE_C))).compile()
+    assert _kernels(compiled.as_text()) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.5 * seq * GRANITE_C * 4
+
+
+def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """The K-step program of the cell whose configuration holds an
+    ``ssd_scan`` (found by its files, at the published widths and the
+    timed sizes), lowered on shapes for the described v5e: nine ungated
+    filters and one grouped attention take the kernels, every layer is a
+    recomputed stretch, and ``memory_analysis()`` puts the step between a
+    quarter and 90 % of the chip's 16.9 GB: the rule the cell's sequence
+    length was chosen by."""
+    import importlib.util
+    import json
+
+    import paddle_tpu as pt
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "chipbench")
+    with open(os.path.join(bench, "configs",
+                           "granite_4_0_h_micro.json")) as fh:
+        sizes = json.load(fh)
+    cell = next(c for c in (
+        json.load(open(os.path.join(bench, "workloads", f)))
+        for f in sorted(os.listdir(os.path.join(bench, "workloads"))))
+        if c["config"] == sizes["name"])
+    found = importlib.util.spec_from_file_location(
+        "granite_config_for_compile",
+        os.path.join(bench, "configs", f"{sizes['name']}.py"))
+    config = importlib.util.module_from_spec(found)
+    found.loader.exec_module(config)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    batch, k = cell["batch_per_chip"], cell["steps_per_window"]
+    built = config.build("train", batch, sizes)
+    main = built["main"]
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            tuple(int(d) for d in shape),
+            jax.dtypes.canonicalize_dtype(dtype), sharding=one_chip)
+
+    feeds = {n: shaped((batch,) + tuple(s["shape"]), s["dtype"])
+             for n, s in built["feeds"].items()}
+    state = {v.name: shaped(v.shape, v.dtype)
+             for v in main.global_block().vars.values() if v.persistable}
+    before = dict(compile_cache.stats().snapshot())
+    exe = pt.Executor(amp=built["amp"])
+    # (products as a step on the chip has them: under conftest's 'highest'
+    # the one-pass attention backward asks for more VMEM than it may take)
+    with jax.default_matmul_precision("default"), exe._call_context(main):
+        multi = exe._make_multi(main, [built["loss"]], False, k, False)
+        step = exe._build_steps(main, multi, False, fingerprint=None)
+        compiled = step._jit.lower(feeds, state, 0).compile()
+    seen = {r: n - before.get(r, 0)
+            for r, n in compile_cache.stats().snapshot().items()
+            if r.startswith("route/")}
+    assert seen["route/ssd_scan:xla"] == 9
+    assert seen["route/short_conv:pallas"] == 9
+    assert seen["route/flash_attention:grouped"] == 1
+    assert seen["route/flash_attention_bwd:one_pass"] == 1
+    assert seen["route/recompute:checkpoint"] == 10
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes
+             + m.generated_code_size_in_bytes)
+    assert 0.25 * 16.9e9 < total < 0.9 * 16.9e9, total
