@@ -527,7 +527,7 @@ def short_conv(x, num_taps=3, param_attr=None, interpret=False, name=None,
     return out
 
 
-def ssd_scan(u, delta, a, bm, cm, d, chunk=256, name=None):
+def ssd_scan(u, delta, a, bm, cm, d, chunk=256, name=None, interpret=False):
     """The selective state-space recurrence of a Mamba-2 layer, chunked
     (ops/ssd_ops.py ``ssd_scan``): ``u`` [B, T, H, P], ``delta`` [B, T, H]
     (positive: the caller's softplus), ``a`` [H] (negative: the caller's
@@ -535,13 +535,23 @@ def ssd_scan(u, delta, a, bm, cm, d, chunk=256, name=None):
     ``d`` [H]; every head's state [P, N] starts at 0,
     S[t] = exp(delta[t] a) S[t-1] + delta[t] u[t] (x) bm[t],
     out[t] = S[t] cm[t] + d u[t], [B, T, H, P].  ``chunk`` positions a
-    chunk; it must divide T."""
+    chunk; it must divide T.
+
+    On a TPU, on one device, head sizes of 64 or 128 with a state and a
+    chunk of whole lane tiles (multiples of 128) take a pair of Pallas
+    kernels (``ops/ssd_kernels.py``; the backward keeps the inputs and the
+    chunks' boundary states, nothing of [chunk, chunk]);
+    every other shape, a mesh and the CPU take the einsum form.
+    ``interpret`` runs the kernels through the Pallas interpreter (CPU
+    tests)."""
     helper = LayerHelper("ssd_scan", name=name)
     out = helper.create_variable_for_type_inference(u.dtype, u.shape)
     helper.append_op(type="ssd_scan",
                      inputs={"U": [u], "Delta": [delta], "A": [a],
                              "Bm": [bm], "Cm": [cm], "D": [d]},
-                     outputs={"Out": [out]}, attrs={"chunk": int(chunk)})
+                     outputs={"Out": [out]},
+                     attrs={"chunk": int(chunk),
+                            **({"interpret": True} if interpret else {})})
     return out
 
 

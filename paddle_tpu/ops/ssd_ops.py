@@ -30,8 +30,20 @@ to 0.15 a chunk's cs reaches -2 400, so a quotient exp(cs_i) / exp(cs_j) is
 overflow.  The stages are five ``jax.named_scope``s inside the op's own
 ``pt.ssd_scan:<block>.<position>`` (they do not start with ``pt.``, so the
 op stays the innermost owner of its time).  Computed in float32; the
-backward is JAX's transpose of the same five stages.  Counted at trace time
-as ``route/ssd_scan:xla`` (the einsum form is the only lowering so far).
+backward is JAX's transpose of the same five stages.
+
+Two lowerings, chosen by ``ssd_kernels.ssd_scan_route`` from what the code
+can see and counted at trace time as ``route/ssd_scan:{pallas,interpret,
+xla}``: on a TPU, on one device, with P a whole or half lane tile (128 or
+64), N and the chunk whole lane tiles, the heads of a group whole head
+blocks and float32 or bfloat16 operands, the pair of Pallas kernels of
+``ops/ssd_kernels.py`` (the same numerics on the [T, H * P] layout the model
+has; a ``jax.custom_vjp`` whose residuals are the op's inputs and the
+chunk-boundary states ``S_before``, never L or another [chunk, chunk]
+array); everywhere else (every CPU run, a mesh, every other shape) the
+einsum form ``ssd_chunked`` below, which is also the kernels' test
+reference.  ``interpret`` is the kernels through the Pallas interpreter,
+which only a test asks for through the op's ``interpret`` attribute.
 """
 from __future__ import annotations
 
@@ -41,6 +53,7 @@ from jax import lax
 
 from ..core import compile_cache
 from ..core.registry import register_op
+from . import ssd_kernels
 
 
 def ssd_chunked(u, delta, a, bm, cm, d, chunk):
@@ -93,15 +106,24 @@ def ssd_chunked(u, delta, a, bm, cm, d, chunk):
 def _ssd_scan(ctx, ins, attrs):
     """U [B, T, H, P], Delta [B, T, H], A [H], Bm and Cm [B, T, G, N],
     D [H] -> Out [B, T, H, P] (this file's docstring); ``chunk`` positions
-    a chunk, which divides T."""
+    a chunk, which divides T.  The kernels or the einsum form, by
+    ``ssd_kernels.ssd_scan_route``."""
     u = ins["U"][0]
     chunk = int(attrs.get("chunk", 256))
     if u.shape[1] % chunk:
         raise ValueError(f"ssd_scan: T {u.shape[1]} is not whole chunks of "
                          f"{chunk}")
-    compile_cache.stats().bump("route/ssd_scan:xla")
-    return {"Out": ssd_chunked(u, ins["Delta"][0], ins["A"][0], ins["Bm"][0],
-                               ins["Cm"][0], ins["D"][0], chunk)}
+    operands = (u, ins["Delta"][0], ins["A"][0], ins["Bm"][0], ins["Cm"][0],
+                ins["D"][0])
+    single = ctx.mesh is None or getattr(ctx.mesh, "size", 1) == 1
+    route = ssd_kernels.ssd_scan_route(
+        (u.shape, operands[3].shape), chunk, u.dtype,
+        attrs.get("interpret", False)) if single else "xla"
+    compile_cache.stats().bump("route/ssd_scan:" + route)
+    if route != "xla":
+        return {"Out": ssd_kernels.ssd_scan(
+            *operands, chunk, interpret=route == "interpret")}
+    return {"Out": ssd_chunked(*operands, chunk)}
 
 
 # ---------------------------------------------------------------------------

@@ -354,14 +354,49 @@ def test_ungated_short_conv_is_one_kernel_a_direction(spec, seq):
         < 0.5 * seq * GRANITE_C * 4
 
 
+def test_ssd_scan_is_one_kernel_a_direction_inside_its_vmem(spec):
+    """``ssd_scan`` at the Granite cell's shape (one 8192-token sequence, 64
+    heads of 64, one group of 128 state features, chunks of 256), value and
+    all six gradients: Mosaic takes both kernels' blocks (a chunk of eight
+    heads, 512 lanes) within ``SSD_VMEM_BYTES`` of scoped VMEM, which is
+    what the route's bound counts; one custom call a direction; what stands
+    in HBM beside operands and gradients is the output, the chunks'
+    boundary states (67 MB) and the head blocks' partial dBm / dCm (2 x 34
+    MB), nothing of [chunks, heads, 256, 256] (537 MB)."""
+    from paddle_tpu.ops import ssd_kernels
+
+    b, t_len, heads, p, groups, n, chunk = 1, 8192, 64, 64, 1, 128, 256
+    shapes = ((b, t_len, heads, p), (b, t_len, groups, n))
+    lanes = ssd_kernels.heads_a_block(heads, groups, p) * p
+    assert lanes == ssd_kernels.SSD_BLOCK_LANES
+    assert ssd_kernels._blocks_fit(chunk, n, lanes)
+    assert ssd_kernels.SSD_VMEM_BYTES <= 32 << 20
+
+    def loss(*xs):
+        return jnp.sum(ssd_kernels.ssd_scan(*xs, chunk) ** 2)
+
+    operands = (spec(shapes[0]), spec((b, t_len, heads)), spec((heads,)),
+                spec(shapes[1]), spec(shapes[1]), spec((heads,)))
+    forward = jax.jit(lambda *xs: ssd_kernels.ssd_scan(*xs, chunk)).lower(
+        *operands).compile()
+    assert _kernels(forward.as_text()) == 1
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))) \
+        .lower(*operands).compile()
+    assert _kernels(compiled.as_text()) == 2
+    wide = b * t_len * heads * p * 4
+    # (the 4-D operands' lanes are half full, so each is copied to the flat
+    # layout a model hands over at no cost; with the 537 MB it would not fit)
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * wide
+
+
 def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     """The K-step program of the cell whose configuration holds an
     ``ssd_scan`` (found by its files, at the published widths and the
     timed sizes), lowered on shapes for the described v5e: nine ungated
-    filters and one grouped attention take the kernels, every layer is a
-    recomputed stretch, and ``memory_analysis()`` puts the step between a
-    quarter and 90 % of the chip's 16.9 GB: the rule the cell's sequence
-    length was chosen by."""
+    filters, nine ``ssd_scan``s and one grouped attention take the kernels,
+    every layer is a recomputed stretch, and ``memory_analysis()`` puts the
+    step between a quarter and 90 % of the chip's 16.9 GB: the rule the
+    cell's sequence length was chosen by."""
     import importlib.util
     import json
 
@@ -406,7 +441,8 @@ def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     seen = {r: n - before.get(r, 0)
             for r, n in compile_cache.stats().snapshot().items()
             if r.startswith("route/")}
-    assert seen["route/ssd_scan:xla"] == 9
+    assert seen["route/ssd_scan:pallas"] == 9
+    assert "route/ssd_scan:xla" not in seen
     assert seen["route/short_conv:pallas"] == 9
     assert seen["route/flash_attention:grouped"] == 1
     assert seen["route/flash_attention_bwd:one_pass"] == 1
