@@ -453,9 +453,12 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out, act)
 
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None, groups=1):
     """y = x * rsqrt(mean(x^2, -1) + epsilon) * scale over the last axis;
-    scale [D] starts at 1 (ops/nn_ops.py ``rms_norm``)."""
+    scale [D] starts at 1 (ops/nn_ops.py ``rms_norm``).  With ``groups`` the
+    mean runs over each of that many equal parts of the last axis on its
+    own (a Mamba-2 layer's gated norm with several state-space groups); the
+    scale stays [D]."""
     from ..initializer import ConstantInitializer
     helper = LayerHelper("rms_norm", name=name)
     scale = helper.create_parameter(
@@ -464,9 +467,15 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
         default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(
         input.dtype, input.shape, lod_level=input.lod_level)
+    if groups < 1 or input.shape[-1] % groups:
+        raise ValueError(f"rms_norm: {input.shape[-1]} features are not "
+                         f"{groups} equal groups")
+    attrs = {"epsilon": epsilon}
+    if groups != 1:          # (the default stays out: a program's digest)
+        attrs["groups"] = int(groups)
     helper.append_op(type="rms_norm",
                      inputs={"X": [input], "Scale": [scale]},
-                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+                     outputs={"Y": [out]}, attrs=attrs)
     if input.lod_level:
         _copy_len(helper, input, out)
     return out
@@ -1562,7 +1571,8 @@ def sampling_id(x, name=None):
 def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
         act="relu", gated=False, gate_attr=None, param_attr=None, name=None,
         scoring="softmax", select_bias_attr=None, renormalize=False,
-        routed_scale=1.0, experts_held=None, expert_offset=0):
+        routed_scale=1.0, experts_held=None, expert_offset=0,
+        shared_hidden=None, shared_attr=None):
     """Mixture-of-Experts FFN — the Program-level expert layer
     (ops/moe_ops.py), in one of two lowerings.
 
@@ -1589,6 +1599,14 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
     a router that keeps its ``num_experts`` outputs.  The choice and the
     renormalisation run over all of them; what the experts held add to the
     result is computed, what the others would add is left out.
+    ``shared_hidden`` adds a SHARED expert of that width, of the routed
+    experts' form (``act``, ``gated``), which every token passes through
+    under weight 1 beside its routed ones (parameters ``<shared_attr
+    name>_up`` [D, Hs], ``_down`` [Hs, D], ``_gate`` where ``gated``); every
+    chip of a deployment computes it alike.  ``act`` ``'relu2'`` is
+    relu(x)^2.  An un-gated ``<name>_up`` whose ``expert_hidden`` is no
+    multiple of 128 while D is one is held [E, expert_hidden, D] (op attr
+    ``up_transposed``): no width is padded, the values are the same matrix.
 
     Returns (out, aux_loss, z_loss): add ``aux_weight * aux_loss`` to the
     training loss to keep experts load-balanced (E * sum_e f_e P_e) and
@@ -1606,24 +1624,42 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
         pa.sharding = ("ep", None, None)
     stacked = num_experts if experts_held is None else experts_held
 
-    def stack(attr, fan_in, fan_out):
+    def stack(attr, fan_in, fan_out, transposed=False, count=(stacked,)):
         # a stack of matrices [E, in, out], each drawn as the matrix it is
         return helper.create_parameter(
-            attr, shape=[stacked, fan_in, fan_out], dtype=input.dtype,
+            attr, dtype=input.dtype, shape=list(count) + (
+                [fan_out, fan_in] if transposed else [fan_in, fan_out]),
             default_initializer=XavierInitializer(fan_in=fan_in,
                                                   fan_out=fan_out))
 
-    def named(suffix):
+    def named(suffix, of=pa):
         # one attr, several stacks: <name>_up, <name>_down, <name>_gate
-        attr = _copy.copy(pa)
-        attr.name = pa.name and f"{pa.name}_{suffix}"
+        attr = _copy.copy(of)
+        attr.name = of.name and f"{of.name}_{suffix}"
         return attr
 
-    w1 = stack(named("up"), D, expert_hidden)
+    # The device lays an array out with a dimension of whole lane tiles
+    # minor where it has one, and a kernel reads row-major: an un-gated up
+    # stack [E, D, H] whose H is no whole lane tiles (and whose D is) would be
+    # copied, with its gradient and the optimizer's moments, on the way to
+    # the grouped product.  It is held [E, H, D] and read transposed.
+    up_transposed = (capacity_factor is None and not gated
+                     and expert_hidden % 128 != 0 and D % 128 == 0)
+    w1 = stack(named("up"), D, expert_hidden, up_transposed)
     w2 = stack(named("down"), expert_hidden, D)
     ins = {"X": [input], "GateW": [gate_w], "W1": [w1], "W2": [w2]}
     if gated:
         ins["WGate"] = [stack(named("gate"), D, expert_hidden)]
+    if shared_hidden is not None:
+        sa = ParamAttr._to_attr(shared_attr)
+
+        def matrix(suffix, fan_in, fan_out):          # a stack of none
+            return stack(named(suffix, sa), fan_in, fan_out, count=())
+
+        ins["SharedUp"] = [matrix("up", D, shared_hidden)]
+        ins["SharedDown"] = [matrix("down", shared_hidden, D)]
+        if gated:
+            ins["SharedGate"] = [matrix("gate", D, shared_hidden)]
     attrs = {"top_k": top_k, "capacity_factor": capacity_factor,
              "activation": act}
     if select_bias_attr is not None:
@@ -1640,6 +1676,8 @@ def moe(input, num_experts, expert_hidden, top_k=2, capacity_factor=1.25,
         attrs["renormalize"] = True
     if routed_scale != 1.0:
         attrs["routed_scale"] = float(routed_scale)
+    if up_transposed:
+        attrs["up_transposed"] = True
     if experts_held is not None:
         attrs.update(experts_held=int(experts_held),
                      expert_offset=int(expert_offset))

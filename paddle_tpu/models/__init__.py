@@ -19,11 +19,12 @@ from .olmoe import olmoe
 from .ouro import ouro, ouro_loss
 from .lfm2 import lfm2, lfm2_loss
 from .granite_hybrid import granite_hybrid, granite_hybrid_loss
+from .nemotron_h import nemotron_h, nemotron_h_loss
 
 __all__ = [
     "mnist_mlp", "mnist_lenet", "alexnet", "vgg16", "vgg19", "vgg_cifar",
     "resnet_imagenet", "resnet50", "resnet_cifar", "googlenet",
     "lstm_text_classification", "seq2seq_attention", "seq2seq_infer", "wide_deep",
     "olmoe", "ouro", "ouro_loss", "lfm2", "lfm2_loss",
-    "granite_hybrid", "granite_hybrid_loss",
+    "granite_hybrid", "granite_hybrid_loss", "nemotron_h", "nemotron_h_loss",
 ]

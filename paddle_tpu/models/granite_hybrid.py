@@ -31,99 +31,19 @@ of values log-spaced from ``time_step_min`` to ``time_step_max``, ``D`` 1.
 from __future__ import annotations
 
 import contextlib
-import math
-
-import numpy as np
 
 from .. import layers
-from ..initializer import NumpyArrayInitializer
 from ..layer_helper import LayerHelper
-from ..param_attr import ParamAttr
+from .hybrid_mixers import attention_mixer, cut, mamba_mixer, p_, proj
 
 MIXERS = ("mamba", "attention")
 
 
-def _p(prefix, name, initializer=None):
-    return ParamAttr(name=f"{prefix}.{name}", initializer=initializer)
-
-
-def _proj(x, size, prefix, name):
-    return layers.fc(x, size=size, num_flatten_dims=2,
-                     param_attr=_p(prefix, name), bias_attr=False)
-
-
-def _vector(prefix, name, values):
-    """A trainable [len(values)] parameter the startup program sets to
-    ``values``."""
-    values = np.asarray(values, "float32")
-    return LayerHelper("granite_vector").create_parameter(
-        _p(prefix, name, NumpyArrayInitializer(values)),
-        shape=list(values.shape), dtype="float32")
-
-
-def _cut(x, start, stop):
-    """x[..., start:stop] of a [B, T, C] variable."""
-    return layers.slice(x, axes=[2], starts=[start], ends=[stop])
-
-
-def dt_bias_start(heads, time_step_min, time_step_max):
-    """The inverse softplus of ``heads`` values log-spaced from
-    ``time_step_min`` to ``time_step_max``: softplus(dt_bias) is the step."""
-    step = np.exp(np.linspace(math.log(time_step_min),
-                              math.log(time_step_max), heads))
-    return (step + np.log(-np.expm1(-step))).astype("float32")
-
-
-def _mamba_mixer(n, hidden_size, heads, head_dim, state, groups, conv_taps,
-                 chunk, norm_eps, time_step_min, time_step_max, prefix):
-    seq_len = n.shape[1]
-    inner, bc = heads * head_dim, groups * state
-    zxd = _proj(n, 2 * inner + 2 * bc + heads, prefix, "in_proj")
-    z = _cut(zxd, 0, inner)
-    xbc = layers.short_conv(
-        _cut(zxd, inner, 2 * inner + 2 * bc), conv_taps, _p(prefix, "conv"),
-        gated=False, bias_attr=_p(prefix, "conv_bias"), act="silu")
-    u = layers.reshape(_cut(xbc, 0, inner), [-1, seq_len, heads, head_dim])
-    bm = layers.reshape(_cut(xbc, inner, inner + bc),
-                        [-1, seq_len, groups, state])
-    cm = layers.reshape(_cut(xbc, inner + bc, inner + 2 * bc),
-                        [-1, seq_len, groups, state])
-    delta = layers.softplus(layers.elementwise_add(
-        _cut(zxd, 2 * inner + 2 * bc, 2 * inner + 2 * bc + heads),
-        _vector(prefix, "dt_bias",
-                dt_bias_start(heads, time_step_min, time_step_max)), axis=2))
-    a = layers.scale(layers.exp(_vector(
-        prefix, "A_log", np.log(np.arange(1, heads + 1)))), -1.0)
-    y = layers.ssd_scan(u, delta, a, bm, cm,
-                        _vector(prefix, "D", np.ones(heads)), chunk=chunk)
-    gated = layers.elementwise_mul(
-        layers.reshape(y, [-1, seq_len, inner]), layers.silu(z))
-    return _proj(layers.rms_norm(gated, norm_eps, _p(prefix, "gate_norm")),
-                 hidden_size, prefix, "out_proj")
-
-
-def _attention_mixer(n, hidden_size, num_heads, num_kv_heads, scale, prefix):
-    seq_len, head = n.shape[1], hidden_size // num_heads
-
-    def heads(x, count):
-        return layers.reshape(x, [-1, seq_len, count, head])
-
-    # flash_attention scales by 1/sqrt(head): the rest goes on q
-    q = layers.scale(_proj(n, hidden_size, prefix, "wq"),
-                     scale * math.sqrt(head))
-    k = _proj(n, num_kv_heads * head, prefix, "wk")
-    v = _proj(n, num_kv_heads * head, prefix, "wv")
-    o = layers.flash_attention(heads(q, num_heads), heads(k, num_kv_heads),
-                               heads(v, num_kv_heads), causal=True)
-    return _proj(layers.reshape(o, [-1, seq_len, hidden_size]), hidden_size,
-                 prefix, "wo")
-
-
 def _ffn(m, hidden_size, ffn_size, prefix):
-    both = _proj(m, 2 * ffn_size, prefix, "ffn_in")
-    return _proj(layers.elementwise_mul(
-        layers.silu(_cut(both, 0, ffn_size)),
-        _cut(both, ffn_size, 2 * ffn_size)), hidden_size, prefix, "ffn_out")
+    both = proj(m, 2 * ffn_size, prefix, "ffn_in")
+    return proj(layers.elementwise_mul(
+        layers.silu(cut(both, 0, ffn_size)),
+        cut(both, ffn_size, 2 * ffn_size)), hidden_size, prefix, "ffn_out")
 
 
 def granite_hybrid(ids, vocab_size, layer_types, hidden_size=2048,
@@ -144,29 +64,30 @@ def granite_hybrid(ids, vocab_size, layer_types, hidden_size=2048,
         raise ValueError(f"granite_hybrid: layer types {bad} are not of "
                          f"{MIXERS}")
     x = layers.scale(layers.embedding(
-        ids, size=[vocab_size, hidden_size], param_attr=_p(prefix, "embed")),
+        ids, size=[vocab_size, hidden_size], param_attr=p_(prefix, "embed")),
         embedding_multiplier)
     for i, kind in enumerate(layer_types):
         at = f"{prefix}.l{i}"
         again = recompute if isinstance(recompute, bool) else i in recompute
         with layers.recompute() if again else contextlib.nullcontext():
-            n = layers.rms_norm(x, norm_eps, _p(at, "mixer_norm"))
+            n = layers.rms_norm(x, norm_eps, p_(at, "mixer_norm"))
             if kind == "mamba":
-                o = _mamba_mixer(n, hidden_size, mamba_heads, mamba_head_dim,
-                                 mamba_state, mamba_groups, conv_taps, chunk,
-                                 norm_eps, time_step_min, time_step_max, at)
+                o = mamba_mixer(n, hidden_size, mamba_heads, mamba_head_dim,
+                                mamba_state, mamba_groups, conv_taps, chunk,
+                                norm_eps, time_step_min, time_step_max, at)
             else:
-                o = _attention_mixer(n, hidden_size, num_heads, num_kv_heads,
-                                     attention_multiplier, at)
+                o = attention_mixer(n, hidden_size, num_heads, num_kv_heads,
+                                    hidden_size // num_heads,
+                                    attention_multiplier, at)
             h = layers.elementwise_add(
                 x, layers.scale(o, residual_multiplier))
-            m = layers.rms_norm(h, norm_eps, _p(at, "ffn_norm"))
+            m = layers.rms_norm(h, norm_eps, p_(at, "ffn_norm"))
             x = layers.elementwise_add(h, layers.scale(
                 _ffn(m, hidden_size, ffn_size, at), residual_multiplier))
-    x = layers.rms_norm(x, norm_eps, _p(prefix, "final_norm"))
+    x = layers.rms_norm(x, norm_eps, p_(prefix, "final_norm"))
     # the tied head: the embedding's own parameter, read transposed
     table = LayerHelper("granite_head").create_parameter(
-        _p(prefix, "embed"), shape=[vocab_size, hidden_size], dtype=x.dtype)
+        p_(prefix, "embed"), shape=[vocab_size, hidden_size], dtype=x.dtype)
     return layers.scale(layers.matmul(x, table, transpose_y=True),
                         1.0 / logits_scaling)
 

@@ -45,6 +45,7 @@ from ..core.registry import register_op
 
 _ACTS = {
     "relu": jax.nn.relu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
     "gelu": jax.nn.gelu,
     "tanh": jnp.tanh,
     "sigmoid": jax.nn.sigmoid,
@@ -170,9 +171,19 @@ def _combine_bwd(tm, all_held, res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _shared_expert(xt, shared, act):
+    """The expert every token passes through, dense: ``act(x Wu) Wd``, or
+    ``(act(x Wg) * (x Wu)) Wd`` where ``shared`` (gate or None, up, down)
+    holds a gate; the products at the default precision, as an ``fc``'s."""
+    gate, up, down = shared
+    hidden = act(xt @ up) if gate is None else act(xt @ gate) * (xt @ up)
+    return hidden @ down
+
+
 def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
               scoring="softmax", select_bias=None, renormalize=False,
-              routed_scale=1.0, expert_offset=0):
+              routed_scale=1.0, expert_offset=0, shared=None,
+              up_transposed=False):
     """The sorted lowering on rows ``xt`` [N, D]: (out [N, D], aux, z).
 
     route: router product and scores in float32 at HIGHEST precision
@@ -195,7 +206,8 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
     ``tiled[r] = xt[token(r)]``, a tile a step of a kernel that stops at
     ``num_tiles``; the gradient is tokens-from-rows, ``d_xt[token(r)] +=
     d_tiled[r]`` over the same tiles.  experts: act(x Wg) * (x Wu) through
-    Wd (no Wg: act(x Wu) Wd) as grouped products; with Wg, gate and up are
+    Wd (no Wg: act(x Wu) Wd, two grouped products a direction, counted
+    ``route/moe:single``) as grouped products; with Wg, gate and up are
     ONE paired product a direction (``gated_grouped_matmul``: the rows read
     once for both stacks, ``act(gate) * up`` in the forward kernel's
     epilogue, the two gradients of the rows summed inside one kernel),
@@ -226,6 +238,16 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
     the experts.  Past ``num_tiles`` the tiled arrays hold nothing anyone
     may read (``rows`` and the cotangent of ``down`` are not even written
     there; the grouped kernels' own outputs are zero).
+
+    ``up_transposed``: the un-gated up stack is held [E, H, D] and read
+    transposed (``grouped_matmul``; ``layers.moe`` says when).
+
+    **A shared expert** (``shared``: gate or None, up [D, Hs], down
+    [Hs, D]) is one more expert of the same form that EVERY token passes
+    through under weight 1, outside the routing: two (three) dense products
+    over all N rows in the stage ``moe.shared``, added after ``combine``.
+    Every chip of an expert-parallel deployment computes it alike on its own
+    tokens, so where the shares of a layer are summed it is counted once.
     """
     from .pallas_kernels import gated_grouped_matmul, grouped_matmul
 
@@ -298,7 +320,9 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
         rows = _dispatch(xt, index, tm, all_held)
     with jax.named_scope("moe.experts"):
         if w_gate is None:
-            hidden = act(grouped_matmul(rows, w_up, tile_group, num_tiles))
+            compile_cache.stats().bump("route/moe:single")
+            hidden = act(grouped_matmul(rows, w_up, tile_group, num_tiles,
+                                        transpose_rhs=up_transposed))
         else:
             compile_cache.stats().bump("route/moe:gated_pair")
             hidden = gated_grouped_matmul(rows, w_gate, w_up, tile_group,
@@ -306,6 +330,10 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
         down = grouped_matmul(hidden, w_down, tile_group, num_tiles)
     with jax.named_scope("moe.combine"):
         out = _combine(down, weight, index, tm, all_held)
+    if shared is not None:
+        compile_cache.stats().bump("route/moe:shared")
+        with jax.named_scope("moe.shared"):
+            out = out + _shared_expert(xt, shared, act).astype(out.dtype)
     return out, aux, z
 
 
@@ -316,6 +344,7 @@ def _moe(ctx, ins, attrs):
     x = ins["X"][0]
     gate_w = ins["GateW"][0]
     w1 = ins["W1"][0]          # [E, D, H], sharded P('ep', ...) on a mesh
+    #                            ([E, H, D] with ``up_transposed``)
     w2 = ins["W2"][0]          # [E, H, D]
     top_k = int(attrs.get("top_k", 2))
     act = _ACTS[attrs.get("activation", "relu")]
@@ -349,21 +378,29 @@ def _moe(ctx, ins, attrs):
             compile_cache.stats().bump("route/moe:share")
         gated = ins.get("WGate")
         bias = ins.get("SelectBias")
+        shared = None
+        if ins.get("SharedUp"):
+            shared_gate = ins.get("SharedGate")
+            shared = (shared_gate[0] if shared_gate else None,
+                      ins["SharedUp"][0], ins["SharedDown"][0])
         out, aux, z = _dropless(
             xt, gate_w, gated[0] if gated else None, w1, w2, top_k, act,
             scoring=scoring, select_bias=bias[0] if bias else None,
             renormalize=bool(attrs.get("renormalize", False)),
             routed_scale=float(attrs.get("routed_scale", 1.0)),
-            expert_offset=int(attrs.get("expert_offset", 0)))
+            expert_offset=int(attrs.get("expert_offset", 0)), shared=shared,
+            up_transposed=bool(attrs.get("up_transposed", False)))
         return {"Out": out.reshape(shape).astype(x.dtype),
                 "AuxLoss": aux, "ZLoss": z}
     if ins.get("WGate") or ins.get("SelectBias") or w1.shape[0] != E or \
             attrs.get("scoring", "softmax") != "softmax" or \
-            attrs.get("renormalize", False):
+            attrs.get("renormalize", False) or ins.get("SharedUp") or \
+            attrs.get("up_transposed", False):
         raise NotImplementedError(
             "moe: gated experts, sigmoid scores, a selection bias, "
-            "renormalised weights and a chip's share of the experts run in "
-            "the dropless lowering only (capacity_factor=None)")
+            "renormalised weights, a shared expert and a chip's share of "
+            "the experts run in the dropless lowering only "
+            "(capacity_factor=None)")
     compile_cache.stats().bump("route/moe:capacity")
 
     logits = xt @ gate_w
@@ -431,6 +468,22 @@ def _moe_shape(op, ins, attrs):
             w1.shape is not None and tuple(gate.shape) != tuple(w1.shape):
         raise ShapeError(
             f"moe: WGate {list(gate.shape)} != W1 {list(w1.shape)}")
+    if ins.get("SharedUp"):
+        up, down = first(ins, "SharedUp"), first(ins, "SharedDown")
+        if up.shape is not None and down.shape is not None and \
+                x.shape is not None and x.shape[-1] >= 0 and (
+                    len(up.shape) != 2 or up.shape[0] != x.shape[-1]
+                    or tuple(down.shape) != tuple(up.shape[::-1])):
+            raise ShapeError(
+                f"moe: SharedUp {list(up.shape)} / SharedDown "
+                f"{list(down.shape)} are not [D, Hs] / [Hs, D] for X's D "
+                f"{x.shape[-1]}")
+        gate = first(ins, "SharedGate")
+        if ins.get("SharedGate") and gate.shape is not None and \
+                up.shape is not None and \
+                tuple(gate.shape) != tuple(up.shape):
+            raise ShapeError(f"moe: SharedGate {list(gate.shape)} != "
+                             f"SharedUp {list(up.shape)}")
     return {"Out": x, "AuxLoss": VarInfo((), "float32"),
             "ZLoss": VarInfo((), "float32")}
 

@@ -324,12 +324,19 @@ def _layer_norm(ctx, ins, attrs):
 @register_op("rms_norm")
 def _rms_norm(ctx, ins, attrs):
     """y = x * rsqrt(mean(x^2, -1) + epsilon) * scale, the statistics in
-    float32 whatever X's dtype."""
+    float32 whatever X's dtype; with ``groups`` G over each of the G equal
+    parts of the last axis on its own."""
     x = ins["X"][0]
+    groups = int(attrs.get("groups", 1))
     x32 = x.astype(jnp.float32)
+    if groups != 1:
+        x32 = x32.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
     inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
                     + attrs.get("epsilon", 1e-5))
-    y = x32 * inv * ins["Scale"][0].astype(jnp.float32)
+    y = x32 * inv
+    if groups != 1:
+        y = y.reshape(x.shape)
+    y = y * ins["Scale"][0].astype(jnp.float32)
     return {"Y": y.astype(x.dtype)}
 
 
@@ -823,6 +830,11 @@ def _rms_norm_shape(op, ins, attrs):
         raise ShapeError(
             f"rms_norm: feature dim {x.shape[-1]} != Scale size "
             f"{scale.shape[-1]}")
+    groups = int(attrs.get("groups", 1))
+    if groups < 1 or (x.shape is not None and x.shape[-1] >= 0
+                      and x.shape[-1] % groups):
+        raise ShapeError(f"rms_norm: {x.shape[-1]} features are not "
+                         f"{groups} equal groups")
     return {"Y": x}
 
 

@@ -870,35 +870,46 @@ def _tgmm(lhs, rhs, tile_group, num_tiles, groups, interpret):
     )(tile_group, num_tiles, lhs, *rhs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _grouped(lhs, rhs, tile_group, num_tiles, interpret):
-    return _gmm((lhs,), (rhs,), tile_group, num_tiles, False, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _grouped(lhs, rhs, tile_group, num_tiles, interpret, transpose_rhs=False):
+    return _gmm((lhs,), (rhs,), tile_group, num_tiles, transpose_rhs,
+                interpret)[0]
 
 
-def _grouped_vjp_fwd(lhs, rhs, tile_group, num_tiles, interpret):
-    return (_gmm((lhs,), (rhs,), tile_group, num_tiles, False, interpret)[0],
-            (lhs, rhs, tile_group, num_tiles))
+def _grouped_vjp_fwd(lhs, rhs, tile_group, num_tiles, interpret,
+                     transpose_rhs=False):
+    return (_gmm((lhs,), (rhs,), tile_group, num_tiles, transpose_rhs,
+                 interpret)[0], (lhs, rhs, tile_group, num_tiles))
 
 
-def _grouped_vjp_bwd(interpret, res, g):
+def _grouped_vjp_bwd(interpret, transpose_rhs, res, g):
     lhs, rhs, tile_group, num_tiles = res
-    return (*_gmm((g,), (rhs,), tile_group, num_tiles, True, interpret),
-            *_tgmm(lhs, (g,), tile_group, num_tiles, rhs.shape[0], interpret),
+    # (the stack's gradient in the stack's own orientation: g^T lhs where
+    # the stack is read transposed, lhs^T g otherwise)
+    a, b = (g, lhs) if transpose_rhs else (lhs, g)
+    return (*_gmm((g,), (rhs,), tile_group, num_tiles, not transpose_rhs,
+                  interpret),
+            *_tgmm(a, (b,), tile_group, num_tiles, rhs.shape[0], interpret),
             None, None)
 
 
 _grouped.defvjp(_grouped_vjp_fwd, _grouped_vjp_bwd)
 
 
-def grouped_matmul(lhs, rhs, tile_group, num_tiles):
+def grouped_matmul(lhs, rhs, tile_group, num_tiles, transpose_rhs=False):
     """``out[r] = lhs[r] @ rhs[tile_group[r // tm]]`` for rows laid out in
     whole tiles per group (see above): ``lhs`` [R, K], ``rhs`` [G, K, N],
     ``tile_group`` int32 [R / tm] non-decreasing with every group present,
-    ``num_tiles`` int32 [1].  Differentiable in ``lhs`` and ``rhs``.  The
-    Pallas kernels everywhere: compiled on the TPU, interpreted on any
+    ``num_tiles`` int32 [1].  With ``transpose_rhs`` the stack is [G, N, K]
+    and read transposed, ``lhs[r] @ rhs[..].T``: the same three kernels, in
+    other places (a stack whose N is no whole lane tiles is held so, with K
+    on the lanes: the device lays a [G, K, N] array out with K minor, a
+    kernel reads row-major, and the stack, its gradient and the optimizer's
+    moments would each be copied).  Differentiable in ``lhs`` and ``rhs``.
+    The Pallas kernels everywhere: compiled on the TPU, interpreted on any
     other backend."""
     return _grouped(lhs, rhs, tile_group, num_tiles,
-                    jax.default_backend() != "tpu")
+                    jax.default_backend() != "tpu", transpose_rhs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))

@@ -168,7 +168,7 @@ def _program(capacity_factor, **kw):
 
 @pytest.mark.parametrize("capacity_factor,gated,ran", [
     (None, True, {"dropless", "gated_pair"}),
-    (None, False, {"dropless"}),
+    (None, False, {"dropless", "single"}),
     (1.25, False, {"capacity"})])
 def test_the_lowering_that_ran_is_counted(capacity_factor, gated, ran):
     loss, feed = _program(capacity_factor,

@@ -157,6 +157,51 @@ def test_dropless_moe_names_its_four_stages_inside_its_op_scope():
     assert not any("pt.moe." in n for n in names)
 
 
+def test_shared_expert_is_a_stage_of_its_moe_op_and_counted_once():
+    """``moe.shared`` sits inside ``pt.moe:<b>.<p>`` forward, backward and,
+    where the layer is a ``layers.recompute`` stretch, recomputed (JAX's
+    ``rematted_computation`` before the op's scope); the four stages stay
+    beside it; ``route/moe:shared`` and ``route/moe:single`` (the un-gated
+    experts' route) are bumped ONCE a lowered op, the recomputed one too
+    (``jax.checkpoint`` traces its stretch once)."""
+    x = layers.data("x", shape=[6, 8], dtype="float32")
+    h = layers.fc(x, size=8, num_flatten_dims=2)
+
+    def experts(h):
+        return layers.moe(h, num_experts=8, expert_hidden=5, top_k=2,
+                          capacity_factor=None, act="relu2", gated=False,
+                          scoring="sigmoid", renormalize=True,
+                          experts_held=2, expert_offset=2,
+                          shared_hidden=7)[0]
+
+    with layers.recompute():
+        h = layers.elementwise_add(h, experts(h))
+    h = layers.elementwise_add(h, experts(h))
+    loss = layers.mean(h)
+    pt.optimizer.SGD(0.1).minimize(loss)
+    feed = {"x": np.random.RandomState(0).rand(3, 6, 8).astype("float32")}
+    names = _op_names(_compile(pt.Executor(), feed, loss).hlo_text())
+    ways = {"fwd": r"/jvp\(pt\.moe:0\.\d+\)/moe\.{}/",
+            "bwd": r"/transpose\(jvp\(pt\.moe:0\.\d+\)\)/moe\.{}/",
+            "first": r"/jvp\(pt\.recompute:0\.\d+\)/pt\.moe:1\.\d+"
+                     r"/moe\.{}/",
+            "back": r"/transpose\(jvp\(pt\.recompute:0\.\d+\)\)/.*"
+                    r"checkpoint/pt\.moe:1\.\d+/moe\.{}/",
+            "again": r"/transpose\(jvp\(pt\.recompute:0\.\d+\)\)/.*"
+                     r"rematted_computation/pt\.moe:1\.\d+/moe\.{}/"}
+    for stage in ("shared", "experts", "combine"):
+        for way, pattern in ways.items():
+            # (of the other stages XLA drops what the backward does not read)
+            assert stage != "shared" and way == "again" or any(
+                re.search(pattern.format(stage), n) for n in names), \
+                (stage, way)
+    assert not any("pt.moe." in n for n in names)
+    stats = compile_cache.stats().snapshot()
+    assert stats["route/moe:shared"] == stats["route/moe:single"] \
+        == stats["route/moe:share"] == stats["route/moe:dropless"] == 2
+    assert "route/moe:gated_pair" not in stats
+
+
 def test_ssd_scan_names_its_five_stages_inside_its_op_scope():
     """``ssd.decay`` / ``ssd.intra`` / ``ssd.states`` / ``ssd.pass`` /
     ``ssd.out`` sit inside ``pt.ssd_scan:<b>.<p>``, forward and backward;

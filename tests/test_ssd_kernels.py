@@ -44,7 +44,8 @@ CASES = [
     (2, 2, 16, 128, 2, 128, 128),    # two blocks a group, two groups
     (1, 2, 4, 64, 1, 128, 256),      # the cell's chunk
     (1, 3, 8, 128, 2, 128, 256),     # and at whole-tile heads, two groups
-]
+    (1, 2, 64, 64, 8, 128, 128),     # eight groups of eight heads, a block a
+]                                    # group (Nemotron-3-Nano's layer)
 
 
 @pytest.mark.parametrize("b,chunks,heads,p,groups,n,chunk", CASES)

@@ -389,14 +389,11 @@ def test_ssd_scan_is_one_kernel_a_direction_inside_its_vmem(spec):
     assert compiled.memory_analysis().temp_size_in_bytes < 5 * wide
 
 
-def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
-    """The K-step program of the cell whose configuration holds an
-    ``ssd_scan`` (found by its files, at the published widths and the
-    timed sizes), lowered on shapes for the described v5e: nine ungated
-    filters, nine ``ssd_scan``s and one grouped attention take the kernels,
-    every layer is a recomputed stretch, and ``memory_analysis()`` puts the
-    step between a quarter and 90 % of the chip's 16.9 GB: the rule the
-    cell's sequence length was chosen by."""
+def _cell_step(one_chip, monkeypatch, name, batch=None, **sizes_over):
+    """(routes counted, bytes ``memory_analysis()`` gives, module text) of
+    the K-step program of the cell whose configuration is ``name`` (found by
+    its files, at the published widths and the timed sizes), lowered on
+    shapes for the described v5e and compiled."""
     import importlib.util
     import json
 
@@ -404,20 +401,19 @@ def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     bench = os.path.join(root, "chipbench")
-    with open(os.path.join(bench, "configs",
-                           "granite_4_0_h_micro.json")) as fh:
-        sizes = json.load(fh)
+    with open(os.path.join(bench, "configs", f"{name}.json")) as fh:
+        sizes = {**json.load(fh), **sizes_over}
     cell = next(c for c in (
         json.load(open(os.path.join(bench, "workloads", f)))
         for f in sorted(os.listdir(os.path.join(bench, "workloads"))))
         if c["config"] == sizes["name"])
     found = importlib.util.spec_from_file_location(
-        "granite_config_for_compile",
-        os.path.join(bench, "configs", f"{sizes['name']}.py"))
+        f"{name}_config_for_compile",
+        os.path.join(bench, "configs", f"{name}.py"))
     config = importlib.util.module_from_spec(found)
     found.loader.exec_module(config)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    batch, k = cell["batch_per_chip"], cell["steps_per_window"]
+    batch, k = batch or cell["batch_per_chip"], cell["steps_per_window"]
     built = config.build("train", batch, sizes)
     main = built["main"]
 
@@ -441,14 +437,148 @@ def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     seen = {r: n - before.get(r, 0)
             for r, n in compile_cache.stats().snapshot().items()
             if r.startswith("route/")}
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes
+             + m.generated_code_size_in_bytes)
+    return seen, total, compiled.as_text()
+
+
+CHIP_BYTES = 16.9e9
+
+
+def test_granite_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """The K-step program of the cell whose configuration holds an
+    ``ssd_scan`` and no experts, lowered on shapes for the described v5e:
+    nine ungated filters, nine ``ssd_scan``s and one grouped attention take
+    the kernels, every layer is a recomputed stretch, and
+    ``memory_analysis()`` puts the step between a quarter and 90 % of the
+    chip's 16.9 GB: the rule the cell's sequence length was chosen by."""
+    seen, total, _ = _cell_step(one_chip, monkeypatch, "granite_4_0_h_micro")
     assert seen["route/ssd_scan:pallas"] == 9
     assert "route/ssd_scan:xla" not in seen
     assert seen["route/short_conv:pallas"] == 9
     assert seen["route/flash_attention:grouped"] == 1
     assert seen["route/flash_attention_bwd:one_pass"] == 1
     assert seen["route/recompute:checkpoint"] == 10
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             - m.alias_size_in_bytes + m.temp_size_in_bytes
-             + m.generated_code_size_in_bytes)
-    assert 0.25 * 16.9e9 < total < 0.9 * 16.9e9, total
+    assert 0.25 * CHIP_BYTES < total < 0.9 * CHIP_BYTES, total
+
+
+# NVIDIA-Nemotron-3-Nano-30B-A3B at the cell's shapes: 8192 positions of
+# 2688 features; 8 un-gated experts of 1856 held under a 128-wide router, 6 a
+# token; Mamba-2 at 8 groups of 128, chunks of 128; 32 query heads over 2
+# key / value heads of 128
+NEMO_T, NEMO_D, NEMO_H, NEMO_HELD, NEMO_TOP = 8192, 2688, 1856, 8, 6
+NEMO_ROWS = (NEMO_T * NEMO_TOP // TILE + NEMO_HELD) * TILE
+
+
+@pytest.mark.parametrize("transposed", [True, False],
+                         ids=["held [E, H, D]", "held [E, D, H]"])
+def test_ungated_experts_compile_at_1856_columns(spec, transposed):
+    """The un-gated grouped products at an expert width that is 14.5 lane
+    tiles (``_largest_tile`` finds no 128-multiple divisor of 1856, so a
+    weight block is the whole [2688, 1856], 20 MB of ``GMM_VMEM_BYTES``
+    double-buffered, and ``_tgmm``'s accumulator [896, 1856]): forward, the
+    rows' gradient and the stacks' gradient of up and of down, six kernels,
+    Mosaic takes them with the up stack held either way.  Held [E, D, H] it
+    compiles too, but the device would lay that array out with D minor and
+    copy it for the kernel (the whole step below holds no such array)."""
+    relu2 = moe_ops._ACTS["relu2"]
+    layout = (spec((NEMO_ROWS // TILE,), jnp.int32), spec((1,), jnp.int32))
+
+    def loss(lhs, up, down, tile_group, num_tiles):
+        hidden = relu2(pallas_kernels._grouped(
+            lhs, up, tile_group, num_tiles, False, transposed))
+        out = pallas_kernels._grouped(hidden, down, tile_group, num_tiles,
+                                      False)
+        return jnp.sum(out * out)
+
+    up = (NEMO_HELD, NEMO_H, NEMO_D) if transposed else \
+        (NEMO_HELD, NEMO_D, NEMO_H)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec((NEMO_ROWS, NEMO_D)), spec(up),
+        spec((NEMO_HELD, NEMO_H, NEMO_D)), *layout).compile()
+    assert _kernels(compiled.as_text()) == 6
+
+
+def test_ssd_scan_kernels_compile_at_eight_groups(spec):
+    """``ssd_scan`` at the Nemotron cell's shape (one 8192-token sequence,
+    64 heads of 64 over 8 groups of 128 state features, chunks of 128),
+    value and all six gradients: a head block is one group's eight heads
+    (512 lanes), Bm and Cm are read through the group's index map, one
+    custom call a direction."""
+    from paddle_tpu.ops import ssd_kernels
+
+    b, heads, p, groups, n, chunk = 1, 64, 64, 8, 128, 128
+    shapes = ((b, NEMO_T, heads, p), (b, NEMO_T, groups, n))
+    assert ssd_kernels.heads_a_block(heads, groups, p) * p \
+        == ssd_kernels.SSD_BLOCK_LANES
+    operands = (spec(shapes[0]), spec((b, NEMO_T, heads)), spec((heads,)),
+                spec(shapes[1]), spec(shapes[1]), spec((heads,)))
+
+    def loss(*xs):
+        return jnp.sum(ssd_kernels.ssd_scan(*xs, chunk) ** 2)
+
+    forward = jax.jit(lambda *xs: ssd_kernels.ssd_scan(*xs, chunk)).lower(
+        *operands).compile()
+    assert _kernels(forward.as_text()) == 1
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))) \
+        .lower(*operands).compile()
+    assert _kernels(compiled.as_text()) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 5 * b * NEMO_T * heads * p * 4
+
+
+def test_attention_at_two_kv_heads_of_128_takes_the_two_pass_backward(spec):
+    """32 query heads over 2 key / value heads of 128 at 8192 positions:
+    dK / dV of a sequence no longer fit the one-pass kernel (``_one_pass_fits``
+    admits 5 120 keys of 128 features), so the backward is the two kernels,
+    inside a program as alone; three custom calls, dK and dV at the 2 heads
+    they have."""
+    before = dict(compile_cache.stats().snapshot())
+    text = _attention_grads(spec, 1, NEMO_T, 32, 2, 128)
+    after = compile_cache.stats().snapshot()
+    assert after.get("route/flash_attention_bwd:two_pass", 0) \
+        - before.get("route/flash_attention_bwd:two_pass", 0) == 1
+    assert after.get("route/flash_attention_bwd:one_pass", 0) \
+        == before.get("route/flash_attention_bwd:one_pass", 0)
+    assert _kernels(text) == 3
+    assert not re.search(rf"f32\[1,{NEMO_T},32,128\]\S* broadcast\(", text)
+
+
+def test_nemotron_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """The K-step program of the cell whose configuration holds sparse
+    experts beside ``ssd_scan``: four filters and four ``ssd_scan``s at eight
+    groups, the grouped attention with its two-pass backward and four
+    ``moe`` ops (a share, un-gated, a shared expert each) take the kernels;
+    six of the nine layers are recomputed stretches, the least that fits;
+    ``memory_analysis()`` puts the step between a quarter and 90 % of the
+    chip's 16.9 GB.  The up stacks are held [8, 1856, 2688] and the module
+    holds no [8, 2688, 1856] array at all: held that way the scan's carried
+    state stood a second time, lane-padded, beside its arguments (12 x 165
+    MB) and the step read 15.31 GB with EVERY layer recomputed."""
+    name = "nemotron_3_nano_30b_a3b"
+    seen, total, text = _cell_step(one_chip, monkeypatch, name)
+    assert seen["route/ssd_scan:pallas"] == 4
+    assert seen["route/short_conv:pallas"] == 4
+    assert seen["route/flash_attention:grouped"] == 1
+    assert seen["route/flash_attention_bwd:two_pass"] == 1
+    for route in ("dropless", "sigmoid", "share", "single", "shared"):
+        assert seen["route/moe:" + route] == 4, route
+    assert seen["route/moe_rows:tiles"] == 4
+    assert seen["route/recompute:checkpoint"] == 6
+    assert not {r for r, n in seen.items() if n and r.endswith(
+        (":xla", ":gated_pair", ":reference"))}
+    assert 0.25 * CHIP_BYTES < total < 0.9 * CHIP_BYTES, total
+    assert f"[{NEMO_HELD},{NEMO_D},{NEMO_H}]" not in text
+    assert f"f32[{NEMO_HELD},{NEMO_H},{NEMO_D}]" in text
+
+
+def test_two_nemotron_sequences_do_not_fit_with_every_layer_recomputed(
+        one_chip, monkeypatch):
+    """Two 8192-token sequences a step, every layer a recomputed stretch
+    (the most the program can give back): over 90 % of the chip, which is
+    why the cell runs one."""
+    _, total, _ = _cell_step(one_chip, monkeypatch, "nemotron_3_nano_30b_a3b",
+                             batch=2, recompute=True)
+    assert total > 0.9 * CHIP_BYTES, total
