@@ -206,14 +206,16 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
     ``tiled[r] = xt[token(r)]``, a tile a step of a kernel that stops at
     ``num_tiles``; the gradient is tokens-from-rows, ``d_xt[token(r)] +=
     d_tiled[r]`` over the same tiles.  experts: act(x Wg) * (x Wu) through
-    Wd (no Wg: act(x Wu) Wd, two grouped products a direction, counted
-    ``route/moe:single``) as grouped products; with Wg, gate and up are
-    ONE paired product a direction (``gated_grouped_matmul``: the rows read
-    once for both stacks, ``act(gate) * up`` in the forward kernel's
-    epilogue, the two gradients of the rows summed inside one kernel),
-    counted at trace time as ``route/moe:gated_pair``, so the stage is six
-    kernels forward and backward.  combine: tokens-from-rows with the
-    weights, ``out[token(r)] += w(r) * down[r]``, its gradient
+    Wd (no Wg: act(x Wu) Wd, counted ``route/moe:single``) as grouped
+    products; with Wg, gate and up are ONE paired product a direction
+    (``gated_grouped_matmul``: the rows read once for both stacks, the two
+    gradients of the rows summed inside one kernel), counted at trace time
+    as ``route/moe:gated_pair``.  Either form's activation is the epilogue
+    of its forward kernel and its derivative a kernel of the backward pass,
+    so the stage is kernels alone, two forward and five backward, their
+    grids ending at ``num_tiles``, and XLA computes nothing over a tiled
+    array.  combine: tokens-from-rows
+    with the weights, ``out[token(r)] += w(r) * down[r]``, its gradient
     rows-from-tokens of the cotangent with ``w(r)`` and the weights' own
     gradient made on the same tile.  Where a call holds a share,
     tokens-from-rows is a scatter-add over the tiles in use, in tile order
@@ -232,12 +234,12 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
     would have added is left out, and nothing stands in for their chips.
     The tile count stays the static worst case (every assignment lands
     here; no token is dropped), and every stage stops at ``num_tiles``: the
-    grouped kernels skip the tiles past it, rows-from-tokens starts no copy
+    grouped kernels' grids end there, rows-from-tokens starts no copy
     for them and tokens-from-rows' loop ends before them, so time goes with
     the rows held and memory with the bound, in dispatch and combine as in
     the experts.  Past ``num_tiles`` the tiled arrays hold nothing anyone
-    may read (``rows`` and the cotangent of ``down`` are not even written
-    there; the grouped kernels' own outputs are zero).
+    may read: no stage writes them, forward or backward (``rows``, the
+    experts' products and every cotangent alike).
 
     ``up_transposed``: the un-gated up stack is held [E, H, D] and read
     transposed (``grouped_matmul``; ``layers.moe`` says when).
@@ -319,14 +321,11 @@ def _dropless(xt, gate_w, w_gate, w_up, w_down, top_k, act,
         index = (assignment, token, row_of, num_tiles)
         rows = _dispatch(xt, index, tm, all_held)
     with jax.named_scope("moe.experts"):
-        if w_gate is None:
-            compile_cache.stats().bump("route/moe:single")
-            hidden = act(grouped_matmul(rows, w_up, tile_group, num_tiles,
-                                        transpose_rhs=up_transposed))
-        else:
-            compile_cache.stats().bump("route/moe:gated_pair")
-            hidden = gated_grouped_matmul(rows, w_gate, w_up, tile_group,
-                                          num_tiles, act)
+        compile_cache.stats().bump(
+            "route/moe:" + ("single" if w_gate is None else "gated_pair"))
+        hidden = gated_grouped_matmul(rows, w_gate, w_up, tile_group,
+                                      num_tiles, act,
+                                      transpose_rhs=up_transposed)
         down = grouped_matmul(hidden, w_down, tile_group, num_tiles)
     with jax.named_scope("moe.combine"):
         out = _combine(down, weight, index, tm, all_held)

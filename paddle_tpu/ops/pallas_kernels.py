@@ -677,9 +677,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 # Rows sorted by group, every group padded to whole row tiles (at least
 # one), so a tile belongs to one group and no store is masked:
 # ``tile_group[i]`` names tile i's group, ``num_tiles[0]`` the tiles in use
-# (the length is the static worst case).  Past the count an OPERAND's tiles
-# are never read (``rows_from_tokens`` does not write them) and a RESULT's
-# are stored as zeros (XLA's elementwise work between kernels reads those).
+# (the length is the static worst case).  Past the count NOTHING is touched.
+# The row axis of every grid ENDS at ``num_tiles[0]`` (a grid bound may be a
+# value), so no grid step exists for a tile past it: an operand's tiles
+# there are never read, a result's never written (as ``rows_from_tokens``
+# leaves its own), and no elementwise work of XLA's stands between two
+# kernels to read them: the experts' activation is the forward kernel's
+# epilogue, and its derivative a kernel over the tiles in use (``_d_pre``).
 # A product is a tiled matmul whose weight block, the whole contraction, is
 # picked by ``tile_group`` and stays in VMEM over a group: one read from HBM.
 #
@@ -703,7 +707,14 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 # experts stage 28.7 -> 23.7 in six kernels for nine: of the pair's three,
 # forward with ``act(gate) * up`` in its epilogue 4.64 ms (two single ones
 # and the XLA fusion: 6.1), the rows' gradient 4.54 (two and XLA's add:
-# 7.5), both stacks' gradients 5.02 (two: 5.6).  PERF.md section 6, PR 31)
+# 7.5), both stacks' gradients 5.02 (two: 5.6).  PERF.md section 6, PR 31.
+# A share of 8 experts held, forward and backward, PR 41: at 28 of 392
+# tiles in use and un-gated [1856, 2688] stacks the stage 8.94 -> 3.25 ms,
+# at 68 of 264 and a [2048, 1792] pair 9.01 -> 5.83: of it the grids that
+# end at the count 0.6 / 0.4 ms over grids of the bound whose steps past
+# the count start no copy; the derivative in the prologue of the backward
+# products instead of a kernel of its own 0.0 / 0.3 ms less, for 298 MB
+# more where every expert is held.  PERF.md section 6, PR 41)
 GMM_BLOCK_ELEMS = 1 << 21        # a weight / gradient block: 8 MiB of f32
 GMM_VMEM_BYTES = 64 << 20        # scoped VMEM these kernels may take
 
@@ -740,8 +751,9 @@ def _mxu_dot(a, b, contract, interpret):
 
 
 def _tile_in_use(i, count):
-    """Row-tile index for a block spec: an unused tile (``i`` past the
-    count) re-reads the last one in use, which costs no copy."""
+    """Row-tile index for a block spec of a grid over the static bound
+    (``rows_from_tokens``'): an unused tile (``i`` past the count) re-reads
+    the last one in use, which costs no copy."""
     return jnp.minimum(i, count[0] - 1)
 
 
@@ -752,122 +764,140 @@ def _gmm_call_params(interpret, *semantics):
         dimension_semantics=semantics, vmem_limit_bytes=GMM_VMEM_BYTES)}
 
 
-def _gmm_kernel(group_ref, count_ref, *refs, stacks, transpose_rhs, act,
-                interpret):
+def _activated(act, pre, up=None):
+    """The experts' elementwise function of their first product(s):
+    ``act(pre)``, gated ``act(pre) * up``."""
+    return act(pre) if up is None else act(pre) * up
+
+
+def _d_pre_kernel(g_ref, *refs, act):
+    """``refs``: the tiles of the products before ``act``, then the blocks
+    of their gradients."""
+    pre_refs, out_refs = refs[:len(refs) // 2], refs[len(refs) // 2:]
+    _, vjp = jax.vjp(functools.partial(_activated, act),
+                     *(ref[...] for ref in pre_refs))
+    for out_ref, d in zip(out_refs, vjp(g_ref[...])):
+        out_ref[...] = d
+
+
+def _d_pre(g, pre, tile_group, num_tiles, act, interpret):
+    """The gradients of ``_activated(act, *pre)`` under the cotangent ``g``
+    on the row tiles in use: ``jax.vjp`` of the function itself on a tile,
+    so every elementwise ``act`` has its derivative here.  Rows past
+    ``num_tiles`` are not written.
+    (results written over ``pre``, ``input_output_aliases``: 298 MB MORE of
+    temporaries at OLMoE's shape as XLA assigns them, not less)"""
+    rows, n = g.shape
+    tile = pl.BlockSpec((rows // tile_group.shape[0], n), lambda i: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_d_pre_kernel, act=act),
+        out_shape=[_sds(g, g.shape, g.dtype)] * len(pre),
+        grid=(num_tiles[0],),
+        in_specs=[tile] * (1 + len(pre)), out_specs=[tile] * len(pre),
+        **_gmm_call_params(interpret, "parallel"),
+    )(g, *pre)
+
+
+def _gmm_kernel(group_ref, *refs, stacks, transpose_rhs, act, interpret):
     """``refs``: the row tile, the stacks' weight blocks, their outputs and,
-    with ``act``, one more for ``act(first output) * second``.  With
+    with ``act``, one more for ``_activated(act, *outputs)``.  With
     ``transpose_rhs``: the stacks' row tiles, their weight blocks, the ONE
-    output that sums the stacks' products."""
-    i = pl.program_id(1)
+    output that sums the stacks' products (and ``act`` of it)."""
     lhs_refs = refs[:stacks if transpose_rhs else 1]
     rhs_refs = refs[len(lhs_refs):len(lhs_refs) + stacks]
     out_refs = refs[len(lhs_refs) + stacks:]
-
-    @pl.when(i < count_ref[0])
-    def _compute():
-        if transpose_rhs:
-            outs = [functools.reduce(operator.add, (
-                _mxu_dot(lhs_ref[...], rhs_ref[0], ((1,), (1,)), interpret)
-                for lhs_ref, rhs_ref in zip(lhs_refs, rhs_refs)))]
-        else:
-            lhs = _mxu_operand(lhs_refs[0][...], interpret)
-            outs = [_mxu_dot(lhs, rhs_ref[0], ((1,), (0,)), interpret)
-                    .astype(out_refs[0].dtype) for rhs_ref in rhs_refs]
-            if act is not None:      # of the values as stored, which the
-                outs.append(act(outs[0]) * outs[1])   # backward reads
-        for out_ref, out in zip(out_refs, outs):
-            out_ref[...] = out.astype(out_ref.dtype)
-
-    @pl.when(i >= count_ref[0])
-    def _unused():
-        for out_ref in out_refs:
-            out_ref[...] = jnp.zeros_like(out_ref)
+    if transpose_rhs:
+        outs = [functools.reduce(operator.add, (
+            _mxu_dot(lhs_ref[...], rhs_ref[0], ((1,), (1,)), interpret)
+            for lhs_ref, rhs_ref in zip(lhs_refs, rhs_refs))
+        ).astype(out_refs[0].dtype)]
+    else:
+        lhs = _mxu_operand(lhs_refs[0][...], interpret)
+        outs = [_mxu_dot(lhs, rhs_ref[0], ((1,), (0,)), interpret)
+                .astype(out_refs[0].dtype) for rhs_ref in rhs_refs]
+    if act is not None:              # of the values as stored, which the
+        outs.append(_activated(act, *outs))            # backward reads
+    for out_ref, out in zip(out_refs, outs):
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
 def _gmm(lhs, rhs, tile_group, num_tiles, transpose_rhs, interpret,
          act=None):
     """``outs[s][r] = lhs[0][r] @ rhs[s][group of r's tile]`` for the stacks
-    ``rhs`` (a tuple of [G, K, N]) and the one ``lhs`` (a 1-tuple), and with
-    ``act`` (two stacks) a third, ``act(outs[0]) * outs[1]``, from the
-    kernel's epilogue.  With ``transpose_rhs`` the 1-tuple of
-    ``sum_s lhs[s][r] @ rhs[s][..].T`` (``rhs[s]`` [G, N, K], one ``lhs`` a
-    stack).  Grid (column blocks, row tiles), rows innermost, so a weight
-    block changes only where the group does."""
+    ``rhs`` (a tuple of [G, K, N]) and the one ``lhs`` (a 1-tuple).  With
+    ``transpose_rhs`` the 1-tuple of ``sum_s lhs[s][r] @ rhs[s][..].T``
+    (``rhs[s]`` [G, N, K], one ``lhs`` a stack).  With ``act`` one more
+    result from the kernel's epilogue, ``_activated(act, *outs)``.  Grid
+    (column blocks, row tiles in use), rows innermost, so a weight block
+    changes only where the group does.  Rows past ``num_tiles`` are not
+    written."""
     rows, k = lhs[0].shape
     tm = rows // tile_group.shape[0]
     n = rhs[0].shape[1] if transpose_rhs else rhs[0].shape[2]
     tn = _largest_tile(n, max(128, GMM_BLOCK_ELEMS // k))
 
-    row = _tile_in_use
-    lhs_spec = pl.BlockSpec((tm, k), lambda j, i, group, count:
-                            (row(i, count), 0))
+    lhs_spec = pl.BlockSpec((tm, k), lambda j, i, group: (i, 0))
     rhs_spec = pl.BlockSpec(
-        (1, tn, k), lambda j, i, group, count: (group[row(i, count)], j, 0)
+        (1, tn, k), lambda j, i, group: (group[i], j, 0)
     ) if transpose_rhs else pl.BlockSpec(
-        (1, k, tn), lambda j, i, group, count: (group[row(i, count)], 0, j))
-    outs = 1 if transpose_rhs else len(rhs) + (act is not None)
+        (1, k, tn), lambda j, i, group: (group[i], 0, j))
+    outs = (1 if transpose_rhs else len(rhs)) + (act is not None)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, stacks=len(rhs),
                           transpose_rhs=transpose_rhs, act=act,
                           interpret=interpret),
         out_shape=[_sds(lhs[0], (rows, n), lhs[0].dtype)] * outs,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(n // tn, rows // tm),
+            num_scalar_prefetch=1, grid=(n // tn, num_tiles[0]),
             in_specs=[lhs_spec] * len(lhs) + [rhs_spec] * len(rhs),
             out_specs=[pl.BlockSpec(
-                (tm, tn), lambda j, i, group, count: (i, j))] * outs),
+                (tm, tn), lambda j, i, group: (i, j))] * outs),
         **_gmm_call_params(interpret, "parallel", "arbitrary"),
-    )(tile_group, num_tiles, *lhs, *rhs)
+    )(tile_group, *lhs, *rhs)
 
 
-def _tgmm_kernel(group_ref, count_ref, lhs_ref, *refs, interpret):
+def _tgmm_kernel(group_ref, lhs_ref, *refs, interpret):
     """``refs``: the stacks' row tiles (cotangents), their output blocks."""
     stacks = len(refs) // 2
     i = pl.program_id(2)
-    # (an unused tile repeats the last group: neither first nor computed)
-    first = jnp.logical_or(
-        i == 0, group_ref[i] != group_ref[jnp.maximum(i - 1, 0)])
 
-    @pl.when(first)
+    @pl.when(jnp.logical_or(
+        i == 0, group_ref[i] != group_ref[jnp.maximum(i - 1, 0)]))
     def _init():
         for out_ref in refs[stacks:]:
             out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(i < count_ref[0])
-    def _compute():
-        lhs = _mxu_operand(lhs_ref[...], interpret)
-        for rhs_ref, out_ref in zip(refs[:stacks], refs[stacks:]):
-            out_ref[0] += _mxu_dot(lhs, rhs_ref[...], ((0,), (0,)),
-                                   interpret).astype(out_ref.dtype)
+    lhs = _mxu_operand(lhs_ref[...], interpret)
+    for rhs_ref, out_ref in zip(refs[:stacks], refs[stacks:]):
+        out_ref[0] += _mxu_dot(lhs, rhs_ref[...], ((0,), (0,)),
+                               interpret).astype(out_ref.dtype)
 
 
 def _tgmm(lhs, rhs, tile_group, num_tiles, groups, interpret):
     """``outs[s][g] = lhs[rows of g].T @ rhs[s][rows of g]``: a [G, K, N] for
     each of the tuple ``rhs`` of [R, N], from the one [R, K].  Grid (K
-    blocks, N blocks, row tiles), rows innermost: a group's output blocks
-    stay in VMEM and accumulate over its tiles."""
+    blocks, N blocks, row tiles in use), rows innermost: a group's output
+    blocks stay in VMEM and accumulate over its tiles."""
     rows, k = lhs.shape
     n = rhs[0].shape[1]
     tm = rows // tile_group.shape[0]
     tk = _largest_tile(k, 1024)
     tn = _largest_tile(n, max(128, GMM_BLOCK_ELEMS // tk))
 
-    row = _tile_in_use
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, interpret=interpret),
         out_shape=[_sds(lhs, (groups, k, n), lhs.dtype)] * len(rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(k // tk, n // tn, rows // tm),
+            num_scalar_prefetch=1, grid=(k // tk, n // tn, num_tiles[0]),
             in_specs=[
-                pl.BlockSpec((tm, tk), lambda a, b, i, group, count:
-                             (row(i, count), a))] + [
-                pl.BlockSpec((tm, tn), lambda a, b, i, group, count:
-                             (row(i, count), b))] * len(rhs),
+                pl.BlockSpec((tm, tk), lambda a, b, i, group: (i, a))] + [
+                pl.BlockSpec((tm, tn), lambda a, b, i, group: (i, b))
+            ] * len(rhs),
             out_specs=[pl.BlockSpec(
-                (1, tk, tn), lambda a, b, i, group, count:
-                (group[row(i, count)], a, b))] * len(rhs)),
+                (1, tk, tn), lambda a, b, i, group:
+                (group[i], a, b))] * len(rhs)),
         **_gmm_call_params(interpret, "parallel", "parallel", "arbitrary"),
-    )(tile_group, num_tiles, lhs, *rhs)
+    )(tile_group, lhs, *rhs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -900,7 +930,8 @@ def grouped_matmul(lhs, rhs, tile_group, num_tiles, transpose_rhs=False):
     """``out[r] = lhs[r] @ rhs[tile_group[r // tm]]`` for rows laid out in
     whole tiles per group (see above): ``lhs`` [R, K], ``rhs`` [G, K, N],
     ``tile_group`` int32 [R / tm] non-decreasing with every group present,
-    ``num_tiles`` int32 [1].  With ``transpose_rhs`` the stack is [G, N, K]
+    ``num_tiles`` int32 [1]; the rows of the result past ``num_tiles`` are
+    not written.  With ``transpose_rhs`` the stack is [G, N, K]
     and read transposed, ``lhs[r] @ rhs[..].T``: the same three kernels, in
     other places (a stack whose N is no whole lane tiles is held so, with K
     on the lanes: the device lays a [G, K, N] array out with K minor, a
@@ -912,25 +943,32 @@ def grouped_matmul(lhs, rhs, tile_group, num_tiles, transpose_rhs=False):
                     jax.default_backend() != "tpu", transpose_rhs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _gated(rows, w_gate, w_up, tile_group, num_tiles, act, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _gated(rows, w_gate, w_up, tile_group, num_tiles, act, interpret,
+           transpose_rhs=False):
     return _gated_vjp_fwd(rows, w_gate, w_up, tile_group, num_tiles, act,
-                          interpret)[0]
+                          interpret, transpose_rhs)[0]
 
 
 def _gated_vjp_fwd(rows, w_gate, w_up, tile_group, num_tiles, act,
-                   interpret):
-    gate, up, hidden = _gmm((rows,), (w_gate, w_up), tile_group, num_tiles,
-                            False, interpret, act)
-    return hidden, (rows, w_gate, w_up, tile_group, num_tiles, gate, up)
+                   interpret, transpose_rhs=False):
+    """``w_gate`` None: un-gated experts, one stack (``transpose_rhs``: held
+    [G, N, K]); the residuals are the products before ``act``."""
+    stacks = (w_up,) if w_gate is None else (w_gate, w_up)
+    *pre, hidden = _gmm((rows,), stacks, tile_group, num_tiles,
+                        transpose_rhs, interpret, act)
+    return hidden, (rows, stacks, tile_group, num_tiles, tuple(pre))
 
 
-def _gated_vjp_bwd(act, interpret, res, g):
-    rows, w_gate, w_up, tile_group, num_tiles, gate, up = res
-    d_pre = jax.vjp(lambda a, b: act(a) * b, gate, up)[1](g)
-    return (*_gmm(d_pre, (w_gate, w_up), tile_group, num_tiles, True,
+def _gated_vjp_bwd(act, interpret, transpose_rhs, res, g):
+    rows, stacks, tile_group, num_tiles, pre = res
+    d_pre = _d_pre(g, pre, tile_group, num_tiles, act, interpret)
+    # (a stack's gradient in the stack's own orientation, as ``_grouped``'s)
+    a, b = (d_pre[0], (rows,)) if transpose_rhs else (rows, d_pre)
+    return (*_gmm(d_pre, stacks, tile_group, num_tiles, not transpose_rhs,
                   interpret),
-            *_tgmm(rows, d_pre, tile_group, num_tiles, w_up.shape[0],
+            *([None] * (2 - len(stacks))),
+            *_tgmm(a, b, tile_group, num_tiles, stacks[0].shape[0],
                    interpret),
             None, None)
 
@@ -938,15 +976,23 @@ def _gated_vjp_bwd(act, interpret, res, g):
 _gated.defvjp(_gated_vjp_fwd, _gated_vjp_bwd)
 
 
-def gated_grouped_matmul(rows, w_gate, w_up, tile_group, num_tiles, act):
+def gated_grouped_matmul(rows, w_gate, w_up, tile_group, num_tiles, act,
+                         transpose_rhs=False):
     """``act(rows @ w_gate[g]) * (rows @ w_up[g])`` in ``grouped_matmul``'s
     layout, gate and up as ONE pass a direction: a tile of ``rows`` is read
     once for both stacks, forward and in the stacks' gradients, and the
     gradient of ``rows`` is summed over the two stacks inside its kernel.
-    ``act`` is an elementwise function; differentiable in ``rows`` and both
-    stacks."""
+    ``w_gate`` None: un-gated experts, ``act(rows @ w_up[g])`` (with
+    ``transpose_rhs`` the stack is [G, N, K], as ``grouped_matmul``'s).
+    ``act``, an elementwise function, is the forward kernel's epilogue and
+    its derivative a kernel over the tiles in use (``_d_pre``), so nothing
+    of XLA's reads a row past ``num_tiles``.
+    Differentiable in ``rows`` and the stacks."""
+    if transpose_rhs and w_gate is not None:
+        raise ValueError("gated_grouped_matmul: only an un-gated stack is "
+                         "read transposed")
     return _gated(rows, w_gate, w_up, tile_group, num_tiles, act,
-                  jax.default_backend() != "tpu")
+                  jax.default_backend() != "tpu", transpose_rhs)
 
 
 # ---------------------------------------------------------------------------

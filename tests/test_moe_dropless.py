@@ -33,18 +33,20 @@ def _choice(w, top_k):
                       kind="stable")[:, :top_k]
 
 
-def _oracle(choice, w, top_k):
+def _oracle(choice, w, top_k, here=None):
     """(out, aux, z): a Python loop over tokens and the experts ``choice``
-    gives them."""
+    gives them, those of the range ``here`` where a share is held."""
     x, router = jnp.asarray(w["x"]), jnp.asarray(w["router"])
     n, e = x.shape[0], router.shape[1]
     logits = x @ router
     probs = jax.nn.softmax(logits, axis=-1)
     rows, count = [], np.zeros(e)
     for t in range(n):
-        acc = 0.0
+        acc = jnp.zeros(w["down"].shape[-1], jnp.float32)
         for ex in choice[t]:
             count[ex] += 1
+            if here is not None and ex not in here:
+                continue
             u = x[t] @ w["up"][ex]
             hid = jax.nn.silu(u) if w["gate"] is None \
                 else jax.nn.silu(x[t] @ w["gate"][ex]) * u
@@ -325,6 +327,24 @@ def _check_combine(case):
                                rtol=1e-6, atol=1e-6)
 
 
+def _share_of_the_op(case, gated):
+    """(weights, mix, system) of a case: ``_dropless`` over the experts the
+    case holds, the router the identity so that ``x`` IS the logits."""
+    n, e, held, first, _, _ = ROWS_CASES[case]
+    rng = np.random.RandomState(9)
+    w = _weights(rng, n, e, 6, e, gated)
+    w.update(x=_logits(case), router=np.eye(e, dtype="float32"))
+    mix = jnp.asarray(rng.randn(n, e).astype("float32"))
+
+    def system(w, top_k):
+        return moe_ops._dropless(
+            *(jnp.asarray(w[k]) for k in ("x", "router")),
+            *(None if w[k] is None else jnp.asarray(w[k])[first:first + held]
+              for k in ("gate", "up", "down")),
+            top_k, jax.nn.silu, expert_offset=first)
+    return w, mix, system
+
+
 def _check_poison(case, monkeypatch):
     """The op with NaN in every tiled array past ``num_tiles`` (the rows
     handed to the experts, what they hand back, and the cotangents the
@@ -352,26 +372,15 @@ def _check_poison(case, monkeypatch):
             return past(clean["single"](
                 past(lhs, num_tiles), rhs, tile_group, num_tiles), num_tiles)
 
-        def gated(rows, w_gate, w_up, tile_group, num_tiles, act):
+        def gated(rows, w_gate, w_up, tile_group, num_tiles, act, **how):
             return past(clean["gated"](
                 past(rows, num_tiles), w_gate, w_up, tile_group, num_tiles,
-                act), num_tiles)
+                act, **how), num_tiles)
 
         with monkeypatch.context() as m:
             m.setattr(pallas_kernels, "grouped_matmul", single)
             m.setattr(pallas_kernels, "gated_grouped_matmul", gated)
-            rng = np.random.RandomState(9)
-            here = slice(first, first + held)
-            w = _weights(rng, n, e, 6, e, True)
-            w.update(x=_logits(case), router=np.eye(e, dtype="float32"))
-            mix = jnp.asarray(rng.randn(n, e).astype("float32"))
-
-            def system(w, top_k):
-                return moe_ops._dropless(
-                    *(jnp.asarray(w[k]) for k in ("x", "router")),
-                    *(jnp.asarray(w[k])[here] for k in ("gate", "up",
-                                                        "down")),
-                    top_k, jax.nn.silu, expert_offset=first)
+            w, mix, system = _share_of_the_op(case, True)
             return _loss_and_grads(system, w, top_k, mix)
 
     (out, aux, z), grads = run(0.0)        # what the tiles hold anyway
@@ -383,17 +392,81 @@ def _check_poison(case, monkeypatch):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("check", ["dispatch", "combine", "poison"])
+def _check_unwritten(case, gated, monkeypatch):
+    """The op as it is, its kernels interpreted (the interpreter fills a
+    result with NaN before a kernel runs): every tiled array that enters or
+    leaves the experts, forward and backward, is NaN past ``num_tiles``,
+    and output, losses and every gradient are finite and the token loop's:
+    no kernel stores there and nothing relies on what stands there."""
+    from paddle_tpu.ops import pallas_kernels
+
+    n, e, held, first, top_k, _ = ROWS_CASES[case]
+    seen = {}
+
+    def spy(name):
+        """The identity that shows its argument, and on the way back its
+        cotangent, to the test."""
+        @jax.custom_vjp
+        def show(a):
+            jax.debug.callback(lambda v: seen.__setitem__(name, v), a)
+            return a
+
+        def back(_, g):
+            jax.debug.callback(lambda v: seen.__setitem__("d " + name, v), g)
+            return (g,)
+
+        show.defvjp(lambda a: (show(a), None), back)
+        return show
+
+    def spied(fn, name):
+        def call(lhs, *rest, **kwargs):
+            return spy(name)(fn(spy("rows of " + name)(lhs), *rest, **kwargs))
+        return call
+
+    monkeypatch.setattr(pallas_kernels, "grouped_matmul", spied(
+        pallas_kernels.grouped_matmul, "down"))
+    monkeypatch.setattr(pallas_kernels, "gated_grouped_matmul", spied(
+        pallas_kernels.gated_grouped_matmul, "hidden"))
+    w, mix, system = _share_of_the_op(case, gated)
+    (out, aux, z), grads = jax.block_until_ready(
+        _loss_and_grads(system, w, top_k, mix))
+    (ref_out, ref_aux, ref_z), refs = _loss_and_grads(
+        functools.partial(_oracle, _choice(w, top_k),
+                          here=range(first, first + held)), w, top_k, mix)
+    # (the skewed cases' inputs reach 40 and the outputs hundreds)
+    np.testing.assert_allclose(out, ref_out, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(aux, ref_aux, rtol=1e-5)
+    np.testing.assert_allclose(z, ref_z, rtol=1e-5)
+    assert set(grads) == {"x", "router", "up", "down"} | (
+        {"gate"} if gated else set())
+    for name in grads:
+        assert np.isfinite(grads[name]).all(), name
+        np.testing.assert_allclose(grads[name], refs[name], rtol=1e-4,
+                                   atol=1e-5, err_msg=name)
+    used = int(_index(case)[3][0]) * ROWS_TILE
+    assert set(seen) == {a + b for a in ("", "d ") for b in (
+        "rows of hidden", "hidden", "rows of down", "down")}
+    for name, tiled in seen.items():
+        assert np.isfinite(tiled[:used]).all(), name
+        assert np.isnan(tiled[used:]).all(), name
+
+
+@pytest.mark.parametrize("check", ["dispatch", "combine", "poison",
+                                   "unwritten-gated", "unwritten-single"])
 @pytest.mark.parametrize("case", sorted(ROWS_CASES))
 def test_rows_move_with_the_tiles_in_use(case, check, monkeypatch):
     """The two primitives of ``_dropless``'s row movement and their
     gradients against the ``jnp.take`` formulas they replaced (bit for bit
     where no sum changed its order, to 1e-6 where one did), in tiles of 8
     rows and passes of 3 tiles so that a last pass overlaps the one before;
-    every tiled array holds NaN past ``num_tiles``."""
+    every tiled array holds NaN past ``num_tiles``: put there (``poison``),
+    or left there by the interpreter because no kernel, the experts' among
+    them, stores past the count (``unwritten``, a case each form)."""
     monkeypatch.setattr(moe_ops, "ROW_TILE", ROWS_TILE)
     monkeypatch.setattr(moe_ops, "TILE_SPAN", ROWS_SPAN)
     if check == "poison":
         _check_poison(case, monkeypatch)
+    elif check.startswith("unwritten"):
+        _check_unwritten(case, check.endswith("gated"), monkeypatch)
     else:
         {"dispatch": _check_dispatch, "combine": _check_combine}[check](case)

@@ -241,10 +241,12 @@ def test_ssd_scan_names_its_five_stages_inside_its_op_scope():
 
 def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
     """Lowered for the TPU (no chip needed to LOWER), the experts stage of a
-    gated ``moe`` op is six Pallas custom calls: the gate/up pair forward
+    gated ``moe`` op is seven Pallas custom calls: the gate/up pair forward
     (gate, up and ``act(gate) * up``), ``down`` forward, and backward the
-    pair's gradient of the rows (two cotangents and two stacks in), the
-    pair's gradient of both stacks (two results) and ``down``'s two.  All carry
+    derivative of ``act(gate) * up`` (the cotangent, gate and up in, the
+    two cotangents out), the pair's gradient of the rows (two cotangents
+    and two stacks in), the pair's gradient of both stacks (two results)
+    and ``down``'s two.  All carry
     ``pt.moe:<b>.<p>/moe.experts`` in their location, which is how
     ``moe_step_ms``, ``moe_experts_roofline_pct`` and the stages' table of
     a traced run find them.  The two calls of ``rows_from_tokens`` stay
@@ -280,10 +282,12 @@ def test_gated_experts_kernels_sit_inside_the_experts_stage(monkeypatch):
     fwd = "/jvp(pt.moe:0.3)/moe.experts/pallas_call"
     bwd = "/transpose(jvp(pt.moe:0.3))/moe.experts/pallas_call"
     inner = "pallas_call"      # inside the jitted ``_rows_call``
-    # (operands with the layout's two, results, where)
+    # (operands with the layout's two, the tiles in use as the grid's bound
+    # and ``tile_group``, of which the derivative takes the bound alone;
+    # results; where)
     assert sorted(calls) == sorted([
-        (5, 3, fwd), (4, 1, fwd), (6, 1, bwd), (5, 2, bwd), (4, 1, bwd),
-        (4, 1, bwd), (3, 1, inner), (5, 2, inner)])
+        (5, 3, fwd), (4, 1, fwd), (4, 2, bwd), (6, 1, bwd), (5, 2, bwd),
+        (4, 1, bwd), (4, 1, bwd), (3, 1, inner), (5, 2, inner)])
     # the row kernels' call sites carry the stage (XLA inlines the calls
     # and joins the names, as for ``rope``)
     sites = sorted(locs[loc][locs[loc].index("/"):] for loc in re.findall(

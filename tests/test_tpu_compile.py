@@ -71,13 +71,15 @@ def test_grouped_matmul_compiles_at_olmoe_widths(spec, layout):
 
 
 @pytest.mark.parametrize("pair,kernels,row_results,row_sums", [
-    (True, 6, 2, 0), (False, 9, 3, 1)])
+    (True, 7, 2, 0), (False, 9, 3, 1)])
 def test_gated_experts_take_six_kernels_and_no_sum_of_row_gradients(
         spec, layout, pair, kernels, row_results, row_sums):
     """The experts stage of a gated ``moe`` op at OLMoE's widths, gradient
-    and all: gate and up as the pair (``_gated``) are three kernels, two
+    and all: gate and up as the pair (``_gated``) are three products, two
     weight blocks each inside ``GMM_VMEM_BYTES``, and with ``down``'s three
-    the program holds six where two ``grouped_matmul`` calls made nine.  Of
+    the program holds six where two ``grouped_matmul`` calls made nine; a
+    seventh kernel is the derivative of ``act(gate) * up`` over the tiles
+    in use, which XLA computes over all of them in the nine's program.  Of
     [rows, d] kernel results it holds ``down``'s output and ONE gradient of
     the rows, so no second one exists and nothing adds two of them."""
     def loss(lhs, w_gate, w_up, w_down, tile_group, num_tiles):
@@ -295,7 +297,7 @@ def test_dropless_moves_rows_with_the_tiles_in_use(
     """``_dropless``, value and every gradient, compiles for the v5e at
     both cells' shapes, its row movement driven from the tiled side: no XLA
     gather makes a bound-sized array ([33792, 2048], [73728, 2048]; the
-    rows come from ``rows_from_tokens``, two more kernels than the six of
+    rows come from ``rows_from_tokens``, two more kernels than the seven of
     the experts), and where a share is held (LFM2) ``moe.combine`` holds no
     [top_k, N, D] array either way ([4, 8192, 2048], [32768, 2048]: the
     tokens' sum is a scatter-add over the tiles in use, counted
@@ -306,7 +308,10 @@ def test_dropless_moves_rows_with_the_tiles_in_use(
     more, and nothing else); OLMoE 2 125 192 192 for 1 823 088 640 (the
     same exchange, 67 MB, and that ``picked``'s 537 MB had found room in a
     gradient's output buffer where ``down``'s 604 MB do not: in the cell's
-    whole step the difference is the 67 MB, 12.233 GB for 12.161)."""
+    whole step the difference is the 67 MB, 12.233 GB for 12.161).  Since
+    PR 41 (the derivative of the activation a kernel over the tiles in use
+    where XLA's fusion stood): LFM2's share 1 560 313 856, OLMoE
+    2 125 159 936; the limits are PR 35's."""
     n, d = 8192, LFM2_D
     bound = (n * top_k // TILE + held) * TILE
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -324,7 +329,7 @@ def test_dropless_moves_rows_with_the_tiles_in_use(
     form = "route/moe_rows:" + ("tiles" if held < experts else "take")
     assert compile_cache.stats().snapshot()[form] == counted.get(form, 0) + 1
     text = compiled.as_text()
-    assert _kernels(text) == 8
+    assert _kernels(text) == 9
     assert not re.search(rf"f32\[{bound},{d}\]\S* gather\(", text)
     if held < experts:
         picked = rf"f32\[({top_k},{n}|{top_k * n}),{d}\]"
@@ -499,6 +504,52 @@ def test_ungated_experts_compile_at_1856_columns(spec, transposed):
         spec((NEMO_ROWS, NEMO_D)), spec(up),
         spec((NEMO_HELD, NEMO_H, NEMO_D)), *layout).compile()
     assert _kernels(compiled.as_text()) == 6
+
+
+@pytest.mark.parametrize(
+    "d,width,experts,top_k,gated,act,route", [
+        (NEMO_D, NEMO_H, 128, NEMO_TOP, False, "relu2",
+         {"scoring": "sigmoid", "renormalize": True, "routed_scale": 2.5,
+          "up_transposed": True}),
+        (LFM2_D, 1792, 32, 4, True, "silu",
+         {"scoring": "sigmoid", "renormalize": True})],
+    ids=["nemotron [8, 1856, 2688] transposed", "lfm2 [8, 2048, 1792] pair"])
+def test_the_experts_stage_is_kernels_over_the_tiles_in_use(
+        spec, monkeypatch, d, width, experts, top_k, gated, act, route):
+    """``_dropless`` with a share of 8 experts held, value and every
+    gradient, at the two cells' shapes (8192 tokens): the forward product
+    with the activation in its epilogue, the derivative's kernel and the
+    two backward products compile within ``GMM_VMEM_BYTES``, their grids'
+    row axis ending at ``num_tiles``, Nemotron's up stack read transposed;
+    seven kernels of the experts (``down``'s three among them) and two of
+    the row movement.  Every instruction under
+    ``moe.experts``, forward and backward, is a kernel (or the pick of one
+    of its results): XLA computes nothing there, and no fusion of the module
+    makes an array of the bound's [rows, H], which would read rows past
+    ``num_tiles`` that no kernel writes."""
+    n, held = NEMO_T, NEMO_HELD
+    bound = (n * top_k // TILE + held) * TILE
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(x, router, stacks, mix):
+        out, aux, z = moe_ops._dropless(
+            x, router, stacks.get("gate"), stacks["up"], stacks["down"],
+            top_k, moe_ops._ACTS[act], **route)
+        return jnp.sum(out * mix) + aux + z
+
+    up = (held, width, d) if route.get("up_transposed") else (held, d, width)
+    stacks = {"up": spec(up), "down": spec((held, width, d)),
+              **({"gate": spec(up)} if gated else {})}
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec((n, d)), spec((d, experts)), stacks,
+        spec((n, d))).compile().as_text()
+    assert _kernels(text) == 9
+    inside = [re.match(r"\s*(?:ROOT )?\S+ = .*? ([\w-]+)\(", line).group(1)
+              for line in text.splitlines()
+              if re.search(r'op_name="[^"]*moe\.experts', line)]
+    assert inside.count("custom-call") == 7
+    assert set(inside) <= {"custom-call", "get-tuple-element"}, inside
+    assert not re.search(rf"f32\[{bound},{width}\]\S* fusion\(", text)
 
 
 def test_ssd_scan_kernels_compile_at_eight_groups(spec):
