@@ -171,18 +171,18 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 #   key block) tile once and makes dq, dk and dv from one recomputation of
 #   the scores: five products, one exponential and one mask a tile.  dk and
 #   dv of a K/V head's whole sequence stay in VMEM, so it runs where they fit
-#   ``FLASH_BWD_VMEM_BYTES`` beside a tile's temporaries (at 1024 x 1024
-#   blocks: up to 5 120 keys of 128 features, 13 312 of 64; at 512 x 512,
-#   12 800 of 128).
+#   ``FLASH_BWD_VMEM_BYTES`` beside what else it holds (at 1024 x 1024 blocks
+#   up to 9 216 keys of 64 or of 128 features; at 512 x 512, 13 824).
 # * TWO kernels (``_flash_bwd_dq_kernel``, ``_flash_bwd_dkv_kernel``), each
 #   recomputing the scores (seven products, two exponentials a tile) with
 #   O(block) VMEM: every longer sequence, and blocks a row of lse cannot be
 #   cut into.
 #
-# Every call asks for ``FLASH_BWD_VMEM_BYTES`` of scoped VMEM: four [1024,
-# 1024] float32 temporaries alone are the default 16 MiB, and the one kernel
-# at 8192 keys of 64 features takes 19.6 MB inside a step.  No more than
-# that: asked for 64 MiB the same kernel ran 6 % slower, alone and in a step.
+# Every call asks for ``FLASH_BWD_VMEM_BYTES`` of scoped VMEM.  What Mosaic
+# asks for the one kernel at 1024 x 1024 blocks, compiling for a v5e with
+# every operand in HBM: 13.6 MiB at 2048 keys, 19.6 at 4096, 27.6 at 8192,
+# 35.6 at 12 288 (26.6 inside Nemotron's step).  No more than 32 MiB: asked
+# for 64 the same kernel ran 6 % slower at 8192 keys, alone and in a step.
 # (on the v5e, the kernels alone, ms forward / two kernels / one, causal,
 # 1024 x 1024 blocks: 32 query over 8 K/V heads of 64 at T 8192 4.9 / 15.8 /
 # 9.0; 2 x 16 heads of 128 at T 4096 1.79 / 4.63 / 2.84; 16 heads 0.71 /
@@ -363,15 +363,15 @@ def _flash_bwd_one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _one_pass_fits(Tq, Tk, D, Dv, block_q, block_k):
     """Whether the one-pass backward takes this call, from the static shapes
-    and the blocks alone: what it holds in VMEM (dk and dv of one K/V head's
-    whole sequence in both pipeline buffers; four [block_q, block_k] float32
-    temporaries; the streamed blocks q, do, dq and k, v, double-buffered)
-    within ``FLASH_BWD_VMEM_BYTES``, and a row of lse that Mosaic can cut
-    into blocks."""
-    resident = 2 * Tk * (D + Dv) * 4
-    tile = 4 * block_q * block_k * 4
-    streamed = 2 * (block_q * (2 * D + Dv) + block_k * (D + Dv)) * 4
-    return (resident + tile + streamed <= FLASH_BWD_VMEM_BYTES
+    and the blocks alone: what Mosaic holds for it in VMEM (dk, dv of a K/V
+    head's whole sequence and the blocks q, do, dq, lse, delta, k, v in both
+    pipeline buffers; the dq accumulator; two float32 tiles) within
+    ``FLASH_BWD_VMEM_BYTES``, and a row of lse it can cut into blocks."""
+    d, dv = -(-D // 128) * 128, -(-Dv // 128) * 128    # whole lane tiles
+    resident = 2 * Tk * (d + dv) * 4
+    streamed = 2 * (block_q * (2 * d + dv + 2) + block_k * (d + dv)) * 4
+    held = block_q * d * 4 + 2 * block_q * block_k * 4
+    return (resident + streamed + held <= FLASH_BWD_VMEM_BYTES
             and (block_q % 128 == 0 or block_q == Tq))
 
 
@@ -612,8 +612,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     The backward is one kernel that visits each (query block, key block)
     tile once where dK and dV of a K/V head's whole sequence fit VMEM
     beside a tile's temporaries (``_one_pass_fits``: from Tq, Tk, the
-    feature sizes and the blocks alone; at 1024 x 1024 blocks up to 5 120
-    keys of 128 features or 13 312 of 64), and the two kernels that each
+    feature sizes and the blocks alone; at 1024 x 1024 blocks up to 9 216
+    keys of 64 or 128 features), and the two kernels that each
     recompute the scores for longer sequences; counted at trace time as
     ``route/flash_attention_bwd:{one_pass,two_pass}``.
 

@@ -196,6 +196,9 @@ BWD_CASES = {
                    "one_pass"),
     "lse_grouped_full": (4, 2, 128, 256, 16, 32, False, (128, 128), True,
                          "one_pass"),
+    # Nemotron's grouping in small: 16 query heads to one K/V head of 128
+    "grouped_16_causal": (16, 1, 512, 512, 128, 128, True, (128, 128),
+                          False, "one_pass"),
     # a row of lse cannot be cut into blocks of 32: the two kernels
     "small_blocks": (4, 2, 128, 128, 16, 16, True, (32, 32), False,
                      "two_pass"),
@@ -246,11 +249,16 @@ def test_flash_backward_routes_match_reference(case):
 
 
 @pytest.mark.parametrize("Tq,Tk,D,Dv,blocks,fits", [
-    (8192, 8192, 64, 64, (1024, 1024), True),        # lfm2-train-scan
+    (8192, 8192, 64, 64, (1024, 1024), True),        # lfm2-, granite-
     (4096, 4096, 128, 128, (1024, 1024), True),      # olmoe-, ouro-
-    (5120, 5120, 128, 128, (1024, 1024), True),
-    (8192, 8192, 128, 128, (1024, 1024), False),
-    (8192, 8192, 128, 128, (512, 512), True),
+    (8192, 8192, 128, 128, (1024, 1024), True),      # nemotron-train-scan
+    (9216, 9216, 128, 128, (1024, 1024), True),      # the longest at 1024
+    (10240, 10240, 128, 128, (1024, 1024), False),
+    (9216, 9216, 64, 64, (1024, 1024), True),        # 64 features take
+    (10240, 10240, 64, 64, (1024, 1024), False),     # the lanes 128 do
+    (12288, 12288, 64, 64, (1024, 1024), False),     # Mosaic asks 35.6 MiB
+    (13824, 13824, 128, 128, (512, 512), True),      # the longest at 512
+    (14336, 14336, 128, 128, (512, 512), False),
     (32768, 32768, 128, 128, (1024, 1024), False),   # benchmark/longctx.py
     (65536, 65536, 128, 128, (1024, 1024), False),
     (65536, 65536, 64, 64, (512, 512), False),

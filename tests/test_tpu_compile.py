@@ -272,14 +272,43 @@ def test_attention_compiles_at_ouro_and_olmoe_heads(spec, batch):
                                      with_value=True)) == 2
 
 
-@pytest.mark.parametrize("seq,kernels", [(5120, 2), (8192, 3), (32768, 3)])
+@pytest.mark.parametrize("seq,kernels", [(5120, 2), (16384, 3), (32768, 3)])
 def test_long_sequences_keep_the_two_backward_kernels(spec, seq, kernels):
     """Where dK and dV of a whole sequence no longer fit the one-pass
-    kernel's VMEM beside a tile's temporaries (``benchmark/longctx.py``:
-    32k and 64k positions of 128 features) the backward is the two
-    kernels; the longest sequence the rule admits at these blocks
-    compiles."""
+    kernel's VMEM beside what else it holds (``benchmark/longctx.py``: 32k
+    and 64k positions of 128 features) the backward is the two kernels."""
     assert _kernels(_attention_grads(spec, 1, seq, 2, 2, 128)) == kernels
+
+
+def _longest_one_pass(d, block=1024):
+    return max(t for t in range(block, 65536 + 1, block)
+               if pallas_kernels._one_pass_fits(t, t, d, d, block, block))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("longer,route,kernels", [
+    (0, "one_pass", 2), (1024, "two_pass", 3)], ids=["longest", "next"])
+def test_the_longest_one_pass_sequence_compiles(spec, d, longer, route,
+                                                kernels):
+    """The longest sequence ``_one_pass_fits`` admits at 1024 x 1024 blocks
+    (9 216 keys, of 64 features as of 128: a row takes whole lane tiles)
+    compiles on the one-pass route within ``FLASH_BWD_VMEM_BYTES``, and the
+    next block up takes the two kernels.  At 32 K / V heads XLA keeps no
+    operand or result of the kernel in VMEM beside it (at two heads it keeps
+    dK and dV there, and the kernel then needs 17 MiB less), so the kernel
+    holds all that the rule counts."""
+    heads = 32
+    seq = _longest_one_pass(d) + longer
+    before = dict(compile_cache.stats().snapshot())
+    text = _attention_grads(spec, 1, seq, heads, heads, d)
+    after = compile_cache.stats().snapshot()
+    key = "route/flash_attention_bwd:" + route
+    assert after.get(key, 0) - before.get(key, 0) == 1
+    results = re.findall(rf"= \(((?:f32\[{heads},{seq},{d}\]\S*(?:, )?){{3}})"
+                         r"\) custom-call\(", text)
+    assert len(results) == (route == "one_pass")
+    assert "S(1)" not in "".join(results)
+    assert _kernels(text) == kernels
 
 
 # the dropless ``moe`` lowering at the two cells' shapes: 8192 tokens of 2048
@@ -580,27 +609,34 @@ def test_ssd_scan_kernels_compile_at_eight_groups(spec):
         < 5 * b * NEMO_T * heads * p * 4
 
 
-def test_attention_at_two_kv_heads_of_128_takes_the_two_pass_backward(spec):
+def test_attention_at_two_kv_heads_of_128_takes_the_one_pass_backward(spec):
     """32 query heads over 2 key / value heads of 128 at 8192 positions:
-    dK / dV of a sequence no longer fit the one-pass kernel (``_one_pass_fits``
-    admits 5 120 keys of 128 features), so the backward is the two kernels,
-    inside a program as alone; three custom calls, dK and dV at the 2 heads
-    they have."""
+    dK / dV of a sequence fit the one-pass kernel beside what else it holds
+    (``_one_pass_fits`` admits 9 216 keys of 128 features; Mosaic asks 26.6
+    MiB inside Nemotron's step), so the backward is one kernel; TWO custom
+    calls, dK and dV at the 2 heads they have, no K / V broadcast to 32."""
+    heads, kv_heads, d = 32, 2, 128
     before = dict(compile_cache.stats().snapshot())
-    text = _attention_grads(spec, 1, NEMO_T, 32, 2, 128)
+    text = _attention_grads(spec, 1, NEMO_T, heads, kv_heads, d)
     after = compile_cache.stats().snapshot()
-    assert after.get("route/flash_attention_bwd:two_pass", 0) \
-        - before.get("route/flash_attention_bwd:two_pass", 0) == 1
     assert after.get("route/flash_attention_bwd:one_pass", 0) \
-        == before.get("route/flash_attention_bwd:one_pass", 0)
-    assert _kernels(text) == 3
-    assert not re.search(rf"f32\[1,{NEMO_T},32,128\]\S* broadcast\(", text)
+        - before.get("route/flash_attention_bwd:one_pass", 0) == 1
+    assert after.get("route/flash_attention_bwd:two_pass", 0) \
+        == before.get("route/flash_attention_bwd:two_pass", 0)
+    assert _kernels(text) == 2
+    results = re.findall(
+        rf"\(f32\[{heads},{NEMO_T},{d}\]\S*, "
+        rf"f32\[{kv_heads},{NEMO_T},{d}\]\S*, "
+        rf"f32\[{kv_heads},{NEMO_T},{d}\]\S*\) custom-call\(", text)
+    assert len(results) == 1
+    assert not re.search(rf"f32\[1,{NEMO_T},{heads},{d}\]\S* broadcast\(",
+                         text)
 
 
 def test_nemotron_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     """The K-step program of the cell whose configuration holds sparse
     experts beside ``ssd_scan``: four filters and four ``ssd_scan``s at eight
-    groups, the grouped attention with its two-pass backward and four
+    groups, the grouped attention with its one-pass backward and four
     ``moe`` ops (a share, un-gated, a shared expert each) take the kernels;
     six of the nine layers are recomputed stretches, the least that fits;
     ``memory_analysis()`` puts the step between a quarter and 90 % of the
@@ -613,7 +649,8 @@ def test_nemotron_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     assert seen["route/ssd_scan:pallas"] == 4
     assert seen["route/short_conv:pallas"] == 4
     assert seen["route/flash_attention:grouped"] == 1
-    assert seen["route/flash_attention_bwd:two_pass"] == 1
+    assert seen["route/flash_attention_bwd:one_pass"] == 1
+    assert seen.get("route/flash_attention_bwd:two_pass", 0) == 0
     for route in ("dropless", "sigmoid", "share", "single", "shared"):
         assert seen["route/moe:" + route] == 4, route
     assert seen["route/moe_rows:tiles"] == 4
