@@ -17,11 +17,6 @@ dir; pass ``--cache-dir`` to keep them for replay via
 ``PADDLE_TPU_AUTOTUNE=1``), proving the full search → persist → replay
 loop in one run.
 
-Device-side tunables cannot be searched in this container (no TPU);
-their rows are pending-hardware stubs carrying the pre-registered
-decision rules (the PR 1 convention) — the first chip session runs
-``python -m paddle_tpu tune <target>`` and fills them.
-
 Usage:
     python benchmark/autotune.py              # full A/B, writes
                                               # autotune_results.json
@@ -44,8 +39,6 @@ RESULTS_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 HOST_TUNABLES = ("executor/run_pipelined", "serving/batcher",
                  "reader/prefetch")
-DEVICE_TUNABLES = ("pallas/flash_attention", "pallas/conv1x1_blocks",
-                   "xla/scoped_vmem_limit_kib")
 
 
 def run_one(name: str, store_dir: str, smoke: bool, quiet: bool = False):
@@ -120,12 +113,6 @@ def main():
 
     rows = [run_one(n, store_dir, smoke=args.smoke) for n in names]
 
-    from paddle_tpu.tuning import search as _search
-    from paddle_tpu.tuning import targets as _targets
-    for n in DEVICE_TUNABLES:
-        _targets.ensure_registered(n)
-    pending = [_search.pending_stub(n) for n in DEVICE_TUNABLES]
-
     out_path = args.json or (None if args.smoke else RESULTS_PATH)
     if out_path:
         from input_pipeline import host_parallel_efficiency
@@ -140,7 +127,6 @@ def main():
             "host_parallel_efficiency": host_parallel_efficiency(),
             "min_speedup_gate": 1.10,
             "rows": rows,
-            "pending_hardware": pending,
         }
         with open(out_path, "w") as f:
             json.dump(doc, f, indent=1)

@@ -266,9 +266,6 @@ def main():
     print(json.dumps(row, indent=1))
     if args.smoke:
         return
-    from paddle_tpu.tuning.search import pending_stub
-    from paddle_tpu.tuning.targets import ensure_registered
-    ensure_registered("pallas/paged_kv_gather")
     result = {
         "benchmark": "decode_continuous_batching",
         "device": "cpu (in-container; no TPU reachable)",
@@ -283,7 +280,6 @@ def main():
                     "the matmul width, so the continuous win should "
                     "widen — commit real rows, never extrapolate these",
             "rows": [],
-            "paged_kv_gather": pending_stub("pallas/paged_kv_gather"),
         },
     }
     with open(args.out, "w") as fh:

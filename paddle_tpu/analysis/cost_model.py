@@ -122,15 +122,10 @@ class CostReport:
     reshard_bytes: float = 0.0             # PT041 conflict charges
     peak_hbm_bytes_per_device: float = 0.0
     op_costs: List[OpCost] = dataclasses.field(default_factory=list)
-    # per-op-CLASS calibrated proxy (measured/predicted ratios from the
-    # opprof profiler applied per op type); None = table constants only
-    calibrated_step_time_s: Optional[float] = None
     peaks: Peaks = NOMINAL
 
     @property
     def step_time_proxy_s(self) -> float:
-        if self.calibrated_step_time_s is not None:
-            return self.calibrated_step_time_s
         return self.peaks.seconds(
             self.flops_per_device, self.hbm_bytes_per_device,
             self.collective_bytes + self.reshard_bytes)
@@ -146,7 +141,6 @@ class CostReport:
             "peak_hbm_bytes_per_device": self.peak_hbm_bytes_per_device,
             "step_time_proxy_s": self.step_time_proxy_s,
             "peaks": self.peaks.name,
-            "calibrated": self.calibrated_step_time_s is not None,
             "top_ops": [
                 {"op": t, "block": b, "index": i,
                  "flops": c.flops, "bytes": c.bytes}
@@ -243,20 +237,9 @@ class _ShapeView:
 def estimate_cost(program, mesh_axes: Dict[str, int],
                   prop: Optional[PropagationResult] = None,
                   shapes=None, assume_batch: int = 64,
-                  batch_axis: str = "dp",
-                  op_class_ratios: Optional[Dict[str, float]] = None
-                  ) -> CostReport:
+                  batch_axis: str = "dp") -> CostReport:
     """Static per-device cost of one training/inference step under the
-    sharding assignment in ``prop`` (replicated everywhere when None).
-
-    ``op_class_ratios`` — measured/predicted correction factors per op
-    TYPE (the opprof calibration table,
-    ``observability.attribution.load_op_class_ratios``): when given, a
-    calibrated proxy replaces the nominal one — each op's compute+HBM
-    term scales by its class ratio (default 1.0), collective/reshard
-    terms stay physical (the ICI model is not what the eager profile
-    measured).  This is the per-op-class successor of the PR 10
-    program-wide scalar ratio."""
+    sharding assignment in ``prop`` (replicated everywhere when None)."""
     from .shape_infer import run_shape_inference
 
     mesh_axes = {k: int(v) for k, v in (mesh_axes or {}).items()}
@@ -363,15 +346,6 @@ def estimate_cost(program, mesh_axes: Dict[str, int],
         moved = max((var_bytes(n, per_device=False)
                      for n in op.input_names), default=0.0)
         report.reshard_bytes += moved
-
-    if op_class_ratios:
-        t = 0.0
-        for c in report.op_costs:
-            ratio = float(op_class_ratios.get(c.loc[2], 1.0))
-            t += ratio * report.peaks.seconds(c.flops, c.bytes) \
-                + c.collective_bytes / report.peaks.ici_bytes_s
-        t += report.reshard_bytes / report.peaks.ici_bytes_s
-        report.calibrated_step_time_s = t
 
     report.peak_hbm_bytes_per_device = _peak_hbm(
         program, lookup, specs, mesh_axes, assume_batch)

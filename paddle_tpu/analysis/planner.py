@@ -284,8 +284,7 @@ def enumerate_candidates(program, mesh_axes: Dict[str, int],
 # Planning
 # ---------------------------------------------------------------------------
 def _score_candidates(program, mesh_axes: Dict[str, int],
-                      batch_axis: str, tp_axis: str, assume_batch: int,
-                      op_class_ratios: Optional[Dict[str, float]] = None):
+                      batch_axis: str, tp_axis: str, assume_batch: int):
     """Propagate + cost every candidate; returns the sorted scored list
     ``[(proxy_s, order, name, param_specs, feed_specs, prop, cost)]``
     best-first."""
@@ -300,48 +299,27 @@ def _score_candidates(program, mesh_axes: Dict[str, int],
         prop = propagate_sharding(program, seeds, shapes=shapes)
         cost = estimate_cost(program, mesh_axes, prop, shapes=shapes,
                              assume_batch=assume_batch,
-                             batch_axis=batch_axis,
-                             op_class_ratios=op_class_ratios)
+                             batch_axis=batch_axis)
         scored.append((cost.step_time_proxy_s, len(scored), name,
                        param_specs, feed_specs, prop, cost))
     scored.sort(key=lambda t: (t[0], t[1]))
     return scored
 
 
-def rank_candidates(program, mesh_axes: Dict[str, int], *,
-                    batch_axis: str = "dp", tp_axis: str = "tp",
-                    assume_batch: int = 64,
-                    op_class_ratios: Optional[Dict[str, float]] = None
-                    ) -> List[Tuple[str, float]]:
-    """``[(candidate_name, step_time_proxy_s)]`` best-first — exactly the
-    scoring :func:`plan` ranks on, exposed so calibration effects
-    (``op_class_ratios`` from the opprof table) are inspectable and
-    testable: a class correction that flips the ranking here flips the
-    shipped plan."""
-    mesh_axes = {str(k): int(v) for k, v in (mesh_axes or {}).items()}
-    return [(name, proxy) for proxy, _, name, *_ in _score_candidates(
-        program, mesh_axes, batch_axis, tp_axis, assume_batch,
-        op_class_ratios)]
-
-
 def plan(program, mesh_axes: Dict[str, int], *, batch_axis: str = "dp",
-         tp_axis: str = "tp", assume_batch: int = 64,
-         op_class_ratios: Optional[Dict[str, float]] = None) -> Plan:
+         tp_axis: str = "tp", assume_batch: int = 64) -> Plan:
     """Propose the cheapest statically-valid sharding plan.
 
     Every candidate is (1) propagated through the IR (PT041/PT042 sites
     feed the cost model's reshard terms), (2) scored by the static cost
-    model — with ``op_class_ratios`` (the opprof per-op-class
-    calibration, ``attribution.load_op_class_ratios``) folded in when
-    given, so measured op-class corrections rank plans instead of the
-    nominal constants alone — and (3) the winner is re-checked against
+    model, and (3) the winner is re-checked against
     the PT030/PT031 spec lints — a plan that fails them is discarded and
     the next-best is taken, so the returned plan always validates clean
     (the ``dp`` fallback cannot fail: batch dims are symbolic).
     """
     mesh_axes = {str(k): int(v) for k, v in (mesh_axes or {}).items()}
     scored = _score_candidates(program, mesh_axes, batch_axis, tp_axis,
-                               assume_batch, op_class_ratios)
+                               assume_batch)
 
     last_err = None
     for _, _, name, param_specs, feed_specs, prop, cost in scored:
@@ -352,11 +330,6 @@ def plan(program, mesh_axes: Dict[str, int], *, batch_axis: str = "dp",
             last_err = report
             continue
         notes = [str(d) for d in prop.report]
-        if op_class_ratios:
-            notes.append(
-                f"ranked with op-class calibration "
-                f"({len(op_class_ratios)} class(es): "
-                f"{', '.join(sorted(op_class_ratios))})")
         return Plan(mesh_axes=mesh_axes, batch_axis=batch_axis,
                     param_specs=dict(param_specs),
                     feed_specs=dict(feed_specs), candidate=name,

@@ -445,34 +445,15 @@ def job_plan(argv):
                     help="print the plan as ONE JSON object only")
     ap.add_argument("--out", default=None,
                     help="also write the plan JSON to this file")
-    ap.add_argument("--calibration", default=None,
-                    help="opprof calibration table (doctor/profile "
-                         "--calibration-out output): rank candidates "
-                         "with its per-op-class measured/predicted "
-                         "ratios instead of the nominal constants alone")
     args = ap.parse_args(argv)
 
     from paddle_tpu.analysis import planner
 
     mesh = _parse_mesh(args.mesh)
     program, _fetch_names = _load_check_target(args.program)
-    ratios = None
-    if args.calibration:
-        from paddle_tpu.observability import attribution
-        try:
-            ratios = attribution.load_op_class_ratios(args.calibration)
-        except (OSError, ValueError) as e:
-            raise SystemExit(f"plan: cannot load calibration "
-                             f"{args.calibration!r}: {e}")
-        if not ratios:
-            # stderr: --json promises ONE JSON object on stdout
-            print("plan: calibration table has no op-class rows; "
-                  "ranking on nominal constants", file=sys.stderr,
-                  flush=True)
     try:
         plan_obj = planner.plan(program, mesh, batch_axis=args.batch_axis,
-                                assume_batch=args.batch,
-                                op_class_ratios=ratios)
+                                assume_batch=args.batch)
     except ValueError as e:
         raise SystemExit(f"plan: {e}")
     if args.out:
@@ -503,16 +484,13 @@ def job_tune(argv):
                     "paired-A/B noise gate), and persist the winner under "
                     "<cache_dir>/tuning/ for trace-time replay via the "
                     "autotune opt-ins (Executor(autotune=True), "
-                    "Trainer.train(autotune=True), PADDLE_TPU_AUTOTUNE=1)."
-                    "  Device-side targets on a host without the "
-                    "accelerator report their pending-hardware stub and "
-                    "pre-registered decision rule instead of searching.")
+                    "Trainer.train(autotune=True), PADDLE_TPU_AUTOTUNE=1).")
     ap.add_argument("target", nargs="?", default=None,
                     help="tunable name (e.g. executor/run_pipelined); "
                          "omit with --list to enumerate")
     ap.add_argument("--list", action="store_true",
-                    help="list registered tunables (spaces, defaults, "
-                         "decision rules) and exit")
+                    help="list registered tunables (spaces, defaults) "
+                         "and exit")
     ap.add_argument("--algo", default="grid", choices=["grid", "halving"],
                     help="search algorithm (default grid; halving for "
                          "large spaces under a tight budget)")
@@ -548,7 +526,7 @@ def job_tune(argv):
                          "table, A/B windows, verdict) to this JSON file")
     args = ap.parse_args(argv)
 
-    from paddle_tpu.core.registry import get_tunable, registered_tunables
+    from paddle_tpu.core.registry import registered_tunables
     from paddle_tpu.tuning import search, targets, tunables
 
     if args.list or args.target is None:
@@ -569,31 +547,23 @@ def job_tune(argv):
     name = args.target
     targets.ensure_registered(name)
     try:
-        entry = get_tunable(name)
+        measure = targets.build_target(name, smoke=args.smoke)
     except KeyError as e:
         raise SystemExit(f"tune: {e}")
-    import jax
-    if entry["side"] == "device" and jax.default_backend() == "cpu":
-        doc = search.pending_stub(name)
-    else:
-        try:
-            measure = targets.build_target(name, smoke=args.smoke)
-        except KeyError as e:
-            raise SystemExit(f"tune: {e}")
 
-        def on_trial(t):
-            print(json.dumps({"trial": t.config, "status": t.status,
-                              "seconds": t.seconds,
-                              "spread_pct": t.spread_pct,
-                              "error": t.error}), flush=True)
+    def on_trial(t):
+        print(json.dumps({"trial": t.config, "status": t.status,
+                          "seconds": t.seconds,
+                          "spread_pct": t.spread_pct,
+                          "error": t.error}), flush=True)
 
-        doc = search.tune(
-            name, measure, algo=args.algo, budget=args.budget,
-            reps=args.reps, pairs=args.pairs,
-            min_speedup=args.min_speedup,
-            trial_timeout_s=args.trial_timeout_s,
-            save=not args.no_save, base=args.cache_dir,
-            on_trial=on_trial)
+    doc = search.tune(
+        name, measure, algo=args.algo, budget=args.budget,
+        reps=args.reps, pairs=args.pairs,
+        min_speedup=args.min_speedup,
+        trial_timeout_s=args.trial_timeout_s,
+        save=not args.no_save, base=args.cache_dir,
+        on_trial=on_trial)
     if args.out:
         try:
             with open(args.out, "w") as f:
@@ -602,8 +572,7 @@ def job_tune(argv):
             raise SystemExit(f"tune: cannot write {args.out!r}: {e}")
     # one summary object on the last line (the trial table is in --out)
     summary = {k: doc.get(k) for k in
-               ("tunable", "status", "winner", "record_path",
-                "decision_rule")
+               ("tunable", "status", "winner", "record_path")
                if doc.get(k) is not None}
     if "ab" in doc:
         summary["speedup"] = doc["ab"]["speedup"]
@@ -748,14 +717,7 @@ def job_doctor(argv):
                          "over (folds into the prediction)")
     ap.add_argument("--calibration-out", default=None,
                     help="merge the calibration row into this JSON "
-                         "table (keyed by program digest; the planner-"
-                         "consumable store).  With --per-op the per-"
-                         "op-class rows merge into the same table")
-    ap.add_argument("--per-op", action="store_true",
-                    help="also run the eager per-op profiler "
-                         "(observability.opprof) on --program and join "
-                         "its measured/modeled table under the step "
-                         "budget — op-level 'where does XLA lose'")
+                         "table (keyed by program digest)")
     ap.add_argument("--json", action="store_true",
                     help="print ONE JSON object only")
     args = ap.parse_args(argv)
@@ -763,133 +725,21 @@ def job_doctor(argv):
     program = None
     if args.program is not None:
         program, _fetch = _load_check_target(args.program)
-    if args.per_op and program is None:
-        ap.error("--per-op needs --program (the eager profiler replays "
-                 "the program op by op)")
     try:
         report = attribution.doctor_report(
             args.log, program=program, assume_batch=args.batch,
             mesh_axes=_parse_mesh(args.mesh))
     except OSError as e:
         raise SystemExit(f"doctor: cannot read log: {e}")
-    per_op = None
-    if args.per_op:
-        from paddle_tpu.observability import opprof
-        per_op = opprof.profile_program(
-            program, batch=args.batch, mesh_axes=_parse_mesh(args.mesh))
-        report["per_op"] = per_op
-    if args.calibration_out:
+    if args.calibration_out and report.get("calibration"):
         try:
-            if report.get("calibration"):
-                attribution.save_calibration([report["calibration"]],
-                                             args.calibration_out)
-            if per_op is not None and per_op.get("op_classes"):
-                attribution.save_op_class_calibration(
-                    per_op["op_classes"], args.calibration_out)
+            attribution.save_calibration([report["calibration"]],
+                                         args.calibration_out)
         except OSError as e:
             raise SystemExit(
                 f"doctor: cannot write {args.calibration_out!r}: {e}")
     if not args.json:
         print(attribution.render_doctor(report), flush=True)
-        if per_op is not None:
-            from paddle_tpu.observability import opprof
-            print(opprof.render_profile(per_op), flush=True)
-    print(json.dumps(report, default=repr), flush=True)
-    return 0
-
-
-def job_profile(argv):
-    """Per-op runtime profiler: measured vs modeled, op by op."""
-    ap = argparse.ArgumentParser(
-        prog="paddle_tpu profile",
-        description="eager per-op profiler + HBM timeline "
-                    "(paddle_tpu.observability.opprof): replay one step "
-                    "of a program op by op with host timers at the "
-                    "compiled step's exact precision, join each op "
-                    "against the static cost model's FLOPs/HBM "
-                    "estimates (roofline verdict, measured/predicted "
-                    "ratio), rank the 'XLA loses here' op classes "
-                    "naming the pre-registered Pallas candidates, and "
-                    "walk the liveness order for the measured live-"
-                    "bytes curve vs the modeled per-device peak.  The "
-                    "per-op table must sum to the eager-replay total "
-                    "within the pinned tolerance or the report says "
-                    "so.  --calibration-out commits the per-op-class "
-                    "calibration table `paddle_tpu plan --calibration` "
-                    "consumes.")
-    ap.add_argument("program", nargs="?", default=None,
-                    help="Program.to_json file, save_inference_model "
-                         "__model__ meta, or a directory containing one")
-    ap.add_argument("--config", default=None,
-                    help="profile a v1 config's TRAINING step instead "
-                         "(minimize_outputs + startup-initialized "
-                         "parameters)")
-    ap.add_argument("--config_args", default=None,
-                    help="k=v,... forwarded to get_config_arg")
-    ap.add_argument("--batch", type=int, default=64,
-                    help="batch for synthesized feeds and the static "
-                         "model's symbolic -1 dims (default 64)")
-    ap.add_argument("--seq-len", type=int, default=8,
-                    help="synthesized sequence length for lod feeds "
-                         "(default 8)")
-    ap.add_argument("--reps", type=int, default=2,
-                    help="timed windows per op (median; default 2)")
-    ap.add_argument("--warmup", type=int, default=1,
-                    help="discarded warmup windows per op (default 1)")
-    ap.add_argument("--top", type=int, default=10,
-                    help="rows in the rendered top-ops table "
-                         "(default 10)")
-    ap.add_argument("--mesh", default=None,
-                    help="axis=size,... folded into the static model's "
-                         "per-device estimates")
-    ap.add_argument("--is-test", action="store_true",
-                    help="profile the inference form of the step")
-    ap.add_argument("--compiled-check", action="store_true",
-                    help="also AOT-compile the step and cross-check "
-                         "the memory view against the executable's "
-                         "memory_analysis (where this jax exposes it)")
-    ap.add_argument("--json", action="store_true",
-                    help="print ONE JSON object only")
-    ap.add_argument("--calibration-out", default=None,
-                    help="merge the per-op-class calibration rows into "
-                         "this JSON table (the planner-consumable "
-                         "store; `paddle_tpu plan --calibration`)")
-    args = ap.parse_args(argv)
-    if (args.program is None) == (args.config is None):
-        ap.error("give exactly one of a program file or --config")
-
-    from paddle_tpu.observability import opprof
-
-    kw = dict(batch=args.batch, seq_len=args.seq_len, reps=args.reps,
-              warmup=args.warmup, top=args.top, is_test=args.is_test,
-              mesh_axes=_parse_mesh(args.mesh),
-              compiled_check=args.compiled_check)
-    if args.config is not None:
-        import paddle_tpu as pt
-        from paddle_tpu.trainer_config_helpers import load_v1_config
-        cfg = load_v1_config(args.config,
-                             **_parse_config_args(args.config_args))
-        cfg.minimize_outputs()
-        exe = pt.Executor()
-        exe.run(cfg.startup_program, feed={}, fetch_list=[])
-        feeds = _synth_feeds(cfg, args.batch, seq_len=args.seq_len)
-        used = _used_feed_names(cfg)
-        feeds = {k: v for k, v in feeds.items() if k in used}
-        report = opprof.profile_program(cfg.main_program, executor=exe,
-                                        feed=feeds, **kw)
-    else:
-        program, _fetch = _load_check_target(args.program)
-        report = opprof.profile_program(program, **kw)
-    if args.calibration_out and report.get("op_classes"):
-        from paddle_tpu.observability import attribution
-        try:
-            attribution.save_op_class_calibration(
-                report["op_classes"], args.calibration_out)
-        except OSError as e:
-            raise SystemExit(
-                f"profile: cannot write {args.calibration_out!r}: {e}")
-    if not args.json:
-        print(opprof.render_profile(report, top=args.top), flush=True)
     print(json.dumps(report, default=repr), flush=True)
     return 0
 
@@ -915,10 +765,6 @@ def main(argv=None):
         # lazy: the attribution engine pulls analysis.cost_model — only
         # the doctor pays for it
         return job_doctor(argv[1:])
-    if argv and argv[0] == "profile":
-        # lazy: the per-op profiler pulls analysis.cost_model AND
-        # tuning.search — only the profiler pays for them
-        return job_profile(argv[1:])
     if argv and argv[0] == "tune":
         # lazy: `import paddle_tpu` must never pull the tuning package
         # (zero-cost-when-unused guard, tier-1 enforced)
@@ -960,11 +806,9 @@ def main(argv=None):
                     "trace "
                     "run.jsonl...` renders span timelines and critical "
                     "paths, `paddle_tpu doctor run.jsonl... [--program "
-                    "prog.json] [--per-op]` explains where the "
+                    "prog.json]` explains where the "
                     "step/request time went and calibrates the cost "
-                    "model, `paddle_tpu profile prog.json` measures "
-                    "every op eagerly against the static model (per-op "
-                    "'where does XLA lose' + HBM timeline), `paddle_tpu "
+                    "model, `paddle_tpu "
                     "tune <target>` searches and persists autotuner "
                     "winners, `paddle_tpu serve --model dir` runs "
                     "the batching inference server over exported "
@@ -978,7 +822,7 @@ def main(argv=None):
                     "--dir dir` runs one sparse parameter-server shard "
                     "behind the batched binary wire protocol (see "
                     "`paddle_tpu check|plan|stats|fleet-stats|trace|"
-                    "doctor|profile|tune|serve|fleet|elastic|pserver "
+                    "doctor|tune|serve|fleet|elastic|pserver "
                     "--help`).")
     ap.add_argument("--config", required=True, help="v1 config file")
     ap.add_argument("--job", default="train",

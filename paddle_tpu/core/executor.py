@@ -52,7 +52,7 @@ from .scope import Scope, global_scope
 # until an autotune opt-in actually replays a winner.
 # ---------------------------------------------------------------------------
 register_tunable(
-    "executor/run_pipelined", side="host",
+    "executor/run_pipelined",
     space={"steps_per_dispatch": (1, 2, 4, 8, 16),
            "prefetch_depth": (1, 2, 4)},
     default={"steps_per_dispatch": 4, "prefetch_depth": 2},
@@ -62,27 +62,6 @@ register_tunable(
                 "prefetch hides staging — both trade memory and tail "
                 "latency, and the right point is workload- and "
                 "host-dependent.")
-
-# XLA's scoped-VMEM budget for Pallas kernels (the knob the PR 1 flash-
-# attention sweep hand-threaded); applied through compiler_options, so a
-# replayed winner is part of the compile-cache fingerprint by
-# construction.  16 MiB is XLA's own default: replay only injects the
-# option when a persisted winner DIFFERS from it.
-_SCOPED_VMEM_DEFAULT_KIB = 16 * 1024
-register_tunable(
-    "xla/scoped_vmem_limit_kib", side="device",
-    space={"scoped_vmem_limit_kib": (16 * 1024, 32 * 1024, 64 * 1024,
-                                     128 * 1024)},
-    default={"scoped_vmem_limit_kib": _SCOPED_VMEM_DEFAULT_KIB},
-    description="xla_tpu_scoped_vmem_limit_kib compiler option: the "
-                "VMEM budget large Pallas blocks (flash-attention 2048-"
-                "row tiles) need beyond the 16 MiB default.",
-    pending_hardware=True,
-    decision_rule="enable a non-default limit only when the on-chip "
-                  "longctx block sweep shows >= 1.10x median step time "
-                  "over the 16 MiB default at the target (tokens, "
-                  "blocks) point, paired-window discipline")
-
 
 # ---------------------------------------------------------------------------
 # Places — the analog of platform::Place (place.h:25-63).  A Place is a
@@ -641,14 +620,11 @@ class Executor:
         # (tier-1 counter-delta assertion).
         self.retry_policy = retry_policy
         # persisted-autotuner replay (paddle_tpu.tuning): tuned call
-        # sites (run_pipelined chunking here; scoped-VMEM compiler
-        # option at compile time) consult the winner store.  None defers
-        # to the `autotune` flag (PADDLE_TPU_AUTOTUNE=1).  Replay NEVER
-        # searches, and with no persisted record every site resolves to
-        # its hand-picked default — byte-identical to autotune off
-        # (tier-1 pins both).  Device-side winners reach the compile
-        # through _effective_compiler_options, so they are part of the
-        # cache fingerprint by construction.
+        # sites (run_pipelined chunking here) consult the winner store.
+        # None defers to the `autotune` flag (PADDLE_TPU_AUTOTUNE=1).
+        # Replay NEVER searches, and with no persisted record every site
+        # resolves to its hand-picked default — byte-identical to
+        # autotune off (tier-1 pins both).
         self.autotune = autotune
         # compiled step variants keyed by CONTENT fingerprint (survives
         # process restarts via the persistent layer; content-identical
@@ -713,29 +689,6 @@ class Executor:
         autotune opt-in, else ``default`` UNCHANGED (the same object).
         The tuning package loads lazily and only on the opted-in path."""
         return resolve_tuned(name, default, self.autotune)
-
-    def _effective_compiler_options(self) -> Dict[str, object]:
-        """compiler_options with device-side tuned winners folded in.
-
-        Feeds BOTH the compile-cache fingerprint (_config_sig) and the
-        actual compile (CachedStep), so a replayed XLA
-        flag can never produce a fingerprint/executable mismatch.  An
-        explicit user-set option always wins; with autotune off, or no
-        record, or a record equal to XLA's own default, this returns
-        ``self.compiler_options`` untouched."""
-        opts = self.compiler_options
-        if not self._autotuning():
-            return opts
-        key = "xla_tpu_scoped_vmem_limit_kib"
-        if key in opts:
-            return opts
-        dflt = {"scoped_vmem_limit_kib": _SCOPED_VMEM_DEFAULT_KIB}
-        cfg = self._tuned("xla/scoped_vmem_limit_kib", dflt)
-        if cfg == dflt:
-            return opts
-        out = dict(opts)
-        out[key] = str(cfg["scoped_vmem_limit_kib"])
-        return out
 
     # -- observability -------------------------------------------------------
     def _observing(self) -> bool:
@@ -1310,7 +1263,7 @@ class Executor:
             return fn
         return compile_cache.CachedStep(
             fn, fingerprint,
-            compiler_options=self._effective_compiler_options(),
+            compiler_options=self.compiler_options,
             label=label, donate=not self.check_nan_inf)
 
     # -- fingerprinting ------------------------------------------------------
@@ -1324,7 +1277,7 @@ class Executor:
         """Executor-configuration component of every cache fingerprint —
         everything on `self` that changes the compiled computation."""
         return (self.use_jit, _specs_sig(self._lowering_options()),
-                _specs_sig(self._effective_compiler_options()))
+                _specs_sig(self.compiler_options))
 
     def _fingerprint_extras(self, program: Program):
         """Subclass hook: extra fingerprint components (ShardedExecutor
@@ -1467,7 +1420,7 @@ class Executor:
     def _lowering_context(options: Dict[str, object], program: Program,
                           base_key, is_test: bool) -> LoweringContext:
         """The ONE construction site (tests/test_repo_lint.py), so that a
-        replay (``observability.opprof``) lowers as the compiled step did."""
+        replay (``observability.nanprov``) lowers as the compiled step did."""
         return LoweringContext(program, base_key, is_test=is_test, **options)
 
     @staticmethod

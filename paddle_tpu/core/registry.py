@@ -192,10 +192,9 @@ def rows_of_one_rank(attrs, ins):
         len({len(o.shape) for o in operands}) == 1
 
 
-def register_tunable(name: str, *, side: str, space: Dict[str, tuple],
-                     default: Dict[str, object], description: str = "",
-                     pending_hardware: bool = False,
-                     decision_rule: str = "") -> dict:
+def register_tunable(name: str, *, space: Dict[str, tuple],
+                     default: Dict[str, object],
+                     description: str = "") -> dict:
     """Declare a named performance knob with a typed search space — the
     autotuner companion of :func:`register_shape_fn`/:func:`register_shard_fn`,
     declared NEXT TO the implementation whose behavior the knob controls
@@ -207,19 +206,12 @@ def register_tunable(name: str, *, side: str, space: Dict[str, tuple],
       string LITERAL at the call site: tests/test_repo_lint.py runs the
       same duplicate-name AST scan + live-registry agreement gate as the
       op/shape/shard registries.
-    * ``side`` — ``"host"`` (searchable in any container: dispatch
-      chunking, reader workers, serving batcher) or ``"device"``
-      (needs the real accelerator: Pallas block configs, XLA flags).
     * ``space`` — ``{param: (candidate, ...)}`` finite typed axes; the
       grid / successive-halving searches enumerate their product.
     * ``default`` — the config shipped today, one value per axis, each a
       member of its axis.  ``tuned(name, default)`` returns exactly this
       object when no persisted winner exists — the byte-identical-when-
       untuned contract pinned by tier-1.
-    * ``pending_hardware`` — device-side entries whose search has not run
-      on a real chip yet; MUST carry a pre-registered ``decision_rule``
-      (the PR 1 convention: the enable threshold is written down before
-      the measurement exists).
 
     Registering is declaration only: nothing here imports the tuning
     package, so training paths that never opt in never load it
@@ -229,9 +221,6 @@ def register_tunable(name: str, *, side: str, space: Dict[str, tuple],
         raise ValueError(f"tunable {name!r} registered twice")
     if "/" not in name:
         raise ValueError(f"tunable {name!r} is not namespaced (sub/name)")
-    if side not in ("host", "device"):
-        raise ValueError(f"tunable {name!r}: side must be 'host' or "
-                         f"'device', got {side!r}")
     if not space:
         raise ValueError(f"tunable {name!r}: empty search space")
     if set(default) != set(space):
@@ -252,15 +241,8 @@ def register_tunable(name: str, *, side: str, space: Dict[str, tuple],
                 f"not in its axis {values} — the search must be able to "
                 f"re-select the shipped config")
         norm[param] = values
-    if pending_hardware and not decision_rule:
-        raise ValueError(
-            f"tunable {name!r}: pending_hardware entries must pre-register "
-            f"a decision_rule (the PR 1 convention: write the enable "
-            f"threshold down before the measurement exists)")
-    entry = {"name": name, "side": side, "space": norm,
-             "default": dict(default), "description": description,
-             "pending_hardware": bool(pending_hardware),
-             "decision_rule": decision_rule}
+    entry = {"name": name, "space": norm, "default": dict(default),
+             "description": description}
     _TUNABLES[name] = entry
     return entry
 
@@ -278,9 +260,9 @@ def resolve_tuned(name: str, default: Dict[str, object],
                   autotune: Optional[bool] = None) -> Dict[str, object]:
     """Call-site replay of a persisted tunable winner — the shared form
     of the per-module resolution copies (reader prefetch, serving
-    batcher, flash-attention blocks, executor dispatch, sparse
-    session).  Returns ``default`` UNCHANGED (the SAME object — the
-    byte-identical-when-untuned contract pinned by tier-1) unless
+    batcher, executor dispatch, sparse session).  Returns ``default``
+    UNCHANGED (the SAME object — the byte-identical-when-untuned
+    contract pinned by tier-1) unless
     autotuning is on, in which case the persisted winner for ``name``
     replaces it.  ``autotune=None`` consults the global ``autotune``
     flag; an explicit bool overrides it (the per-instance opt-ins).

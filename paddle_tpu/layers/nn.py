@@ -1401,7 +1401,7 @@ def autoincreased_step_counter(counter_name=None, begin=1, step=1):
     return counter
 
 
-def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
+def flash_attention(q, k, v, causal=False, block_q=1024, block_k=1024,
                     sequence_parallel=True, interpret=False, name=None):
     """Fused O(T)-memory attention (Pallas kernel on TPU; exact).  q/k/v:
     [B, T, H, D] or [BH, T, D].  The long-context path the reference never
@@ -1417,17 +1417,7 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     K and V may have fewer heads than Q, a whole divisor (query head h
     reads K / V head h // group).
 
-    ``block_q``/``block_k`` default to the swept 1024x1024 tiles — or,
-    when the ``autotune`` flag is on, to the persisted
-    ``pallas/flash_attention`` winner for this topology.  Resolution
-    happens HERE, at graph-build time, so the chosen blocks are op attrs
-    and every compile-cache fingerprint sees them."""
-    if block_q is None or block_k is None:
-        from ..core.registry import resolve_tuned
-        cfg = resolve_tuned("pallas/flash_attention",
-                            {"block_q": 1024, "block_k": 1024})
-        block_q = cfg["block_q"] if block_q is None else block_q
-        block_k = cfg["block_k"] if block_k is None else block_k
+    ``block_q``/``block_k`` default to the swept 1024x1024 tiles."""
     helper = LayerHelper("flash_attention", name=name)
     out_shape = tuple(q.shape[:-1]) + (v.shape[-1],)
     out = helper.create_variable_for_type_inference(q.dtype, out_shape)

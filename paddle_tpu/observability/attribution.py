@@ -39,8 +39,7 @@ from typing import Dict, List, Optional, Tuple
 __all__ = [
     "BUDGET_TOLERANCE", "step_budget", "serving_budget", "decode_budget",
     "remote_budget", "executable_facts", "calibration_row",
-    "save_calibration", "save_op_class_calibration",
-    "load_op_class_ratios", "doctor_report", "render_doctor",
+    "save_calibration", "doctor_report", "render_doctor",
 ]
 
 # Budget components must reconcile with the measured wall within this
@@ -170,9 +169,7 @@ _HINTS = {
                   "AOT-compile with Executor.compile() / "
                   "Trainer.train(warmup=True)",
     "compute_ms": "compute-bound {pct}%: the chip is the bottleneck — "
-                  "tune device knobs (`python -m paddle_tpu tune "
-                  "xla/scoped_vmem_limit_kib`) or shard "
-                  "(`python -m paddle_tpu plan`)",
+                  "shard (`python -m paddle_tpu plan`)",
 }
 
 
@@ -460,12 +457,14 @@ def calibration_row(program, measured_step_ms: float,
     return row
 
 
-def _read_calibration_doc(path: str) -> dict:
-    """Existing table -> {"programs": {...}, "op_classes": {...}}
-    (tolerates the PR 10 format-1 layout and a bare programs map)."""
+def save_calibration(rows: List[dict], path: str) -> dict:
+    """Merge per-program calibration rows into a JSON table keyed by
+    program digest (atomic rewrite); returns the merged table.  Reads
+    the format-1 layout and a bare programs map too; any other section
+    of an existing table is dropped."""
     import json
+    import os
     programs: Dict[str, dict] = {}
-    op_classes: Dict[str, dict] = {}
     try:
         with open(path) as f:
             prev = json.load(f)
@@ -473,72 +472,16 @@ def _read_calibration_doc(path: str) -> dict:
             p = prev.get("programs", prev)
             if isinstance(p, dict):
                 programs.update(p)
-            if isinstance(prev.get("op_classes"), dict):
-                op_classes.update(prev["op_classes"])
     except (OSError, ValueError):
         pass   # first write, or an unreadable table: start fresh
-    return {"programs": programs, "op_classes": op_classes}
-
-
-def _write_calibration_doc(doc: dict, path: str) -> dict:
-    import json
-    import os
-    out = {"format": 2, "programs": doc["programs"]}
-    if doc.get("op_classes"):
-        out["op_classes"] = doc["op_classes"]
+    for row in rows:
+        programs[row["program"]] = row
+    out = {"format": 2, "programs": programs}
     tmp = f"{path}.tmp.{os.getpid()}"
     with open(tmp, "w") as f:
         json.dump(out, f, indent=1, sort_keys=True)
     os.replace(tmp, path)
     return out
-
-
-def save_calibration(rows: List[dict], path: str) -> dict:
-    """Merge per-program calibration rows into a JSON table keyed by
-    program digest (atomic rewrite, op-class rows preserved); returns
-    the merged table."""
-    doc = _read_calibration_doc(path)
-    for row in rows:
-        doc["programs"][row["program"]] = row
-    return _write_calibration_doc(doc, path)
-
-
-def save_op_class_calibration(rows: List[dict], path: str) -> dict:
-    """Merge per-op-CLASS rows (``opprof.op_class_rows`` output — the
-    calibration_row schema extended with ``op_type``) into the same
-    table under ``op_classes``, keyed ``<digest>:<op_type>`` so
-    re-profiling a program overwrites its classes instead of
-    accumulating duplicates.  The per-program rows are preserved —
-    one file carries both granularities for the planner."""
-    doc = _read_calibration_doc(path)
-    for row in rows:
-        doc["op_classes"][f"{row['program']}:{row['op_type']}"] = row
-    return _write_calibration_doc(doc, path)
-
-
-def load_op_class_ratios(table) -> Dict[str, float]:
-    """Per-op-TYPE correction ratios for the planner
-    (``analysis.planner.plan(op_class_ratios=...)``): the MEDIAN
-    measured/predicted ratio per op type across every program in the
-    table's ``op_classes`` section.  ``table`` is a path or an
-    already-loaded dict; {} when the table has no op-class rows (the
-    planner then ranks on the uncorrected nominal constants)."""
-    import json
-    import statistics
-    if isinstance(table, (str, bytes)) or hasattr(table, "__fspath__"):
-        with open(table) as f:
-            table = json.load(f)
-    if not isinstance(table, dict):
-        raise ValueError("calibration table must be a JSON object")
-    by_type: Dict[str, List[float]] = {}
-    for row in (table.get("op_classes") or {}).values():
-        if not isinstance(row, dict) or "op_type" not in row:
-            continue   # foreign/hand-edited rows must not crash the load
-        r = row.get("ratio")
-        if isinstance(r, (int, float)) and r > 0:
-            by_type.setdefault(str(row["op_type"]), []).append(float(r))
-    return {t: float(statistics.median(rs))
-            for t, rs in sorted(by_type.items())}
 
 
 # ---------------------------------------------------------------------------

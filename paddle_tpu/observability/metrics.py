@@ -206,15 +206,6 @@ METRIC_NAMES = (
      "relaunch)"),
     ("elastic/resize_ms", "histogram",
      "wall time of one resize boundary: drain start to workers relaunched"),
-    # per-op profiler (observability.opprof): writes are cold paths by
-    # construction — a profile run IS the workload, like tuning; training
-    # paths never reach these helpers (opprof is lazy-import gated)
-    ("opprof/runs", "counter",
-     "per-op profile runs executed (profile CLI / doctor --per-op)"),
-    ("opprof/ops", "counter",
-     "ops measured by the per-op profiler (one per op per run)"),
-    ("opprof/op_ms", "histogram",
-     "measured per-op eager wall time (median of timed windows)"),
     # sparse parameter server (paddle_tpu.sparse): the session resolves
     # an observe switch ONCE at construction (obs.enabled() unless
     # overridden) and only writes when observing — training paths that
@@ -304,7 +295,7 @@ METRIC_NAMES = (
      "malformed/truncated/unknown-version trace contexts rejected at a "
      "wire rim (ignored-and-counted: the request still serves)"),
     # fleet metrics collector (observability.collector, lazy-import
-    # gated like attribution/opprof): only written inside collector
+    # gated like attribution): only written inside collector
     # merges — a fleet-stats run IS the workload
     ("collector/merges", "counter",
      "fleet snapshot merges executed by the metrics collector"),
@@ -370,7 +361,6 @@ HISTOGRAM_BUCKETS: Dict[str, Tuple[float, ...]] = {
     "serving/decode_inter_token_ms": _MS_BUCKETS,
     "tuning/trial_ms": _MS_BUCKETS,
     "http/request_ms": _MS_BUCKETS,
-    "opprof/op_ms": _MS_BUCKETS,
     "elastic/resize_ms": _MS_BUCKETS,
     "sparse/pull_ms": _MS_BUCKETS,
     "sparse/push_ms": _MS_BUCKETS,

@@ -31,11 +31,9 @@ def make_eager_context(executor, program, feed_arrays, state, step: int,
     """``(env, ctx, bw_idx)`` for an eager per-op replay of one step: the
     executor's own prologue (core/executor.py ``_step_prologue``, the one
     the compiled step traces) on concrete arrays, plus the position of the
-    ``backward`` op.  Shared by the NaN bisect here and the per-op
-    profiler (``observability.opprof``) so both replay at the SAME
-    precision the compiled step computed at — a diagnosis or a per-op
-    timing taken at another precision would describe a different
-    computation."""
+    ``backward`` op.  The NaN bisect here is its one caller: it replays at
+    the SAME precision the compiled step computed at — a diagnosis taken
+    at another precision would describe a different computation."""
     import jax.numpy as jnp
 
     env, ctx = executor._step_prologue(

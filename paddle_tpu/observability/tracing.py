@@ -91,8 +91,6 @@ SPAN_NAMES = (
     ("fleet/autoscale", "one executed autoscaler decision: trigger "
      "snapshot -> replica added or drained+removed; decision details "
      "attach as span events"),
-    ("opprof/op", "one op's measured windows in a per-op profile run "
-     "(observability.opprof eager replay); labels: op_type, index"),
     ("elastic/resize", "one committed mesh resize boundary of the "
      "elastic training service: drain -> merge replicas -> re-plan -> "
      "re-shard -> relaunch; phase completions attach as span events"),

@@ -14,32 +14,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ..core.registry import register_op, register_tunable
-
-# Pre-registered Pallas expansion candidate (ROADMAP item 5): the
-# optimizer step is pure memory traffic — every param/moment leaf is
-# read and written once with trivial arithmetic — so XLA's per-op
-# kernels pay one HBM round-trip per leaf per tensor.  The candidate is
-# ONE fused Pallas kernel sweeping all leaves (flattened+concatenated
-# views, one grid).  Declared pending-hardware so the first chip session
-# measures it for free (`python -m paddle_tpu tune
-# pallas/fused_optimizer_update`); the opprof 'XLA loses here' report
-# references this rule id when optimizer-update op classes dominate a
-# measured profile.
-register_tunable(
-    "pallas/fused_optimizer_update", side="device",
-    space={"fused": (False, True), "block_elems": (4096, 8192, 16384)},
-    default={"fused": False, "block_elems": 8192},
-    description="fuse the per-leaf optimizer update ops (sgd/momentum/"
-                "adam/... families) into one Pallas kernel over all "
-                "param leaves; block_elems is the per-grid-step slab",
-    pending_hardware=True,
-    decision_rule="flip fused=True only when an on-chip paired A/B over "
-                  "a real training step (benchmark/opprof.py workloads) "
-                  "shows >= 1.10x median step time with >= 75% of pairs "
-                  "favoring, AND the opprof per-op table attributes "
-                  ">= 10% of measured step time to optimizer-update op "
-                  "classes (otherwise the fusion cannot pay)")
+from ..core.registry import register_op
 
 
 def _lr(ins):

@@ -188,27 +188,6 @@ def _mm(a, b, ta, tb, block_m, block_n, block_k, interpret,
     return res if (out_stats or a_colsum) else res[0]
 
 
-# Autotuner knob declaration (paddle_tpu.tuning), next to the kernel it
-# tunes: the blocked-matmul tile shape every conv1x1 pass instantiates.
-# Search needs the chip (benchmark/conv_kernel.py is the measurement
-# driver); until an on-chip run commits a winner the 512/512/1024
-# defaults below stand, per the pre-registered rule.
-from ..core.registry import register_tunable  # noqa: E402
-
-register_tunable(
-    "pallas/conv1x1_blocks", side="device",
-    space={"block_m": (256, 512, 1024), "block_n": (256, 512, 1024),
-           "block_k": (512, 1024, 2048)},
-    default={"block_m": 512, "block_n": 512, "block_k": 1024},
-    description="blocked-matmul tile shape for the Pallas 1x1-conv "
-                "kernel family (fwd/dgrad/K-streaming wgrad share it).",
-    pending_hardware=True,
-    decision_rule="adopt a non-default tile only when the on-chip "
-                  "conv_kernel A/B shows >= 1.10x geomean over the "
-                  "512/512/1024 default across the ResNet-50 eligible "
-                  "shapes, with no per-shape regression > 5%")
-
-
 # ---------------------------------------------------------------------------
 # differentiable matmul: backward runs the same kernels (dgrad/wgrad)
 # ---------------------------------------------------------------------------

@@ -1358,50 +1358,7 @@ def short_conv(x, w, bias=None, act=None, interpret=False):
 # ---------------------------------------------------------------------------
 # op registration (layer: layers.flash_attention)
 # ---------------------------------------------------------------------------
-from ..core.registry import register_op, register_tunable  # noqa: E402
-
-# Autotuner knob declaration (paddle_tpu.tuning), next to the kernel it
-# tunes.  Replay is fingerprint-coherent by construction: the winning
-# blocks land in the flash_attention OP ATTRS (layers.flash_attention
-# resolves omitted block_q/block_k through tuned() under the autotune
-# flag), so they are part of the Program content digest every compile-
-# cache key hashes.  Search needs the chip: benchmark/longctx.py --sweep
-# is the measurement driver.
-register_tunable(
-    "pallas/flash_attention", side="device",
-    space={"block_q": (512, 1024, 2048), "block_k": (1024, 2048, 4096)},
-    default={"block_q": 1024, "block_k": 1024},
-    description="flash-attention Pallas tile shape: rows of Q per grid "
-                "step and the K-stream slab; 2048-row tiles additionally "
-                "need the scoped-VMEM limit raised "
-                "(xla/scoped_vmem_limit_kib).",
-    pending_hardware=True,
-    decision_rule="flip the default only when the on-chip longctx sweep "
-                  "shows >= 1.10x median ms/step over 1024x1024 at BOTH "
-                  "32k and 64k tokens (paired-window discipline, "
-                  "spread < gain)")
-
-# Paged KV-cache gather for the decode slot pool (serving/decode.py):
-# replace the contiguous [S, Tmax, D] slabs with fixed-size pages plus a
-# per-slot page table, gathered into the attention tile by a Pallas
-# kernel — the vLLM layout, removing the max-len * slots HBM reservation.
-# On this CPU container the contiguous slabs are strictly better (the
-# gather is pure overhead without HBM pressure), so the search is
-# pre-registered pending hardware rather than fabricated here.
-register_tunable(
-    "pallas/paged_kv_gather", side="device",
-    space={"page_size": (16, 32, 64, 128), "gather_block": (128, 256, 512)},
-    default={"page_size": 64, "gather_block": 256},
-    description="paged KV-cache layout for incremental decode: tokens "
-                "per cache page and the rows-per-grid-step of the Pallas "
-                "page-table gather feeding attention_with_cache.",
-    pending_hardware=True,
-    decision_rule="adopt paging only when the on-chip decode benchmark "
-                  "shows >= 1.15x decode tokens/s over the contiguous "
-                  "slabs at >= 50% slot occupancy with mixed-length "
-                  "traces, OR the contiguous reservation exceeds 25% of "
-                  "HBM at the serving config — below either bar the "
-                  "gather is pure overhead and the slabs stay")
+from ..core.registry import register_op  # noqa: E402
 
 
 @register_op("flash_attention")

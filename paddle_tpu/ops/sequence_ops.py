@@ -22,35 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.registry import register_op, register_tunable
-
-# Pre-registered Pallas expansion candidate (ROADMAP item 5): the lod
-# sequence family (sequence_expand/pool/concat/slice/pad/unpad, ...) is
-# gather/scatter over padded [B, T, ...] layouts — XLA lowers the masked
-# forms to select+reduce chains that re-read the padded tensor per op.
-# The candidate is hand-written Pallas gather/scatter kernels indexed by
-# the @LEN companions directly.  Declared pending-hardware so the first
-# chip session measures it for free (`python -m paddle_tpu tune
-# pallas/lod_gather_scatter`); the opprof 'XLA loses here' report
-# references this rule id when lod sequence op classes dominate a
-# measured profile.
-register_tunable(
-    "pallas/lod_gather_scatter", side="device",
-    space={"kernel": ("xla", "pallas"), "block_rows": (128, 256, 512)},
-    default={"kernel": "xla", "block_rows": 256},
-    description="route the lod gather/scatter sequence ops (sequence_"
-                "expand/pool/concat/slice/pad/unpad families) through "
-                "hand-written Pallas kernels indexed by @LEN instead of "
-                "XLA's masked select+reduce lowering",
-    pending_hardware=True,
-    decision_rule="flip kernel=pallas only when an on-chip paired A/B "
-                  "over a sequence-heavy step (benchmark/opprof.py lstm "
-                  "workload) shows >= 1.15x median step time with "
-                  ">= 75% of pairs favoring — the bar is higher than "
-                  "the generic 1.10x because the masked-XLA form "
-                  "co-fuses with neighbors and the kernel forfeits "
-                  "that; AND the opprof per-op table attributes >= 10% "
-                  "of measured step time to lod sequence op classes")
+from ..core.registry import register_op
 
 
 def _mask(lens, T, dtype=jnp.float32):

@@ -209,7 +209,7 @@ class ShardedExecutor(Executor):
                 # accumulators) and GSPMD keeps params on input shardings.
                 jitted[key] = compile_cache.CachedStep(
                     fn, fingerprint,
-                    compiler_options=self._effective_compiler_options(),
+                    compiler_options=self.compiler_options,
                     in_shardings=(feed_sh,
                                   self._state_shardings(program, state),
                                   None),

@@ -49,7 +49,7 @@ __all__ = ["prefetch", "interleave", "THREAD_NAME_PREFIX"]
 # so the winner is host-dependent by nature); buffer_size bounds decoded
 # samples in flight (backpressure vs burst absorption).
 register_tunable(
-    "reader/prefetch", side="host",
+    "reader/prefetch",
     space={"num_workers": (1, 2, 4), "buffer_size": (2, 4, 8, 16)},
     default={"num_workers": 1, "buffer_size": 8},
     description="prefetch engine defaults: decode worker threads and the "

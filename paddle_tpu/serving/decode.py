@@ -82,7 +82,7 @@ DECODE_SLOTS_DEFAULT = {"slots": 8, "step_wait_ms": 1.0}
 # when the offered load can't fill them; step_wait_ms bounds the idle
 # poll when the pool is empty.
 register_tunable(
-    "serving/decode_slots", side="host",
+    "serving/decode_slots",
     space={"slots": (2, 4, 8, 16), "step_wait_ms": (0.5, 1.0, 2.0, 5.0)},
     default=dict(DECODE_SLOTS_DEFAULT),
     description="decode slot pool: concurrent KV-cache slots (the "

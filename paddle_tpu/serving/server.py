@@ -76,7 +76,7 @@ logger = logging.getLogger("paddle_tpu")
 # bucket list), max_wait_ms trades first-request latency against batch
 # fill — the right point depends on model cost per row and offered load.
 register_tunable(
-    "serving/batcher", side="host",
+    "serving/batcher",
     space={"max_batch": (8, 16, 32, 64), "max_wait_ms": (1.0, 2.0, 5.0,
                                                          10.0)},
     default={"max_batch": 32, "max_wait_ms": 5.0},

@@ -88,11 +88,10 @@ THREAD_NAME_PREFIX = "pt-sparse"
 _PUSH_LINGER_S = 0.5
 
 # Autotuner knob declarations (paddle_tpu.tuning), next to the host hot
-# path they control.  All three are HOST-side: searchable in this
-# container (benchmark/ctr.py measures them on the real CTR workload),
-# no pending-hardware stub.
+# path they control, searchable on any host (benchmark/ctr.py measures
+# them on the real CTR workload).
 register_tunable(
-    "sparse/hot_rows", side="host",
+    "sparse/hot_rows",
     space={"cache_rows": (0, 1024, 16384, 65536, 262144)},
     default={"cache_rows": 0},
     description="hot-rows LRU capacity of the sparse session's cache-"
@@ -101,7 +100,7 @@ register_tunable(
                 "serving-style pull loop clears the 1.10x gate — the "
                 "hit rate must pay for the per-row cache bookkeeping.")
 register_tunable(
-    "sparse/prefetch", side="host",
+    "sparse/prefetch",
     space={"depth": (0, 1, 2, 4)},
     default={"depth": 0},
     description="pull-ahead prefetch depth: batches prepared ahead of "
@@ -112,7 +111,7 @@ register_tunable(
                 "tolerates pulls running up to depth+1 batches ahead "
                 "of pushes (bounded-staleness async updates).")
 register_tunable(
-    "sparse/push_flush", side="host",
+    "sparse/push_flush",
     space={"batch": (1, 2, 4, 8)},
     default={"batch": 1},
     description="async-push worker drain size: queued gradient pushes "

@@ -128,12 +128,11 @@ class SGD:
         (``paddle_tpu.tuning``) into this loop's omitted knobs: the
         pipelined path's ``steps_per_dispatch``/``prefetch_depth`` and
         reader ``num_workers``/``buffer_size`` (any knob given
-        explicitly — argument or pipeline dict — always wins), plus the
-        executor's device-side tuned compiler options.  ``False`` forces
-        it off; ``None`` (default) defers to the executor /
-        ``autotune`` flag (``PADDLE_TPU_AUTOTUNE=1``).  Replay never
-        searches — with no persisted record every knob keeps its
-        hand-picked default.  Search with ``python -m paddle_tpu tune
+        explicitly — argument or pipeline dict — always wins).
+        ``False`` forces it off; ``None`` (default) defers to the
+        executor / ``autotune`` flag (``PADDLE_TPU_AUTOTUNE=1``).
+        Replay never searches — with no persisted record every knob
+        keeps its hand-picked default.  Search with ``python -m paddle_tpu tune
         <target>``.  Like ``validate``, the override applies to this
         call only.
 

@@ -1,5 +1,5 @@
-"""Persistent autotuner: searched-and-cached configs for kernels, XLA
-flags, and host-side pipeline/serving knobs.
+"""Persistent autotuner: searched-and-cached configs for host-side
+pipeline, serving and sparse knobs.
 
 Three layers (ROADMAP item 3 generalized from the PR 1 one-off VMEM
 sweep into infrastructure):
@@ -8,9 +8,8 @@ sweep into infrastructure):
   to their implementation via ``core.registry.register_tunable`` (the
   ``register_shape_fn`` pattern; same repo-lint AST + live-registry
   gates) — dispatch chunking in ``core/executor.py``, reader prefetch in
-  ``reader/pipeline.py``, the serving batcher in ``serving/server.py``,
-  Pallas block configs and the scoped-VMEM XLA flag beside their
-  kernels.  Declaring never imports this package.
+  ``reader/pipeline.py``, the serving batcher in ``serving/server.py``.
+  Declaring never imports this package.
 * :mod:`.search` — grid + successive-halving searches under the PR 2
   measurement discipline (warmup discard, median of windows, paired
   alternating A/B with median-of-pair-ratios) and a NOISE GATE that
@@ -34,7 +33,7 @@ This package is imported LAZILY everywhere outside itself (tier-1 lint):
 training paths that never opt in never load it.
 """
 from .search import (SearchResult, Trial, grid_search,  # noqa: F401
-                     paired_ab, pending_stub, successive_halving,
+                     paired_ab, successive_halving,
                      time_windows, tune)
 from .store import (TUNING_FORMAT, clear_memo, list_records,  # noqa: F401
                     load_record, record_fingerprint, save_record, tuned)
@@ -47,7 +46,7 @@ __all__ = [
     "registered_tunables", "grid_configs", "space_digest",
     "validate_config",
     "Trial", "SearchResult", "time_windows", "grid_search",
-    "successive_halving", "paired_ab", "tune", "pending_stub",
+    "successive_halving", "paired_ab", "tune",
     "TUNING_FORMAT", "tuned", "save_record", "load_record",
     "record_fingerprint", "list_records", "clear_memo",
 ]

@@ -39,7 +39,7 @@ from . import tunables as _tn
 
 __all__ = [
     "Trial", "SearchResult", "time_windows", "run_trial", "grid_search",
-    "successive_halving", "paired_ab", "tune", "pending_stub",
+    "successive_halving", "paired_ab", "tune",
 ]
 
 
@@ -302,25 +302,6 @@ def paired_ab(measure, default_config: Dict[str, object],
 # ---------------------------------------------------------------------------
 # Orchestration
 # ---------------------------------------------------------------------------
-def pending_stub(name: str) -> dict:
-    """The pending-hardware result document for a device-side tunable on
-    a host without the accelerator (the PR 1 stub convention: the harness
-    and the pre-registered decision rule ship; the rows wait for a
-    chip)."""
-    entry = _tn.get_tunable(name)
-    import jax
-    return {
-        "tunable": name, "status": "pending_hardware",
-        "backend": jax.default_backend(),
-        "side": entry["side"],
-        "decision_rule": entry["decision_rule"],
-        "note": "device-side search target; run `python -m paddle_tpu "
-                "tune " + name + "` on a host with the accelerator — the "
-                "pre-registered decision rule above governs enabling the "
-                "winner",
-    }
-
-
 def tune(name: str, measure, *, algo: str = "grid",
          budget: Optional[int] = None, reps: int = 3, warmup: int = 1,
          pairs: int = 5, min_speedup: float = 1.10,
@@ -333,15 +314,8 @@ def tune(name: str, measure, *, algo: str = "grid",
 
     Returns a result document (JSON-serializable) carrying the trial
     table, the A/B verdict with raw windows, and the stored-record path
-    when a winner shipped.  Device-side tunables on a chipless host
-    return the pending-hardware stub instead of searching."""
+    when a winner shipped."""
     entry = _tn.get_tunable(name)
-    import jax
-    if entry["side"] == "device" and jax.default_backend() == "cpu":
-        doc = pending_stub(name)
-        obs.emit_event("tuning", event="pending", tunable=name,
-                       backend=doc["backend"])
-        return doc
     search_fn = {"grid": grid_search,
                  "halving": successive_halving}.get(algo)
     if search_fn is None:

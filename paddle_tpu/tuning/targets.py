@@ -7,13 +7,6 @@ can time — the subsystem's representative hot loop, sized so a full grid
 finishes in minutes on a CPU container (``smoke=True`` shrinks it to
 seconds for path checks).
 
-Host-side targets run anywhere; device-side targets (Pallas blocks, XLA
-flags) build real kernel workloads and are only reached on a host with
-the accelerator — ``search.tune`` short-circuits them into the
-pending-hardware stub on CPU, so ``tune pallas/flash_attention`` in this
-container documents the pre-registered decision rule instead of
-fabricating numbers.
-
 Every builder constructs its fixture ONCE (model, synthetic data) and
 returns a closure measuring one window: per-config compile costs land in
 the engine's warmup-discard windows, exactly like the committed
@@ -29,9 +22,6 @@ import numpy as np
 __all__ = ["TARGETS", "build_target", "target_names"]
 
 
-# ---------------------------------------------------------------------------
-# Host-side targets
-# ---------------------------------------------------------------------------
 def _target_run_pipelined(smoke: bool) -> Callable[[dict], None]:
     """Pipelined-dispatch chunking on a dispatch-overhead-bound workload:
     a small MLP whose per-step device time is tiny, so steps_per_dispatch
@@ -318,128 +308,6 @@ def _target_decode_slots(smoke: bool) -> Callable[[dict], None]:
     return measure
 
 
-# ---------------------------------------------------------------------------
-# Device-side targets (reached only with the accelerator present;
-# search.tune returns the pending-hardware stub on CPU)
-# ---------------------------------------------------------------------------
-def _target_flash_blocks(smoke: bool) -> Callable[[dict], None]:
-    """Flash-attention tile shape at 32k tokens — the longctx sweep's
-    grid point, one config per trial."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops.pallas_kernels import flash_attention
-
-    T = 2048 if smoke else 32768
-    rng = np.random.RandomState(0)
-    qkv = tuple(jnp.asarray(rng.randn(8, T, 64), jnp.bfloat16)
-                for _ in range(3))
-    steps = 2 if smoke else 10
-    # one jitted window PER CONFIG, reused across that config's windows:
-    # the compile lands in the engine's warmup-discarded window instead
-    # of polluting every timed one (same memoization longctx's
-    # _sweep_measure uses)
-    compiled = {}
-
-    def measure(cfg: dict):
-        key = (cfg["block_q"], cfg["block_k"])
-        if key not in compiled:
-            def loss_fn(qkv, bq=cfg["block_q"], bk=cfg["block_k"]):
-                q, k, v = qkv
-                o = flash_attention(q, k, v, causal=True, block_q=bq,
-                                    block_k=bk)
-                return jnp.sum(o.astype(jnp.float32) ** 2) * 1e-6
-
-            grad = jax.value_and_grad(loss_fn)
-
-            @jax.jit
-            def window(qkv):
-                def body(carry, _):
-                    l, g = grad(carry)
-                    new = tuple(t - 1e-6 * gt.astype(t.dtype)
-                                for t, gt in zip(carry, g))
-                    return new, l
-                _, losses = jax.lax.scan(body, qkv, None, length=steps)
-                return losses
-            compiled[key] = window
-        float(compiled[key](qkv)[-1])       # completion barrier
-    return measure
-
-
-def _target_conv1x1_blocks(smoke: bool) -> Callable[[dict], None]:
-    """Conv1x1 Pallas tile shape on the worst measured pass (deep-K
-    wgrad) of a representative ResNet-50 shape."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops.pallas_conv import _to_pixel_major, pallas_matmul
-
-    N, C, H, W, M = (2, 128, 16, 16, 256) if smoke \
-        else (128, 512, 28, 28, 128)
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(N, C, H, W), jnp.bfloat16)
-    g = jnp.asarray(rng.randn(N, M, H, W), jnp.bfloat16)
-    xm, _ = _to_pixel_major(x)
-    gm, _ = _to_pixel_major(g)
-    steps = 2 if smoke else 50
-    compiled = {}        # per-config jitted window (compile -> warmup)
-
-    def measure(cfg: dict):
-        key = (cfg["block_m"], cfg["block_n"], cfg["block_k"])
-        if key not in compiled:
-            @jax.jit
-            def window(xm, gm, bm=cfg["block_m"], bn=cfg["block_n"],
-                       bk=cfg["block_k"]):
-                def body(carry, _):
-                    xc, gc = carry
-                    dw = pallas_matmul(gc, xc, True, False, bm, bn, bk)
-                    s = jnp.sum(dw * dw[:1])
-                    f = (1.0 - 1e-12 * s)
-                    return (xc * f.astype(xc.dtype),
-                            gc * f.astype(gc.dtype)), s
-                _, ss = jax.lax.scan(body, (xm, gm), None, length=steps)
-                return ss[-1]
-            compiled[key] = window
-        float(compiled[key](xm, gm))
-    return measure
-
-
-def _target_scoped_vmem(smoke: bool) -> Callable[[dict], None]:
-    """Scoped-VMEM limit at the sweep point it gates: 2048-row flash
-    blocks, which the 16 MiB default rejects.  A config whose compile is
-    rejected records a failed trial — that IS the sweep result for it."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops.pallas_kernels import flash_attention
-
-    T = 2048 if smoke else 32768
-    rng = np.random.RandomState(0)
-    qkv = tuple(jnp.asarray(rng.randn(8, T, 64), jnp.bfloat16)
-                for _ in range(3))
-    steps = 2 if smoke else 10
-    compiled = {}        # per-config AOT executable (compile -> warmup)
-
-    def measure(cfg: dict):
-        key = int(cfg["scoped_vmem_limit_kib"])
-        if key not in compiled:
-            def window(qkv):
-                def body(carry, _):
-                    q, k, v = carry
-                    o = flash_attention(q, k, v, causal=True,
-                                        block_q=2048, block_k=1024)
-                    s = jnp.sum(o.astype(jnp.float32) ** 2) * 1e-6
-                    return tuple(t * (1.0 - 1e-12 * s).astype(t.dtype)
-                                 for t in carry), s
-                _, losses = jax.lax.scan(body, qkv, None, length=steps)
-                return losses
-            compiled[key] = jax.jit(window).lower(qkv).compile(
-                compiler_options={"xla_tpu_scoped_vmem_limit_kib":
-                                  str(key)})
-        float(compiled[key](qkv)[-1])
-    return measure
-
-
 TARGETS: Dict[str, Callable[[bool], Callable[[dict], None]]] = {
     "executor/run_pipelined": _target_run_pipelined,
     "reader/prefetch": _target_reader_prefetch,
@@ -448,22 +316,17 @@ TARGETS: Dict[str, Callable[[bool], Callable[[dict], None]]] = {
     "sparse/hot_rows": _target_sparse_hot_rows,
     "sparse/prefetch": _target_sparse_prefetch,
     "sparse/push_flush": _target_sparse_push_flush,
-    "pallas/flash_attention": _target_flash_blocks,
-    "pallas/conv1x1_blocks": _target_conv1x1_blocks,
-    "xla/scoped_vmem_limit_kib": _target_scoped_vmem,
 }
 
 
 #: target name -> module whose import registers the tunable (lazily
-#: imported subsystems: serving, the sparse parameter server, the
-#: flag-gated Pallas conv kernels)
+#: imported subsystems: serving, the sparse parameter server)
 _REGISTERING_MODULE = {
     "serving/batcher": "paddle_tpu.serving.server",
     "serving/decode_slots": "paddle_tpu.serving.decode",
     "sparse/hot_rows": "paddle_tpu.sparse.session",
     "sparse/prefetch": "paddle_tpu.sparse.session",
     "sparse/push_flush": "paddle_tpu.sparse.session",
-    "pallas/conv1x1_blocks": "paddle_tpu.ops.pallas_conv",
 }
 
 

@@ -72,10 +72,12 @@ def validate_config(entry: dict, config: Dict[str, object]) -> List[str]:
 
 
 def space_digest(entry: dict) -> str:
-    """Schema-version digest: space axes + defaults + side.  Folded into
-    the persistence fingerprint, so editing a tunable's declaration
-    orphans its stored winners (they fall back to defaults silently)."""
-    payload = repr((entry["name"], entry["side"],
+    """Schema-version digest: space axes + defaults.  Folded into the
+    persistence fingerprint, so editing a tunable's declaration orphans
+    its stored winners (they fall back to defaults silently)."""
+    # "host" stands where a declaration's side once did (every tunable is
+    # host-side): the digests, and so the winners already stored, hold
+    payload = repr((entry["name"], "host",
                     tuple(sorted((p, tuple(v))
                           for p, v in entry["space"].items())),
                     tuple(sorted(entry["default"].items()))))
@@ -85,13 +87,10 @@ def space_digest(entry: dict) -> str:
 def describe(name: str) -> str:
     """One human-readable block for the CLI's registry table."""
     e = get_tunable(name)
-    lines = [f"{e['name']}  [{e['side']}]"
-             + ("  (pending hardware)" if e["pending_hardware"] else "")]
+    lines = [e["name"]]
     if e["description"]:
         lines.append(f"  {e['description']}")
     for p in sorted(e["space"]):
         lines.append(f"  {p}: {list(e['space'][p])} (default "
                      f"{e['default'][p]!r})")
-    if e["decision_rule"]:
-        lines.append(f"  decision rule: {e['decision_rule']}")
     return "\n".join(lines)

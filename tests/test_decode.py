@@ -278,7 +278,7 @@ def test_decode_bench_smoke_row_complete():
 def test_committed_decode_results_structure():
     """The committed JSON carries real CPU rows (accepted at the 1.3x
     bar or an explicit refusal WITH raw windows) + the pending-hardware
-    TPU stub wired to the pre-registered paged-gather decision rule."""
+    TPU stub."""
     with open(RESULTS) as fh:
         data = json.load(fh)
     assert data["benchmark"] == "decode_continuous_batching"
@@ -292,10 +292,6 @@ def test_committed_decode_results_structure():
     assert cpu["static"]["decode_tokens_per_s"] > 0
     assert cpu["doctor"]["steps"] > 0
     assert data["tpu"]["status"] == "pending-hardware"
-    pg = data["tpu"]["paged_kv_gather"]
-    assert pg["tunable"] == "pallas/paged_kv_gather"
-    assert pg["status"] == "pending_hardware"
-    assert "1.15x" in pg["decision_rule"]
 
 
 @pytest.mark.slow

@@ -109,7 +109,7 @@ def test_no_new_broad_except_swallows():
 def test_lowering_context_is_constructed_in_the_executor_only():
     """How executor options become a LoweringContext is written ONCE
     (``Executor._lowering_context``): an eager replay that built its own
-    (observability.nanprov and opprof each did) would lower under another
+    (as observability.nanprov once did) would lower under another
     configuration than the compiled step the day an option is added."""
     sites = [rel for rel, tree in _iter_sources()
              for node in ast.walk(tree)
@@ -332,7 +332,7 @@ def test_tuning_package_only_imported_lazily():
     assert not problems, "\n".join(problems)
     # and the ONE sanctioned lazy replay site exists: the shared
     # core.registry.resolve_tuned helper every call site (executor,
-    # reader, serving, flash-attention layer, sparse session) now
+    # reader, serving, sparse session) now
     # routes through (round-15 dedup of the per-module copies)
     with open(os.path.join(ROOT, "core", "registry.py")) as fh:
         assert "from ..tuning.store import tuned" in fh.read()
@@ -392,8 +392,8 @@ def test_lint_gate_covers_tuning_package():
 def test_tunable_registry_matches_ast_scan():
     """Agreement gate for the autotuner knob declarations: every live
     register_tunable name is a string literal the duplicate lint can
-    see.  (ast - live is legitimate: serving and the flag-gated Pallas
-    conv module register lazily.)  Every declared entry must also pass
+    see.  (ast - live is legitimate: serving and the sparse session
+    register lazily.)  Every declared entry must also pass
     the registry's own validation — importing the declaring modules here
     IS that check, since register_tunable validates at call time."""
     import importlib
@@ -403,7 +403,6 @@ def test_tunable_registry_matches_ast_scan():
     # surface the lazily-imported declarations so live is maximal
     importlib.import_module("paddle_tpu.serving.server")
     importlib.import_module("paddle_tpu.serving.decode")
-    importlib.import_module("paddle_tpu.ops.pallas_conv")
     importlib.import_module("paddle_tpu.sparse.session")
 
     ast_names = {n for n, _, _ in _registered_names("register_tunable")}
@@ -415,25 +414,9 @@ def test_tunable_registry_matches_ast_scan():
         f"{sorted(missing)}")
     assert live >= {"executor/run_pipelined", "reader/prefetch",
                     "serving/batcher", "serving/decode_slots",
-                    "pallas/paged_kv_gather", "sparse/hot_rows",
-                    "sparse/prefetch", "sparse/push_flush",
-                    "pallas/flash_attention",
-                    "pallas/conv1x1_blocks", "xla/scoped_vmem_limit_kib",
-                    "pallas/fused_optimizer_update",
-                    "pallas/lod_gather_scatter"}, \
+                    "sparse/hot_rows", "sparse/prefetch",
+                    "sparse/push_flush"}, \
         f"expected initial tunable coverage missing: {sorted(live)}"
-    # the sparse session knobs are HOST-side (measurable in-container,
-    # ISSUE 15): they must never ship as pending-hardware stubs
-    from paddle_tpu.core.registry import get_tunable as _gt
-    for n in ("sparse/hot_rows", "sparse/prefetch", "sparse/push_flush"):
-        assert _gt(n)["side"] == "host" and not _gt(n)["pending_hardware"]
-    # device-side entries must carry their pre-registered decision rule
-    from paddle_tpu.core.registry import get_tunable
-    for n in live:
-        e = get_tunable(n)
-        if e["pending_hardware"]:
-            assert e["decision_rule"], \
-                f"pending-hardware tunable {n!r} without a decision rule"
 
 
 # ---------------------------------------------------------------------------
@@ -600,8 +583,8 @@ def _top_level_obs_submodule_imports(submod: str):
     """(rel, lineno) of every TOP-LEVEL import of
     ``paddle_tpu/observability/<submod>.py`` from any OTHER module —
     the static half of a lazy-only observability submodule's zero-cost
-    contract (attribution and opprof both pull analysis.cost_model;
-    opprof additionally pulls tuning.search)."""
+    contract (attribution and collector pull what the hot path must
+    not pay for)."""
     target = f"observability.{submod}"
     own = f"paddle_tpu/observability/{submod}.py"
 
@@ -633,37 +616,6 @@ def _top_level_obs_submodule_imports(submod: str):
                 visit(child, nested)
         visit(tree, False)
     return found
-
-
-def test_opprof_module_only_imported_lazily():
-    """The per-op profiler (observability/opprof.py) pulls
-    analysis.cost_model AND tuning.search; like attribution, only the
-    opted-in surfaces (profile/doctor CLI branches, benchmark driver)
-    may import it — no top-level import anywhere else, and the
-    observability package __init__ must not import it (the `observe`
-    hot path stays profiler-free)."""
-    toplevel = _top_level_obs_submodule_imports("opprof")
-    assert not toplevel, (
-        "top-level import of observability.opprof — must be lazy "
-        "(inside a function) so training paths never pay for the "
-        "cost-model/tuning import chain: " + ", ".join(toplevel))
-    # and the sanctioned lazy sites exist (profile + doctor --per-op)
-    with open(os.path.join(ROOT, "cli.py")) as fh:
-        src = fh.read()
-    assert "from paddle_tpu.observability import opprof" in src
-
-
-def test_lint_gate_covers_opprof_module():
-    """observability/opprof.py is inside every lint's scan set, its
-    opprof/* metric names are frozen in METRIC_NAMES, and its span name
-    is frozen in SPAN_NAMES (the used==registered span check then keeps
-    the walk instrumented)."""
-    rels = {rel for rel, _ in _iter_sources()}
-    assert "paddle_tpu/observability/opprof.py" in rels
-    registered = {n for n, _ in _metric_names_table()}
-    assert {n for n in registered if n.startswith("opprof/")} >= {
-        "opprof/runs", "opprof/ops", "opprof/op_ms"}
-    assert "opprof/op" in set(_span_names_table())
 
 
 def test_collector_module_only_imported_lazily():

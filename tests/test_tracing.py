@@ -284,6 +284,27 @@ def test_calibration_table_merges_by_program(tmp_path):
     assert doc["programs"]["aaa"]["ratio"] == 4.0   # re-doctor overwrites
 
 
+@pytest.mark.parametrize("older", [
+    {"format": 2, "programs": {"aaa": {"program": "aaa", "ratio": 2.0}},
+     "op_classes": {"aaa:mul": {"program": "aaa", "op_type": "mul",
+                                "ratio": 1.5}}},
+    {"format": 1, "programs": {"aaa": {"program": "aaa", "ratio": 2.0}}},
+    {"aaa": {"program": "aaa", "ratio": 2.0}},
+], ids=["op_class_rows", "format_1", "bare_map"])
+def test_calibration_table_of_an_older_tree_still_merges(tmp_path, older):
+    """A table an older tree wrote, op-class rows included, loads: its
+    program rows merge with the new one and any other section is
+    dropped."""
+    from paddle_tpu.observability import attribution
+    path = tmp_path / "cal.json"
+    path.write_text(json.dumps(older))
+    doc = attribution.save_calibration(
+        [{"program": "bbb", "ratio": 3.0}], str(path))
+    assert doc == json.loads(path.read_text())
+    assert set(doc) == {"format", "programs"}
+    assert set(doc["programs"]) == {"aaa", "bbb"}
+
+
 def test_executable_facts_via_compat():
     """cost_analysis()/memory_analysis() guarded through compat: on this
     jax a compiled step exposes flops; the wrapper never raises."""

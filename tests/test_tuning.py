@@ -37,7 +37,7 @@ def knob(tuning):
     name = "test/knob"
     core_registry._TUNABLES.pop(name, None)
     entry = register_tunable(
-        name, side="host",
+        name,
         space={"a": (1, 2), "b": (10, 20)},
         default={"a": 1, "b": 10},
         description="test knob")
@@ -65,25 +65,21 @@ def autotune_env(tmp_path, tuning, monkeypatch):
 def test_register_tunable_validates_declarations(knob):
     name, _ = knob
     with pytest.raises(ValueError, match="registered twice"):
-        register_tunable(name, side="host", space={"a": (1,)},
+        register_tunable(name, space={"a": (1,)},
                          default={"a": 1})
     for kwargs, match in [
-        (dict(side="gpu", space={"a": (1,)}, default={"a": 1}),
-         "side"),
-        (dict(side="host", space={}, default={}), "empty"),
-        (dict(side="host", space={"a": (1,)}, default={"a": 1, "b": 2}),
+        (dict(space={}, default={}), "empty"),
+        (dict(space={"a": (1,)}, default={"a": 1, "b": 2}),
          "default keys"),
-        (dict(side="host", space={"a": (1, 2)}, default={"a": 3}),
+        (dict(space={"a": (1, 2)}, default={"a": 3}),
          "not in its axis"),
-        (dict(side="host", space={"a": (1, 1)}, default={"a": 1}),
+        (dict(space={"a": (1, 1)}, default={"a": 1}),
          "duplicate values"),
-        (dict(side="device", space={"a": (1,)}, default={"a": 1},
-              pending_hardware=True), "decision_rule"),
     ]:
         with pytest.raises(ValueError, match=match):
             register_tunable("test/bad", **kwargs)
     with pytest.raises(ValueError, match="not namespaced"):
-        register_tunable("flatname", side="host", space={"a": (1,)},
+        register_tunable("flatname", space={"a": (1,)},
                          default={"a": 1})
 
 
@@ -360,13 +356,6 @@ def test_tune_refusal_persists_nothing(tuning, knob, tmp_path, monkeypatch):
         assert doc["ab"]["default_windows"]
 
 
-def test_tune_device_side_pending_stub_on_cpu(tuning):
-    doc = tuning.tune("pallas/flash_attention", None)
-    assert doc["status"] == "pending_hardware"
-    assert doc["backend"] == "cpu"
-    assert "1.10x" in doc["decision_rule"]
-
-
 # ---------------------------------------------------------------------------
 # Replay acceptance: zero search cost, zero added retraces, byte-identical
 # defaults when untuned
@@ -395,7 +384,6 @@ def test_untuned_call_sites_resolve_todays_defaults(tuning):
     assert exe._tuned("executor/run_pipelined", d) is d
     exe_on = pt.Executor(autotune=True)      # on, but no record
     assert exe_on._tuned("executor/run_pipelined", d) == d
-    assert exe_on._effective_compiler_options() == {}
 
     from paddle_tpu.reader.pipeline import _tuned_defaults
     assert _tuned_defaults(None, None) == (8, 1)
@@ -511,53 +499,101 @@ def test_replay_reaches_every_host_call_site(tuning, knob, autotune_env):
     s_explicit = srv_mod.Server(max_batch=64, autotune=True)
     assert s_explicit.max_batch == 64              # explicit wins
 
-    # flash-attention layer: the winner lands in the OP ATTRS (the
-    # fingerprint-coherent replay point)
-    tuning.save_record("pallas/flash_attention",
-                       {"block_q": 2048, "block_k": 2048}, base=base)
+
+def _store_record_of_a_retired_tunable(tuning, base, name, space, config):
+    """Leave in the store a record under ``name``, a tunable this tree no
+    longer declares, as a tree that declared it would have written it."""
+    core_registry._TUNABLES.pop(name, None)
+    register_tunable(name, space=space,
+                     default={k: v[0] for k, v in space.items()})
+    try:
+        tuning.save_record(name, config, base=base)
+    finally:
+        core_registry._TUNABLES.pop(name, None)
     tuning.clear_memo()
+
+
+@pytest.mark.parametrize("given", [{}, {"block_q": 512}, {"block_k": 2048}],
+                         ids=["omitted", "block_q", "block_k"])
+@pytest.mark.parametrize("autotune", ["off", "on", "on_with_old_record"])
+def test_flash_attention_blocks_are_1024_unless_given(
+        tuning, autotune_env, given, autotune):
+    """layers.flash_attention writes 1024 for an omitted block and the
+    given value for a given one, whatever the autotune switch and the
+    store hold: no tunable picks a kernel's blocks."""
+    from paddle_tpu import flags
+    flags.set_flag("autotune", autotune != "off")
+    if autotune == "on_with_old_record":
+        _store_record_of_a_retired_tunable(
+            tuning, autotune_env, "pallas/flash_attention",
+            {"block_q": (512, 1024, 2048), "block_k": (1024, 2048, 4096)},
+            {"block_q": 2048, "block_k": 4096})
     q = layers.data("q", shape=[16, 64], dtype="float32")
-    out = layers.flash_attention(q, q, q)
+    layers.flash_attention(q, q, q, **given)
     op = [o for o in pt.default_main_program().global_block().ops
           if o.type == "flash_attention"][-1]
-    assert op.attrs["block_q"] == 2048
-    assert op.attrs["block_k"] == 2048
-    # explicit blocks win over the record
-    out2 = layers.flash_attention(q, q, q, block_q=512)
-    op2 = [o for o in pt.default_main_program().global_block().ops
-           if o.type == "flash_attention"][-1]
-    assert op2.attrs["block_q"] == 512
-    assert op2.attrs["block_k"] == 2048
-    del out, out2
+    assert (op.attrs["block_q"], op.attrs["block_k"]) == (
+        given.get("block_q", 1024), given.get("block_k", 1024))
 
 
-def test_scoped_vmem_winner_reaches_compiler_options_and_fingerprint(
-        tuning, knob, autotune_env):
-    """A persisted xla/scoped_vmem winner lands in the effective
-    compiler options AND the compile fingerprint; the default-valued
-    record injects nothing (absence == XLA default)."""
-    base = autotune_env
-    exe = pt.Executor(autotune=True)
-    assert exe._effective_compiler_options() == {}
+# space_digest of each host tunable as the tree that still declared device
+# tunables computed it: stored winners keep replaying
+_HOST_TUNABLE_DIGESTS = {
+    "executor/run_pipelined": "2007ee5ec8f33b19",
+    "reader/prefetch": "a842fd2382bdfff8",
+    "serving/batcher": "c26cf6d514a8526b",
+    "serving/decode_slots": "2dd372579ee6563b",
+    "sparse/hot_rows": "72aa43e024f589bc",
+    "sparse/prefetch": "32d8545d3c928733",
+    "sparse/push_flush": "a824c4afd0e8df53",
+}
 
-    tuning.save_record("xla/scoped_vmem_limit_kib",
-                       {"scoped_vmem_limit_kib": 16 * 1024}, base=base)
-    tuning.clear_memo()
-    assert exe._effective_compiler_options() == {}   # default value: no-op
 
-    tuning.save_record("xla/scoped_vmem_limit_kib",
-                       {"scoped_vmem_limit_kib": 64 * 1024}, base=base)
-    tuning.clear_memo()
-    assert exe._effective_compiler_options() \
-        == {"xla_tpu_scoped_vmem_limit_kib": "65536"}
-    # and the fingerprint sees it (vs an autotune-off executor)
-    assert exe._config_sig() != pt.Executor(autotune=False)._config_sig()
-    # explicit user option wins over the record
-    exe_user = pt.Executor(
-        autotune=True,
-        compiler_options={"xla_tpu_scoped_vmem_limit_kib": "32768"})
-    assert exe_user._effective_compiler_options() \
-        == {"xla_tpu_scoped_vmem_limit_kib": "32768"}
+@pytest.mark.parametrize("name", sorted(_HOST_TUNABLE_DIGESTS))
+def test_host_tunable_space_digest_is_unchanged(tuning, name):
+    from paddle_tpu.tuning import targets
+    targets.ensure_registered(name)
+    assert tuning.space_digest(tuning.get_tunable(name)) \
+        == _HOST_TUNABLE_DIGESTS[name]
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["executor", "sharded_one_device"])
+def test_autotune_leaves_fingerprint_and_compiler_options_alone(
+        tuning, autotune_env, monkeypatch, sharded):
+    """Executor(autotune=True) and Executor(autotune=False) compile one
+    program to the same fingerprint and hand CachedStep the same
+    compiler_options: no tunable reaches the compile."""
+    import jax
+
+    from paddle_tpu.core import compile_cache
+    from paddle_tpu.parallel import ShardedExecutor
+
+    seen = []
+    real = compile_cache.CachedStep
+
+    def spy(fn, fingerprint, **kw):
+        seen.append((fingerprint, kw.get("compiler_options")))
+        return real(fn, fingerprint, **kw)
+
+    monkeypatch.setattr(compile_cache, "CachedStep", spy)
+    loss = _tiny_net()
+    feed = _feeds(1)[0]
+    for autotune in (False, True):
+        if sharded:
+            from jax.sharding import Mesh
+            exe = ShardedExecutor(
+                mesh=Mesh(np.array(jax.devices()[:1]), ("dp",)),
+                autotune=autotune)
+        else:
+            exe = pt.Executor(autotune=autotune)
+        exe.run(pt.default_startup_program(), feed={}, fetch_list=[])
+        exe.run(pt.default_main_program(), feed=feed, fetch_list=[loss])
+    # startup then main, autotune off, then the same two with it on (the
+    # second startup finds the scope filled, so only the mains compare)
+    assert len(seen) == 4, seen
+    assert seen[1] == seen[3]
+    assert all(opts == {} for _, opts in seen)
 
 
 def test_import_paddle_tpu_does_not_load_tuning():
@@ -656,8 +692,18 @@ def test_tune_cli_lists_registry(tuning, capsys):
     from paddle_tpu import cli
     assert cli.main(["tune", "--list"]) == 0
     out = capsys.readouterr().out
-    assert "executor/run_pipelined" in out
-    assert "decision rule" in out
+    listed = {line for line in out.splitlines()
+              if line and not line.startswith(" ")}
+    assert listed == set(_HOST_TUNABLE_DIGESTS)
+
+
+def test_profile_is_not_a_subcommand(capsys):
+    """The eager per-op profiler is gone: `paddle_tpu profile` reaches
+    the trainer's own parser, which refuses it."""
+    from paddle_tpu import cli
+    with pytest.raises(SystemExit) as e:
+        cli.main(["profile", "prog.json"])
+    assert e.value.code == 2
 
 
 def test_tuning_events_reach_stats_summary(tuning, knob, tmp_path):
