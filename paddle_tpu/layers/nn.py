@@ -39,7 +39,7 @@ __all__ = [
     "margin_rank_loss", "squared_l2_distance", "squared_l2_norm",
     "kldiv_loss", "modified_huber_loss", "bilinear_tensor_product",
     "short_conv",
-    "ssd_scan",
+    "ssd_scan", "gated_delta_scan",
 ]
 
 
@@ -561,6 +561,26 @@ def ssd_scan(u, delta, a, bm, cm, d, chunk=256, name=None, interpret=False):
                      outputs={"Out": [out]},
                      attrs={"chunk": int(chunk),
                             **({"interpret": True} if interpret else {})})
+    return out
+
+
+def gated_delta_scan(q, k, v, g, beta, scale, chunk=64, name=None):
+    """The gated delta rule of Kimi Delta Attention, chunked
+    (ops/delta_ops.py ``gated_delta_scan``): ``q`` and ``k`` [B, T, H, K]
+    (L2-normalised: the caller's), ``v`` [B, T, H, V], ``g`` [B, T, H, K]
+    (log-decays a key channel, <= 0: the caller's), ``beta`` [B, T, H] (in
+    (0, 1): the caller's sigmoid); every head's state [K, V] starts at 0,
+    S[t] = (I - beta[t] k[t] k[t]^T) Diag(exp(g[t])) S[t-1]
+    + beta[t] k[t] v[t]^T, out[t] = S[t]^T (scale q[t]), [B, T, H, V].
+    ``chunk`` positions a chunk; it must divide T."""
+    helper = LayerHelper("gated_delta_scan", name=name)
+    out = helper.create_variable_for_type_inference(
+        v.dtype, tuple(q.shape[:3]) + (v.shape[-1],))
+    helper.append_op(type="gated_delta_scan",
+                     inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
+                             "Beta": [beta]},
+                     outputs={"Out": [out]},
+                     attrs={"scale": float(scale), "chunk": int(chunk)})
     return out
 
 
@@ -1415,7 +1435,10 @@ def flash_attention(q, k, v, causal=False, block_q=1024, block_k=1024,
     no other scale: a model whose scale is s multiplies q by s * sqrt(D)
     before the call (``layers.scale``; XLA fuses it behind the projection).
     K and V may have fewer heads than Q, a whole divisor (query head h
-    reads K / V head h // group).
+    reads K / V head h // group).  V's head size may differ from Q's and
+    K's (latent attention: q and k of 192, v of 128): the output takes V's,
+    and the kernels' blocks hold the whole of each (192 lands in VMEM as
+    whole lane tiles of 256).
 
     ``block_q``/``block_k`` default to the swept 1024x1024 tiles."""
     helper = LayerHelper("flash_attention", name=name)

@@ -366,7 +366,11 @@ def _one_pass_fits(Tq, Tk, D, Dv, block_q, block_k):
     and the blocks alone: what Mosaic holds for it in VMEM (dk, dv of a K/V
     head's whole sequence and the blocks q, do, dq, lse, delta, k, v in both
     pipeline buffers; the dq accumulator; two float32 tiles) within
-    ``FLASH_BWD_VMEM_BYTES``, and a row of lse it can cut into blocks."""
+    ``FLASH_BWD_VMEM_BYTES``, and a row of lse it can cut into blocks.  D
+    and Dv may differ; each is counted at whole lane tiles, so a head size
+    of 192 (latent attention's q and k) lands in VMEM as 256 lanes: at 8192
+    keys of 192 / 128 and 1024 x 1024 blocks that is 43.0 MB, over the
+    32 MiB: the two kernels."""
     d, dv = -(-D // 128) * 128, -(-Dv // 128) * 128    # whole lane tiles
     resident = 2 * Tk * (d + dv) * 4
     streamed = 2 * (block_q * (2 * d + dv + 2) + block_k * (d + dv)) * 4
@@ -607,7 +611,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     have fewer heads than Q, a whole divisor (grouped-query heads): query
     head h reads K / V head h // (H / H_kv), through the kernels' index
     maps, and dK / dV sum over a group's query heads inside the backward
-    kernel; counted as ``route/flash_attention:grouped``.
+    kernel; counted as ``route/flash_attention:grouped``.  V's head size Dv
+    may differ from Q's and K's D (latent attention: 192 against 128); out
+    is [..., Dv], counted as ``route/flash_attention:value_dims``.
 
     The backward is one kernel that visits each (query block, key block)
     tile once where dK and dV of a K/V head's whole sequence fit VMEM
@@ -647,6 +653,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
             f"batch-heads do not divide Q's {q3.shape[0]}")
     if q3.shape[0] != k3.shape[0]:
         compile_cache.stats().bump("route/flash_attention:grouped")
+    if v3.shape[-1] != q3.shape[-1]:
+        compile_cache.stats().bump("route/flash_attention:value_dims")
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     interpret = bool(interpret)
@@ -1111,6 +1119,9 @@ SHORT_CONV_ROWS = 64             # positions a block: X, dX and the cotangent,
 SHORT_CONV_LANES = 512           # features a pass inside a block, where that
 #                                  divides C (else 256, else 128)
 _HALO = 8                        # rows of a halo view (a sublane tile)
+SHORT_CONV_VMEM_BYTES = 32 << 20  # scoped VMEM asked for where the blocks
+#                                  pass the default 16 MiB: 12 288 channels
+#                                  ungated ask 20.5 MiB backward
 
 
 def _shifted(v, edge, shift, row, down):
@@ -1254,10 +1265,19 @@ def _short_conv_specs(x):
     return (b, t // SHORT_CONV_ROWS), views
 
 
-def _short_conv_params(interpret):
-    return {"interpret": True} if interpret else {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"))}
+def _short_conv_params(interpret, width, channels):
+    """The backward's blocks, double-buffered, hold X (``width`` wide) with
+    its two halos and dX, the cotangent (``channels`` wide) with its halo:
+    where they pass the default scoped VMEM, both calls ask for
+    ``SHORT_CONV_VMEM_BYTES``."""
+    if interpret:
+        return {"interpret": True}
+    held = 2 * 4 * ((2 * SHORT_CONV_ROWS + 2 * _HALO) * width
+                    + (SHORT_CONV_ROWS + _HALO) * channels)
+    more = {"vmem_limit_bytes": SHORT_CONV_VMEM_BYTES} \
+        if held > 16 << 20 else {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"), **more)}
 
 
 def _resident(rows, channels):
@@ -1281,7 +1301,8 @@ def _short_conv_call(x, w, bias, act, interpret):
         in_specs=[block, before, _resident(taps, channels)]
         + [_resident(1, channels) for _ in more],
         out_specs=views(channels)[0],
-        **_short_conv_params(interpret))(x, x, w.T, *more)
+        **_short_conv_params(interpret, x.shape[2], channels))(
+            x, x, w.T, *more)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))
@@ -1303,7 +1324,8 @@ def _short_conv_bwd_call(x, w, bias, g, act, interpret):
         + [_resident(1, channels) for _ in more],
         out_specs=[views(x.shape[2])[0], _resident(taps, channels)]
         + [_resident(1, channels) for _ in more],
-        **_short_conv_params(interpret))(x, x, x, g, g, w.T, *more)
+        **_short_conv_params(interpret, x.shape[2], channels))(
+            x, x, x, g, g, w.T, *more)
     return dx, dw.T.astype(w.dtype), \
         None if gated else db[0][0].astype(bias.dtype)
 
@@ -1398,19 +1420,22 @@ def _flash_attention_op(ctx, ins, attrs):
 
 
 # ---------------------------------------------------------------------------
-# Static shape/dtype rule: flash_attention is shape-preserving on Q.
+# Static shape/dtype rule: Out is Q's shape at V's head size.
 # ---------------------------------------------------------------------------
-from ..analysis.shape_infer import ShapeError, dim_ok, first  # noqa: E402
+from ..analysis.shape_infer import (ShapeError, VarInfo, dim_ok,  # noqa: E402
+                                    first)
 from ..core.registry import register_shape_fn  # noqa: E402
 
 
 @register_shape_fn("flash_attention")
 def _flash_attention_shape(op, ins, attrs):
+    """Q and K share their head size D; V's, Dv, may differ (latent
+    attention: 192 against 128), and Out is Q's shape at Dv."""
     q, k, v = first(ins, "Q"), first(ins, "K"), first(ins, "V")
     for name, o in (("K", k), ("V", v)):
         if q.shape is not None and o.shape is not None:
-            if len(o.shape) != len(q.shape) or \
-                    not dim_ok(q.shape[-1], o.shape[-1]):
+            if len(o.shape) != len(q.shape) or (
+                    name == "K" and not dim_ok(q.shape[-1], o.shape[-1])):
                 raise ShapeError(
                     f"flash_attention: Q {list(q.shape)} vs {name} "
                     f"{list(o.shape)} (rank or head dim mismatch)")
@@ -1422,15 +1447,34 @@ def _flash_attention_shape(op, ins, attrs):
                 raise ShapeError(
                     f"flash_attention: {name}'s {o.shape[at]} heads do "
                     f"not divide Q's {q.shape[at]}")
-    return {"Out": q}
+    if q.shape is None or v.shape is None:
+        return {"Out": q}
+    return {"Out": VarInfo(tuple(q.shape[:-1]) + (v.shape[-1],), q.dtype)}
 
 
-# Sharding propagation: flash_attention is shape-preserving on Q (the
-# kernel runs per-shard under shard_map; batch/head sharding rides along).
-from ..analysis.shard_prop import shard_same_as  # noqa: E402
+# Sharding propagation: Out keeps Q's sharding on every axis but the head
+# size, which is V's (the kernel runs per-shard under shard_map; batch/head
+# sharding rides along).
+from ..analysis.shard_prop import first_in  # noqa: E402
 from ..core.registry import register_shard_fn  # noqa: E402
 
-register_shard_fn("flash_attention")(shard_same_as("Q"))
+
+@register_shard_fn("flash_attention")
+def _flash_attention_shard(op, ins, attrs):
+    q, v = first_in(ins, "Q"), first_in(ins, "V")
+    if q.spec is None:
+        return {}
+    return {"Out": tuple(q.spec[:-1]) + (v.entry(-1),)}
+
+
+def _flash_attention_shard_backward(op, outs, ins, attrs):
+    out = first_in(outs, "Out")
+    if out.spec is None:
+        return {}
+    return {"Q": tuple(out.spec[:-1]) + (first_in(ins, "Q").entry(-1),)}
+
+
+_flash_attention_shard.backward = _flash_attention_shard_backward
 
 
 # ---------------------------------------------------------------------------

@@ -87,6 +87,29 @@ def test_flash_cross_attention_kernel():
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("D,Dv", [(192, 128), (64, 32)])
+def test_flash_value_head_size_of_its_own(D, Dv):
+    """V's head size differs from Q's and K's (latent attention's 192 / 128):
+    the kernel's output is [B, T, H, Dv], equal to the formula's, and the
+    call is counted as ``route/flash_attention:value_dims``."""
+    B, T, H = 1, 256, 2
+    q, k = (jnp.asarray(R.randn(B, T, H, D), jnp.float32) for _ in range(2))
+    v = jnp.asarray(R.randn(B, T, H, Dv), jnp.float32)
+    before = profiler.compile_stats().snapshot().get(
+        "route/flash_attention:value_dims", 0)
+    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
+                          use_pallas=True, interpret=True)
+    assert out.shape == (B, T, H, Dv)
+    assert profiler.compile_stats().snapshot()[
+        "route/flash_attention:value_dims"] == before + 1
+    for h in range(H):
+        np.testing.assert_allclose(
+            np.asarray(out[:, :, h]),
+            _ref(np.asarray(q[:, :, h]), np.asarray(k[:, :, h]),
+                 np.asarray(v[:, :, h]), True, D ** -0.5),
+            atol=2e-5, rtol=2e-5)
+
+
 def test_flash_causal_cross_falls_back():
     BH, Tq, Tk, D = 1, 64, 128, 16
     q = R.randn(BH, Tq, D).astype("float32")
@@ -199,6 +222,12 @@ BWD_CASES = {
     # Nemotron's grouping in small: 16 query heads to one K/V head of 128
     "grouped_16_causal": (16, 1, 512, 512, 128, 128, True, (128, 128),
                           False, "one_pass"),
+    # latent attention: q and k of 192 (whole lane tiles of 256 in VMEM),
+    # v of 128; and 64 / 32
+    "value_dims_192": (4, 4, 256, 256, 192, 128, True, (128, 128), False,
+                       "one_pass"),
+    "value_dims_64": (2, 2, 256, 256, 64, 32, True, (128, 128), False,
+                      "one_pass"),
     # a row of lse cannot be cut into blocks of 32: the two kernels
     "small_blocks": (4, 2, 128, 128, 16, 16, True, (32, 32), False,
                      "two_pass"),
@@ -253,6 +282,7 @@ def test_flash_backward_routes_match_reference(case):
     (4096, 4096, 128, 128, (1024, 1024), True),      # olmoe-, ouro-
     (8192, 8192, 128, 128, (1024, 1024), True),      # nemotron-train-scan
     (9216, 9216, 128, 128, (1024, 1024), True),      # the longest at 1024
+    (8192, 8192, 192, 128, (1024, 1024), False),     # kimi-linear-train-scan
     (10240, 10240, 128, 128, (1024, 1024), False),
     (9216, 9216, 64, 64, (1024, 1024), True),        # 64 features take
     (10240, 10240, 64, 64, (1024, 1024), False),     # the lanes 128 do

@@ -670,3 +670,28 @@ def test_two_nemotron_sequences_do_not_fit_with_every_layer_recomputed(
     _, total, _ = _cell_step(one_chip, monkeypatch, "nemotron_3_nano_30b_a3b",
                              batch=2, recompute=True)
     assert total > 0.9 * CHIP_BYTES, total
+
+
+def test_kimi_linear_cell_step_compiles_and_fits_the_chip(one_chip,
+                                                          monkeypatch):
+    """The K-step program of the cell whose configuration holds the gated
+    delta rule and latent attention: four ``gated_delta_scan``s (XLA), four
+    ungated filters over 12 288 channels (the kernels, which ask for more
+    than the default scoped VMEM there), attention at 192 / 128 head sizes
+    through the kernels (two backward kernels at 8192 keys), four ``moe``
+    ops (a share of 8 SiLU-gated experts with a shared one); every layer
+    two recomputed stretches; ``memory_analysis()`` puts the step between a
+    quarter and 90 % of the chip's 16.9 GB (12.14 GB; the KDA mixers outside
+    ``layers.recompute``, the op's own stretches kept, need 18.16; with the
+    chunk's triangle by XLA's solve, each layer ONE stretch read 15.46 GB and
+    the op without its own stretches 17.59)."""
+    seen, total, _ = _cell_step(one_chip, monkeypatch, "kimi_linear_48b_a3b")
+    assert seen["route/gated_delta_scan:xla"] == 4
+    assert seen["route/short_conv:pallas"] == 4
+    assert seen["route/flash_attention:pallas"] == 1
+    assert seen["route/flash_attention:value_dims"] == 1
+    assert seen["route/flash_attention_bwd:two_pass"] == 1
+    for route in ("dropless", "sigmoid", "share", "gated_pair", "shared"):
+        assert seen["route/moe:" + route] == 4, route
+    assert seen["route/recompute:checkpoint"] == 10
+    assert 0.25 * CHIP_BYTES < total < 0.9 * CHIP_BYTES, total

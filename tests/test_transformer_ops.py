@@ -332,6 +332,30 @@ def test_flash_attention_shape_rule_takes_heads_that_divide():
         rule(None, qkv(q, (-1, 64, 8, 64), (-1, 64, 3, 64)), {})
     with pytest.raises(ShapeError, match="head dim mismatch"):
         rule(None, qkv(q, (-1, 64, 8, 32)), {})
+    # V's head size may differ from Q's and K's: Out takes it
+    assert rule(None, qkv((-1, 64, 32, 192), (-1, 64, 32, 192),
+                          (-1, 64, 32, 128)), {})["Out"].shape \
+        == (-1, 64, 32, 128)
+    with pytest.raises(ShapeError, match="head dim mismatch"):
+        rule(None, qkv((-1, 64, 32, 192), (-1, 64, 32, 128),
+                       (-1, 64, 32, 128)), {})
+
+
+def test_flash_attention_shard_rule_gives_out_the_value_head_size():
+    from paddle_tpu.analysis.shard_prop import ShardInfo
+    from paddle_tpu.core.registry import get_shard_fn
+
+    rule = get_shard_fn("flash_attention")
+    q = ShardInfo(("dp", None, "tp", None), (-1, 64, 32, 192))
+    v = ShardInfo(("dp", None, "tp", None), (-1, 64, 32, 128))
+    out = rule(None, {"Q": [q], "K": [q], "V": [v]}, {})
+    assert out == {"Out": ("dp", None, "tp", None)}
+    back = rule.backward(
+        None, {"Out": [ShardInfo(("dp", None, "tp", None),
+                                 (-1, 64, 32, 128))]},
+        {"Q": [q], "K": [q], "V": [v]}, {})
+    assert back == {"Q": ("dp", None, "tp", None)}
+    assert rule(None, {"Q": [ShardInfo(None, (-1, 64, 32, 192))]}, {}) == {}
 
 
 def test_short_conv_shape_and_sharding_rules():
