@@ -564,7 +564,8 @@ def ssd_scan(u, delta, a, bm, cm, d, chunk=256, name=None, interpret=False):
     return out
 
 
-def gated_delta_scan(q, k, v, g, beta, scale, chunk=64, name=None):
+def gated_delta_scan(q, k, v, g, beta, scale, chunk=64, name=None,
+                     interpret=False):
     """The gated delta rule of Kimi Delta Attention, chunked
     (ops/delta_ops.py ``gated_delta_scan``): ``q`` and ``k`` [B, T, H, K]
     (L2-normalised: the caller's), ``v`` [B, T, H, V], ``g`` [B, T, H, K]
@@ -572,7 +573,15 @@ def gated_delta_scan(q, k, v, g, beta, scale, chunk=64, name=None):
     (0, 1): the caller's sigmoid); every head's state [K, V] starts at 0,
     S[t] = (I - beta[t] k[t] k[t]^T) Diag(exp(g[t])) S[t-1]
     + beta[t] k[t] v[t]^T, out[t] = S[t]^T (scale q[t]), [B, T, H, V].
-    ``chunk`` positions a chunk; it must divide T."""
+    ``chunk`` positions a chunk; it must divide T.
+
+    On a TPU, on one device, head sizes of whole lane tiles (multiples of
+    128) and a chunk of whole sub-blocks of 16 take a pair of Pallas kernels
+    (``ops/delta_kernels.py``; the state stays in VMEM across the chunks and
+    the backward keeps the inputs and the chunks' boundary states); every
+    other shape, a mesh and the CPU take the chunked XLA form.
+    ``interpret`` runs the kernels through the Pallas interpreter (CPU
+    tests)."""
     helper = LayerHelper("gated_delta_scan", name=name)
     out = helper.create_variable_for_type_inference(
         v.dtype, tuple(q.shape[:3]) + (v.shape[-1],))
@@ -580,7 +589,8 @@ def gated_delta_scan(q, k, v, g, beta, scale, chunk=64, name=None):
                      inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
                              "Beta": [beta]},
                      outputs={"Out": [out]},
-                     attrs={"scale": float(scale), "chunk": int(chunk)})
+                     attrs={"scale": float(scale), "chunk": int(chunk),
+                            **({"interpret": True} if interpret else {})})
     return out
 
 

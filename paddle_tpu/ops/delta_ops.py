@@ -43,8 +43,20 @@ the next, each under ``jax.checkpoint``, so what a stretch keeps for its
 backward (some 25 arrays the size of q) lives only while that stretch's
 backward runs.  The stages are ``jax.named_scope``s
 inside the op's own ``pt.gated_delta_scan:<block>.<position>``; they do not
-start with ``pt.``, so the op stays the innermost owner of its time.  One
-lowering, counted at trace time as ``route/gated_delta_scan:xla``.
+start with ``pt.``, so the op stays the innermost owner of its time.
+
+Two lowerings, chosen by ``delta_kernels.gated_delta_scan_route`` from what
+the code can see and counted at trace time as ``route/gated_delta_scan:
+{pallas,interpret,xla}``: on a TPU, on one device, with K and V whole lane
+tiles, the chunk whole sub-blocks, T whole chunks and float32 or bfloat16
+operands, the pair of Pallas kernels of ``ops/delta_kernels.py`` (the same
+numerics on the [T, H * K] layout the model has, the state held in VMEM
+across the chunks; a ``jax.custom_vjp`` whose residuals are the op's inputs
+and the chunk-boundary states, so it needs no stretches); everywhere else
+(every CPU run, a mesh, every other shape) ``delta_chunked`` below, which is
+also the kernels' test reference.  ``interpret`` is the kernels through the
+Pallas interpreter, which only a test asks for through the op's
+``interpret`` attribute.
 """
 from __future__ import annotations
 
@@ -57,8 +69,9 @@ from jax import lax
 
 from ..core import compile_cache
 from ..core.registry import register_op
+from . import delta_kernels
 
-SUB = 16        # positions of a diagonal sub-block (fla's)
+SUB = delta_kernels.SUB  # positions of a diagonal sub-block (fla's)
 STRETCH = 1024  # positions the backward recomputes at a time
 
 
@@ -239,16 +252,24 @@ def delta_chunked(q, k, v, g, beta, scale, chunk):
 def _gated_delta_scan(ctx, ins, attrs):
     """Q, K [B, T, H, K], V [B, T, H, V], G [B, T, H, K], Beta [B, T, H] ->
     Out [B, T, H, V] (this file's docstring); ``scale`` multiplies q,
-    ``chunk`` positions a chunk, which divides T."""
+    ``chunk`` positions a chunk, which divides T.  The kernels or the
+    chunked XLA form, by ``delta_kernels.gated_delta_scan_route``."""
     q = ins["Q"][0]
     chunk = int(attrs.get("chunk", 64))
     if q.shape[1] % chunk:
         raise ValueError(f"gated_delta_scan: T {q.shape[1]} is not whole "
                          f"chunks of {chunk}")
-    compile_cache.stats().bump("route/gated_delta_scan:xla")
-    return {"Out": delta_chunked(q, ins["K"][0], ins["V"][0], ins["G"][0],
-                                 ins["Beta"][0], float(attrs["scale"]),
-                                 chunk)}
+    operands = (q, ins["K"][0], ins["V"][0], ins["G"][0], ins["Beta"][0],
+                float(attrs["scale"]), chunk)
+    single = ctx.mesh is None or getattr(ctx.mesh, "size", 1) == 1
+    route = delta_kernels.gated_delta_scan_route(
+        (q.shape, operands[2].shape), chunk, q.dtype,
+        attrs.get("interpret", False)) if single else "xla"
+    compile_cache.stats().bump("route/gated_delta_scan:" + route)
+    if route != "xla":
+        return {"Out": delta_kernels.gated_delta_scan(
+            *operands, interpret=route == "interpret")}
+    return {"Out": delta_chunked(*operands)}
 
 
 # ---------------------------------------------------------------------------

@@ -675,18 +675,19 @@ def test_two_nemotron_sequences_do_not_fit_with_every_layer_recomputed(
 def test_kimi_linear_cell_step_compiles_and_fits_the_chip(one_chip,
                                                           monkeypatch):
     """The K-step program of the cell whose configuration holds the gated
-    delta rule and latent attention: four ``gated_delta_scan``s (XLA), four
-    ungated filters over 12 288 channels (the kernels, which ask for more
-    than the default scoped VMEM there), attention at 192 / 128 head sizes
-    through the kernels (two backward kernels at 8192 keys), four ``moe``
-    ops (a share of 8 SiLU-gated experts with a shared one); every layer
-    two recomputed stretches; ``memory_analysis()`` puts the step between a
-    quarter and 90 % of the chip's 16.9 GB (12.14 GB; the KDA mixers outside
-    ``layers.recompute``, the op's own stretches kept, need 18.16; with the
-    chunk's triangle by XLA's solve, each layer ONE stretch read 15.46 GB and
-    the op without its own stretches 17.59)."""
+    delta rule and latent attention: four ``gated_delta_scan``s (the pair of
+    kernels, no XLA form), four ungated filters over 12 288 channels (the
+    kernels, which ask for more than the default scoped VMEM there),
+    attention at 192 / 128 head sizes through the kernels (two backward
+    kernels at 8192 keys), four ``moe`` ops (a share of 8 SiLU-gated experts
+    with a shared one); every layer two recomputed stretches;
+    ``memory_analysis()`` puts the step between a quarter and 90 % of the
+    chip's 16.9 GB (11.57 GB: the kernels keep the inputs and the chunks'
+    boundary states; the XLA form with its own stretches of 1 024 positions
+    needed 12.14, without them 17.59)."""
     seen, total, _ = _cell_step(one_chip, monkeypatch, "kimi_linear_48b_a3b")
-    assert seen["route/gated_delta_scan:xla"] == 4
+    assert seen["route/gated_delta_scan:pallas"] == 4
+    assert "route/gated_delta_scan:xla" not in seen
     assert seen["route/short_conv:pallas"] == 4
     assert seen["route/flash_attention:pallas"] == 1
     assert seen["route/flash_attention:value_dims"] == 1
@@ -695,3 +696,37 @@ def test_kimi_linear_cell_step_compiles_and_fits_the_chip(one_chip,
         assert seen["route/moe:" + route] == 4, route
     assert seen["route/recompute:checkpoint"] == 10
     assert 0.25 * CHIP_BYTES < total < 0.9 * CHIP_BYTES, total
+    assert total < 12.0e9, total
+
+
+def test_gated_delta_scan_is_one_kernel_a_direction_inside_its_vmem(spec):
+    """``gated_delta_scan`` at the Kimi Linear cell's shape (one 8192-token
+    sequence, 32 heads of 128, chunks of 64), value and all five gradients:
+    Mosaic takes both kernels' blocks (a chunk of eight heads, 1 024 lanes)
+    within ``DELTA_VMEM_BYTES`` of scoped VMEM, which is what the route's
+    bound counts; one custom call a direction; what stands in HBM beside
+    operands and gradients is the output and its cotangent, the chunks'
+    boundary states (268 MB = 2 of q's size) and the operands copied from
+    the [T, H, K] layout to the flat one the kernels read, nothing of
+    [chunks, heads, 64, 64] or of the chunked form's stretches."""
+    from paddle_tpu.ops import delta_kernels
+
+    b, t_len, heads, kd, chunk = 1, 8192, 32, 128, 64
+    shape = (b, t_len, heads, kd)
+    lanes = delta_kernels.heads_a_block(heads, kd) * kd
+    assert lanes == delta_kernels.DELTA_BLOCK_LANES
+    assert delta_kernels._blocks_fit(chunk, kd, kd, lanes // kd)
+    assert delta_kernels.DELTA_VMEM_BYTES <= 32 << 20
+
+    def run(*xs):
+        return delta_kernels.gated_delta_scan(*xs, kd ** -0.5, chunk)
+
+    operands = (spec(shape),) * 4 + (spec((b, t_len, heads)),)
+    forward = jax.jit(run).lower(*operands).compile()
+    assert _kernels(forward.as_text()) == 1
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *xs: jnp.sum(run(*xs) ** 2), argnums=tuple(range(5)))) \
+        .lower(*operands).compile()
+    assert _kernels(compiled.as_text()) == 2
+    wide = b * t_len * heads * kd * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * wide
