@@ -163,32 +163,43 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 # backward kernels (FlashAttention-2: recompute p from (q, k, lse) per block)
 # ---------------------------------------------------------------------------
-# Two routes, chosen by ``_one_pass_fits`` from the static shapes and the
-# blocks alone and counted at trace time as
-# ``route/flash_attention_bwd:{one_pass,two_pass}``:
+# Two routes, chosen by ``_bwd_route`` from the static shapes and the blocks
+# alone and counted at trace time as
+# ``route/flash_attention_bwd:{one_pass,two_pass}`` (and ``:raised_vmem``
+# where the one kernel asks for more scoped VMEM than the other calls):
 #
 # * ONE kernel (``_flash_bwd_one_pass_kernel``) that visits each (query block,
 #   key block) tile once and makes dq, dk and dv from one recomputation of
 #   the scores: five products, one exponential and one mask a tile.  dk and
 #   dv of a K/V head's whole sequence stay in VMEM, so it runs where they fit
-#   ``FLASH_BWD_VMEM_BYTES`` beside what else it holds (at 1024 x 1024 blocks
-#   up to 9 216 keys of 64 or of 128 features; at 512 x 512, 13 824).
+#   beside what else it holds (``_one_pass_bytes``): within
+#   ``FLASH_BWD_VMEM_BYTES`` at 1024 x 1024 blocks up to 9 216 keys of 64 or
+#   of 128 features; asking for more, up to ``FLASH_BWD_ONE_PASS_MAX_BYTES``,
+#   up to 14 336 keys of those and 8192 keys of latent attention's 192 / 128.
 # * TWO kernels (``_flash_bwd_dq_kernel``, ``_flash_bwd_dkv_kernel``), each
 #   recomputing the scores (seven products, two exponentials a tile) with
 #   O(block) VMEM: every longer sequence, and blocks a row of lse cannot be
 #   cut into.
 #
-# Every call asks for ``FLASH_BWD_VMEM_BYTES`` of scoped VMEM.  What Mosaic
-# asks for the one kernel at 1024 x 1024 blocks, compiling for a v5e with
-# every operand in HBM: 13.6 MiB at 2048 keys, 19.6 at 4096, 27.6 at 8192,
-# 35.6 at 12 288 (26.6 inside Nemotron's step).  No more than 32 MiB: asked
-# for 64 the same kernel ran 6 % slower at 8192 keys, alone and in a step.
+# Every other call asks for ``FLASH_BWD_VMEM_BYTES`` of scoped VMEM.  What
+# Mosaic asks for the one kernel at 1024 x 1024 blocks, compiling for a v5e
+# with every operand in HBM: 13.6 MiB at 2048 keys, 19.6 at 4096, 27.6 at
+# 8192, 31.7 at 10 240, 35.6 at 12 288 (26.6 inside Nemotron's step), 39.9
+# at 8192 keys of 192 / 128 (40.0 inside Kimi Linear's step), 1 to 2 MiB
+# under ``_one_pass_bytes``.  No more than it needs: asked for 64 MiB the
+# one kernel ran 6 % slower at 8192 keys of 64, alone and in a step.
 # (on the v5e, the kernels alone, ms forward / two kernels / one, causal,
 # 1024 x 1024 blocks: 32 query over 8 K/V heads of 64 at T 8192 4.9 / 15.8 /
 # 9.0; 2 x 16 heads of 128 at T 4096 1.79 / 4.63 / 2.84; 16 heads 0.71 /
-# 2.25 / 1.34.  With the tile not transposed the one kernel read 10.0, 3.13,
-# 1.50; with queries innermost and dq resident 11.8, 3.27, 1.64.)
-FLASH_BWD_VMEM_BYTES = 32 << 20    # scoped VMEM a backward kernel may take
+# 2.25 / 1.34; 32 heads of 192 / 128 at T 8192 10.1 / 27.5 / 18.3 at 42
+# MiB, the same at 40 (21.0 at 512 x 512 blocks and 32 MiB); 32 heads of 128
+# at T 10 240 9.6 / 27.1 / 16.5 at 34 MiB (20.1 at 512 x 512), at T 14 336
+# 18.0 / 51.1 / 30.9 at 42 MiB.  With the tile not transposed the one kernel
+# read 10.0, 3.13, 1.50; with queries innermost and dq resident 11.8, 3.27,
+# 1.64.)
+MIB = 1 << 20
+FLASH_BWD_VMEM_BYTES = 32 * MIB    # scoped VMEM a backward kernel asks for
+FLASH_BWD_ONE_PASS_MAX_BYTES = 42 * MIB    # the most the one kernel asks
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -361,34 +372,57 @@ def _flash_bwd_one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _one_pass_fits(Tq, Tk, D, Dv, block_q, block_k):
-    """Whether the one-pass backward takes this call, from the static shapes
-    and the blocks alone: what Mosaic holds for it in VMEM (dk, dv of a K/V
-    head's whole sequence and the blocks q, do, dq, lse, delta, k, v in both
-    pipeline buffers; the dq accumulator; two float32 tiles) within
-    ``FLASH_BWD_VMEM_BYTES``, and a row of lse it can cut into blocks.  D
-    and Dv may differ; each is counted at whole lane tiles, so a head size
-    of 192 (latent attention's q and k) lands in VMEM as 256 lanes: at 8192
-    keys of 192 / 128 and 1024 x 1024 blocks that is 43.0 MB, over the
-    32 MiB: the two kernels."""
+def _one_pass_bytes(Tq, Tk, D, Dv, block_q, block_k):
+    """What Mosaic holds in VMEM for the one-pass backward, from the static
+    shapes and the blocks alone: dk, dv of a K/V head's whole sequence and
+    the blocks q, do, dq, lse, delta, k, v in both pipeline buffers; the dq
+    accumulator; two float32 tiles.  D and Dv may differ; each is counted
+    at whole lane tiles, so a head size of 192 (latent attention's q and k)
+    lands in VMEM as 256 lanes: at 8192 keys of 192 / 128 and 1024 x 1024
+    blocks that is 43.0 MB, 41.0 MiB (Mosaic asks 39.9 MiB alone, 40.0
+    inside Kimi Linear's step)."""
     d, dv = -(-D // 128) * 128, -(-Dv // 128) * 128    # whole lane tiles
     resident = 2 * Tk * (d + dv) * 4
     streamed = 2 * (block_q * (2 * d + dv + 2) + block_k * (d + dv)) * 4
     held = block_q * d * 4 + 2 * block_q * block_k * 4
-    return (resident + streamed + held <= FLASH_BWD_VMEM_BYTES
+    return resident + streamed + held
+
+
+def _one_pass_fits(Tq, Tk, D, Dv, block_q, block_k, limit=None):
+    """Whether the one-pass backward fits this call: ``_one_pass_bytes``
+    within ``limit`` (``FLASH_BWD_VMEM_BYTES`` unless given), and a row of
+    lse it can cut into blocks."""
+    limit = FLASH_BWD_VMEM_BYTES if limit is None else limit
+    return (_one_pass_bytes(Tq, Tk, D, Dv, block_q, block_k) <= limit
             and (block_q % 128 == 0 or block_q == Tq))
 
 
-def _flash_bwd_params(interpret, *semantics):
+def _bwd_route(Tq, Tk, D, Dv, block_q, block_k):
+    """(route, scoped VMEM the call asks for) of the backward, from the
+    static shapes and the blocks alone: the one kernel at
+    ``FLASH_BWD_VMEM_BYTES`` where ``_one_pass_fits`` admits it there; else
+    the one kernel asking for its ``_one_pass_bytes`` rounded up to a whole
+    MiB, where that is within ``FLASH_BWD_ONE_PASS_MAX_BYTES``; else the
+    two kernels.  The blocks stay as asked: on the v5e, at 8192 keys of
+    192 / 128 and 32 heads, one kernel at 1024 x 1024 and 42 MiB took 18.3
+    ms, at 512 x 512 and 32 MiB (which fit there) 21.0, the two kernels
+    27.5."""
+    if not _one_pass_fits(Tq, Tk, D, Dv, block_q, block_k,
+                          FLASH_BWD_ONE_PASS_MAX_BYTES):
+        return "two_pass", FLASH_BWD_VMEM_BYTES
+    need = -(-_one_pass_bytes(Tq, Tk, D, Dv, block_q, block_k) // MIB) * MIB
+    return "one_pass", max(need, FLASH_BWD_VMEM_BYTES)
+
+
+def _flash_bwd_params(interpret, vmem_bytes, *semantics):
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics,
-        vmem_limit_bytes=FLASH_BWD_VMEM_BYTES)}
+        dimension_semantics=semantics, vmem_limit_bytes=vmem_bytes)}
 
 
 def _flash_bwd_one_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
-                        block_k, interpret):
+                        block_k, interpret, vmem_bytes):
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     Dv = v.shape[2]
@@ -433,13 +467,14 @@ def _flash_bwd_one_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
         ],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-        **_flash_bwd_params(interpret, "parallel", "arbitrary", "arbitrary"),
+        **_flash_bwd_params(interpret, vmem_bytes, "parallel", "arbitrary",
+                            "arbitrary"),
     )(q, k, v, g, lse.reshape(BH, 1, Tq), delta.reshape(BH, 1, Tq))
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _flash_bwd_two_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
-                        block_k, interpret):
+                        block_k, interpret, vmem_bytes):
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     Dv = v.shape[2]
@@ -447,7 +482,8 @@ def _flash_bwd_two_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
     nk = Tk // block_k
     group = BH // k.shape[0]
     kv = _kv_of(group)
-    kwargs = _flash_bwd_params(interpret, "parallel", "parallel", "arbitrary")
+    kwargs = _flash_bwd_params(interpret, vmem_bytes, "parallel", "parallel",
+                               "arbitrary")
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
@@ -509,7 +545,8 @@ def _flash_bwd_two_pass(q, k, v, g, lse, delta, causal, sm_scale, block_q,
 
 def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                interpret, g_lse=None):
-    """dq, dk, dv by the route ``_one_pass_fits`` gives the static shapes."""
+    """dq, dk, dv by the route and scoped VMEM ``_bwd_route`` gives the
+    static shapes."""
     # delta_i = sum_d dO_i · O_i  (rescaling term of dsoftmax); O(T·Dv) work,
     # fused by XLA — not worth a kernel.  A cotangent on lse folds in here:
     # dL/ds_ij = p_ij (dp_ij - delta_i + g_lse_i), so delta_eff = delta -
@@ -518,14 +555,15 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                     axis=-1, keepdims=True)                # [BH, Tq, 1]
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32).reshape(delta.shape)
-    one_pass = _one_pass_fits(q.shape[1], k.shape[1], q.shape[2], v.shape[2],
-                              block_q, block_k)
-    compile_cache.stats().bump(
-        "route/flash_attention_bwd:" + ("one_pass" if one_pass
-                                        else "two_pass"))
-    bwd = _flash_bwd_one_pass if one_pass else _flash_bwd_two_pass
+    route, vmem_bytes = _bwd_route(q.shape[1], k.shape[1], q.shape[2],
+                                   v.shape[2], block_q, block_k)
+    stats = compile_cache.stats()
+    stats.bump("route/flash_attention_bwd:" + route)
+    if vmem_bytes > FLASH_BWD_VMEM_BYTES:
+        stats.bump("route/flash_attention_bwd:raised_vmem")
+    bwd = _flash_bwd_one_pass if route == "one_pass" else _flash_bwd_two_pass
     return bwd(q, k, v, g, lse, delta, causal, sm_scale, block_q, block_k,
-               interpret)
+               interpret, vmem_bytes)
 
 
 def _reference_attention(q, k, v, causal, sm_scale):
@@ -617,11 +655,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 
     The backward is one kernel that visits each (query block, key block)
     tile once where dK and dV of a K/V head's whole sequence fit VMEM
-    beside a tile's temporaries (``_one_pass_fits``: from Tq, Tk, the
+    beside a tile's temporaries (``_bwd_route``: from Tq, Tk, the
     feature sizes and the blocks alone; at 1024 x 1024 blocks up to 9 216
-    keys of 64 or 128 features), and the two kernels that each
-    recompute the scores for longer sequences; counted at trace time as
-    ``route/flash_attention_bwd:{one_pass,two_pass}``.
+    keys of 64 or 128 features in the scoped VMEM every call asks for, up to
+    14 336 asking for more), and the two kernels that each recompute the
+    scores for longer sequences; counted at trace time as
+    ``route/flash_attention_bwd:{one_pass,two_pass}``, and ``:raised_vmem``
+    where the one kernel asked for more.
 
     use_pallas=None auto-selects the Pallas kernel on TPU only; every other
     backend gets the exact jnp reference.  interpret=True (explicit, as the

@@ -236,15 +236,11 @@ BWD_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(BWD_CASES))
-def test_flash_backward_routes_match_reference(case):
-    """dQ, dK and dV of both routes (interpreted) against autodiff of the
-    plain formula: several blocks in both directions, so accumulation
-    across blocks, over a group's query heads and the causal skip are all
-    exercised; the route is the one the rule gives the static shape."""
-    heads, kv_heads, Tq, Tk, D, Dv, causal, blocks, with_lse, route = \
-        BWD_CASES[case]
-    rng = np.random.RandomState(len(case))
+def _grads_against_formula(heads, kv_heads, Tq, Tk, D, Dv, causal, blocks,
+                           with_lse, seed):
+    """dQ, dK and dV of the kernels (interpreted) against autodiff of the
+    plain formula; the backward routes the call counted."""
+    rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(heads, Tq, D), jnp.float32)
     k = jnp.asarray(rng.randn(kv_heads, Tk, D), jnp.float32)
     v = jnp.asarray(rng.randn(kv_heads, Tk, Dv), jnp.float32)
@@ -269,12 +265,67 @@ def test_flash_backward_routes_match_reference(case):
     before = _bwd_routes()
     got = jax.grad(kernels, argnums=(0, 1, 2))(q, k, v)
     seen = {r: n - before.get(r, 0) for r, n in _bwd_routes().items()}
-    assert seen.get(route) == 1 and sum(seen.values()) == 1
     want = jax.grad(formula, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4)
+    return {r: n for r, n in seen.items() if n}
+
+
+@pytest.mark.parametrize("case", sorted(BWD_CASES))
+def test_flash_backward_routes_match_reference(case):
+    """dQ, dK and dV of both routes (interpreted) against autodiff of the
+    plain formula: several blocks in both directions, so accumulation
+    across blocks, over a group's query heads and the causal skip are all
+    exercised; the route is the one the rule gives the static shape."""
+    *shape, route = BWD_CASES[case]
+    assert _grads_against_formula(*shape, seed=len(case)) == {route: 1}
+
+
+@pytest.mark.parametrize("causal,with_lse", [(True, False), (False, True)])
+def test_flash_backward_with_raised_vmem_matches_reference(
+        monkeypatch, causal, with_lse):
+    """Latent attention's head sizes (q and k of 192, v of 128) where the
+    one kernel does not fit the scoped VMEM every call asks for and does fit
+    the most it may ask for (the two limits lowered so that 1 024 keys stand
+    for the cell's 8 192): the one kernel, asking for its count rounded up
+    to a whole MiB."""
+    monkeypatch.setattr(pallas_kernels, "FLASH_BWD_VMEM_BYTES", 16 << 20)
+    monkeypatch.setattr(pallas_kernels, "FLASH_BWD_ONE_PASS_MAX_BYTES",
+                        24 << 20)
+    shape = (2, 2, 1024, 1024, 192, 128, causal, (1024, 1024), with_lse)
+    assert pallas_kernels._bwd_route(*shape[2:6], *shape[7]) == (
+        "one_pass", 21 << 20)
+    assert _grads_against_formula(*shape, seed=3) == {
+        "one_pass": 1, "raised_vmem": 1}
+
+
+MIB = 1 << 20
+
+
+@pytest.mark.parametrize("T,D,Dv,blocks,want", [
+    (8192, 192, 128, (1024, 1024), ("one_pass", 42 * MIB)),  # kimi-linear-
+    (8192, 64, 64, (1024, 1024), ("one_pass", 32 * MIB)),    # lfm2-, granite-
+    (4096, 128, 128, (1024, 1024), ("one_pass", 32 * MIB)),  # olmoe-, ouro-
+    (8192, 128, 128, (1024, 1024), ("one_pass", 32 * MIB)),  # nemotron-
+    (9216, 128, 128, (1024, 1024), ("one_pass", 32 * MIB)),
+    (10240, 128, 128, (1024, 1024), ("one_pass", 34 * MIB)),
+    (14336, 64, 64, (1024, 1024), ("one_pass", 42 * MIB)),
+    (15360, 128, 128, (1024, 1024), ("two_pass", 32 * MIB)),
+    (32768, 128, 128, (1024, 1024), ("two_pass", 32 * MIB)),
+    (8192, 192, 128, (512, 512), ("one_pass", 32 * MIB)),
+    (4096, 128, 128, (2048, 1024), ("one_pass", 34 * MIB)),  # a tile's
+    (4096, 128, 128, (2048, 2048), ("two_pass", 32 * MIB)),  # temporaries
+    (256, 16, 16, (32, 32), ("two_pass", 32 * MIB)),         # no row blocks
+])
+def test_flash_backward_vmem_is_a_rule_on_static_shape(T, D, Dv, blocks,
+                                                       want):
+    """The one kernel in the scoped VMEM every call asks for where it fits
+    there; else the one kernel asking for its count rounded up to a whole
+    MiB where that is within the most it may ask for; else the two kernels.
+    The five other attention cells keep the 32 MiB they asked for."""
+    assert pallas_kernels._bwd_route(T, T, D, Dv, *blocks) == want
 
 
 @pytest.mark.parametrize("Tq,Tk,D,Dv,blocks,fits", [

@@ -217,10 +217,12 @@ def test_short_conv_is_one_kernel_a_direction(spec, batch):
         < 1.5 * batch * LFM2_T * LFM2_D * 4
 
 
-def _attention_grads(spec, batch, seq, heads, kv_heads, d, with_value=False):
+def _attention_grads(spec, batch, seq, heads, kv_heads, d, with_value=False,
+                     dv=None):
     """The compiled module text of ``flash_attention``'s gradients (and the
-    value) from the [B, T, H, D] arrays a model hands the op, at the blocks
-    the cells run (the layer's default 1024 x 1024)."""
+    value) from the [B, T, H, D] arrays a model hands the op (V's head size
+    ``dv``, D unless given), at the blocks the cells run (the layer's
+    default 1024 x 1024)."""
     def loss(q, k, v, mix):
         return jnp.sum(pallas_kernels.flash_attention(
             q, k, v, causal=True, block_q=1024, block_k=1024,
@@ -233,8 +235,8 @@ def _attention_grads(spec, batch, seq, heads, kv_heads, d, with_value=False):
     with jax.default_matmul_precision("default"):
         return jax.jit(grad(loss, argnums=(0, 1, 2))).lower(
             spec((batch, seq, heads, d)), spec((batch, seq, kv_heads, d)),
-            spec((batch, seq, kv_heads, d)),
-            spec((batch, seq, heads, d))).compile().as_text()
+            spec((batch, seq, kv_heads, dv or d)),
+            spec((batch, seq, heads, dv or d))).compile().as_text()
 
 
 @pytest.mark.parametrize("batch", [1, 2])
@@ -280,35 +282,65 @@ def test_long_sequences_keep_the_two_backward_kernels(spec, seq, kernels):
     assert _kernels(_attention_grads(spec, 1, seq, 2, 2, 128)) == kernels
 
 
-def _longest_one_pass(d, block=1024):
-    return max(t for t in range(block, 65536 + 1, block)
-               if pallas_kernels._one_pass_fits(t, t, d, d, block, block))
+def _longest_one_pass(d, limit=None):
+    return max(t for t in range(1024, 65536 + 1, 1024)
+               if pallas_kernels._one_pass_fits(t, t, d, d, 1024, 1024,
+                                                limit))
+
+
+def _bwd_seen(before):
+    return {r.rsplit(":", 1)[1]: n - before.get(r, 0)
+            for r, n in compile_cache.stats().snapshot().items()
+            if r.startswith("route/flash_attention_bwd:")
+            and n != before.get(r, 0)}
 
 
 @pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("longer,route,kernels", [
-    (0, "one_pass", 2), (1024, "two_pass", 3)], ids=["longest", "next"])
-def test_the_longest_one_pass_sequence_compiles(spec, d, longer, route,
-                                                kernels):
+@pytest.mark.parametrize("raised,longer,routes,kernels", [
+    (False, 0, {"one_pass": 1}, 2),
+    (False, 1024, {"one_pass": 1, "raised_vmem": 1}, 2),
+    (True, 0, {"one_pass": 1, "raised_vmem": 1}, 2),
+    (True, 1024, {"two_pass": 1}, 3)],
+    ids=["longest", "next", "longest_raised", "past"])
+def test_the_longest_one_pass_sequence_compiles(spec, d, raised, longer,
+                                                routes, kernels):
     """The longest sequence ``_one_pass_fits`` admits at 1024 x 1024 blocks
-    (9 216 keys, of 64 features as of 128: a row takes whole lane tiles)
-    compiles on the one-pass route within ``FLASH_BWD_VMEM_BYTES``, and the
-    next block up takes the two kernels.  At 32 K / V heads XLA keeps no
-    operand or result of the kernel in VMEM beside it (at two heads it keeps
-    dK and dV there, and the kernel then needs 17 MiB less), so the kernel
-    holds all that the rule counts."""
+    within ``FLASH_BWD_VMEM_BYTES`` (9 216 keys, of 64 features as of 128: a
+    row takes whole lane tiles) compiles on the one-pass route; the next
+    block up (10 240 keys) takes the one kernel asking for 34 MiB (Mosaic
+    asks 31.7), the longest within ``FLASH_BWD_ONE_PASS_MAX_BYTES`` (14 336
+    keys) the one kernel asking for 42, and the next block up from there
+    the two kernels.  At 32 K / V heads XLA keeps no operand or result of
+    the kernel in VMEM beside it (at two heads it keeps dK and dV there, and
+    the kernel then needs 17 MiB less), so the kernel holds all that the
+    rule counts."""
     heads = 32
-    seq = _longest_one_pass(d) + longer
+    limit = pallas_kernels.FLASH_BWD_ONE_PASS_MAX_BYTES if raised else None
+    seq = _longest_one_pass(d, limit) + longer
     before = dict(compile_cache.stats().snapshot())
     text = _attention_grads(spec, 1, seq, heads, heads, d)
-    after = compile_cache.stats().snapshot()
-    key = "route/flash_attention_bwd:" + route
-    assert after.get(key, 0) - before.get(key, 0) == 1
+    assert _bwd_seen(before) == routes
     results = re.findall(rf"= \(((?:f32\[{heads},{seq},{d}\]\S*(?:, )?){{3}})"
                          r"\) custom-call\(", text)
-    assert len(results) == (route == "one_pass")
+    assert len(results) == ("one_pass" in routes)
     assert "S(1)" not in "".join(results)
     assert _kernels(text) == kernels
+
+
+def test_latent_attention_takes_the_one_pass_backward(spec):
+    """Kimi Linear's MLA layer: 32 heads, 8192 keys, q and k of 192 (256
+    lanes in VMEM), v of 128.  The one kernel holds 43.0 MB by the count,
+    over ``FLASH_BWD_VMEM_BYTES``, and asks for 42 MiB (Mosaic asks 39.9):
+    TWO kernels, the forward and the one-pass backward, whose results are
+    dQ at 192, dK at 192 and dV at 128 features."""
+    heads, seq, d, dv = 32, 8192, 192, 128
+    before = dict(compile_cache.stats().snapshot())
+    text = _attention_grads(spec, 1, seq, heads, heads, d, dv=dv)
+    assert _bwd_seen(before) == {"one_pass": 1, "raised_vmem": 1}
+    assert _kernels(text) == 2
+    assert len(re.findall(
+        rf"\(f32\[{heads},{seq},{d}\]\S*, f32\[{heads},{seq},{d}\]\S*, "
+        rf"f32\[{heads},{seq},{dv}\]\S*\) custom-call\(", text)) == 1
 
 
 # the dropless ``moe`` lowering at the two cells' shapes: 8192 tokens of 2048
@@ -678,20 +710,22 @@ def test_kimi_linear_cell_step_compiles_and_fits_the_chip(one_chip,
     delta rule and latent attention: four ``gated_delta_scan``s (the pair of
     kernels, no XLA form), four ungated filters over 12 288 channels (the
     kernels, which ask for more than the default scoped VMEM there),
-    attention at 192 / 128 head sizes through the kernels (two backward
-    kernels at 8192 keys), four ``moe`` ops (a share of 8 SiLU-gated experts
-    with a shared one); every layer two recomputed stretches;
-    ``memory_analysis()`` puts the step between a quarter and 90 % of the
-    chip's 16.9 GB (11.57 GB: the kernels keep the inputs and the chunks'
-    boundary states; the XLA form with its own stretches of 1 024 positions
-    needed 12.14, without them 17.59)."""
+    attention at 192 / 128 head sizes through the kernels (one backward
+    kernel at 8192 keys, asking for 42 MiB), four ``moe`` ops (a share
+    of 8 SiLU-gated experts with a shared one); every layer two recomputed
+    stretches; ``memory_analysis()`` puts the step between a quarter and
+    90 % of the chip's 16.9 GB (11.57 GB: the kernels keep the inputs and
+    the chunks' boundary states; the XLA form with its own stretches of
+    1 024 positions needed 12.14, without them 17.59)."""
     seen, total, _ = _cell_step(one_chip, monkeypatch, "kimi_linear_48b_a3b")
     assert seen["route/gated_delta_scan:pallas"] == 4
     assert "route/gated_delta_scan:xla" not in seen
     assert seen["route/short_conv:pallas"] == 4
     assert seen["route/flash_attention:pallas"] == 1
     assert seen["route/flash_attention:value_dims"] == 1
-    assert seen["route/flash_attention_bwd:two_pass"] == 1
+    assert seen["route/flash_attention_bwd:one_pass"] == 1
+    assert seen["route/flash_attention_bwd:raised_vmem"] == 1
+    assert seen.get("route/flash_attention_bwd:two_pass", 0) == 0
     for route in ("dropless", "sigmoid", "share", "gated_pair", "shared"):
         assert seen["route/moe:" + route] == 4, route
     assert seen["route/recompute:checkpoint"] == 10
