@@ -40,6 +40,7 @@ __all__ = [
     "kldiv_loss", "modified_huber_loss", "bilinear_tensor_product",
     "short_conv",
     "ssd_scan", "gated_delta_scan",
+    "block_diffusion_attention", "block_diffusion_noise",
 ]
 
 
@@ -1450,6 +1451,10 @@ def flash_attention(q, k, v, causal=False, block_q=1024, block_k=1024,
     and the kernels' blocks hold the whole of each (192 lands in VMEM as
     whole lane tiles of 256).
 
+    ``causal`` masks every key after the query; the same kernels take one
+    other mask, the block-diffusion mask over a noisy and a clean stream
+    (``block_diffusion_attention``, an op of its own).
+
     ``block_q``/``block_k`` default to the swept 1024x1024 tiles."""
     helper = LayerHelper("flash_attention", name=name)
     out_shape = tuple(q.shape[:-1]) + (v.shape[-1],)
@@ -1464,6 +1469,49 @@ def flash_attention(q, k, v, causal=False, block_q=1024, block_k=1024,
                             # the EXACT fused-kernel code path
                             "interpret": interpret})
     return out
+
+
+def block_diffusion_attention(q, k, v, block, block_q=1024, block_k=1024,
+                              interpret=False, name=None):
+    """Attention of block diffusion (ops/block_diffusion_ops.py): q [B, 2L,
+    H, D], k and v [B, 2L, H_kv, D] (H_kv dividing H) hold the noised
+    sequence at positions 0..L-1 and the clean one at L..2L-1.  A noisy
+    query sees the noisy keys of its own block of ``block`` positions and
+    the clean keys of the blocks before; a clean query the clean keys of
+    its own block and those before; nothing else.  Softmax at D^-1/2
+    through the flash kernels, which skip every tile the mask empties
+    (``pallas_kernels.BlockDiffusionMask``); ``block`` is a power of two
+    dividing L.  ``interpret`` runs the kernels through the Pallas
+    interpreter (CPU tests)."""
+    helper = LayerHelper("block_diffusion_attention", name=name)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, tuple(q.shape[:-1]) + (v.shape[-1],))
+    attrs = {"block_size": int(block), "block_q": block_q, "block_k": block_k}
+    if interpret:
+        attrs["interpret"] = True
+    helper.append_op(type="block_diffusion_attention",
+                     inputs={"Q": [q], "K": [k], "V": [v]},
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def block_diffusion_noise(ids, level, draw, block, mask_id, grid=1024,
+                          name=None):
+    """The forward process of block diffusion (ops/block_diffusion_ops.py):
+    ``ids`` [B, L] clean tokens; ``level`` [B, L / block] and ``draw`` [B,
+    L] integers in [0, grid).  Block b is masked at the rate t_b = (level_b
+    + 1) / grid, token i where draw_i <= level of its block.  Returns
+    (the noised ids [B, L], ``mask_id`` where masked; the loss weight [B,
+    L] float32, 1 / t_b where masked and 0 elsewhere)."""
+    helper = LayerHelper("block_diffusion_noise", name=name)
+    out = helper.create_variable_for_type_inference(ids.dtype, ids.shape)
+    weight = helper.create_variable_for_type_inference("float32", ids.shape)
+    helper.append_op(type="block_diffusion_noise",
+                     inputs={"X": [ids], "Level": [level], "Draw": [draw]},
+                     outputs={"Out": [out], "Weight": [weight]},
+                     attrs={"block_size": int(block), "mask_id": int(mask_id),
+                            "grid": int(grid)})
+    return out, weight
 
 
 def _triple(v):

@@ -21,6 +21,7 @@ from .lfm2 import lfm2, lfm2_loss
 from .granite_hybrid import granite_hybrid, granite_hybrid_loss
 from .nemotron_h import nemotron_h, nemotron_h_loss
 from .kimi_linear import kimi_linear, kimi_linear_loss
+from .sdar_moe import sdar_moe, sdar_moe_loss
 
 __all__ = [
     "mnist_mlp", "mnist_lenet", "alexnet", "vgg16", "vgg19", "vgg_cifar",
@@ -28,5 +29,5 @@ __all__ = [
     "lstm_text_classification", "seq2seq_attention", "seq2seq_infer", "wide_deep",
     "olmoe", "ouro", "ouro_loss", "lfm2", "lfm2_loss",
     "granite_hybrid", "granite_hybrid_loss", "nemotron_h", "nemotron_h_loss",
-    "kimi_linear", "kimi_linear_loss",
+    "kimi_linear", "kimi_linear_loss", "sdar_moe", "sdar_moe_loss",
 ]

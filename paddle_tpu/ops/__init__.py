@@ -25,6 +25,7 @@ from . import pallas_kernels  # noqa: F401
 from . import moe_ops         # noqa: F401
 from . import ssd_ops         # noqa: F401
 from . import delta_ops       # noqa: F401
+from . import block_diffusion_ops  # noqa: F401
 
 
 @register_op("backward")

@@ -15,7 +15,9 @@ blocks, one accumulating dk/dv over query blocks), a rule on the static
 shapes — so the [T, T] probability matrix is never materialized in either
 direction and O(T) memory holds for *training*, not just inference.
 On non-TPU backends the jnp reference runs instead (CPU tests exercise the
-kernels in interpret mode).
+kernels in interpret mode).  The kernels take a static mask: none, causal,
+or the block-diffusion mask over a noisy and a clean stream
+(``BlockDiffusionMask``), under which each tile is skipped, whole or cut.
 
 ``grouped_matmul`` is the product of rows sorted by group with each group's
 own matrix (the experts of the dropless ``moe`` lowering), forward and both
@@ -31,6 +33,7 @@ from __future__ import annotations
 
 import functools
 import operator
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +59,108 @@ def _sds(x, shape, dtype):
 
 
 # ---------------------------------------------------------------------------
+# the masks: none (False), causal (True), block diffusion
+# ---------------------------------------------------------------------------
+class BlockDiffusionMask(typing.NamedTuple):
+    """The block-diffusion mask (BD3-LM's vectorised training, arXiv:
+    2503.09573) over 2L positions: the noised sequence at 0..L-1, the clean
+    one at L..2L-1.  With p(i) = i mod L and beta(i) = p(i) // ``block``:
+
+    * noisy -> noisy: beta(k) = beta(q) (both ways inside the block);
+    * noisy -> clean: beta(k) < beta(q) (the clean blocks before);
+    * clean -> clean: beta(k) <= beta(q) (causal over blocks);
+    * clean -> noisy: never.
+
+    L^2 + L * block entries a head of the (2L)^2 square.  ``block`` is a
+    power of two (the kernels find a position's block by a shift) and
+    divides ``seq_len`` (L).  ``kind`` names the mask in the kernels'
+    route counters."""
+    block: int
+    seq_len: int
+    kind = "block_diffusion"
+
+    def check(self):
+        if self.block < 1 or self.block & (self.block - 1) \
+                or self.seq_len % self.block:
+            raise ValueError(
+                f"block-diffusion mask: a block of {self.block} positions "
+                f"is no power of two dividing {self.seq_len}")
+        return self
+
+
+def block_diffusion_allowed(mask, positions):
+    """[2L, 2L] bool: which (query, key) entries ``mask`` allows, dense (the
+    plain formula the kernels are held to)."""
+    i = jnp.arange(positions)
+    clean = i >= mask.seq_len
+    beta = (i % mask.seq_len) // mask.block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    bq, bk = beta[:, None], beta[None, :]
+    return jnp.where(
+        q_clean, k_clean & (bk <= bq),
+        jnp.where(k_clean, bk < bq, bk == bq))
+
+
+class _Tile(typing.NamedTuple):
+    """One (query block, key block) tile under a ``BlockDiffusionMask``, as
+    traced scalars.  An entry is allowed where its key block kb and query
+    block qb keep kb + ``past`` <= qb <= kb + ``reach``: ``past`` is 1 for
+    a noisy query over clean keys (the blocks before), else 0; ``reach`` 0
+    for noisy over noisy (the query's own block), else L (no lower bound).
+    ``whole``: every entry allowed; ``cut``: some, not all."""
+    q_at: jax.Array        # the tile's first query's position in its stream
+    k_at: jax.Array
+    past: jax.Array
+    reach: jax.Array
+    whole: jax.Array
+    cut: jax.Array
+
+
+def _diffusion_tile(mask, q0, block_q, k0, block_k):
+    """``_Tile`` of the tile whose first query is q0 and first key k0
+    (traced), from the static blocks: L is whole blocks of both, so a tile
+    lies in one stream each way."""
+    length, shift = mask.seq_len, mask.block.bit_length() - 1
+    q_clean, k_clean = q0 >= length, k0 >= length
+    q_at = q0 - jnp.where(q_clean, length, 0)
+    k_at = k0 - jnp.where(k_clean, length, 0)
+    q_lo, q_hi = q_at >> shift, (q_at + block_q - 1) >> shift
+    k_lo, k_hi = k_at >> shift, (k_at + block_k - 1) >> shift
+    past = jnp.where(k_clean & jnp.logical_not(q_clean), 1, 0)
+    reach = jnp.where(k_clean, length, 0)
+    seen = k_clean | jnp.logical_not(q_clean)       # a clean query: no noisy
+    any_ = seen & (k_lo + past <= q_hi) & (q_lo <= k_hi + reach)
+    whole = seen & (k_hi + past <= q_lo) & (q_hi <= k_lo + reach)
+    return _Tile(q_at, k_at, past, reach, whole,
+                 any_ & jnp.logical_not(whole))
+
+
+def _diffusion_keep(mask, tile, shape, keys_first):
+    """The allowed entries of a cut tile, bool of ``shape``: [queries, keys]
+    (or [keys, queries] with ``keys_first``)."""
+    shift = mask.block.bit_length() - 1
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    qb = (tile.q_at + lax.broadcasted_iota(jnp.int32, shape, q_axis)) >> shift
+    kb = (tile.k_at + lax.broadcasted_iota(jnp.int32, shape, k_axis)) >> shift
+    return (kb + tile.past <= qb) & (qb <= kb + tile.reach)
+
+
+def _visit(mask, q0, block_q, k0, block_k, compute, keys_first=False):
+    """``compute(keep)`` on one tile of a ``BlockDiffusionMask``: skipped
+    where the mask allows none of it (no product, no exponential), with
+    ``keep`` None where it allows all, else with ``keep(shape)`` its
+    allowed entries."""
+    tile = _diffusion_tile(mask, q0, block_q, k0, block_k)
+    pl.when(tile.whole)(lambda: compute(None))
+    pl.when(tile.cut)(lambda: compute(
+        lambda shape: _diffusion_keep(mask, tile, shape, keys_first)))
+
+
+def _masked(s, keep):
+    return s if keep is None else jnp.where(keep(s.shape), s, NEG_INF)
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
@@ -64,7 +169,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     through VMEM one [block_k, D] tile at a time (O(T) memory), with the
     online-softmax running stats (m, l) and the output accumulator living in
     VMEM scratch across the k dimension.  Also emits the per-row logsumexp
-    (the only residual the fused backward needs)."""
+    (the only residual the fused backward needs).  ``causal`` is the mask:
+    False, True or a ``BlockDiffusionMask``."""
     j = pl.program_id(1)
     kb = pl.program_id(2)
 
@@ -74,17 +180,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _compute():
+    def _compute(keep=None):
         q32 = q_ref[0].astype(jnp.float32) * sm_scale      # [bq, D]
         kblk = k_ref[0].astype(jnp.float32)                # [bk, D]
         vblk = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q32, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, bk]
-        if causal:
+        if causal is True:
             qpos = j * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
+        s = _masked(s, keep)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -97,9 +204,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
-    if causal:
+    if causal is True:
         # blocks strictly above the diagonal contribute nothing — skip them
         pl.when(kb * block_k <= (j + 1) * block_q - 1)(_compute)
+    elif causal:
+        _visit(causal, j * block_q, block_q, kb * block_k, block_k, _compute)
     else:
         _compute()
 
@@ -214,7 +323,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _compute():
+    def _compute(keep=None):
         q32 = q_ref[0].astype(jnp.float32) * sm_scale      # [bq, D]
         kblk = k_ref[0].astype(jnp.float32)                # [bk, D]
         vblk = v_ref[0].astype(jnp.float32)                # [bk, Dv]
@@ -224,10 +333,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q32, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, bk]
-        if causal:
+        if causal is True:
             qpos = j * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
+        s = _masked(s, keep)
         p = jnp.exp(s - lse)                               # normalized probs
         dp = jax.lax.dot_general(
             do, vblk, (((1,), (1,)), ((), ())),
@@ -237,8 +347,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, kblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, D]
 
-    if causal:
+    if causal is True:
         pl.when(kb * block_k <= (j + 1) * block_q - 1)(_compute)
+    elif causal:
+        _visit(causal, j * block_q, block_q, kb * block_k, block_k, _compute)
     else:
         _compute()
 
@@ -263,7 +375,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _compute():
+    def _compute(keep=None):
         q32 = q_ref[0].astype(jnp.float32) * sm_scale      # [bq, D]
         kblk = k_ref[0].astype(jnp.float32)                # [bk, D]
         vblk = v_ref[0].astype(jnp.float32)                # [bk, Dv]
@@ -273,10 +385,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q32, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, bk]
-        if causal:
+        if causal is True:
             qpos = j * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
+        s = _masked(s, keep)
         p = jnp.exp(s - lse)                               # [bq, bk]
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -289,9 +402,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q32, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, D]
 
-    if causal:
+    if causal is True:
         # query blocks entirely above the diagonal see this key block masked
         pl.when((j + 1) * block_q - 1 >= kb * block_k)(_compute)
+    elif causal:
+        _visit(causal, j * block_q, block_q, kb * block_k, block_k, _compute)
     else:
         _compute()
 
@@ -331,7 +446,7 @@ def _flash_bwd_one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init_q():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _compute():
+    def _compute(keep=None):
         q32 = q_ref[0].astype(jnp.float32) * sm_scale      # [bq, D]
         kblk = k_ref[0].astype(jnp.float32)                # [bk, D]
         vblk = v_ref[0].astype(jnp.float32)                # [bk, Dv]
@@ -341,10 +456,11 @@ def _flash_bwd_one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         st = jax.lax.dot_general(
             kblk, q32, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, bq]
-        if causal:
+        if causal is True:
             kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, st.shape, 0)
             qpos = j * block_q + lax.broadcasted_iota(jnp.int32, st.shape, 1)
             st = jnp.where(kpos <= qpos, st, NEG_INF)
+        st = _masked(st, keep)
         pt = jnp.exp(st - lse)                             # normalized probs
         rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
         dv_ref[0, rows, :] += jax.lax.dot_general(
@@ -362,8 +478,11 @@ def _flash_bwd_one_pass_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
             dst, kblk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, D]
 
-    if causal:
+    if causal is True:
         pl.when(kb * block_k <= (j + 1) * block_q - 1)(_compute)
+    elif causal:
+        _visit(causal, j * block_q, block_q, kb * block_k, block_k, _compute,
+               keys_first=True)
     else:
         _compute()
 
@@ -561,17 +680,25 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     stats.bump("route/flash_attention_bwd:" + route)
     if vmem_bytes > FLASH_BWD_VMEM_BYTES:
         stats.bump("route/flash_attention_bwd:raised_vmem")
+    if isinstance(causal, BlockDiffusionMask):
+        stats.bump("route/flash_attention_bwd:" + causal.kind)
     bwd = _flash_bwd_one_pass if route == "one_pass" else _flash_bwd_two_pass
     return bwd(q, k, v, g, lse, delta, causal, sm_scale, block_q, block_k,
                interpret, vmem_bytes)
 
 
 def _reference_attention(q, k, v, causal, sm_scale):
+    """The plain formula, [BH, T, D]: the dense scores under the mask
+    ``causal`` (False, True, or a ``BlockDiffusionMask``), softmax, the
+    product with V; K and V repeated to Q's heads where they are fewer."""
     group = q.shape[0] // k.shape[0]
     if group > 1:                 # grouped-query heads: K, V head i // group
         k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     s = jnp.einsum("bqd,bkd->bqk", q * sm_scale, k)
-    if causal:
+    if isinstance(causal, BlockDiffusionMask):
+        s = jnp.where(block_diffusion_allowed(causal, s.shape[-1])[None], s,
+                      NEG_INF)
+    elif causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
         s = jnp.where(mask[None], s, NEG_INF)
@@ -644,7 +771,7 @@ def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None,
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
-                    block_k=128, use_pallas=None, interpret=None):
+                    block_k=128, use_pallas=None, interpret=None, mask=None):
     """Fused attention.  q,k,v: [B, T, H, D] (or [BH, T, D]).  K and V may
     have fewer heads than Q, a whole divisor (grouped-query heads): query
     head h reads K / V head h // (H / H_kv), through the kernels' index
@@ -669,9 +796,24 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     Which of the three ran is counted at trace time as
     ``route/flash_attention:{pallas,interpret,reference}`` in
     ``profiler.compile_stats()``.
+
+    ``mask``, a ``BlockDiffusionMask`` (for ``causal``, which must then be
+    False): T = 2L positions, the noisy stream and the clean one, under the
+    block-diffusion mask, through the same kernels: a tile the mask empties
+    is skipped (no product, no exponential), a tile it allows whole takes no
+    element mask, a tile it cuts takes one.  The blocks are at most L, which
+    must be whole blocks of both; else the reference runs.  Such a call
+    counts its routes as any call does, and ``route/flash_attention:
+    block_diffusion`` beside them (``route/flash_attention_bwd:
+    block_diffusion`` beside the backward's, by the same rule at 2L keys).
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if mask is not None:
+        if causal:
+            raise ValueError("flash_attention: a block-diffusion mask is "
+                             "not causal")
+        causal = mask.check()
     squeeze_heads = q.ndim == 4
     if squeeze_heads:
         B, Tq_out, H, _ = q.shape
@@ -699,19 +841,30 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
         use_pallas = jax.default_backend() == "tpu"
     interpret = bool(interpret)
     Tq, Tk = q3.shape[1], k3.shape[1]
-    bq = min(block_q, Tq)
-    bk = min(block_k, Tk)
-    # ragged tail (kernel needs block-divisible lengths) or causal
-    # cross-attention (kernel's diagonal offset assumes Tq==Tk) run the
-    # exact jnp reference, as does every non-TPU backend
-    if (use_pallas or interpret) and not (
-            Tq % bq or Tk % bk or (causal and Tq != Tk)):
-        compile_cache.stats().bump(
-            "route/flash_attention:" + ("interpret" if interpret
-                                        else "pallas"))
+    if mask is not None:
+        length = mask.seq_len
+        if Tq != 2 * length or Tk != 2 * length:
+            raise ValueError(f"flash_attention: a block-diffusion mask over "
+                             f"2 x {length} positions, not Tq={Tq}, Tk={Tk}")
+        bq, bk = min(block_q, length), min(block_k, length)
+        kernel = (use_pallas or interpret) and not (length % bq
+                                                    or length % bk)
+        compile_cache.stats().bump("route/flash_attention:" + mask.kind)
+    else:
+        bq = min(block_q, Tq)
+        bk = min(block_k, Tk)
+        # ragged tail (kernel needs block-divisible lengths) or causal
+        # cross-attention (kernel's diagonal offset assumes Tq==Tk) run the
+        # exact jnp reference, as does every non-TPU backend
+        kernel = (use_pallas or interpret) and not (
+            Tq % bq or Tk % bk or (causal and Tq != Tk))
+    compile_cache.stats().bump(
+        "route/flash_attention:" + (
+            "reference" if not kernel else "interpret" if interpret
+            else "pallas"))
+    if kernel:
         out = _flash(q3, k3, v3, causal, sm_scale, bq, bk, interpret)
     else:
-        compile_cache.stats().bump("route/flash_attention:reference")
         out = _reference_attention(q3, k3, v3, causal, sm_scale)
     if squeeze_heads:
         out = jnp.moveaxis(
@@ -1534,14 +1687,31 @@ _flash_attention_shard.backward = _flash_attention_shard_backward
 # would do for the rows, but a loop of it over the tiles in use leaves a
 # while loop's result, which the TPU compiler holds twice where a kernel
 # reads it (1.44 GB in LFM2's step; PERF.md section 6, PR 35).
+# The tokens (and the weights) sit in SMEM, whole, from before the grid
+# starts, where they fit ``ROWS_SMEM_BYTES``; beyond it (two streams of
+# 8192 tokens, 8 experts a token: 133 120 rows, 1.06 MB of tokens and
+# weights) each tile's come as SMEM blocks of ``tm``, fetched a grid step
+# ahead like any block.  The whole form is kept where it fits because the
+# blocked one is no faster there, and slower at the shares' few tiles:
+# alone on a v5e, ms a call (whole / blocked; plain, then weighted),
+# OLMoE's 73 728 rows 3.071 / 2.993 and 4.147 / 4.138, LFM2's 33 792 at 72
+# tiles in use 0.506 / 0.504 and 0.666 / 0.673, Nemotron's 50 176 at 32
+# 0.345 / 0.356 and 0.488 / 0.489, Kimi Linear's 66 560 at 24 0.296 /
+# 0.309 and 0.388 / 0.416 (PERF.md section 6).
 ROW_LANES = 128
+ROWS_SMEM_BYTES = 768 << 10       # of SMEM's 1 MiB, for whole arrays
 
 
-def _rows_kernel(token_ref, count_ref, *refs, tm, n, weighted):
-    """``refs``: the source in HBM, the output tile, the scratch the rows
-    land in, the DMA semaphore; ``weighted``: the rows' weights (in SMEM)
-    first, the partner tile [tm, D] after the source, and a second output
-    [1, 1, tm] for the dots."""
+def _rows_kernel(*refs, tm, n, weighted, blocked=False):
+    """``refs``: the row tokens and the count of tiles in use (``blocked``:
+    the count, then this tile's tokens); the source in HBM, the output
+    tile, the scratch the rows land in, the DMA semaphore; ``weighted``:
+    the rows' weights (in SMEM) first, the partner tile [tm, D] after the
+    source, and a second output [1, 1, tm] for the dots."""
+    if blocked:
+        count_ref, token_ref, *refs = refs
+    else:
+        token_ref, count_ref, *refs = refs
     if weighted:
         weight_ref, src_ref, partner_ref, out_ref, dots_ref, landed, sem = refs
     else:
@@ -1550,10 +1720,17 @@ def _rows_kernel(token_ref, count_ref, *refs, tm, n, weighted):
 
     @pl.when(i < count_ref[0])
     def _tile():
-        base = i * tm
+        if blocked:                      # this tile's block, [1, 1, tm]
+            def entry(ref, r):
+                return ref[0, 0, r]
+        else:
+            base = i * tm
+
+            def entry(ref, r):
+                return ref[base + r]
 
         def start(r, carry):
-            t = token_ref[base + r]
+            t = entry(token_ref, r)
 
             @pl.when(t < n)
             def _row():
@@ -1568,7 +1745,7 @@ def _rows_kernel(token_ref, count_ref, *refs, tm, n, weighted):
         lax.fori_loop(0, tm, start, 0)
 
         def wait(r, carry):
-            @pl.when(token_ref[base + r] < n)
+            @pl.when(entry(token_ref, r) < n)
             def _row():
                 pltpu.make_async_copy(src_ref.at[0], landed.at[r],
                                       sem).wait()
@@ -1585,7 +1762,7 @@ def _rows_kernel(token_ref, count_ref, *refs, tm, n, weighted):
             dots_ref[0] = dots.reshape(1, tm).astype(dots_ref.dtype)
 
             def scale(r, carry):         # a row is whole sublanes here
-                landed[r] = landed[r] * weight_ref[base + r]
+                landed[r] = landed[r] * entry(weight_ref, r)
                 return carry
 
             lax.fori_loop(0, tm, scale, 0)
@@ -1593,18 +1770,23 @@ def _rows_kernel(token_ref, count_ref, *refs, tm, n, weighted):
             out_ref[:, c * lanes:(c + 1) * lanes] = landed[:, c, :]
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
-def _rows_call(token, num_tiles, src, weight, partner, tm, interpret):
+@functools.partial(jax.jit, static_argnames=("tm", "interpret", "blocked"))
+def _rows_call(token, num_tiles, src, weight, partner, tm, interpret,
+               blocked):
     """The ``pallas_call`` of ``rows_from_tokens`` (``weight`` and
-    ``partner`` both given or both None).  Jitted, so a program whose
-    layers call it at one shape lowers the kernel once."""
+    ``partner`` both given or both None; ``blocked``: the tokens and
+    weights as SMEM blocks a tile).  Jitted, so a program whose layers call
+    it at one shape lowers the kernel once."""
     n, d = src.shape
     rows = token.shape[0]
     weighted = weight is not None
     lanes = d if d % ROW_LANES else ROW_LANES
 
-    def tile(i, token, count, *weight):
-        return _tile_in_use(i, count), 0
+    def tile(i, *prefetched):
+        return _tile_in_use(i, prefetched[-1 if blocked else 1]), 0
+
+    def tile_row(i, *prefetched):
+        return tile(i, *prefetched)[0], 0, 0
 
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     out_shape = [_sds(src, (rows, d), src.dtype)]
@@ -1613,13 +1795,24 @@ def _rows_call(token, num_tiles, src, weight, partner, tm, interpret):
         in_specs.append(pl.BlockSpec((tm, d), tile))
         out_shape.append(_sds(src, (rows // tm, 1, tm), src.dtype))
         out_specs.append(pl.BlockSpec(
-            (1, 1, tm), lambda i, token, count, weight:
-            (_tile_in_use(i, count), 0, 0)))
+            (1, 1, tm), lambda i, *prefetched:
+            (tile(i, *prefetched)[0], 0, 0)))
+    if blocked:                 # the tile's tokens (and weights) as blocks
+        prefetch = (num_tiles,)
+        smem = [pl.BlockSpec((1, 1, tm), tile_row,
+                             memory_space=pltpu.SMEM)] * (1 + weighted)
+        in_specs = smem + in_specs
+        operands = tuple(x.reshape(rows // tm, 1, tm)
+                         for x in (token, *([weight] if weighted else [])))
+    else:
+        prefetch = (token, num_tiles, *([weight] if weighted else []))
+        operands = ()
     out = pl.pallas_call(
-        functools.partial(_rows_kernel, tm=tm, n=n, weighted=weighted),
+        functools.partial(_rows_kernel, tm=tm, n=n, weighted=weighted,
+                          blocked=blocked),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 + weighted, grid=(rows // tm,),
+            num_scalar_prefetch=len(prefetch), grid=(rows // tm,),
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((tm, d // lanes, lanes), src.dtype),
@@ -1627,8 +1820,8 @@ def _rows_call(token, num_tiles, src, weight, partner, tm, interpret):
         **({"interpret": True} if interpret else
            {"compiler_params": pltpu.CompilerParams(
                dimension_semantics=("arbitrary",))}),
-    )(token, num_tiles, *([weight] if weighted else []),
-      src.reshape(n, d // lanes, lanes), *([partner] if weighted else []))
+    )(*prefetch, *operands, src.reshape(n, d // lanes, lanes),
+      *([partner] if weighted else []))
     return (out[0], out[1].reshape(rows)) if weighted else out[0]
 
 
@@ -1639,8 +1832,12 @@ def rows_from_tokens(src, token, num_tiles, tm, weight=None, partner=None):
     ``>= N``: a padding row, zeros).  The rows of the tiles past the count
     are never written.  With ``weight`` [R] and ``partner`` [R, D], of the
     same rows: ``(weight[r] * src[token[r]], <src[token[r]], partner[r]>)``,
-    the second [R].  Not differentiable (``_dropless`` holds the
-    transposes).  The Pallas kernel everywhere: compiled on the TPU,
-    interpreted on any other backend."""
+    the second [R].  ``token`` and ``weight`` sit in SMEM whole where they
+    fit ``ROWS_SMEM_BYTES``, else a tile's at a time.  Not differentiable
+    (``_dropless`` holds the transposes).  The Pallas kernel everywhere:
+    compiled on the TPU, interpreted on any other backend."""
+    blocked = 4 * token.shape[0] * (1 + (weight is not None)) \
+        > ROWS_SMEM_BYTES
     return _rows_call(token, num_tiles, src, weight, partner, tm=tm,
-                      interpret=jax.default_backend() != "tpu")
+                      interpret=jax.default_backend() != "tpu",
+                      blocked=blocked)

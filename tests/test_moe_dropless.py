@@ -452,7 +452,9 @@ def _check_unwritten(case, gated, monkeypatch):
 
 
 @pytest.mark.parametrize("check", ["dispatch", "combine", "poison",
-                                   "unwritten-gated", "unwritten-single"])
+                                   "unwritten-gated", "unwritten-single",
+                                   "dispatch-blocked", "combine-blocked",
+                                   "unwritten-gated-blocked"])
 @pytest.mark.parametrize("case", sorted(ROWS_CASES))
 def test_rows_move_with_the_tiles_in_use(case, check, monkeypatch):
     """The two primitives of ``_dropless``'s row movement and their
@@ -461,9 +463,16 @@ def test_rows_move_with_the_tiles_in_use(case, check, monkeypatch):
     rows and passes of 3 tiles so that a last pass overlaps the one before;
     every tiled array holds NaN past ``num_tiles``: put there (``poison``),
     or left there by the interpreter because no kernel, the experts' among
-    them, stores past the count (``unwritten``, a case each form)."""
+    them, stores past the count (``unwritten``, a case each form).
+    ``blocked``: the row kernel takes each tile's tokens and weights as SMEM
+    blocks, the form of a bound whose whole arrays do not fit SMEM."""
+    from paddle_tpu.ops import pallas_kernels
+
     monkeypatch.setattr(moe_ops, "ROW_TILE", ROWS_TILE)
     monkeypatch.setattr(moe_ops, "TILE_SPAN", ROWS_SPAN)
+    if check.endswith("-blocked"):
+        monkeypatch.setattr(pallas_kernels, "ROWS_SMEM_BYTES", 0)
+        check = check[:-len("-blocked")]
     if check == "poison":
         _check_poison(case, monkeypatch)
     elif check.startswith("unwritten"):

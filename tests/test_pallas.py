@@ -351,3 +351,186 @@ def test_flash_backward_vmem_is_a_rule_on_static_shape(T, D, Dv, blocks,
 def test_flash_backward_route_is_a_rule_on_static_shape(Tq, Tk, D, Dv,
                                                        blocks, fits):
     assert pallas_kernels._one_pass_fits(Tq, Tk, D, Dv, *blocks) is fits
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion mask: the noisy stream at 0..L-1, the clean at L..2L-1
+# ---------------------------------------------------------------------------
+def _four_rules(L, b):
+    """[2L, 2L] bool, position by position from the mask's four rules."""
+    allowed = np.zeros((2 * L, 2 * L), bool)
+    for i in range(2 * L):
+        for j in range(2 * L):
+            qb, kb = (i % L) // b, (j % L) // b
+            if i < L and j < L:              # noisy -> noisy: its block
+                allowed[i, j] = kb == qb
+            elif i < L:                      # noisy -> clean: blocks before
+                allowed[i, j] = kb < qb
+            elif j >= L:                     # clean -> clean: causal by block
+                allowed[i, j] = kb <= qb
+    return allowed                           # clean -> noisy: never
+
+
+@pytest.mark.parametrize("L,b", [(16, 1), (16, 4), (32, 16), (64, 4)])
+def test_the_mask_is_its_four_rules_and_holds_L2_plus_Lb(L, b):
+    got = np.asarray(pallas_kernels.block_diffusion_allowed(
+        pallas_kernels.BlockDiffusionMask(b, L), 2 * L))
+    np.testing.assert_array_equal(got, _four_rules(L, b))
+    assert got.sum() == L * L + L * b
+
+
+def _diffusion_qkv(L, heads, kv_heads, D, seed):
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.randn(h, 2 * L, D), jnp.float32)
+            for h in (heads, kv_heads, kv_heads, heads)]
+
+
+def _flash_routes():
+    return {k: v for k, v in profiler.compile_stats().snapshot().items()
+            if k.startswith("route/flash_attention")}
+
+
+# L, block, (block_q, block_k), query heads, K/V heads, D, backward route
+MASK_CASES = {
+    # a block of one position: clean -> clean is the causal mask
+    "b1": (64, 1, (32, 32), 2, 2, 16, "two_pass"),
+    # tiles that hold many blocks, cut along the diagonal
+    "b4_tiles_hold_blocks": (128, 4, (128, 128), 4, 2, 16, "one_pass"),
+    "b4_blocks_differ": (128, 4, (128, 64), 2, 1, 32, "one_pass"),
+    # a tile inside one block: every tile skipped or whole
+    "b16_tile_inside_a_block": (64, 16, (8, 8), 2, 2, 16, "two_pass"),
+    # a block boundary through the middle of a query tile, not a key tile
+    "b16_boundary_cuts_the_tile": (64, 16, (32, 16), 4, 2, 16, "two_pass"),
+    "b16_one_pass_grouped": (128, 16, (128, 32), 8, 2, 16, "one_pass"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_block_diffusion_kernels_match_the_masked_softmax(case):
+    """Forward and both backward routes of the kernels under the
+    block-diffusion mask (interpreted) against a dense softmax under the
+    mask written as its four rules, and autodiff of it: tiles skipped,
+    whole and cut in every direction; the route is the rule's at 2L keys
+    and is counted as any call's, with the mask's kind beside it."""
+    L, b, (bq, bk), heads, kv_heads, D, route = MASK_CASES[case]
+    q, k, v, w = _diffusion_qkv(L, heads, kv_heads, D, seed=len(case))
+    mask = pallas_kernels.BlockDiffusionMask(b, L)
+    allowed = jnp.asarray(_four_rules(L, b))
+
+    def dense(q, k, v):
+        group = q.shape[0] // k.shape[0]
+        k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
+        s = jnp.einsum("bqd,bkd->bqk", q, k) * D ** -0.5
+        p = jax.nn.softmax(jnp.where(allowed[None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bqk,bkd->bqd", p, v)
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, block_q=bq, block_k=bk,
+                               interpret=True, mask=mask)
+
+    np.testing.assert_allclose(np.asarray(kernels(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(_reference_attention(q, k, v, mask, D ** -0.5)),
+        np.asarray(dense(q, k, v)), atol=2e-5, rtol=2e-5)
+    before = _flash_routes()
+    got = jax.grad(lambda *a: jnp.sum(w * kernels(*a)),
+                   argnums=(0, 1, 2))(q, k, v)
+    seen = {r: n - before.get(r, 0) for r, n in _flash_routes().items()
+            if n != before.get(r, 0)}
+    want = jax.grad(lambda *a: jnp.sum(w * dense(*a)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, c in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=1e-4, rtol=1e-4)
+    assert seen == {"route/flash_attention:interpret": 1,
+                    "route/flash_attention:block_diffusion": 1,
+                    "route/flash_attention_bwd:" + route: 1,
+                    "route/flash_attention_bwd:block_diffusion": 1,
+                    **({"route/flash_attention:grouped": 1}
+                       if heads != kv_heads else {})}
+
+
+@pytest.mark.parametrize("bq,bk", [(32, 32), (64, 16)])
+def test_a_block_of_one_is_causal_attention_over_the_clean_stream(bq, bk):
+    """With a block of one position the clean stream's queries see the
+    clean keys up to themselves and nothing else: its rows, value and
+    gradient, are the causal kernels' over the clean stream alone."""
+    L, D = 64, 16
+    q, k, v, w = _diffusion_qkv(L, 2, 2, D, seed=9)
+    mask = pallas_kernels.BlockDiffusionMask(1, L)
+
+    def clean_rows(q, k, v):
+        return flash_attention(q, k, v, block_q=bq, block_k=bk,
+                               interpret=True, mask=mask)[:, L:]
+
+    def causal(q, k, v):
+        return flash_attention(q[:, L:], k[:, L:], v[:, L:], causal=True,
+                               block_q=bq, block_k=bk, use_pallas=True,
+                               interpret=True)
+
+    np.testing.assert_allclose(np.asarray(clean_rows(q, k, v)),
+                               np.asarray(causal(q, k, v)),
+                               atol=2e-6, rtol=2e-6)
+    for a, c in zip(
+            jax.grad(lambda *x: jnp.sum(w[:, L:] * clean_rows(*x)),
+                     argnums=(0, 1, 2))(q, k, v),
+            jax.grad(lambda *x: jnp.sum(w[:, L:] * causal(*x)),
+                     argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("L,b,bq,bk", [(64, 4, 32, 32), (64, 16, 8, 16),
+                                       (64, 16, 32, 8), (128, 1, 64, 32),
+                                       (128, 32, 64, 64)])
+def test_each_tile_is_skipped_whole_or_cut_as_the_mask_holds_it(L, b, bq,
+                                                                 bk):
+    """The kernels' tile predicates against the dense mask: a tile is
+    skipped where it allows no entry, whole where it allows all of them,
+    cut (an element mask) where it allows some."""
+    mask = pallas_kernels.BlockDiffusionMask(b, L)
+    allowed = _four_rules(L, b)
+    kinds = {"skipped": 0, "whole": 0, "cut": 0}
+    for j in range(2 * L // bq):
+        for kb in range(2 * L // bk):
+            tile = pallas_kernels._diffusion_tile(
+                mask, jnp.int32(j * bq), bq, jnp.int32(kb * bk), bk)
+            part = allowed[j * bq:(j + 1) * bq, kb * bk:(kb + 1) * bk]
+            want = ("whole" if part.all() else "cut" if part.any()
+                    else "skipped")
+            got = ("whole" if bool(tile.whole) else "cut" if bool(tile.cut)
+                   else "skipped")
+            assert got == want, (j, kb)
+            kinds[got] += 1
+            if got == "cut":
+                keep = pallas_kernels._diffusion_keep(mask, tile, part.shape,
+                                                      False)
+                np.testing.assert_array_equal(np.asarray(keep), part)
+    assert kinds["skipped"] > 0 and kinds["whole"] + kinds["cut"] > 0
+
+
+def test_block_diffusion_routes_are_counted_with_the_mask_kind():
+    """The kernels (interpreted) count ``:interpret``, the reference
+    ``:reference``, each with ``:block_diffusion`` beside it."""
+    L, D = 32, 16
+    q, k, v, _ = _diffusion_qkv(L, 2, 2, D, seed=1)
+    mask = pallas_kernels.BlockDiffusionMask(4, L)
+    before = dict(profiler.compile_stats().snapshot())
+    a = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True,
+                        mask=mask)
+    c = flash_attention(q, k, v, use_pallas=False, mask=mask)
+    seen = {r: n - before.get(r, 0)
+            for r, n in profiler.compile_stats().snapshot().items()
+            if n != before.get(r, 0) and r.startswith("route/")}
+    assert seen == {"route/flash_attention:interpret": 1,
+                    "route/flash_attention:reference": 1,
+                    "route/flash_attention:block_diffusion": 2}
+    np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=2e-5,
+                               rtol=2e-5)
+    with pytest.raises(ValueError, match="no power of two"):
+        flash_attention(q, k, v, interpret=True,
+                        mask=pallas_kernels.BlockDiffusionMask(3, L))
+    with pytest.raises(ValueError, match="not causal"):
+        flash_attention(q, k, v, causal=True, interpret=True, mask=mask)

@@ -764,3 +764,58 @@ def test_gated_delta_scan_is_one_kernel_a_direction_inside_its_vmem(spec):
     assert _kernels(compiled.as_text()) == 2
     wide = b * t_len * heads * kd * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * wide
+
+
+def test_block_diffusion_attention_compiles_at_16384_keys(spec):
+    """The SDAR cell's attention, value and gradients: one 8192-token
+    sequence's two streams (16 384 positions, blocks of 4), 32 query heads
+    over 4 K / V heads of 128, at the layer's 1024 x 1024 blocks: the
+    forward kernel and, at 16 384 keys, the rule's two backward kernels."""
+    mask = pallas_kernels.BlockDiffusionMask(4, 8192)
+
+    def loss(q, k, v, mix):
+        return jnp.sum(pallas_kernels.flash_attention(
+            q, k, v, block_q=1024, block_k=1024, use_pallas=True,
+            mask=mask) * mix)
+
+    before = dict(compile_cache.stats().snapshot())
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            spec((1, 16384, 32, 128)), spec((1, 16384, 4, 128)),
+            spec((1, 16384, 4, 128)),
+            spec((1, 16384, 32, 128))).compile().as_text()
+    seen = {r: n - before.get(r, 0)
+            for r, n in compile_cache.stats().snapshot().items()
+            if r.startswith("route/") and n != before.get(r, 0)}
+    assert seen == {"route/flash_attention:pallas": 1,
+                    "route/flash_attention:block_diffusion": 1,
+                    "route/flash_attention:grouped": 1,
+                    "route/flash_attention_bwd:two_pass": 1,
+                    "route/flash_attention_bwd:block_diffusion": 1}
+    assert _kernels(text) == 3
+
+
+def test_sdar_cell_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """The K-step program of the cell whose configuration trains by block
+    diffusion: four ``block_diffusion_attention``s through the kernels (at
+    16 384 keys of 128 the backward is the two kernels), eight ropes, four
+    ``moe`` ops (16 SiLU-gated experts under their softmax router, top-8,
+    both streams routed), every layer two recomputed stretches;
+    ``memory_analysis()`` puts the step between a quarter and 90 % of the
+    chip's 16.9 GB.  The rows kernel of the experts' combine takes its
+    tokens and weights a tile at a time: whole, the 133 120 rows' are 1.06
+    MB, over SMEM's 1 MiB."""
+    seen, total, _ = _cell_step(one_chip, monkeypatch, "sdar_30b_a3b")
+    assert seen["route/block_diffusion_attention:kernel"] == 4
+    assert not seen.get("route/block_diffusion_attention:xla")
+    assert seen["route/flash_attention:pallas"] == 4
+    assert seen["route/flash_attention:block_diffusion"] == 4
+    assert seen["route/flash_attention_bwd:two_pass"] == 4
+    assert seen["route/flash_attention_bwd:block_diffusion"] == 4
+    assert not seen.get("route/flash_attention_bwd:one_pass")
+    assert seen["route/rope:pallas"] == 8
+    for route in ("dropless", "gated_pair"):
+        assert seen["route/moe:" + route] == 4, route
+    assert not seen.get("route/moe:share")
+    assert seen["route/recompute:checkpoint"] == 8
+    assert 0.25 * CHIP_BYTES < total < 0.9 * CHIP_BYTES, total
